@@ -556,7 +556,7 @@ class TelemetryArgs(BaseArgs):
     # trace output dir; None derives <save_path>/telemetry/traces
     profile_output_path: str | None = None
     # per-device peak TFLOPs for MFU; None auto-detects from device_kind (TPU v2-v6e table,
-    # utils/telemetry.py), or set DOLOMITE_PEAK_TFLOPS_PER_DEVICE
+    # utils/telemetry.py — a TPU the table does not know is an error unless this is set)
     peak_tflops_per_device: float | None = None
     # capture the jitted train step's compiled-program perf signature at run start and
     # write it as a `program_signature` record (utils/program_signature.py): cost flops,

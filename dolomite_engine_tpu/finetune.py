@@ -57,7 +57,6 @@ from .utils import (
     log_rank_0,
     preemption_requested,
     register_crash_hook,
-    setup_tf32,
     step_annotation,
     trace_annotation,
     uninstall_preemption_handler,
@@ -405,8 +404,6 @@ def evaluate(
 
 def main(mode: Mode = Mode.training, args: TrainingArgs | None = None) -> None:
     """Reference `finetune.py:214-311`."""
-    setup_tf32()
-
     if args is None:
         args = get_args(mode)
 

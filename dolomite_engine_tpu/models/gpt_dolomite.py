@@ -81,9 +81,7 @@ def resolve_named_remat_policy(policy: str):
       middle ground: one [B, S, H] tensor per block survives, the MLP backward starts
       from it instead of waiting on an attention recompute.
     - ``offload_dots``: `save_dots`' recompute point with the saved dot outputs parked
-      in pinned host memory instead of HBM (``offload_dot_with_no_batch_dims``). Needs a
-      backend with a ``pinned_host`` memory space (`utils/jax_compat`); elsewhere it
-      falls back to ``save_dots`` with a warning — same FLOPs, no host traffic.
+      in pinned host memory instead of HBM (``offload_dot_with_no_batch_dims``).
     """
     if policy == "full":
         return None
@@ -94,20 +92,6 @@ def resolve_named_remat_policy(policy: str):
             ATTENTION_OUT_CHECKPOINT_NAME
         )
     if policy == "offload_dots":
-        from ..utils.jax_compat import pinned_host_supported
-
-        if not pinned_host_supported():
-            import logging
-
-            from ..utils import log_rank_0
-
-            log_rank_0(
-                logging.WARNING,
-                "gradient_checkpointing_args.policy=offload_dots needs a pinned_host "
-                "memory space, which this backend does not expose — falling back to "
-                "save_dots (same recompute point, dots stay in HBM)",
-            )
-            return jax.checkpoint_policies.dots_saveable
         return jax.checkpoint_policies.offload_dot_with_no_batch_dims(
             "device", "pinned_host"
         )

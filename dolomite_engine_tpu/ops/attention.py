@@ -315,13 +315,12 @@ def sdpa_attention(
 
 
 def _use_splash_kernel() -> bool:
-    """Opt-in switch for the splash-attention kernel (the production MaxText kernel: GQA
-    without KV-head repetition, fused bwd option). Numerics are pinned by tests in interpret
-    mode; it stays opt-in until measured against the legacy flash kernel on hardware
-    (PROFILE.md pending list). Selection lives in the central KernelConfig
+    """Whether causal flash attention lowers through the splash kernel (the production
+    MaxText kernel: GQA without KV-head repetition, fused bwd option) or the legacy
+    flash kernel. Numerics are pinned by tests in interpret mode; splash against legacy
+    flash on hardware: not measured. Selection lives in the central KernelConfig
     (`ops/pallas/config.py` — ``kernel_args`` block / ``DOLOMITE_KERNELS``; the legacy
-    ``DOLOMITE_SPLASH_ATTENTION=1`` spelling still works as an env alias), which also
-    folds in the one cached capability probe (`utils/packages.is_pallas_available`)."""
+    ``DOLOMITE_SPLASH_ATTENTION=1`` spelling still works as an env alias)."""
     from .pallas import use_pallas
 
     return use_pallas("splash_attention")
@@ -337,7 +336,32 @@ def _tpu_splash_attention(
 ) -> jax.Array:
     """GQA-native Pallas splash attention: K/V keep their kv-head count (no `_repeat_kv`
     HBM blowup); the kernel maps q head h to kv head h // (Hq // Hkv). Causal-only (alibi
-    needs an additive bias splash's mask objects don't express)."""
+    needs an additive bias splash's mask objects don't express).
+
+    Under a mesh the kernel runs per shard (`parallel.sharding.shard_kernel`): batch over
+    the data axes, heads over tp when tp divides both head counts (whole GQA groups stay
+    together), the sequence whole."""
+    from ..parallel.sharding import kernel_sharding, logical_spec, shard_kernel
+
+    q_heads = logical_spec(q.shape, (None, None, "act_heads", None))
+    kv_heads = logical_spec(k.shape, (None, None, "act_kv_heads", None))
+    heads_divide = q_heads is not None and None not in (q_heads[1][2], kv_heads[1][2])
+    q_side = (q.shape, ("act_batch", None, "act_heads" if heads_divide else None, None))
+    kv_side = (k.shape, ("act_batch", None, "act_kv_heads" if heads_divide else None, None))
+    segments = () if segment_ids is None else (segment_ids,)
+    sharding = kernel_sharding(
+        (q_side, kv_side, kv_side, *((s.shape, ("act_batch", None)) for s in segments)),
+        (q_side,),
+    )
+
+    def local(q, k, v, *seg):
+        return (_splash_attention_local(q, k, v, seg[0] if seg else None, softmax_scale, interpret),)
+
+    (out,) = shard_kernel(local, sharding)(q, k, v, *segments)
+    return out
+
+
+def _splash_attention_local(q, k, v, segment_ids, softmax_scale: float, interpret: bool):
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as _sk,
         splash_attention_mask as _sm,
@@ -417,8 +441,7 @@ def _tpu_flash_attention(
 
 
 def _pick_block(length: int) -> int:
-    """Largest of 512/256/128 dividing `length` (both Pallas kernels assert block | seq;
-    512x512 measured ~2.4x over the legacy kernel's defaults at S=2048, D=128 on v5e).
+    """Largest of 512/256/128 dividing `length` (both Pallas kernels assert block | seq).
     Shared by the legacy flash and splash paths so block-size tuning can't silently
     diverge between the two sides of the A/B."""
     for block in (512, 256, 128):
@@ -544,14 +567,26 @@ def attention(
         segment_ids = attention_mask.astype(jnp.int32)
         attention_mask = None
 
-    use_flash = (
-        implementation == AttentionImplementation.flash_attention_2
-        and jax.default_backend() == "tpu"
-        and dropout == 0.0
-        and attention_mask is None
-        and q.shape[1] == k.shape[1]  # no decode-with-cache in the kernel path
-        and q.shape[1] % 128 == 0  # kernel tiling requires block | seq
-    )
+    use_flash = False
+    if implementation == AttentionImplementation.flash_attention_2 and jax.default_backend() == "tpu":
+        # off-TPU the request quietly means sdpa (the CPU tests rely on it); on a TPU a
+        # dropped kernel is said once per reason — a run that believes it measures the
+        # flash kernel must be able to see that it does not
+        dropped = [
+            reason
+            for reason, hit in (
+                ("attention dropout is on", dropout != 0.0),
+                ("an attention_mask that is not a plain key-side padding mask", attention_mask is not None),
+                ("query and key lengths differ (kv cache)", q.shape[1] != k.shape[1]),
+                ("sequence length is not a multiple of 128", q.shape[1] % 128 != 0),
+            )
+            if hit
+        ]
+        use_flash = not dropped
+        if dropped:
+            from ..utils import warn_rank_0
+
+            warn_rank_0("flash_attention_2 lowers as sdpa here: " + "; ".join(dropped))
     if use_flash:
         if causal and alibi_bias is None and _use_splash_kernel():
             return _tpu_splash_attention(q, k, v, segment_ids, softmax_scale)

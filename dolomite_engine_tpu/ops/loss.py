@@ -217,14 +217,16 @@ def _chunked_ce_terms_bwd(logit_scale, upcast, compute_dtype, want_z, residuals,
 
     def body(dtable_acc, xs):
         h, y = xs
-        _, chunk_vjp = jax.vjp(
+        terms, chunk_vjp = jax.vjp(
             lambda h_, t_: _chunk_ce_terms(
                 h_, t_, y, logit_scale, upcast, compute_dtype, want_z
             ),
             h,
             table,
         )
-        dh, dt = chunk_vjp(cts)
+        # the scan carry sums the chunk terms in fp32, but without `upcast` a bf16 chunk
+        # emits bf16 terms — and jax.vjp takes cotangents only in its outputs' own dtype
+        dh, dt = chunk_vjp(tuple(ct.astype(term.dtype) for ct, term in zip(cts, terms)))
         # fp32 accumulation across chunks regardless of the table's compute dtype (the
         # unchunked reference accumulates its table grad inside one fp32 matmul)
         return dtable_acc + dt.astype(jnp.float32), dh

@@ -25,7 +25,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
-from ..utils.jax_compat import shard_map
 
 
 def route(
@@ -310,7 +309,7 @@ def experts_ep_a2a(
         return jnp.zeros_like(x).at[token_index].add(contrib)
 
     t_spec = P(token_axes, None)
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(

@@ -43,8 +43,9 @@ all-XLA reference lowering. Explicit ``xla``/``pallas`` spellings — YAML or en
 override the table.
 
 Call sites gate on :func:`use_pallas`, which resolves ``auto`` and folds in the
-capability probe (`utils/packages.is_pallas_available`) so a build without Pallas
-degrades to XLA instead of crashing. Tests override per-family via the
+capability probe (`utils/packages.pallas_import_error`): a family that resolves to
+Pallas on a build where Pallas does not import RAISES — nothing degrades to XLA while
+the record still says ``pallas``. Tests override per-family via the
 :func:`kernel_overrides` context manager; telemetry reports the RESOLVED map
 (:func:`active_kernel_backends`) so every run records what actually lowered.
 """
@@ -71,22 +72,21 @@ KERNEL_FAMILIES = (
 
 # Families promoted to Pallas per detected platform when a family resolves to ``auto``.
 # Keys: "tpu" is the generic TPU row; "tpu:<gen>" rows override it for one generation;
-# anything else (cpu, gpu, unknown) promotes nothing — XLA stays the reference there.
+# anything else (cpu, gpu) promotes nothing — XLA stays the reference there.
 #
-# The generic TPU row promotes the families whose wins are architectural (traffic scales
-# with resident tokens instead of the worst case: paged/prefill attention; one HBM
-# round-trip instead of three: rmsnorm, fused rope+QKV; splash measured 0.408 vs 0.358
-# MFU, PROFILE.md) — `moe_dispatch` and `fused_ce` stay on XLA pending real-TPU
-# `bench_sweep.py --kernels` A/Bs (the chunked XLA CE already delivers the memory win;
-# the grouped-GEMM numbers so far are CPU-emulator only). v2/v3 keep only the
-# conservative pair: their cores have half the VMEM of v4+ (8 MB), which the paged
-# kernels' per-page DMA windows and the splash block sizes were not tuned for.
+# A family is in the generic TPU row only if the TPU compiler accepts it at real widths
+# (`tests/ops/test_tpu_compile.py` compiles each for a described v5e) — the argument for
+# each is architectural (one HBM round-trip instead of three: rmsnorm, fused rope+QKV;
+# GQA without KV repetition: splash); none has an on-chip A/B yet (not measured).
+# `paged_attention` and `prefill_attention` are OUT: Mosaic refuses both kernels
+# (ROADMAP.md S5 has the shapes and messages), so serving decode/prefill lower through
+# the XLA gather path on TPU until they are rewritten. `moe_dispatch` and `fused_ce`
+# stay on XLA pending on-chip A/Bs. v2/v3 keep only the conservative pair: their cores
+# have half the VMEM of v4+ (8 MB), which the splash block sizes were not tuned for.
 _PLATFORM_PROMOTIONS: dict[str, frozenset[str]] = {
     "tpu": frozenset(
         {
             "splash_attention",
-            "paged_attention",
-            "prefill_attention",
             "paged_kv_quant",
             "rmsnorm",
             "fused_rope_qkv",
@@ -137,10 +137,8 @@ def _detect_platform_key() -> str:
 
         backend = jax.default_backend()
         if backend == "tpu":
-            try:
-                backend = f"tpu:{_normalize_tpu_kind(jax.devices()[0].device_kind)}"
-            except Exception:
-                backend = "tpu:unknown"
+            # a TPU whose kind cannot be read is an error, not a generic-row default
+            backend = f"tpu:{_normalize_tpu_kind(jax.devices()[0].device_kind)}"
         _PLATFORM_KEY = backend
     return _PLATFORM_KEY
 
@@ -239,18 +237,25 @@ def resolved_kernel_backend(family: str) -> KernelBackend:
 
 
 def use_pallas(family: str) -> bool:
-    """True when `family` resolves to Pallas AND the Pallas build probe passes."""
+    """True when `family` resolves to Pallas — an explicit ``pallas`` or a TPU ``auto``
+    promotion. A build whose Pallas import fails cannot honour either, and that is an
+    error here rather than a quiet XLA run that still reports ``pallas``."""
     if resolved_kernel_backend(family) is not KernelBackend.pallas:
         return False
-    from ...utils.packages import is_pallas_available
+    from ...utils.packages import pallas_import_error
 
-    return is_pallas_available()
+    error = pallas_import_error()
+    if error is not None:
+        raise RuntimeError(
+            f"kernel family '{family}' resolves to pallas but jax.experimental.pallas "
+            "does not import in this build"
+        ) from error
+    return True
 
 
 def active_kernel_backends() -> dict[str, str]:
-    """family -> backend-name map of what would lower right now (telemetry `run_start`
-    and `serving` records; ``auto`` is resolved and "pallas" is reported only when the
-    probe passes)."""
+    """family -> backend-name map of what lowers right now (telemetry `run_start` and
+    `serving` records), with ``auto`` resolved."""
     return {
         family: (KernelBackend.pallas if use_pallas(family) else KernelBackend.xla).value
         for family in KERNEL_FAMILIES

@@ -137,32 +137,33 @@ def fused_ce_rowwise(
     row_spec = pl.BlockSpec((block_rows, hdim), lambda i, j: (i, 0))
     scalar_spec = pl.BlockSpec((block_rows, 1), lambda i, j: (i, 0))
 
-    lse, lab = pl.pallas_call(
-        functools.partial(
-            _fused_ce_kernel,
-            block_v=block_v,
-            vocab=vocab,
-            logit_scale=logit_scale,
-            compute_dtype=compute_dtype,
-        ),
-        grid=grid,
-        in_specs=[
-            row_spec,
-            pl.BlockSpec((block_v, hdim), lambda i, j: (j, 0)),
-            scalar_spec,
-        ],
-        out_specs=(scalar_spec, scalar_spec),
-        out_shape=(
-            jax.ShapeDtypeStruct((padded_rows, 1), jnp.float32),
-            jax.ShapeDtypeStruct((padded_rows, 1), jnp.float32),
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((block_rows, 1), jnp.float32),
-            pltpu.VMEM((block_rows, 1), jnp.float32),
-            pltpu.VMEM((block_rows, 1), jnp.float32),
-        ],
-        interpret=interpret,
-    )(h, t, y2d)
+    with jax.named_scope("pallas_fused_ce"):
+        lse, lab = pl.pallas_call(
+            functools.partial(
+                _fused_ce_kernel,
+                block_v=block_v,
+                vocab=vocab,
+                logit_scale=logit_scale,
+                compute_dtype=compute_dtype,
+            ),
+            grid=grid,
+            in_specs=[
+                row_spec,
+                pl.BlockSpec((block_v, hdim), lambda i, j: (j, 0)),
+                scalar_spec,
+            ],
+            out_specs=(scalar_spec, scalar_spec),
+            out_shape=(
+                jax.ShapeDtypeStruct((padded_rows, 1), jnp.float32),
+                jax.ShapeDtypeStruct((padded_rows, 1), jnp.float32),
+            ),
+            scratch_shapes=[
+                pltpu.VMEM((block_rows, 1), jnp.float32),
+                pltpu.VMEM((block_rows, 1), jnp.float32),
+                pltpu.VMEM((block_rows, 1), jnp.float32),
+            ],
+            interpret=interpret,
+        )(h, t, y2d)
     return lse[:rows, 0], lab[:rows, 0]
 
 

@@ -89,12 +89,13 @@ def _gmm_call(xp, w, block_expert, block_rows: int, interpret: bool):
         ],
         out_specs=pl.BlockSpec((block_rows, n_dim), lambda b, ge: (b, 0)),
     )
-    return pl.pallas_call(
-        _gmm_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((num_blocks * block_rows, n_dim), xp.dtype),
-        interpret=interpret,
-    )(block_expert, xp, w)
+    with jax.named_scope("pallas_moe_dispatch"):
+        return pl.pallas_call(
+            _gmm_kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((num_blocks * block_rows, n_dim), xp.dtype),
+            interpret=interpret,
+        )(block_expert, xp, w)
 
 
 def _tgmm_call(xp, dy, w_shape, block_expert, block_rows: int, interpret: bool):
@@ -113,12 +114,13 @@ def _tgmm_call(xp, dy, w_shape, block_expert, block_rows: int, interpret: bool):
         ],
         out_specs=pl.BlockSpec((1, k_dim, n_dim), lambda b, ge: (ge[b], 0, 0)),
     )
-    return pl.pallas_call(
-        _tgmm_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(w_shape, jnp.float32),
-        interpret=interpret,
-    )(block_expert, xp, dy)
+    with jax.named_scope("pallas_moe_dispatch"):
+        return pl.pallas_call(
+            _tgmm_kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct(w_shape, jnp.float32),
+            interpret=interpret,
+        )(block_expert, xp, dy)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))

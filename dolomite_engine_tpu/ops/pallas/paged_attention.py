@@ -163,8 +163,8 @@ def paged_decode_attention(
 
     in_specs = [
         pl.BlockSpec((1, width, num_q_heads, head_dim), q_index),
-        pl.BlockSpec(memory_space=pltpu.ANY),
-        pl.BlockSpec(memory_space=pltpu.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
     ]
     operands = [q, k_pages, v_pages]
     if quantized:
@@ -193,9 +193,10 @@ def paged_decode_attention(
         page_size=page_size,
         quantized=quantized,
     )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        interpret=_interpret_default(interpret),
-    )(lengths.astype(jnp.int32), page_table.astype(jnp.int32), *operands)
+    with jax.named_scope("pallas_paged_attention"):
+        return pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+            interpret=_interpret_default(interpret),
+        )(lengths.astype(jnp.int32), page_table.astype(jnp.int32), *operands)

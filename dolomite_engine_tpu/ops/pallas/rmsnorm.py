@@ -63,7 +63,22 @@ def _interpret_default(interpret: bool | None) -> bool:
     return pallas_interpret_mode()
 
 
-def _rmsnorm_fwd_call(x, weight, eps: float, residual, interpret: bool | None):
+def _rmsnorm_fwd_call(x, weight, eps: float, residual, interpret: bool | None, sharding):
+    """The forward kernel, once per shard of `sharding`'s mesh (`fused_rmsnorm` resolves
+    it; None = one device)."""
+    from ...parallel.sharding import shard_kernel
+
+    if residual is None:
+        (normed,) = shard_kernel(
+            lambda x, w: _rmsnorm_local(x, w, eps, None, interpret)[:1], sharding
+        )(x, weight)
+        return normed, None
+    return shard_kernel(
+        lambda x, r, w: _rmsnorm_local(x, w, eps, r, interpret), sharding
+    )(x, residual, weight)
+
+
+def _rmsnorm_local(x, weight, eps: float, residual, interpret: bool | None):
     from jax.experimental import pallas as pl
 
     interpret = _interpret_default(interpret)
@@ -78,31 +93,35 @@ def _rmsnorm_fwd_call(x, weight, eps: float, residual, interpret: bool | None):
     row_spec = pl.BlockSpec((block_rows, dim), lambda i: (i, 0))
     w_spec = pl.BlockSpec((1, dim), lambda i: (0, 0))
 
+    # the scope names the custom call in compiled HLO (utils/program_signature
+    # `hlo_tpu_kernels` counts kernels by it)
     if residual is None:
-        out = pl.pallas_call(
-            functools.partial(_rmsnorm_kernel, eps=eps),
-            grid=grid,
-            in_specs=[row_spec, w_spec],
-            out_specs=row_spec,
-            out_shape=jax.ShapeDtypeStruct((padded, dim), x.dtype),
-            interpret=interpret,
-        )(rows2d, w2d)
+        with jax.named_scope("pallas_rmsnorm"):
+            out = pl.pallas_call(
+                functools.partial(_rmsnorm_kernel, eps=eps),
+                grid=grid,
+                in_specs=[row_spec, w_spec],
+                out_specs=row_spec,
+                out_shape=jax.ShapeDtypeStruct((padded, dim), x.dtype),
+                interpret=interpret,
+            )(rows2d, w2d)
         return out[:rows].reshape(shape), None
 
     res2d, _ = _flatten_rows(residual)
     if padded != rows:
         res2d = jnp.pad(res2d, ((0, padded - rows), (0, 0)))
-    out, stream = pl.pallas_call(
-        functools.partial(_rmsnorm_residual_kernel, eps=eps),
-        grid=grid,
-        in_specs=[row_spec, row_spec, w_spec],
-        out_specs=(row_spec, row_spec),
-        out_shape=(
-            jax.ShapeDtypeStruct((padded, dim), x.dtype),
-            jax.ShapeDtypeStruct((padded, dim), x.dtype),
-        ),
-        interpret=interpret,
-    )(rows2d, res2d, w2d)
+    with jax.named_scope("pallas_rmsnorm"):
+        out, stream = pl.pallas_call(
+            functools.partial(_rmsnorm_residual_kernel, eps=eps),
+            grid=grid,
+            in_specs=[row_spec, row_spec, w_spec],
+            out_specs=(row_spec, row_spec),
+            out_shape=(
+                jax.ShapeDtypeStruct((padded, dim), x.dtype),
+                jax.ShapeDtypeStruct((padded, dim), x.dtype),
+            ),
+            interpret=interpret,
+        )(rows2d, res2d, w2d)
     return out[:rows].reshape(shape), stream[:rows].reshape(shape)
 
 
@@ -125,17 +144,17 @@ def _rmsnorm_grads(s, weight, eps: float, dy):
     return ds.astype(s.dtype), dw
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
-def _fused_rmsnorm(x, weight, eps: float, interpret: bool | None):
-    out, _ = _rmsnorm_fwd_call(x, weight, eps, None, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
+def _fused_rmsnorm(x, weight, eps: float, interpret: bool | None, sharding):
+    out, _ = _rmsnorm_fwd_call(x, weight, eps, None, interpret, sharding)
     return out
 
 
-def _fused_rmsnorm_fwd(x, weight, eps, interpret):
-    return _fused_rmsnorm(x, weight, eps, interpret), (x, weight)
+def _fused_rmsnorm_fwd(x, weight, eps, interpret, sharding):
+    return _fused_rmsnorm(x, weight, eps, interpret, sharding), (x, weight)
 
 
-def _fused_rmsnorm_bwd(eps, interpret, residuals, dy):
+def _fused_rmsnorm_bwd(eps, interpret, sharding, residuals, dy):
     x, weight = residuals
     dx, dw = _rmsnorm_grads(x, weight, eps, dy)
     return dx, dw
@@ -144,17 +163,17 @@ def _fused_rmsnorm_bwd(eps, interpret, residuals, dy):
 _fused_rmsnorm.defvjp(_fused_rmsnorm_fwd, _fused_rmsnorm_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _fused_rmsnorm_residual(x, residual, weight, eps: float, interpret: bool | None):
-    return _rmsnorm_fwd_call(x, weight, eps, residual, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _fused_rmsnorm_residual(x, residual, weight, eps: float, interpret: bool | None, sharding):
+    return _rmsnorm_fwd_call(x, weight, eps, residual, interpret, sharding)
 
 
-def _fused_rmsnorm_residual_fwd(x, residual, weight, eps, interpret):
-    out, stream = _rmsnorm_fwd_call(x, weight, eps, residual, interpret)
+def _fused_rmsnorm_residual_fwd(x, residual, weight, eps, interpret, sharding):
+    out, stream = _rmsnorm_fwd_call(x, weight, eps, residual, interpret, sharding)
     return (out, stream), (stream, weight)
 
 
-def _fused_rmsnorm_residual_bwd(eps, interpret, residuals, cotangents):
+def _fused_rmsnorm_residual_bwd(eps, interpret, sharding, residuals, cotangents):
     stream, weight = residuals
     dy, dstream = cotangents
     ds, dw = _rmsnorm_grads(stream, weight, eps, dy)
@@ -182,11 +201,20 @@ def fused_rmsnorm(
         f"weight {None if weight is None else weight.shape} must match hidden dim "
         f"{x.shape[-1:]}"
     )
+    from ...parallel.sharding import kernel_sharding
+
     eps = float(np.float32(eps))  # hashable static for custom_vjp nondiff  # dolint: disable=tracer-python-cast,tracer-numpy-call
+    # rows are independent: under a mesh batch and sequence shard the way the block's
+    # residual stream does and only the hidden dim stays whole. Resolved HERE, while the
+    # model's rules and mesh are live, and passed down as a static
+    rows = (x.shape, ("act_batch", "act_seq", None) if x.ndim == 3 else (None,) * x.ndim)
+    scale = (weight.shape, (None,))
     if residual is None:
-        return _fused_rmsnorm(x, weight, eps, interpret)
+        sharding = kernel_sharding((rows, scale), (rows,))
+        return _fused_rmsnorm(x, weight, eps, interpret, sharding)
     assert residual.shape == x.shape and residual.dtype == x.dtype, (
         residual.shape,
         x.shape,
     )
-    return _fused_rmsnorm_residual(x, residual, weight, eps, interpret)
+    sharding = kernel_sharding((rows, rows, scale), (rows, rows))
+    return _fused_rmsnorm_residual(x, residual, weight, eps, interpret, sharding)

@@ -85,8 +85,18 @@ class MeshManager:
 
         try:
             device_array = mesh_utils.create_device_mesh(shape, devices=devices)
-        except Exception:
-            # fall back to row-major assignment (e.g. CPU test meshes / exotic topologies)
+        except (AssertionError, NotImplementedError, ValueError) as error:
+            # shapes the TPU topology helper cannot place (and hand-picked device subsets):
+            # row-major assignment is still a valid mesh, but which axis rides which ICI
+            # link is then arbitrary — so it is said, not swallowed
+            import logging
+
+            from ..utils.logger import log_rank_0
+
+            log_rank_0(
+                logging.WARNING,
+                f"create_device_mesh{shape} failed ({error!r}); assigning devices row-major",
+            )
             device_array = np.asarray(devices).reshape(shape)
 
         MeshManager.mesh = Mesh(device_array, MESH_AXES)

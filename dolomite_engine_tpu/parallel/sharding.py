@@ -80,30 +80,22 @@ def get_logical_axis_rules(
 def _ambient_mesh():
     """The mesh the surrounding program activated, under either JAX API: the new
     `jax.sharding.set_mesh` (abstract mesh) or the classic `with mesh:` resource env."""
-    # jax < 0.5 has no get_abstract_mesh; fall through to the classic resource env there
-    get_abstract_mesh = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract_mesh is not None:
-        m = get_abstract_mesh()
-        if m is not None and not m.empty:
-            return m
-    try:  # classic context; private import keeps the deprecated public shim quiet
-        from jax._src import mesh as _mesh_lib
+    abstract = jax.sharding.get_abstract_mesh()
+    if not abstract.empty:
+        return abstract
+    # classic context; the private import keeps the deprecated public shim quiet. A moved
+    # symbol must fail loudly: swallowed, every logical constraint below would turn into a
+    # no-op and the whole program would silently replicate
+    from jax._src import mesh as _mesh_lib
 
-        pm = _mesh_lib.thread_resources.env.physical_mesh
-        return None if pm.empty else pm
-    except Exception:
-        return None
+    physical = _mesh_lib.thread_resources.env.physical_mesh
+    return None if physical.empty else physical
 
 
-def logical_constraint(x, axes):
-    """`nn.with_logical_constraint` that binds under the classic ``with mesh:`` context.
-
-    flax's version only engages when `jax.sharding.set_mesh` is active (its
-    `global_mesh_defined` check ignores the resource-env mesh) — and `set_mesh` cannot be
-    entered inside `jit`, where our model code runs. So resolve the ambient logical-axis
-    rules (set by `ModelWrapper.apply_scope`) here and emit a bare-PartitionSpec
-    `with_sharding_constraint`, which jit resolves against whichever mesh context is live.
-    No rules or no mesh -> no-op, so meshless single-chip programs are untouched.
+def logical_spec(shape: tuple[int, ...], axes) -> tuple[Mesh, PartitionSpec] | None:
+    """Resolve logical axis names for an array of `shape` against the ambient rules and
+    mesh: ``(mesh, PartitionSpec)``, or None when the trace has no rules or no mesh
+    (meshless single-chip programs).
 
     Resolution follows flax: first matching rule wins; names without a rule (or mapping to
     None) leave the dimension unconstrained-as-replicated; axes absent from the mesh are
@@ -115,13 +107,14 @@ def logical_constraint(x, axes):
     """
     rules = nn.get_logical_axis_rules()
     mesh = _ambient_mesh() if rules else None
-    if not rules or mesh is None:
-        return x
+    if mesh is None:
+        return None
     table: dict[str, tuple[str, ...] | str | None] = {}
     for name, target in rules:
         table.setdefault(name, target)
-    axis_names = set(mesh.axis_names)
-    mesh_sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
+    # inside a shard_map body the mesh's axes are manual: the block is already per-shard
+    # there, and nothing is left to constrain or to map over
+    mesh_sizes = {a: n for a, n in mesh.shape.items() if a not in mesh.manual_axes}
     entries = []
     used: set[str] = set()  # a mesh axis may shard at most one dim; first dim wins
     for dim, a in enumerate(axes):
@@ -132,15 +125,69 @@ def logical_constraint(x, axes):
         kept: list[str] = []
         size = 1
         for t in target if isinstance(target, tuple) else (target,):
-            if t not in axis_names or t in used:
+            if t not in mesh_sizes or t in used:
                 continue
-            if x.shape[dim] % (size * mesh_sizes[t]) != 0:
+            if shape[dim] % (size * mesh_sizes[t]) != 0:
                 continue
             kept.append(t)
             size *= mesh_sizes[t]
         used.update(kept)
         entries.append(tuple(kept) if len(kept) > 1 else (kept[0] if kept else None))
-    return jax.lax.with_sharding_constraint(x, PartitionSpec(*entries))
+    return mesh, PartitionSpec(*entries)
+
+
+def logical_constraint(x, axes):
+    """`nn.with_logical_constraint` that binds under the classic ``with mesh:`` context.
+
+    flax's version only engages when `jax.sharding.set_mesh` is active (its
+    `global_mesh_defined` check ignores the resource-env mesh) — and `set_mesh` cannot be
+    entered inside `jit`, where our model code runs. So resolve the ambient logical-axis
+    rules (set by `ModelWrapper.apply_scope`) here (:func:`logical_spec`) and emit a
+    bare-PartitionSpec `with_sharding_constraint`, which jit resolves against whichever
+    mesh context is live. No rules or no mesh -> no-op, so meshless single-chip programs
+    are untouched.
+    """
+    resolved = logical_spec(x.shape, axes)
+    if resolved is None:
+        return x
+    return jax.lax.with_sharding_constraint(x, resolved[1])
+
+
+def kernel_sharding(operands: tuple, results: tuple) -> tuple | None:
+    """Resolve a kernel's operands and results, each a ``(shape, logical axes)`` pair, to
+    ``(mesh, in_specs, out_specs)`` for :func:`shard_kernel`, or None when the trace has
+    no multi-device mesh. The result is hashable and must be taken where the model is
+    traced (inside `apply_scope` and the mesh context) and handed on as a static
+    argument: a `custom_vjp`'s rules are traced later — in the backward pass, or when a
+    remat replays the forward — when neither context is live any more."""
+    first = logical_spec(*operands[0])
+    if first is None:
+        return None
+    mesh = first[0]
+    if all(n == 1 or a in mesh.manual_axes for a, n in mesh.shape.items()):
+        return None  # one device, or already inside a shard_map body
+    in_specs, out_specs = (
+        tuple(logical_spec(shape, axes)[1] for shape, axes in arrays)
+        for arrays in (operands, results)
+    )
+    return mesh, in_specs, out_specs
+
+
+def shard_kernel(kernel, sharding: tuple | None):
+    """``kernel``, run once per shard of the mesh in `sharding` (:func:`kernel_sharding`).
+
+    GSPMD cannot partition a Mosaic kernel ("Mosaic kernels cannot be automatically
+    partitioned. Please wrap the call in a shard_map"), so every `pallas_call` that can
+    trace under a multi-device mesh — any sharded training or serving program — goes
+    through here. `kernel` returns a tuple, sees per-shard blocks, and may ask
+    `jax.lax.axis_index` where a shard's position matters. With `sharding` None it is
+    returned as it is."""
+    if sharding is None:
+        return kernel
+    mesh, in_specs, out_specs = sharding
+    return jax.shard_map(
+        kernel, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
+    )
 
 
 def logical_to_mesh_sharding(logical_spec_tree, mesh: Mesh, rules: LogicalRules):

@@ -60,7 +60,6 @@ from .utils import (
     log_rank_0,
     preemption_requested,
     register_crash_hook,
-    setup_tf32,
     step_annotation,
     trace_annotation,
     uninstall_preemption_handler,
@@ -542,8 +541,6 @@ def train(
 
 def main(mode: Mode = Mode.training, args: TrainingArgs | None = None) -> None:
     """Reference `pretrain.py:283-371`."""
-    setup_tf32()
-
     if args is None:
         args = get_args(mode)
 

@@ -880,12 +880,14 @@ class ServingEngine:
         so the recorded abstract args reproduce exactly the program that served."""
         if name in self._program_records:
             return
+        # under a mesh keep where the placed arrays live (weights, pool); the per-call host
+        # scalars are uncommitted and follow them, as they do in the real call
         sharded = self.mesh is not None
         self._program_records[name] = (
             fn,
             jax.tree.map(
                 lambda x: jax.ShapeDtypeStruct(
-                    x.shape, x.dtype, sharding=x.sharding if sharded else None
+                    x.shape, x.dtype, sharding=x.sharding if sharded and x.committed else None
                 ),
                 args,
             ),

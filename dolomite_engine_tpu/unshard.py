@@ -13,11 +13,10 @@ import jax
 from .arguments import UnshardingArgs, get_args
 from .checkpointing import load_checkpoint_for_inference
 from .enums import Mode
-from .utils import init_distributed, setup_tf32
+from .utils import init_distributed
 
 
 def main(args: UnshardingArgs | None = None) -> None:
-    setup_tf32()
     if args is None:
         args = get_args(Mode.unsharding)
 

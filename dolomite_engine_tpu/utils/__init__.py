@@ -40,10 +40,10 @@ from .mixed_precision import dtype_to_string, normalize_dtype_string, string_to_
 from .packages import (
     is_aim_available,
     is_colorlog_available,
-    is_pallas_available,
     is_torch_available,
     is_transformers_available,
     is_wandb_available,
+    pallas_import_error,
     pallas_interpret_mode,
 )
 from .pydantic import BaseArgs
@@ -113,38 +113,40 @@ def init_distributed(timeout_minutes: int | None = None) -> None:
     )
 
 
-def enable_compilation_cache(cache_dir: str | None = None) -> None:
-    """Persistent XLA compilation cache for every trainer/CLI entry point.
+# where the compile cache lives when JAX_COMPILATION_CACHE_DIR is unset: one fixed,
+# git-ignored directory at the root of the checkout. The directory is part of the cache
+# key, so it never depends on ~, a temp name, a pid or a time — a path that moves never hits
+DEFAULT_COMPILATION_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_compilation_cache",
+)
+
+
+def enable_compilation_cache() -> str | None:
+    """Persistent XLA compilation cache for every trainer/CLI/bench entry point; returns
+    the directory in use, or None when the cache stays off.
 
     TPU compiles of a full train step run 20-60s; the cache makes every restart after the
-    first (crash recovery, preemption resume, config-identical relaunch) skip straight to
-    execution. The reference has no equivalent (torch eager + on-the-fly Triton); this is
-    free on XLA. Opt out with `DOLOMITE_COMPILATION_CACHE=0`; the directory can be pointed
-    at shared storage with `JAX_COMPILATION_CACHE_DIR`.
+    first (crash recovery, preemption resume, config-identical relaunch, a second process
+    in the same chip call) skip straight to execution. Placement is the environment's:
+    with `JAX_COMPILATION_CACHE_DIR` set, jax itself reads the variable and this function
+    sets NO path; unset, the cache goes to :data:`DEFAULT_COMPILATION_CACHE_DIR`. Opt out
+    with `DOLOMITE_COMPILATION_CACHE=0`.
     """
     toggle = os.environ.get("DOLOMITE_COMPILATION_CACHE", "")
     if toggle == "0":
-        return
+        return None
     # default: TPU only. XLA:CPU caches AOT machine code and warns (worst case SIGILL) when
     # the loading host's CPU features differ from the compiling host's — not worth it for
     # sub-second CPU-test compiles. `DOLOMITE_COMPILATION_CACHE=1` force-enables anywhere.
     if toggle != "1" and jax.default_backend() != "tpu":
-        return
-    cache_dir = cache_dir or os.environ.get(
-        "JAX_COMPILATION_CACHE_DIR",
-        os.path.join(os.path.expanduser("~"), ".cache", "dolomite_tpu", "xla_cache"),
-    )
-    try:
-        os.makedirs(cache_dir, exist_ok=True)
+        return None
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not cache_dir:
+        cache_dir = DEFAULT_COMPILATION_CACHE_DIR
         jax.config.update("jax_compilation_cache_dir", cache_dir)
-        # default min-compile-time is 1s which already excludes trivial CPU-test programs;
-        # make it explicit so the behavior is pinned across jax upgrades
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    except (OSError, AttributeError) as e:  # unwritable HOME / future jax renames
-        log_rank_0(logging.WARNING, f"compilation cache disabled: {e}")
-
-
-def setup_tf32(use_tf32: bool = True) -> None:
-    """Parity shim for reference `utils/__init__.py:61` (`setup_tf32`). TPUs have no TF32; the
-    matching knob is the default matmul precision."""
-    jax.config.update("jax_default_matmul_precision", "tensorfloat32" if use_tf32 else "highest")
+    os.makedirs(cache_dir, exist_ok=True)
+    # default min-compile-time is 1s which already excludes trivial CPU-test programs;
+    # make it explicit so the behavior is pinned across jax upgrades
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    return cache_dir

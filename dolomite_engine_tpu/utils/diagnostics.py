@@ -704,13 +704,8 @@ def build_model_report(
         _leaf_device_bytes(l) for l in (*param_leaves, *opt_leaves, *fp8_leaves)
     )
 
-    bytes_limit = None
-    try:
-        stats = jax.local_devices()[0].memory_stats()
-        if stats:
-            bytes_limit = int(stats.get("bytes_limit")) if stats.get("bytes_limit") else None
-    except Exception:
-        pass
+    stats = jax.local_devices()[0].memory_stats()  # None on CPU backends
+    bytes_limit = int(stats["bytes_limit"]) if stats and stats.get("bytes_limit") else None
     hbm = {
         "state_bytes_per_device": state_bytes_per_device,
         "bytes_limit": bytes_limit,

@@ -42,14 +42,15 @@ def is_torch_available() -> bool:
 
 
 @cache
-def is_pallas_available() -> bool:
-    """Whether `jax.experimental.pallas` (+ the TPU dialect) imports in this build."""
+def pallas_import_error() -> ImportError | None:
+    """The error `jax.experimental.pallas` (+ the TPU dialect) raises on import in this
+    build, or None when both import."""
     try:
         import jax.experimental.pallas  # noqa: F401
         from jax.experimental.pallas import tpu  # noqa: F401
-    except Exception:
-        return False
-    return True
+    except ImportError as error:
+        return error
+    return None
 
 
 @cache
@@ -57,13 +58,9 @@ def pallas_interpret_mode() -> bool:
     """Whether Pallas kernels must run in interpret mode on this backend.
 
     True off-TPU (CPU tier-1 parity tests, local debugging), False on real TPUs where
-    Mosaic compiles the kernel. ``DOLOMITE_PALLAS_INTERPRET=1`` forces interpret mode on
-    TPU too (kernel debugging without leaving the pod). Cached: the backend cannot change
-    mid-process."""
-    import os
-
-    if os.environ.get("DOLOMITE_PALLAS_INTERPRET", "") == "1":
-        return True
+    Mosaic compiles the kernel — no environment switch can turn a TPU run into an
+    interpreted one (a kernel under debug takes its own ``interpret=`` argument).
+    Cached: the backend cannot change mid-process."""
     import jax
 
     return jax.default_backend() != "tpu"

@@ -5,8 +5,9 @@ one jitted program: ``cost_analysis`` flops / bytes accessed, ``memory_analysis`
 temp/argument/output/alias bytes (the static buffer assignment — meaningful on CPU, where
 wall-clock TPU claims are not), the donation map (how many inputs alias outputs), the
 input/output sharding specs, and an HLO feature section — a top-K op histogram, the
-largest value shape in the program, and named shape presence checks (e.g. "the chunked-CE
-grad program never materializes a ``[B,S,V]`` fp32 logits buffer").
+largest value shape in the program, named shape presence checks (e.g. "the chunked-CE
+grad program never materializes a ``[B,S,V]`` fp32 logits buffer"), and the Pallas
+kernels the compiled program really contains (``tpu_kernels``, from its custom calls).
 
 Three consumers share this one extraction path (no private ``memory_analysis()`` /
 ``cost_analysis()`` plumbing elsewhere):
@@ -136,6 +137,23 @@ def hlo_largest_buffer(text: str) -> dict[str, Any] | None:
     return {"shape": best_shape, "bytes": int(best_bytes)}
 
 
+def hlo_tpu_kernels(compiled_text: str) -> dict[str, int]:
+    """Pallas kernels in a compiled TPU program: its ``tpu_custom_call`` instructions,
+    counted by instruction name, which XLA takes from the innermost name scope around
+    the ``pallas_call`` — this package's kernels sit in
+    ``jax.named_scope("pallas_<family>")``, jax's splash kernels in a scope of their own
+    kernel name. Empty off-TPU, where kernels run interpreted and leave no custom call:
+    a family reported as ``pallas`` that is missing here did not lower as a kernel."""
+    counts: dict[str, int] = {}
+    for line in compiled_text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' not in line:
+            continue
+        match = re.match(r"\s*(?:ROOT )?%([\w\-]+?)(?:\.\d+)? = ", line)
+        name = match.group(1) if match else "unnamed"
+        counts[name] = counts.get(name, 0) + 1
+    return dict(sorted(counts.items()))
+
+
 def _count_donated_inputs(lowered_text: str) -> int:
     """Donated inputs, from the lowering's argument attributes: ``tf.aliasing_output``
     marks an input aliased onto an output, ``jax.buffer_donor`` a donation the aliaser
@@ -173,8 +191,6 @@ class ProgramSignature:
 
 
 def _normalize_cost(cost: Any) -> dict[str, float]:
-    if isinstance(cost, (list, tuple)):  # older jax: one dict per computation
-        cost = cost[0] if cost else None
     if not cost:
         return {}
     out: dict[str, float] = {}
@@ -219,6 +235,7 @@ def extract_signature(
     in_specs: list[str] = []
     out_specs: list[str] = []
     if compiled is not None:
+        hlo["tpu_kernels"] = hlo_tpu_kernels(compiled.as_text())
         try:
             cost = _normalize_cost(compiled.cost_analysis())
         except Exception:

@@ -344,47 +344,43 @@ _PEAK_TFLOPS_BY_KIND: tuple[tuple[str, float], ...] = (
 
 
 def detect_peak_tflops_per_device(device=None) -> float | None:
-    """Per-device peak bf16 TFLOPs from `device_kind`, for MFU. `DOLOMITE_PEAK_TFLOPS_PER_DEVICE`
-    overrides (unlisted accelerators, promised-vs-real quotas); None when unknown (CPU) — MFU
-    is then omitted rather than fabricated."""
-    env = os.environ.get("DOLOMITE_PEAK_TFLOPS_PER_DEVICE")
-    if env:
-        return float(env)
+    """Per-device peak bf16 TFLOPs from `device_kind`, for MFU. None off-TPU (CPU) — MFU
+    is then omitted rather than fabricated. A TPU this table does not know is an error,
+    not a default: add its row (with the source of the number) instead."""
     if device is None:
-        devices = jax.local_devices()
-        if not devices:
-            return None
-        device = devices[0]
-    kind = getattr(device, "device_kind", "").lower()
+        device = jax.local_devices()[0]
+    kind = device.device_kind.lower()
     for pattern, peak in _PEAK_TFLOPS_BY_KIND:
         if pattern in kind:
             return peak
+    if "tpu" in kind or getattr(device, "platform", None) == "tpu":
+        raise ValueError(
+            f"no peak TFLOPs known for TPU device_kind '{device.device_kind}' "
+            "(utils/telemetry._PEAK_TFLOPS_BY_KIND)"
+        )
     return None
 
 
 def collect_memory_gauges() -> dict[str, int]:
-    """Device HBM gauges from `memory_stats()` (absent on CPU backends) + host peak RSS, so
+    """Device HBM gauges from `memory_stats()` (None on CPU backends) + host peak RSS, so
     the window records show memory even where the device runtime reports none."""
     gauges: dict[str, int] = {}
     for i, device in enumerate(jax.local_devices()):
-        try:
-            stats = device.memory_stats()
-        except Exception:  # some backends raise instead of returning None
-            stats = None
+        # supported on TPU (an error there is a real one and propagates); the CPU
+        # backend returns None
+        stats = device.memory_stats()
         if not stats:
             continue
-        for key in ("bytes_in_use", "peak_bytes_in_use", "bytes_limit"):
+        # live arrays are bytes_in_use; a loaded program's temporaries are bytes_reserved
+        for key in ("bytes_in_use", "peak_bytes_in_use", "peak_bytes_reserved", "bytes_limit"):
             if key in stats:
                 gauges[f"device{i}/{key}"] = int(stats[key])
-    try:
-        import resource
+    import resource
 
-        # ru_maxrss is KiB on Linux
-        gauges["host/peak_rss_bytes"] = (
-            int(resource.getrusage(resource.RUSAGE_SELF).ru_maxrss) * 1024
-        )
-    except Exception:
-        pass
+    # ru_maxrss is KiB on Linux
+    gauges["host/peak_rss_bytes"] = (
+        int(resource.getrusage(resource.RUSAGE_SELF).ru_maxrss) * 1024
+    )
     return gauges
 
 

@@ -73,14 +73,24 @@ def test_ring_without_sp_mesh_falls_back(caplog):
     np.testing.assert_allclose(np.asarray(out_ring), np.asarray(out_sdpa), atol=1e-6)
 
 
-def test_splash_attention_matches_sdpa_interpret():
+@pytest.mark.parametrize("sharded", [False, True], ids=["one_device", "mesh_2x2x2"])
+def test_splash_attention_matches_sdpa_interpret(sharded, request):
     """Splash kernel (interpret mode) == sdpa for causal GQA with packed segment ids, fwd and
-    grad. On TPU this is the opt-in DOLOMITE_SPLASH_ATTENTION=1 path (no KV-head repeat)."""
+    grad (no KV-head repeat). Under a mesh the kernel must run per shard — batch over the
+    data axes, whole GQA groups over tp — because GSPMD cannot partition a Mosaic kernel."""
+    import contextlib
+
+    from flax import linen as nn
+
     from dolomite_engine_tpu.ops.attention import _tpu_splash_attention, sdpa_attention, make_attention_mask
+    from dolomite_engine_tpu.parallel.sharding import get_logical_axis_rules
+
+    scope = contextlib.ExitStack()
+    if sharded:
+        scope.enter_context(request.getfixturevalue("mesh_2x2x2"))
+        scope.enter_context(nn.logical_axis_rules(get_logical_axis_rules(stage=3)))
 
     rng = np.random.RandomState(0)
-    # D=128: the pinned jax's splash kernel rejects head_dim not divisible by 128 (the
-    # NotImplementedError names it); 128 is also the realistic serving head dim
     B, S, Hq, Hkv, D = 2, 256, 4, 2, 128
     q = jnp.asarray(rng.randn(B, S, Hq, D), jnp.float32)
     k = jnp.asarray(rng.randn(B, S, Hkv, D), jnp.float32)
@@ -99,11 +109,12 @@ def test_splash_attention_matches_sdpa_interpret():
         mask = make_attention_mask(B, S, S, causal=True, segment_ids_q=seg)
         return sdpa_attention(_repeat_kv(q, Hq), _repeat_kv(k, Hq), _repeat_kv(v, Hq), mask, None, scale)
 
-    out = splash(q, k, v)
     expected = ref(q, k, v)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(expected), atol=2e-5, rtol=2e-5)
-
-    g_s = jax.grad(lambda a, b, c: splash(a, b, c).sum(), argnums=(0, 1, 2))(q, k, v)
     g_r = jax.grad(lambda a, b, c: ref(a, b, c).sum(), argnums=(0, 1, 2))(q, k, v)
+    with scope:
+        assert ("shard_map" in str(jax.make_jaxpr(splash)(q, k, v))) == sharded
+        out = jax.jit(splash)(q, k, v)
+        g_s = jax.jit(jax.grad(lambda a, b, c: splash(a, b, c).sum(), argnums=(0, 1, 2)))(q, k, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(expected), atol=2e-5, rtol=2e-5)
     for s_, r_ in zip(g_s, g_r):
         np.testing.assert_allclose(np.asarray(s_), np.asarray(r_), atol=5e-5, rtol=5e-5)

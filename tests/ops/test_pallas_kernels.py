@@ -14,8 +14,9 @@ this suite pins the numerics in tier-1 exactly like the splash-attention pattern
   token-for-token parity vs `generate_tokens` with paged KV + prefix cache + chunked
   prefill + speculation all active.
 
-All model paths are unsharded (no mesh) — the sharded-model path fails at seed from the
-logical-axis rules skew and would mask the kernels under test.
+Model paths are unsharded (no mesh) except `test_kernels_run_per_shard_under_a_mesh`:
+GSPMD cannot partition a Mosaic kernel, so under a multi-device mesh every promoted
+family runs through `parallel.sharding.shard_kernel`, checked there against XLA.
 """
 
 import json
@@ -160,11 +161,14 @@ def _fake_tpu_platform(monkeypatch):
 
 
 def test_platform_defaults_promote_on_tpu(_fake_tpu_platform):
-    # proven families lower Pallas on a v5e with NO flags; the pending-A/B families
-    # stay on the XLA reference
+    # the families the TPU compiler accepts lower Pallas on a v5e with NO flags; the two
+    # it refuses (paged/prefill attention, ROADMAP S5) and the pending-A/B families stay
+    # on the XLA reference by the table — not by a fallback at the call site
     assert platform_default_backend("rmsnorm") is KernelBackend.pallas
-    assert platform_default_backend("paged_attention") is KernelBackend.pallas
+    assert platform_default_backend("splash_attention") is KernelBackend.pallas
     assert platform_default_backend("fused_rope_qkv") is KernelBackend.pallas
+    assert platform_default_backend("paged_attention") is KernelBackend.xla
+    assert platform_default_backend("prefill_attention") is KernelBackend.xla
     assert platform_default_backend("moe_dispatch") is KernelBackend.xla
     assert platform_default_backend("fused_ce") is KernelBackend.xla
     assert resolved_kernel_backend("rmsnorm") is KernelBackend.pallas
@@ -177,10 +181,10 @@ def test_platform_defaults_per_generation_row(monkeypatch):
     # v2/v3 use the conservative row: elementwise fusions only
     monkeypatch.setattr(kernel_config_module, "_PLATFORM_KEY", "tpu:v3")
     assert platform_default_backend("rmsnorm") is KernelBackend.pallas
-    assert platform_default_backend("paged_attention") is KernelBackend.xla
+    assert platform_default_backend("splash_attention") is KernelBackend.xla
     # an unknown future generation falls back to the generic tpu row
     monkeypatch.setattr(kernel_config_module, "_PLATFORM_KEY", "tpu:v9x")
-    assert platform_default_backend("paged_attention") is KernelBackend.pallas
+    assert platform_default_backend("splash_attention") is KernelBackend.pallas
 
 
 def test_promotion_precedence_auto_env_yaml(_fake_tpu_platform, monkeypatch):
@@ -192,14 +196,14 @@ def test_promotion_precedence_auto_env_yaml(_fake_tpu_platform, monkeypatch):
     monkeypatch.setenv("DOLOMITE_KERNELS", "rmsnorm=xla")
     assert resolved_kernel_backend("rmsnorm") is KernelBackend.xla
     # ...and the untouched families keep resolving through the table
-    assert resolved_kernel_backend("paged_attention") is KernelBackend.pallas
+    assert resolved_kernel_backend("splash_attention") is KernelBackend.pallas
     # YAML (installed KernelArgs) beats env
-    KernelArgs(rmsnorm="pallas", paged_attention="xla").install()
+    KernelArgs(rmsnorm="pallas", splash_attention="xla").install()
     try:
         assert resolved_kernel_backend("rmsnorm") is KernelBackend.pallas
-        assert resolved_kernel_backend("paged_attention") is KernelBackend.xla
+        assert resolved_kernel_backend("splash_attention") is KernelBackend.xla
         # a family the YAML leaves on auto still resolves through the table
-        assert resolved_kernel_backend("prefill_attention") is KernelBackend.pallas
+        assert resolved_kernel_backend("fused_rope_qkv") is KernelBackend.pallas
     finally:
         install_kernel_config(None)
 
@@ -216,6 +220,52 @@ def test_env_auto_spelling(_fake_tpu_platform, monkeypatch):
     assert config.fused_ce is KernelBackend.auto
     assert resolved_kernel_backend("rmsnorm") is KernelBackend.xla
     assert resolved_kernel_backend("fused_ce") is KernelBackend.xla  # pending-A/B family
+
+
+def test_pallas_that_cannot_be_honoured_is_an_error(_fake_tpu_platform, monkeypatch):
+    """A build whose Pallas import fails: an explicit ``pallas`` and a TPU ``auto``
+    promotion both raise — nothing trains on XLA while the record says pallas — and a
+    family that resolves to xla never consults the probe."""
+    from dolomite_engine_tpu.utils import packages
+
+    monkeypatch.setattr(
+        packages, "pallas_import_error", lambda: ImportError("no pallas in this build")
+    )
+    with kernel_overrides(fused_ce="pallas"):
+        with pytest.raises(RuntimeError, match="'fused_ce' resolves to pallas"):
+            use_pallas("fused_ce")
+    with pytest.raises(RuntimeError, match="'rmsnorm' resolves to pallas"):
+        use_pallas("rmsnorm")  # auto, promoted on the faked v5e
+    with pytest.raises(RuntimeError):
+        active_kernel_backends()
+    assert not use_pallas("moe_dispatch")  # auto -> xla on a TPU: no kernel, no probe
+
+
+def test_family_out_of_the_table_resolves_to_xla_on_a_v5e(monkeypatch):
+    """Demotion is by the table: on a device that reports what a v5e reports, the two
+    families Mosaic refuses resolve to xla through the real detection path, the others to
+    pallas; and a TPU whose kind cannot be read is an error, not the generic row."""
+    from dolomite_engine_tpu.ops.pallas import config as kernel_config_module
+
+    class _Device:
+        device_kind = "TPU v5 lite"
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "devices", lambda *a: [_Device()])
+    kernel_config_module._reset_platform_cache()
+    try:
+        assert kernel_config_module._detect_platform_key() == "tpu:v5e"
+        assert resolved_kernel_backend("paged_attention") is KernelBackend.xla
+        assert resolved_kernel_backend("prefill_attention") is KernelBackend.xla
+        assert resolved_kernel_backend("fused_rope_qkv") is KernelBackend.pallas
+        assert active_kernel_backends()["paged_attention"] == "xla"
+
+        kernel_config_module._reset_platform_cache()
+        monkeypatch.setattr(jax, "devices", lambda *a: [object()])
+        with pytest.raises(AttributeError, match="device_kind"):
+            resolved_kernel_backend("rmsnorm")
+    finally:
+        kernel_config_module._reset_platform_cache()
 
 
 # ------------------------------------------------------------------- fused rmsnorm
@@ -1097,3 +1147,73 @@ def test_fused_rope_qkv_through_model_and_jit():
     with kernel_overrides(fused_rope_qkv="pallas"):
         out = jax.jit(lambda p, i: model.apply({"params": p}, i).logits)(params, ids)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5, rtol=1e-5)
+
+
+# ------------------------------------------------------------------- kernels under a mesh
+
+
+def test_kernels_run_per_shard_under_a_mesh(mesh_2x2x2):
+    """A sharded train step with the training-path families on Pallas: the TPU refuses a
+    bare `pallas_call` under GSPMD ("Mosaic kernels cannot be automatically
+    partitioned"), so rmsnorm and rope+QKV must trace as `shard_map`s — also in the
+    backward pass and inside remat, where the model's rules context is gone — and still
+    match the XLA lowering in loss and gradients. GQA at tp=2 puts the K/V boundary
+    inside the second shard, so the per-shard rope width is exercised too."""
+    from dolomite_engine_tpu.enums import Mode
+    from dolomite_engine_tpu.model_wrapper.pretraining import ModelWrapperForPretraining
+
+    text = jnp.asarray(np.random.RandomState(0).randint(3, 96, (4, 33)), jnp.int32)
+
+    def loss_and_grad():
+        # a fresh wrapper a side: jit and remat cache traces by function and shapes, not
+        # by kernel selection
+        wrapper = ModelWrapperForPretraining(
+            mode=Mode.training,
+            pretrained_config=dict(
+                model_type="gpt_dolomite", vocab_size=96, n_positions=32, n_embd=32, n_layer=2,
+                n_head=4, num_key_value_heads=2, attention_head_type="gqa",
+                position_embedding_type="rope", activation_function="swiglu",
+                normalization_function="rmsnorm", add_bias=False, resid_pdrop=0.0,
+                embd_pdrop=0.0, attn_pdrop=0.0,
+            ),
+            dtype="fp32",
+            sequence_length=32,
+            zero_stage=3,
+            sequence_parallel=True,
+            gradient_checkpointing_args={"checkpoint_every": 1},
+        )
+        params = wrapper.init_params(jax.random.PRNGKey(0), mesh_2x2x2)
+        return jax.value_and_grad(lambda p: wrapper.loss(p, text, train=True)), params
+
+    with mesh_2x2x2:
+        fn, params = loss_and_grad()
+        loss_ref, grads_ref = jax.jit(fn)(params)
+        with kernel_overrides(rmsnorm="pallas", fused_rope_qkv="pallas"):
+            fn, params = loss_and_grad()
+            jaxpr = str(jax.make_jaxpr(fn)(params))
+            loss_ker, grads_ker = jax.jit(fn)(params)
+    # forward AND backward kernels sit inside shard_maps (2 norms + rope per block, ln_f)
+    assert jaxpr.count("shard_map") >= 2 * (2 * 3 + 1)
+    np.testing.assert_allclose(float(loss_ker), float(loss_ref), rtol=1e-6)
+    for ker, ref in zip(jax.tree.leaves(grads_ker), jax.tree.leaves(grads_ref)):
+        np.testing.assert_allclose(np.asarray(ker), np.asarray(ref), atol=2e-6, rtol=1e-5)
+
+
+def test_quantize_kernel_runs_per_shard_under_a_mesh(mesh_2x2x2):
+    """The page-quantization kernel under a tp mesh: kv heads shard like the pool, and
+    the bytes stay those of the XLA reference."""
+    from flax import linen as nn
+
+    from dolomite_engine_tpu.ops.kv_quant import quantize_pages_xla
+    from dolomite_engine_tpu.ops.pallas.kv_quant import quantize_pages_pallas
+    from dolomite_engine_tpu.parallel.sharding import get_logical_axis_rules
+
+    values = jax.random.normal(jax.random.PRNGKey(0), (6, PAGE, 4, 8), jnp.float32)
+    valid = jnp.arange(PAGE)[None, :] < jnp.asarray([[16], [3], [0], [9], [1], [16]])
+    q_ref, s_ref = quantize_pages_xla(values, valid, 127.0, jnp.int8)
+    with mesh_2x2x2, nn.logical_axis_rules(get_logical_axis_rules(stage=0)):
+        jaxpr = str(jax.make_jaxpr(lambda v, m: quantize_pages_pallas(v, m, 127.0, jnp.int8))(values, valid))
+        q_ker, s_ker = jax.jit(lambda v, m: quantize_pages_pallas(v, m, 127.0, jnp.int8))(values, valid)
+    assert "shard_map" in jaxpr
+    np.testing.assert_array_equal(np.asarray(q_ker), np.asarray(q_ref))
+    np.testing.assert_array_equal(np.asarray(s_ker), np.asarray(s_ref))

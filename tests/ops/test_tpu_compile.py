@@ -1,0 +1,184 @@
+"""The TPU's own compiler on the kernels of the main path, without a chip.
+
+The interpret-mode parity suite (`test_pallas_kernels.py`) pins what the kernels compute;
+it cannot see what Mosaic refuses — a block whose last dim is not a multiple of 128, a
+primitive without a TPU lowering, a kernel GSPMD cannot partition. libtpu is installed
+here and compiles for a chip that is described, not attached
+(`jax.experimental.topologies`), so every Pallas family the promotion table turns on for
+a TPU (`ops/pallas/config._PLATFORM_PROMOTIONS`) is compiled with ``interpret=False`` at
+the widths that run: the flagship `chip_smoke.py` drives (n_embd 2560, 32 heads of 80,
+seq 4096, 16-token pages, the engine's 512-token prefill chunk) and the `bench.py`
+configuration (n_embd 1024, 16 query / 8 kv heads of 64, seq 2048). A family that stops
+compiling fails here, at no chip time; one that is demoted leaves this file with its row
+in the table. Nothing runs, so nothing here says anything about results or speed.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or libtpu logs under /tmp
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from flax import linen as nn
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from dolomite_engine_tpu.ops.attention import _tpu_splash_attention
+from dolomite_engine_tpu.ops.pallas.config import _PLATFORM_PROMOTIONS
+from dolomite_engine_tpu.ops.pallas.kv_quant import quantize_pages_pallas
+from dolomite_engine_tpu.ops.pallas.rmsnorm import fused_rmsnorm
+from dolomite_engine_tpu.ops.pallas.rope_qkv import fused_rope_qkv
+from dolomite_engine_tpu.parallel.mesh import MESH_AXES
+from dolomite_engine_tpu.parallel.sharding import get_logical_axis_rules, logical_constraint
+
+# name -> (n_embd, query heads, kv heads, head_dim, batch, seq)
+WIDTHS = {
+    "smoke_2560_32x80_s4096": (2560, 32, 32, 80, 2, 4096),
+    "bench_1024_16-8x64_s2048": (1024, 16, 8, 64, 8, 2048),
+}
+DECODE_SLOTS, PREFILL_CHUNK, PAGE_SIZE = 8, 512, 16
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """Four described v5e devices — and the persistent compile cache off while this
+    module compiles: an executable for a described chip is written to it but cannot be
+    read back without a chip."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    # libtpu greets a machine without a TPU on the process's stderr, past pytest's capture
+    # and into the middle of its progress line: keep fd 2 shut while it starts
+    stderr_fd = os.dup(2)
+    with open(os.devnull, "w") as devnull:
+        os.dup2(devnull.fileno(), 2)
+        try:
+            devices = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2").devices
+        except Exception as error:  # no libtpu here, or one that cannot describe a v5e
+            pytest.skip(f"no TPU topology description here: {error!r}")
+        finally:
+            os.dup2(stderr_fd, 2)
+            os.close(stderr_fd)
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield devices
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    compilation_cache.reset_cache()
+
+
+def _sum_sq(*outputs) -> jax.Array:
+    return sum((out.astype(jnp.float32) ** 2).sum() for out in outputs)
+
+
+def _rmsnorm(case: str, width):
+    embd, _, _, _, batch, seq = width
+    rows = (DECODE_SLOTS, 1, embd) if case == "decode" else (batch, seq, embd)
+    x, weight = jax.ShapeDtypeStruct(rows, jnp.bfloat16), jax.ShapeDtypeStruct((embd,), jnp.float32)
+    if case == "fwd":
+        return (lambda x, w: fused_rmsnorm(x, w, 1e-5, interpret=False)), (x, weight)
+    residual = lambda x, r, w: fused_rmsnorm(x, w, 1e-5, residual=r, interpret=False)
+    if case == "grad":
+        return jax.grad(lambda x, r, w: _sum_sq(*residual(x, r, w)), argnums=(0, 1, 2)), (x, x, weight)
+    return residual, (x, x, weight)
+
+
+def _rope_qkv(case: str, width):
+    _, heads, kv_heads, head_dim, batch, seq = width
+    batch, seq = {"decode": (DECODE_SLOTS, 1), "chunk": (1, PREFILL_CHUNK)}.get(case, (batch, seq))
+    qkv = jax.ShapeDtypeStruct((batch, seq, (heads + 2 * kv_heads) * head_dim), jnp.bfloat16)
+    table = jax.ShapeDtypeStruct((batch, seq, head_dim), jnp.bfloat16)
+    rope = lambda qkv, cos, sin: fused_rope_qkv(qkv, cos, sin, heads, kv_heads, head_dim, interpret=False)
+    if case == "grad":
+        return jax.grad(lambda qkv, cos, sin: _sum_sq(rope(qkv, cos, sin))), (qkv, table, table)
+    return rope, (qkv, table, table)
+
+
+def _splash(case: str, width):
+    _, heads, kv_heads, head_dim, batch, seq = width
+    q = jax.ShapeDtypeStruct((batch, seq, heads, head_dim), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((batch, seq, kv_heads, head_dim), jnp.bfloat16)
+    segments = jax.ShapeDtypeStruct((batch, seq), jnp.int32)
+    attend = lambda q, k, v, seg: _tpu_splash_attention(q, k, v, seg, head_dim**-0.5)
+    if case == "grad":
+        return (
+            jax.grad(lambda q, k, v, seg: _sum_sq(attend(q, k, v, seg)), argnums=(0, 1, 2)),
+            (q, kv, kv, segments),
+        )
+    return attend, (q, kv, kv, segments)
+
+
+def _paged_kv_quant(case: str, width):
+    _, _, kv_heads, head_dim, _, _ = width
+    # the pages one decode step re-encodes: a write window of two pages per slot
+    pages = jax.ShapeDtypeStruct((2 * DECODE_SLOTS, PAGE_SIZE, kv_heads, head_dim), jnp.float32)
+    valid = jax.ShapeDtypeStruct((2 * DECODE_SLOTS, PAGE_SIZE), jnp.bool_)
+    return (lambda v, m: quantize_pages_pallas(v, m, 127.0, jnp.int8, interpret=False)), (pages, valid)
+
+
+# family -> (builder, cases); the families of the generic TPU row, no more and no fewer
+FAMILIES = {
+    "rmsnorm": (_rmsnorm, ("fwd", "residual", "grad", "decode")),
+    "fused_rope_qkv": (_rope_qkv, ("fwd", "grad", "decode", "chunk")),
+    "splash_attention": (_splash, ("fwd", "grad")),
+    "paged_kv_quant": (_paged_kv_quant, ("int8",)),
+}
+
+
+def test_every_promoted_family_is_compiled_here():
+    assert set(FAMILIES) == set(_PLATFORM_PROMOTIONS["tpu"])
+
+
+@pytest.mark.parametrize("width", WIDTHS)
+@pytest.mark.parametrize(
+    "family,case", [(family, case) for family, (_, cases) in FAMILIES.items() for case in cases]
+)
+def test_kernel_compiles_for_v5e(v5e, family, case, width):
+    fn, args = FAMILIES[family][0](case, WIDTHS[width])
+    one_chip = SingleDeviceSharding(v5e[0])
+    args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip) for a in args]
+    assert "tpu_custom_call" in jax.jit(fn).lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("width", WIDTHS)
+def test_sharded_block_compiles_for_v5e_2x2(v5e, width):
+    """Norm, rope+QKV and splash, forward and backward, under fsdp 2 x tp 2 with
+    sequence parallelism — the layout `chip_smoke.py --chips 4` trains on. GSPMD refuses
+    to partition a Mosaic kernel, so each has to arrive inside a `shard_map`
+    (`parallel.sharding.shard_kernel`), also where the backward is traced."""
+    embd, heads, kv_heads, head_dim, batch, seq = WIDTHS[width]
+    mesh = Mesh(np.asarray(v5e).reshape(1, 2, 1, 2, 1), MESH_AXES)
+    fused = (heads + 2 * kv_heads) * head_dim
+
+    def block(x, residual, weight, w_qkv, cos, sin, segments):
+        h, stream = fused_rmsnorm(x, weight, 1e-5, residual=residual, interpret=False)
+        qkv = logical_constraint(h @ w_qkv, ("act_batch", "act_seq_inner", "act_heads"))
+        qkv = fused_rope_qkv(qkv, cos, sin, heads, kv_heads, head_dim, interpret=False)
+        q, k, v = jnp.split(qkv, [heads * head_dim, (heads + kv_heads) * head_dim], axis=-1)
+        out = _tpu_splash_attention(
+            q.reshape(batch, seq, heads, head_dim),
+            k.reshape(batch, seq, kv_heads, head_dim),
+            v.reshape(batch, seq, kv_heads, head_dim),
+            segments,
+            head_dim**-0.5,
+        )
+        return _sum_sq(out, stream)
+
+    def spec(shape, dtype, *axes):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=NamedSharding(mesh, P(*axes)))
+
+    activations = spec((batch, seq, embd), jnp.bfloat16, "fsdp", "tp")
+    table = spec((1, seq, head_dim), jnp.bfloat16)
+    args = (
+        activations,
+        activations,
+        spec((embd,), jnp.float32),
+        spec((embd, fused), jnp.bfloat16, "fsdp", "tp"),
+        table,
+        table,
+        spec((batch, seq), jnp.int32, "fsdp"),
+    )
+    with mesh, nn.logical_axis_rules(get_logical_axis_rules(stage=3, sequence_parallel=True)):
+        text = jax.jit(jax.grad(block, argnums=(0, 3))).lower(*args).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') >= 6  # 1 + 2 + 3 kernels, fwd + bwd

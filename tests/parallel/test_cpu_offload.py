@@ -17,7 +17,6 @@ from dolomite_engine_tpu.model_wrapper.pretraining import ModelWrapperForPretrai
 from dolomite_engine_tpu.optimization import get_optimizer, get_scheduler
 from dolomite_engine_tpu.parallel.mesh import MeshManager, named_sharding
 from dolomite_engine_tpu.train_utils import make_train_step, offload_jit_kwargs, resolve_cpu_offload
-from dolomite_engine_tpu.utils.jax_compat import pinned_host_supported
 
 
 def _wrapper():
@@ -43,10 +42,6 @@ def _optimizer():
     )
 
 
-@pytest.mark.skipif(
-    not pinned_host_supported(),
-    reason="backend exposes no pinned_host memory space (jax<0.5 CPU)",
-)
 def test_offloaded_state_parks_on_pinned_host(eight_devices):
     """State creation with offload: opt-state leaves live in pinned_host, params on device,
     ZeRO sharding layout (specs) unchanged, values identical to the device-resident init."""

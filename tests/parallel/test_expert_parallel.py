@@ -25,7 +25,6 @@ from dolomite_engine_tpu.ops.moe import (
 )
 from dolomite_engine_tpu.optimization import get_optimizer, get_scheduler
 from dolomite_engine_tpu.parallel.mesh import MeshManager, named_sharding
-from dolomite_engine_tpu.utils.jax_compat import mesh_context
 from dolomite_engine_tpu.train_utils import make_train_step
 
 from ..test_commons import assert_allclose
@@ -116,7 +115,7 @@ def test_ep_a2a_matches_eager_op(eight_devices):
             capacity_factor=4.0,
         )
 
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         out = jax.jit(lambda a, b: a2a(a, b))(w_fc, w_proj)
         assert_allclose(out, ref, atol=1e-5, rtol=1e-5)
 
@@ -139,7 +138,7 @@ def test_ep_a2a_capacity_drops_tokens(eight_devices):
     mesh = Mesh(devices, ("dp", "fsdp", "sp", "tp", "ep"))
     x, weights, selected, w_fc, b_fc, w_proj, b_proj, E = _op_fixtures()
 
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         out = jax.jit(
             lambda: experts_ep_a2a(
                 x, weights, selected, w_fc, b_fc, w_proj, b_proj, jax.nn.gelu, E, mesh,

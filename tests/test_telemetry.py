@@ -185,19 +185,20 @@ def test_tracker_fanout_scalars(tmp_path):
     assert values["counter/io_retries"] == 1
 
 
-def test_detect_peak_tflops_env_override(monkeypatch):
-    monkeypatch.setenv("DOLOMITE_PEAK_TFLOPS_PER_DEVICE", "123.5")
-    assert detect_peak_tflops_per_device() == 123.5
-    monkeypatch.delenv("DOLOMITE_PEAK_TFLOPS_PER_DEVICE")
-
+def test_detect_peak_tflops_by_device_kind():
     class _FakeDevice:
         device_kind = "TPU v4"
 
     assert detect_peak_tflops_per_device(_FakeDevice()) == 275.0
-    _FakeDevice.device_kind = "TPU v5 lite"
+    _FakeDevice.device_kind = "TPU v5 lite"  # what a v5e reports
     assert detect_peak_tflops_per_device(_FakeDevice()) == 197.0
     _FakeDevice.device_kind = "cpu"
     assert detect_peak_tflops_per_device(_FakeDevice()) is None
+    assert detect_peak_tflops_per_device() is None  # this process runs on the CPU
+    # a TPU the table does not know is an error, never a default
+    _FakeDevice.device_kind = "TPU v9 mega"
+    with pytest.raises(ValueError, match="v9 mega"):
+        detect_peak_tflops_per_device(_FakeDevice())
 
 
 # --------------------------------------------------------------------------- on-demand profiler

@@ -2,8 +2,9 @@
 
 Usage: python tools/bench_sweep.py --n_embd 2048 --n_layer 16 --micro_bs 8 --ckpt 1 [--steps 10]
 
-Prints one JSON line per run with mfu/step_time/HBM. Used to tune bench.py toward the
->=0.40 MFU north star (BASELINE.md); findings recorded in PROFILE.md.
+Prints one JSON line per run with mfu/step_time/HBM (`mfu` is null off-TPU: a CPU has no
+peak in the table, and no number is made up for it). No on-chip sweep is on record: not
+measured.
 
 Kernel-tier A/B mode (docs/PERFORMANCE.md "Kernel tier"):
 
@@ -29,7 +30,17 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-_PEAK_TFLOPS = {"tpu": 197.0, "cpu": 0.5, "gpu": 100.0}
+
+def _mfu(model_tflops: float, step_seconds: float) -> float | None:
+    """MFU against the ONE peaks table (utils/telemetry): None on a CPU, an error on a TPU
+    the table does not know."""
+    from dolomite_engine_tpu.utils import detect_peak_tflops_per_device
+
+    peak = detect_peak_tflops_per_device()
+    if peak is None:
+        return None
+    return round(model_tflops / step_seconds / jax.device_count() / peak, 4)
+
 
 KERNEL_AB_FAMILIES = (
     "paged_attention",
@@ -284,7 +295,6 @@ def run_remat_ab(args) -> None:
         run_timed_windows,
     )
     from dolomite_engine_tpu.distributed import create_sharded_train_state
-    from dolomite_engine_tpu.utils.jax_compat import pinned_host_supported
     from dolomite_engine_tpu.utils.program_signature import capture_jit_signature
 
     backend = jax.default_backend()
@@ -315,15 +325,8 @@ def run_remat_ab(args) -> None:
         0, config["vocab_size"], size=(1, args.micro_bs, args.seq + 1)
     ).astype(np.int32)
 
-    policies = [p for p in REMAT_AB_POLICIES if p != "offload_dots" or pinned_host_supported()]
-    if len(policies) < len(REMAT_AB_POLICIES):
-        print(
-            json.dumps({"bench": "train_fast_path", "policy": "offload_dots",
-                        "skipped": "no pinned_host memory space on this backend"}),
-            flush=True,
-        )
     baseline = {}
-    for policy in policies:
+    for policy in REMAT_AB_POLICIES:
         wrapper = ModelWrapperForPretraining(
             mode=Mode.training,
             pretrained_config=config,
@@ -378,7 +381,7 @@ def run_remat_ab(args) -> None:
             gradient_checkpointing_method="block",
             gradient_checkpointing_args={"checkpoint_every": args.ckpt or 1, "policy": policy},
         )
-        mfu = tflops / (step_ms / 1e3) / jax.device_count() / _PEAK_TFLOPS.get(backend, 100.0)
+        mfu = _mfu(tflops, step_ms / 1e3)
         if policy == "full":
             baseline = {"step_ms": step_ms, "temp_bytes": temp_bytes}
         line = {
@@ -388,7 +391,7 @@ def run_remat_ab(args) -> None:
             "ckpt": args.ckpt or 1,
             "fused_loss": args.fused_loss,
             "step_ms": round(step_ms, 2),
-            "mfu": round(mfu, 4),
+            "mfu": mfu,
             "train_step_hbm_high_water": temp_bytes,
             "peak_bytes_in_use": peak_bytes,
             "train_step_time_ratio": (
@@ -610,7 +613,7 @@ def main() -> None:
         gradient_checkpointing_method="block" if args.ckpt else None,
         gradient_checkpointing_args=gc_args,
     )
-    mfu = model_tflops / step_time / n_devices / _PEAK_TFLOPS.get(backend, 100.0)
+    mfu = _mfu(model_tflops, step_time)
 
     mem = {}
     try:
@@ -625,7 +628,7 @@ def main() -> None:
         "model": model_type, "n_embd": args.n_embd, "n_layer": args.n_layer,
         "scan": args.scan, "micro_bs": args.micro_bs,
         "accum": args.accum, "ckpt": args.ckpt, "params_m": round(n_params / 1e6, 1),
-        "mfu": round(mfu, 4), "step_ms": round(step_time * 1e3, 1),
+        "mfu": mfu, "step_ms": round(step_time * 1e3, 1),
         "win_ms": [round(w * 1e3, 1) for w in window_times],
         "tok_s": round(tokens_per_step / step_time / n_devices, 0),
         "compile_s": round(compile_s, 1), **mem,

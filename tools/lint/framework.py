@@ -32,6 +32,7 @@ DEFAULT_ROOTS = (
     "tools",
     "scripts",
     "bench.py",
+    "chip_smoke.py",
     "__graft_entry__.py",
 )
 EXCLUDED_PREFIXES = ("tools/lint",)

@@ -64,7 +64,6 @@ def _train_step_suite(model_type: str):
     from dolomite_engine_tpu.models.gpt_dolomite import REMAT_POLICY_NAMES
     from dolomite_engine_tpu.parallel.mesh import MeshManager, named_sharding
     from dolomite_engine_tpu.train_utils import make_train_step
-    from dolomite_engine_tpu.utils.jax_compat import pinned_host_supported
     from dolomite_engine_tpu.utils.program_signature import capture_jit_signature
 
     t = _TRAIN
@@ -94,9 +93,8 @@ def _train_step_suite(model_type: str):
     MeshManager()
     mesh = MeshManager.get_mesh()
     tokens = np.zeros((1, t["micro_bs"], t["seq"] + 1), np.int32)
-    policies = [p for p in REMAT_POLICY_NAMES if p != "offload_dots" or pinned_host_supported()]
 
-    for policy in policies:
+    for policy in REMAT_POLICY_NAMES:
         wrapper = ModelWrapperForPretraining(
             mode=Mode.training,
             pretrained_config=config,

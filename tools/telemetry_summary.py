@@ -4,7 +4,7 @@
 
 Accepts one or more sink files, or directories (a run's save_path or its `telemetry/`
 subdir — every `*.jsonl` underneath is read and merged, so multi-host runs summarize in one
-call). Output is paste-ready for PROFILE.md / bench reports: step-time percentiles
+call). Output is paste-ready for PERF.md / bench reports: step-time percentiles
 (steady-state, first-step compile excluded), the goodput breakdown as a % of wall-clock,
 MFU, cumulative counter totals, plus the training-health records — run exit status, the
 `model_report` introspection (param groups/bytes/sharding/HBM), the latest per-group
