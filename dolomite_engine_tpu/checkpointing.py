@@ -49,7 +49,7 @@ import orbax.checkpoint as ocp
 from .arguments import InferenceArgs, TrainingArgs, UnshardingArgs, args_from_dict
 from .enums import Mode
 from .train_utils import TrainState
-from .utils import ExperimentsTracker, get_telemetry, load_yaml, log_rank_0, retry_io, trace_annotation
+from .utils import ExperimentsTracker, get_telemetry, load_yaml, log_rank_0, retry_io
 
 _TRAINING_CONFIG = "training_config.yml"
 _LATEST = "latest_checkpointed_iteration.json"
@@ -282,7 +282,7 @@ def save_checkpoint(
     checkpointer = _get_checkpointer()
     # labeled scope: in captured traces the checkpoint device->host copy (and the sync wait)
     # shows up under the same name as the goodput bucket
-    with trace_annotation("checkpoint_save"):
+    with get_telemetry().span("checkpoint_save"):
         retry_io(
             lambda: checkpointer.save(os.path.abspath(_state_path(base)), to_save, force=True),
             description=f"start checkpoint save global_step{iteration}",

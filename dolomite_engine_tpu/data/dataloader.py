@@ -21,7 +21,7 @@ from typing import Callable, Iterator
 import jax
 import numpy as np
 
-from ..utils.telemetry import get_telemetry, trace_annotation
+from ..utils.telemetry import get_telemetry
 
 
 class ResumableDataLoader:
@@ -209,7 +209,7 @@ class DispatchingDataLoader:
             for row, key in enumerate(self._keys):
                 code = int(header[row, 0])
                 full[key] = None if code <= 0 else np.asarray(next(it))
-            with trace_annotation("dataloader_assemble"):
+            with get_telemetry().span("dataloader_assemble"):
                 # one device_put per array: XLA slices each device's shard itself — same
                 # placement as the per-key make_array_from_callback lambdas this replaces,
                 # without one host callback per (key, device). Every process holds the
@@ -256,7 +256,7 @@ class ShardedDataLoader:
 
     def __iter__(self) -> Iterator:
         for batch in self.local_loader:
-            with trace_annotation("dataloader_assemble"):
+            with get_telemetry().span("dataloader_assemble"):
                 out = {
                     k: (
                         jax.make_array_from_process_local_data(self.sharding, np.asarray(v))

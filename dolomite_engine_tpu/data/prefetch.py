@@ -46,7 +46,7 @@ import time
 from contextlib import nullcontext
 from typing import Any, Callable, Iterator
 
-from ..utils.telemetry import get_telemetry, trace_annotation
+from ..utils.telemetry import get_telemetry
 
 # state_dict marker distinguishing prefetcher-written dataloader state from the bare
 # loader state older checkpoints hold (load_state_dict accepts both)
@@ -198,9 +198,9 @@ class StepPrefetcher:
     def _fetch_step(self, stream: Iterator) -> tuple[Any, Any]:
         """One (pre-fetch snapshot, assembled step batch); StopIteration propagates."""
         snapshot = self.loader.state_dict() if self._stateful else None
-        with trace_annotation("data_fetch"):
+        with get_telemetry().span("data_fetch"):
             micros = [next(stream) for _ in range(self.micros_per_step)]
-        with trace_annotation("prefetch_assemble"), (
+        with get_telemetry().span("prefetch_assemble"), (
             self._mesh if self._mesh is not None else nullcontext()
         ):
             batch = self._assemble(micros)
@@ -260,13 +260,13 @@ class StepPrefetcher:
         snapshot = self.loader.state_dict() if self._stateful else None
         start = time.perf_counter()
         try:
-            with trace_annotation("data_fetch"):
+            with get_telemetry().span("data_fetch"):
                 micros = [next(self._source) for _ in range(self.micros_per_step)]
         except StopIteration:
             self._finished = True
             raise
         self.last_wait_seconds = time.perf_counter() - start
-        with trace_annotation("prefetch_assemble"), (
+        with get_telemetry().span("prefetch_assemble"), (
             self._mesh if self._mesh is not None else nullcontext()
         ):
             batch = self._assemble(micros)

@@ -57,8 +57,6 @@ from .utils import (
     log_rank_0,
     preemption_requested,
     register_crash_hook,
-    step_annotation,
-    trace_annotation,
     uninstall_preemption_handler,
     uninstall_telemetry,
     unregister_crash_hook,
@@ -175,7 +173,7 @@ def train(
         jax_rng = jax.random.PRNGKey(args.random_args.seed)
 
     if eval_during_training and starting_iteration == 0:
-        with telemetry.timer("eval"), trace_annotation("eval"):
+        with telemetry.span("loop.eval", bucket="eval"):
             evaluate(
                 val_dataloader, model, state, starting_iteration, experiments_tracker, eval_step
             )
@@ -207,10 +205,12 @@ def train(
     if ft_args.preemption_checkpointing:
         install_preemption_handler()
 
-    # running mean folds EVERY step (reference `train_utils.py:130-141`): accumulate the
-    # device scalar asynchronously, sync to host only at log time
-    loss_running_sum = jnp.zeros((), jnp.float32)
+    # running mean folds EVERY step (reference `train_utils.py:130-141`): the steps' device
+    # scalars are kept as they are and read on the host only at log time — no device
+    # program of the loop's own per step
+    loss_running_sum = 0.0
     loss_running_count = 0
+    unread_losses: list = []
     progress = ProgressBar(starting_iteration, num_training_steps)
 
     global_step = starting_iteration
@@ -218,7 +218,29 @@ def train(
     consecutive_nonfinite = 0
     preempted = False
     exit_status = "ok"
+    metrics = None  # the newest dispatched step's outputs (what a profiler capture waits for)
+
+    def save(step: int) -> None:
+        with telemetry.span("loop.checkpoint", bucket="checkpoint"):
+            # the PREFETCHER's state, not the loader's: the loader runs ahead of
+            # consumption, the prefetcher's snapshot+skip accounts for batches
+            # buffered but not yet consumed (resume-exact at any depth)
+            save_checkpoint(
+                args,
+                model,
+                state,
+                prefetcher,
+                experiments_tracker,
+                step,
+                jax_rng=jax_rng,
+            )
+
     try:
+        # Every boundary of an iteration is one `telemetry.span`: the loop thread's spans
+        # tile the iteration (docs/OBSERVABILITY.md "Spans of a training iteration"), so
+        # the step record's split sums to its wall time and a profile attributes every
+        # idle gap of the device to a part of the loop.
+        telemetry.begin_iterations()
         while global_step < num_training_steps:
             global_step += 1
 
@@ -226,116 +248,108 @@ def train(
             # the data bucket charges only the time the loop truly waited on data —
             # residual queue wait in async mode, the raw micro fetch at prefetch_depth=0
             # (assembly is excluded in both modes and lands in the `other` bucket)
-            batch = next(batch_iter)
+            with telemetry.span("loop.data_wait"):
+                batch = next(batch_iter)
             data_seconds = prefetcher.last_wait_seconds
 
             step_start = time.perf_counter()
 
-            jax_rng, step_rng = jax.random.split(jax_rng)
+            with telemetry.span("loop.rng"):  # an eager device program (threefry split)
+                jax_rng, step_rng = jax.random.split(jax_rng)
             with get_profiler_context(
-                args.logging_args.torch_profiler_trace_path, global_step
-            ), step_annotation(global_step):
+                args.logging_args.torch_profiler_trace_path, global_step, lambda: metrics
+            ), telemetry.span("train_step", step=global_step):
                 state, metrics = train_step(state, batch, step_rng)
-
-            step_skipped = False
-            if ft_args.skip_nonfinite_steps:
-                # host sync per step — the price of counting consecutive skips promptly
-                step_skipped = bool(metrics["skipped"])
-
-            if not step_skipped:  # a skipped step's loss is non-finite; keep the mean clean
-                loss_running_sum = loss_running_sum + metrics["loss"]
-                loss_running_count += 1
 
             logging_step = global_step % log_interval == 0
             sync_step = logging_step or monitor.wants_step_metrics
-            if sync_step:
-                # syncing here puts the outstanding device work in the step bucket below,
-                # so window goodput stays honest without a per-step host sync
-                loss = float(metrics["loss"])
-                grad_norm = float(metrics["grad_norm"])
+            with telemetry.span("loop.sync"):
+                step_skipped = False
+                if ft_args.skip_nonfinite_steps:
+                    # host sync per step — the price of counting consecutive skips promptly
+                    step_skipped = bool(metrics["skipped"])
+
+                if not step_skipped:  # a skipped step's loss is non-finite; keep the mean clean
+                    unread_losses.append(metrics["loss"])
+
+                if sync_step:
+                    # syncing here puts the outstanding device work in the step bucket
+                    # below, so window goodput stays honest without a per-step host sync
+                    loss = float(metrics["loss"])
+                    grad_norm = float(metrics["grad_norm"])
             step_seconds = time.perf_counter() - step_start
-            telemetry.record_step(global_step, data_seconds, step_seconds)
-            # feeds the flight recorder + anomaly detectors BEFORE the nonfinite abort can
-            # fire, so a NaN-abort's flight record contains the offending step
-            monitor.observe_step(
-                global_step,
-                loss=loss if sync_step else None,
-                grad_norm=grad_norm if sync_step else None,
-                step_seconds=step_seconds,
-                data_seconds=data_seconds,
-                skipped=step_skipped,
-            )
-            if monitor.health_due(global_step) and "health" in metrics:
-                monitor.emit_health(global_step, metrics["health"])
 
-            if ft_args.skip_nonfinite_steps:
-                consecutive_nonfinite = handle_nonfinite_step(
-                    step_skipped,
-                    consecutive_nonfinite,
+            with telemetry.span("loop.account"):
+                # feeds the flight recorder + anomaly detectors BEFORE the nonfinite abort
+                # can fire, so a NaN-abort's flight record contains the offending step
+                monitor.observe_step(
                     global_step,
-                    ft_args.max_consecutive_nonfinite_steps,
+                    loss=loss if sync_step else None,
+                    grad_norm=grad_norm if sync_step else None,
+                    step_seconds=step_seconds,
+                    data_seconds=data_seconds,
+                    skipped=step_skipped,
                 )
+                if monitor.health_due(global_step) and "health" in metrics:
+                    monitor.emit_health(global_step, metrics["health"])
 
-            if logging_step:
-                track_train_metrics(
-                    global_step=global_step,
-                    train_loss_step=loss,
-                    grad_norm=grad_norm,
-                    current_lr=float(lr_schedule(global_step)),
-                    experiments_tracker=experiments_tracker,
-                    loss_running_mean=float(loss_running_sum) / max(loss_running_count, 1),
-                    step_time=data_seconds + step_seconds,
-                )
-                progress.set_postfix(loss=loss, step_s=data_seconds + step_seconds)
+                if ft_args.skip_nonfinite_steps:
+                    consecutive_nonfinite = handle_nonfinite_step(
+                        step_skipped,
+                        consecutive_nonfinite,
+                        global_step,
+                        ft_args.max_consecutive_nonfinite_steps,
+                    )
 
-            progress.track(global_step)
+            with telemetry.span("loop.log"):
+                if logging_step:
+                    loss_running_sum += float(np.sum(jax.device_get(unread_losses)))
+                    loss_running_count += len(unread_losses)
+                    unread_losses.clear()
+                    track_train_metrics(
+                        global_step=global_step,
+                        train_loss_step=loss,
+                        grad_norm=grad_norm,
+                        # the schedule is eager jax: a few small device programs a log
+                        current_lr=float(lr_schedule(global_step)),
+                        experiments_tracker=experiments_tracker,
+                        loss_running_mean=loss_running_sum / max(loss_running_count, 1),
+                        step_time=data_seconds + step_seconds,
+                    )
+                    progress.set_postfix(loss=loss, step_s=data_seconds + step_seconds)
+
+                progress.track(global_step)
 
             if eval_during_training and eval_interval and global_step % eval_interval == 0:
-                with telemetry.timer("eval"), trace_annotation("eval"):
+                with telemetry.span("loop.eval", bucket="eval"):
                     evaluate(
                         val_dataloader, model, state, global_step, experiments_tracker, eval_step
                     )
 
             if global_step % save_interval == 0 or global_step == num_training_steps:
-                with telemetry.timer("checkpoint"):
-                    # the PREFETCHER's state, not the loader's: the loader runs ahead of
-                    # consumption, the prefetcher's snapshot+skip accounts for batches
-                    # buffered but not yet consumed (resume-exact at any depth)
-                    save_checkpoint(
-                        args,
-                        model,
-                        state,
-                        prefetcher,
-                        experiments_tracker,
-                        global_step,
-                        jax_rng=jax_rng,
-                    )
+                save(global_step)
                 last_saved_step = global_step
 
-            # the window record is emitted after eval/checkpoint so their buckets land in
-            # the window of the step that paid for them
-            if logging_step:
-                telemetry.emit_window(global_step)
-            telemetry.poll_profiler(global_step)
+            with telemetry.span("loop.poll"):
+                telemetry.poll_profiler(global_step, metrics)
+                preempted = preemption_requested()
+                if preempted:
+                    log_rank_0(
+                        logging.WARNING,
+                        f"preemption notice: saving final checkpoint at step {global_step} "
+                        "and exiting",
+                    )
+            if preempted and last_saved_step != global_step:
+                save(global_step)
 
-            if preemption_requested():
-                preempted = True
-                log_rank_0(
-                    logging.WARNING,
-                    f"preemption notice: saving final checkpoint at step {global_step} "
-                    "and exiting",
-                )
-                if last_saved_step != global_step:
-                    with telemetry.timer("checkpoint"):
-                        save_checkpoint(
-                            args,
-                            model,
-                            state,
-                            prefetcher,
-                            experiments_tracker,
-                            global_step,
-                            jax_rng=jax_rng,
-                        )
+            # The iteration ends here: the step record carries its whole split, and the
+            # window record — written after eval/checkpoint so their buckets land in the
+            # window of the step that paid for them — is the first of the next one's.
+            telemetry.record_step(global_step, data_seconds, step_seconds)
+            if logging_step:
+                with telemetry.span("loop.window"):
+                    telemetry.emit_window(global_step)
+            if preempted:
                 break
 
         finish_pending_checkpoint()  # commit an in-flight async save before exiting

@@ -263,22 +263,26 @@ class GPTDolomiteModel(nn.Module):
         config = self.config
         batch, seq = input_ids.shape
 
-        hidden_states = self.wte(input_ids) if inputs_embeds is None else inputs_embeds
-
         if position_ids is None:
             offset = 0 if cache_index is None else cache_index
             position_ids = jnp.arange(seq)[None, :] + offset
 
-        if self.pe_type == PositionEmbeddingType.learned_absolute:
-            hidden_states = hidden_states + self.wpe(position_ids)
+        # phase scopes (docs/OBSERVABILITY.md "Phases of the train step"): `embed`, `blocks`
+        # and `final_norm` name every operation of this module in a profile, the layer
+        # scan's `while` included, forward and backward
+        with jax.named_scope("embed"):
+            hidden_states = self.wte(input_ids) if inputs_embeds is None else inputs_embeds
 
-        if config.m_emb is not None:
-            hidden_states = hidden_states * config.m_emb
+            if self.pe_type == PositionEmbeddingType.learned_absolute:
+                hidden_states = hidden_states + self.wpe(position_ids)
 
-        hidden_states = self.drop(hidden_states, deterministic=deterministic)
-        hidden_states = logical_constraint(
-            hidden_states, ("act_batch", "act_seq", "act_embed")
-        )
+            if config.m_emb is not None:
+                hidden_states = hidden_states * config.m_emb
+
+            hidden_states = self.drop(hidden_states, deterministic=deterministic)
+            hidden_states = logical_constraint(
+                hidden_states, ("act_batch", "act_seq", "act_embed")
+            )
 
         # cache length from the first standard KV cache (RNN hybrids mix cache kinds);
         # paged caches ("page_table" present) gather to max_pages * page_size views
@@ -291,54 +295,59 @@ class GPTDolomiteModel(nn.Module):
                     else:
                         key_length = c["k"].shape[1]
                     break
-        rope_cos_sin, alibi_bias = compute_position_stuff(
-            config,
-            position_ids,
-            self.rope_params,
-            config.n_head,
-            attention_mask,
-            batch,
-            key_length,
-            self.dtype,
-        )
+        with jax.named_scope("embed"):
+            rope_cos_sin, alibi_bias = compute_position_stuff(
+                config,
+                position_ids,
+                self.rope_params,
+                config.n_head,
+                attention_mask,
+                batch,
+                key_length,
+                self.dtype,
+            )
 
         if self.scan_layers:
             assert kv_caches is None, (
                 "scan_layers is a training-path feature; for generation convert the "
                 "checkpoint with unstack_block_params and rebuild without scan_layers"
             )
-            hidden_states, _ = self.h_scan(
-                hidden_states,
-                attention_mask,
-                segment_ids,
-                rope_cos_sin,
-                alibi_bias,
-                None,
-                None,
-                deterministic,
-            )
-            return self.ln_f(hidden_states), None, []
+            with jax.named_scope("blocks"):
+                hidden_states, _ = self.h_scan(
+                    hidden_states,
+                    attention_mask,
+                    segment_ids,
+                    rope_cos_sin,
+                    alibi_bias,
+                    None,
+                    None,
+                    deterministic,
+                )
+            with jax.named_scope("final_norm"):
+                return self.ln_f(hidden_states), None, []
 
         new_caches = [] if kv_caches is not None else None
         extras = []  # per-block extra outputs (MoE router logits etc.)
-        for i, block in enumerate(self.h):
-            out = block(
-                hidden_states,
-                attention_mask,
-                segment_ids,
-                rope_cos_sin,
-                alibi_bias,
-                None if kv_caches is None else kv_caches[i],
-                cache_index,
-                deterministic,
-            )
-            hidden_states, cache = out[0], out[1]
-            if len(out) > 2 and out[2] is not None:
-                extras.append(out[2])
-            if new_caches is not None:
-                new_caches.append(cache)
+        with jax.named_scope("blocks"):
+            for i, block in enumerate(self.h):
+                out = block(
+                    hidden_states,
+                    attention_mask,
+                    segment_ids,
+                    rope_cos_sin,
+                    alibi_bias,
+                    None if kv_caches is None else kv_caches[i],
+                    cache_index,
+                    deterministic,
+                )
+                hidden_states, cache = out[0], out[1]
+                if len(out) > 2 and out[2] is not None:
+                    extras.append(out[2])
+                if new_caches is not None:
+                    new_caches.append(cache)
 
-        hidden_states = self.ln_f(hidden_states)
+        with jax.named_scope("final_norm"):
+            hidden_states = self.ln_f(hidden_states)
         return hidden_states, new_caches, extras
 
 
@@ -504,33 +513,37 @@ class GPTDolomiteForCausalLM(nn.Module):
         logits = None
         loss = None
         aux_loss = None
+        # the `head_loss` phase scope: labels, head matmul and loss (the chunked loss's
+        # backward rule opens the same scope itself, ops/loss.py)
         if use_fused:
             # chunked LM-head matmul + CE; never materializes [B, S, V] logits (ops/loss.py)
-            if labels is None:
-                labels = derive_causal_labels(input_ids, attention_mask, segment_ids)
-            head_in, head_table = self._lm_head_operands(hidden_states)
-            loss = fused_linear_cross_entropy(
-                head_in,
-                head_table,
-                labels,
-                chunk_size=self.config.loss_chunk_size,
-                upcast=self.config.upcast_logits_for_loss,
-                logit_scale=None if self.config.m_width is None else 1.0 / self.config.m_width,
-                compute_dtype=self.dtype,
-                z_loss_coef=self.config.z_loss_coef,
-            )
-        else:
-            logits = self.compute_logits(hidden_states)
-            if want_loss:
-                loss = causal_lm_loss(
-                    logits,
-                    input_ids,
+            with jax.named_scope("head_loss"):
+                if labels is None:
+                    labels = derive_causal_labels(input_ids, attention_mask, segment_ids)
+                head_in, head_table = self._lm_head_operands(hidden_states)
+                loss = fused_linear_cross_entropy(
+                    head_in,
+                    head_table,
+                    labels,
+                    chunk_size=self.config.loss_chunk_size,
                     upcast=self.config.upcast_logits_for_loss,
-                    attention_mask=attention_mask,
-                    segment_ids=segment_ids,
-                    labels=labels,
+                    logit_scale=None if self.config.m_width is None else 1.0 / self.config.m_width,
+                    compute_dtype=self.dtype,
                     z_loss_coef=self.config.z_loss_coef,
                 )
+        else:
+            with jax.named_scope("head_loss"):
+                logits = self.compute_logits(hidden_states)
+                if want_loss:
+                    loss = causal_lm_loss(
+                        logits,
+                        input_ids,
+                        upcast=self.config.upcast_logits_for_loss,
+                        attention_mask=attention_mask,
+                        segment_ids=segment_ids,
+                        labels=labels,
+                        z_loss_coef=self.config.z_loss_coef,
+                    )
 
         if want_loss:
             aux_loss = self.compute_aux_loss(extras, attention_mask, segment_ids)

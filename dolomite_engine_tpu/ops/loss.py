@@ -121,6 +121,7 @@ def causal_lm_loss(
     return loss
 
 
+@jax.named_scope("ce_chunk")  # a scan body starts with no name of its own in a profile
 def _chunk_ce_terms(
     h: jax.Array,
     table: jax.Array,
@@ -198,9 +199,10 @@ def _chunked_ce_terms(
             return (carry[0] + loss_sum, carry[1] + z_sum, carry[2] + num), None
 
     zero = jnp.zeros((), jnp.float32)
-    (loss_sum, z_sum, num_tokens), _ = jax.lax.scan(
-        body, (zero, zero, zero), (hidden_c, labels_c)
-    )
+    with jax.named_scope("loss_chunks"):
+        (loss_sum, z_sum, num_tokens), _ = jax.lax.scan(
+            body, (zero, zero, zero), (hidden_c, labels_c)
+        )
     return loss_sum, z_sum, num_tokens
 
 
@@ -231,10 +233,14 @@ def _chunked_ce_terms_bwd(logit_scale, upcast, compute_dtype, want_z, residuals,
         # unchunked reference accumulates its table grad inside one fp32 matmul)
         return dtable_acc + dt.astype(jnp.float32), dh
 
-    dtable, dhidden_c = jax.lax.scan(
-        body, jnp.zeros(table.shape, jnp.float32), (hidden_c, labels_c)
-    )
-    return dhidden_c, None, dtable.astype(table.dtype)
+    # a custom_vjp's backward rule is traced under the scopes of the call (the model's
+    # `head_loss`) but not under those its forward opened: it opens the forward's own, so
+    # that a profile tells the two scans apart by JAX's `transpose(...)` wrapper alone
+    with jax.named_scope("loss_chunks"):
+        dtable, dhidden_c = jax.lax.scan(
+            body, jnp.zeros(table.shape, jnp.float32), (hidden_c, labels_c)
+        )
+        return dhidden_c, None, dtable.astype(table.dtype)
 
 
 _chunked_ce_terms.defvjp(_chunked_ce_terms_fwd, _chunked_ce_terms_bwd)

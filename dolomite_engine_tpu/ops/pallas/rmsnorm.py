@@ -131,6 +131,8 @@ def _rmsnorm_local(x, weight, eps: float, residual, interpret: bool | None):
 # inference/serving path that justifies a kernel.
 
 
+# a backward rule is traced outside the scopes its forward opened: it opens the forward's
+@jax.named_scope("pallas_rmsnorm")
 def _rmsnorm_grads(s, weight, eps: float, dy):
     s32 = s.astype(jnp.float32)
     variance = jnp.mean(jnp.square(s32), axis=-1, keepdims=True)

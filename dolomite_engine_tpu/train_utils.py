@@ -15,7 +15,7 @@ Parity: reference `dolomite_engine/train_utils.py` (236 LoC):
 from __future__ import annotations
 
 import logging
-from contextlib import nullcontext
+from contextlib import contextmanager, nullcontext
 from typing import Any, Callable
 
 import jax
@@ -23,7 +23,7 @@ import jax.numpy as jnp
 import optax
 from flax import struct
 
-from .utils import ExperimentsTracker, get_telemetry, log_rank_0
+from .utils import ExperimentsTracker, get_telemetry, log_rank_0, profiler_call_at_step_boundary
 from .utils.diagnostics import per_group_health
 
 
@@ -122,13 +122,18 @@ def make_train_step(
         # (flax overwrite-with-gradient contract, ops/fp8.py) — overwritten, never optimized
         grad_fn = jax.value_and_grad(micro_loss, argnums=(0, 1) if use_fp8 else 0)
 
+        # Phase scopes (docs/OBSERVABILITY.md "Phases of the train step"): with the model's
+        # own (`embed`, `blocks`, `final_norm`, `head_loss`) they give every operation of
+        # the step a phase in a profile; JAX's `transpose(...)` wrapper tells backward from
+        # forward. Scopes are metadata: the compiled operations are what they were.
         new_fp8 = state.fp8
         if gradient_accumulation_steps == 1:
             micro = jax.tree.map(lambda x: x[0], batch)
             loss, grads = grad_fn(state.params, state.fp8, micro, rng)
             if use_fp8:
                 grads, new_fp8 = grads
-            grads = jax.tree.map(lambda g: g.astype(jnp.float32), grads)
+            with jax.named_scope("grad_clip"):
+                grads = jax.tree.map(lambda g: g.astype(jnp.float32), grads)
         else:
 
             def accum_fn(carry, xs):
@@ -150,11 +155,13 @@ def make_train_step(
                 lambda p: jnp.zeros(p.shape, jnp.float32), state.params
             )
             rngs = jax.random.split(rng, gradient_accumulation_steps)
-            (grads, loss, new_fp8), _ = jax.lax.scan(
-                accum_fn, (zero_grads, jnp.zeros((), jnp.float32), state.fp8), (batch, rngs)
-            )
+            with jax.named_scope("accumulate"):
+                (grads, loss, new_fp8), _ = jax.lax.scan(
+                    accum_fn, (zero_grads, jnp.zeros((), jnp.float32), state.fp8), (batch, rngs)
+                )
 
-        grads, grad_norm = clip_grad_norm(grads, gradient_clipping)
+        with jax.named_scope("grad_clip"):
+            grads, grad_norm = clip_grad_norm(grads, gradient_clipping)
 
         def apply_update(operand):
             grads, opt_state, params, old_fp8, stepped_fp8 = operand
@@ -163,19 +170,20 @@ def make_train_step(
             return new_params, new_opt_state, stepped_fp8
 
         operand = (grads, state.opt_state, state.params, state.fp8, new_fp8)
-        if skip_nonfinite:
-            step_ok = jnp.isfinite(loss) & jnp.isfinite(grad_norm)
-            new_params, new_opt_state, new_fp8 = jax.lax.cond(
-                step_ok,
-                apply_update,
-                # identity: params/opt-state flow through untouched and fp8 reverts to its
-                # PRE-step scaling state (the stepped one saw the non-finite amax)
-                lambda operand: (operand[2], operand[1], operand[3]),
-                operand,
-            )
-        else:
-            step_ok = None
-            new_params, new_opt_state, new_fp8 = apply_update(operand)
+        with jax.named_scope("optimizer"):
+            if skip_nonfinite:
+                step_ok = jnp.isfinite(loss) & jnp.isfinite(grad_norm)
+                new_params, new_opt_state, new_fp8 = jax.lax.cond(
+                    step_ok,
+                    apply_update,
+                    # identity: params/opt-state flow through untouched and fp8 reverts to
+                    # its PRE-step scaling state (the stepped one saw the non-finite amax)
+                    lambda operand: (operand[2], operand[1], operand[3]),
+                    operand,
+                )
+            else:
+                step_ok = None
+                new_params, new_opt_state, new_fp8 = apply_update(operand)
 
         new_state = TrainState(
             step=state.step + 1, params=new_params, opt_state=new_opt_state, fp8=new_fp8
@@ -184,7 +192,8 @@ def make_train_step(
         if step_ok is not None:
             metrics["skipped"] = (~step_ok).astype(jnp.int32)
         if collect_health:
-            metrics["health"] = per_group_health(state.params, grads, new_params)
+            with jax.named_scope("health"):
+                metrics["health"] = per_group_health(state.params, grads, new_params)
         return new_state, metrics
 
     return train_step
@@ -293,10 +302,34 @@ def reset_profiler_schedule() -> None:
     _PROFILER_SCHEDULE_DONE = False
 
 
+@contextmanager
+def _whole_steps_trace(trace_path: str, last_outputs):
+    """`jax.profiler.trace` around the dispatch of a step, entered once the step before has
+    finished on the device and left once this one has: the capture holds whole steps."""
+    trace = jax.profiler.trace(trace_path)
+    started = profiler_call_at_step_boundary(trace.__enter__, last_outputs(), "start")
+    try:
+        yield
+    finally:
+        if started:
+            profiler_call_at_step_boundary(
+                lambda: trace.__exit__(None, None, None), last_outputs(), "stop"
+            )
+
+
 def get_profiler_context(
-    trace_path: str | None, global_step: int, wait: int = 5, active: int = 1
+    trace_path: str | None,
+    global_step: int,
+    last_outputs: Callable[[], Any] = lambda: None,
+    wait: int = 5,
+    active: int = 1,
 ):
     """jax.profiler trace of ABSOLUTE global steps (wait, wait + active], one-shot per run.
+
+    `last_outputs()` returns the outputs of the newest dispatched step (None before the
+    first): the context calls it on entry — the step before — and on exit — the step
+    dispatched inside it — and starts and stops the trace only after each is ready
+    (`utils/telemetry.profiler_call_at_step_boundary`, shared with the on-demand path).
 
     The reference torch-profiler schedule (`train_utils.py:182-194`) is wait 5 / warmup 5 /
     active 1; XLA has no warmup notion (the first step compiled already), so the explicit
@@ -318,7 +351,7 @@ def get_profiler_context(
     if wait < global_step <= wait + active and jax.process_index() == 0:
         if global_step == wait + active:
             _PROFILER_SCHEDULE_DONE = True  # window fully traced: one-shot per run
-        return jax.profiler.trace(trace_path)
+        return _whole_steps_trace(trace_path, last_outputs)
     return nullcontext()
 
 

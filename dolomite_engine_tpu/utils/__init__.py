@@ -57,9 +57,8 @@ from .telemetry import (
     detect_peak_tflops_per_device,
     get_telemetry,
     install_telemetry,
+    profiler_call_at_step_boundary,
     stable_config_hash,
-    step_annotation,
-    trace_annotation,
     uninstall_telemetry,
 )
 from .tracking import ExperimentsTracker, ProgressBar

@@ -15,12 +15,14 @@ Sink schema (one JSON object per line; see docs/OBSERVABILITY.md):
 
     {"kind": "run_start", "ts", "rank", "devices", "device_kind",
      "peak_tflops_per_device", "model_tflops_per_step", "schema": 1}
-    {"kind": "step",   "ts", "rank", "step", "t": {"data", "step" | "compile"}}
+    {"kind": "step",   "ts", "rank", "step", "t": {"data", "step" | "compile",
+     "wall", "split": {span name: seconds}}}   # wall/split: inside a train loop only
     {"kind": "window", "ts", "rank", "step", "window_seconds",
      "goodput": {"compile","data","step","checkpoint","eval","other","goodput_pct"},
      "step_time": {"count","mean","min","max"}, "mfu_pct", "tflops_per_group",
      "counters": {...cumulative...}, "gauges": {...device memory, host rss...}}
-    {"kind": "event",  "ts", "rank", "event", "step", ...}   # nan_skip, loader_stall, anomaly
+    {"kind": "event",  "ts", "rank", "event", "step", ...}   # nan_skip, loader_stall, anomaly,
+                                                             # compile (seconds, program)
     {"kind": "health", "ts", "rank", "step", "stats"}        # per-group norms (diagnostics.py)
     {"kind": "model_report", ...}                            # one-shot introspection (diagnostics.py)
     {"kind": "serving", "ts", "rank", "step", "queue_depth", "slots_active", "num_slots",
@@ -39,11 +41,24 @@ Cross-module counters (`utils/retry.py`, `utils/fault_tolerance.py`, `checkpoint
 registry that degrades to a no-op when no train loop installed telemetry — inference tools
 and unit tests pay nothing.
 
+Spans: :meth:`Telemetry.span` is the one primitive that cuts a boundary of the program —
+once, on both clocks. It enters a ``jax.profiler.TraceAnnotation`` (``StepTraceAnnotation``
+for the step's dispatch), reads ``time.perf_counter()`` at both ends, adds the duration to
+the current iteration's split (the ``step`` record's ``t.split``; the loop thread's
+outermost spans tile the iteration, so the parts sum to ``t.wall``) and, where a ``bucket``
+is named, to that goodput bucket. A captured trace and the sink therefore cut at the same
+places. Span names are lower case with no ``(``, ``:``, ``$`` or space.
+
+Compilations: one ``jax.monitoring`` listener a process counts every XLA backend compilation
+(cache loads too) from the start of a train loop (:meth:`Telemetry.begin_iterations`) into
+the installed telemetry's ``compiles`` counter and writes a ``compile`` event with its seconds, the program's name and the step it fell in — a run that
+recompiles at step 40,000 says so.
+
 On-demand profiling: :class:`OnDemandProfiler` is polled once per step (same pattern as the
 fault-tolerance preemption flag); touching the trigger file — or SIGUSR1 — captures an N-step
-`jax.profiler` trace mid-run, no restart. Steps are labeled via
-:func:`step_annotation` (``jax.profiler.StepTraceAnnotation``) and dataloader/checkpoint/eval
-scopes via :func:`trace_annotation`, so captured traces read as the goodput buckets do.
+`jax.profiler` trace mid-run, no restart. A capture starts and stops only after the last
+dispatched step has finished on the device (:func:`profiler_call_at_step_boundary`, shared
+with the fixed schedule of `train_utils.get_profiler_context`), so it holds whole steps.
 """
 
 from __future__ import annotations
@@ -56,7 +71,6 @@ import signal
 import socket
 import threading
 import time
-from contextlib import contextmanager, nullcontext
 from typing import Any
 
 import jax
@@ -86,6 +100,11 @@ RECORD_SCHEMA: dict[str, tuple[str, ...]] = {
         # probe passes, so the record reflects what actually ran)
         "kernels",
     ),
+    # `t` = {"data": residual queue wait, "step" | "compile": dispatch-to-sync wall time}
+    # and, when written from a train loop (Telemetry.begin_iterations), "wall" (the whole
+    # iteration, boundary to boundary) and "split" ({span name: seconds}: the loop thread's
+    # outermost spans, which tile the iteration — the record is written once the iteration
+    # has ended, and its own write is charged to the next one as `loop.record`)
     "step": ("step", "t"),
     "window": (
         "step",
@@ -217,6 +236,9 @@ KNOWN_COUNTERS: tuple[str, ...] = (
     "checkpoints_pruned",
     "loader_batches",
     "profiles_captured",
+    # XLA backend compilations (persistent-cache loads included) since the train loop began
+    # (begin_iterations); each also writes a `compile` event naming the program and the step
+    "compiles",
     # async input pipeline (data/prefetch.py): consumer found the prefetch queue empty at
     # a steady-state step — the background worker is not keeping up with the loop
     "prefetch_stalls",
@@ -270,6 +292,8 @@ KNOWN_EVENTS: tuple[str, ...] = (
     "profile_start",
     "profiles_captured",
     "anomaly",
+    # one XLA backend compilation: seconds, program (the jitted function's name), step
+    "compile",
     # serving-fleet fault tolerance (serving/cluster/health.py + router.py): one event
     # per downward health edge, per completed drain/rejoin, and when a threaded
     # Router.wait timed out with work still pending (fields name who/why)
@@ -460,16 +484,21 @@ class QuantileSketch:
         return bool(self.values)
 
 
-def step_annotation(step: int):
-    """Label one train step in captured traces (`StepTraceAnnotation` groups per-step work in
-    the profiler UI and feeds its step-time histogram)."""
-    return jax.profiler.StepTraceAnnotation("train_step", step_num=step)
-
-
-def trace_annotation(name: str):
-    """Named scope in captured traces (dataloader fetch, checkpoint save, eval) matching the
-    goodput bucket names."""
-    return jax.profiler.TraceAnnotation(name)
+def profiler_call_at_step_boundary(call, last_outputs, what: str) -> bool:
+    """Start or stop a profiler capture (`call`) once the last dispatched step has finished
+    on the device, so that a capture holds whole steps: dispatch is asynchronous, and a
+    trace stopped right after it ends while the step's device work is still queued.
+    `last_outputs` is that step's outputs (None: nothing dispatched yet). Shared by both
+    capture paths (:class:`OnDemandProfiler`, `train_utils.get_profiler_context`); never
+    raises into training."""
+    try:
+        if last_outputs is not None:
+            jax.block_until_ready(last_outputs)
+        call()
+    except Exception as error:  # a failed capture must never kill training
+        log_rank_0(logging.WARNING, f"profiler capture failed to {what}: {error!r}")
+        return False
+    return True
 
 
 class OnDemandProfiler:
@@ -499,6 +528,7 @@ class OnDemandProfiler:
         self.num_steps = max(int(num_steps), 1)
         self._signal_flag = threading.Event()
         self._active_since: int | None = None
+        self._last_outputs = None  # of the newest polled step, while a capture is active
         self._captures = 0
         if use_signal:
             self._install_signal_handler()
@@ -529,23 +559,26 @@ class OnDemandProfiler:
     def active(self) -> bool:
         return self._active_since is not None
 
-    def poll(self, step: int, telemetry: "Telemetry | None" = None) -> None:
-        """Once per train step, after the step ran: start a capture if triggered, stop one
-        that has covered `num_steps` steps."""
+    def poll(self, step: int, telemetry: "Telemetry | None" = None, last_outputs=None) -> None:
+        """Once per train step, after the step was dispatched: start a capture if
+        triggered, stop one that has covered `num_steps` steps — either only after
+        `last_outputs` (the step's outputs) are ready, so the capture holds whole steps."""
         if self._active_since is not None:
+            self._last_outputs = last_outputs
             if step - self._active_since >= self.num_steps:
                 self._stop(step, telemetry)
             return
         if self._consume_trigger():
-            self._start(step, telemetry)
+            self._start(step, telemetry, last_outputs)
 
-    def _start(self, step: int, telemetry: "Telemetry | None") -> None:
+    def _start(self, step: int, telemetry: "Telemetry | None", last_outputs=None) -> None:
         trace_dir = os.path.join(self.output_path, f"step{step + 1}")
-        try:
+
+        def start():
             os.makedirs(trace_dir, exist_ok=True)
             jax.profiler.start_trace(trace_dir)
-        except Exception as error:  # a failed capture must never kill training
-            log_rank_0(logging.WARNING, f"on-demand profile failed to start: {error!r}")
+
+        if not profiler_call_at_step_boundary(start, last_outputs, "start"):
             return
         self._active_since = step
         log_rank_0(
@@ -557,11 +590,9 @@ class OnDemandProfiler:
             telemetry.event("profile_start", step=step, trace_dir=trace_dir)
 
     def _stop(self, step: int, telemetry: "Telemetry | None") -> None:
-        try:
-            jax.profiler.stop_trace()
-        except Exception as error:
-            log_rank_0(logging.WARNING, f"on-demand profile failed to stop: {error!r}")
+        profiler_call_at_step_boundary(jax.profiler.stop_trace, self._last_outputs, "stop")
         self._active_since = None
+        self._last_outputs = None
         self._captures += 1
         log_rank_0(logging.INFO, f"on-demand profile captured through step {step}")
         if telemetry is not None:
@@ -571,6 +602,47 @@ class OnDemandProfiler:
         """End-of-run cleanup: commit a capture the run ended inside of."""
         if self._active_since is not None:
             self._stop(self._active_since + self.num_steps, None)
+
+
+def _annotation(name: str, step: int | None):
+    if step is None:
+        return jax.profiler.TraceAnnotation(name)
+    return jax.profiler.StepTraceAnnotation(name, step_num=step)
+
+
+class _Span:
+    """The context manager behind :meth:`Telemetry.span`. The host clock is read outside
+    the profiler annotation, so the annotation's own cost lies inside the span and the
+    loop's spans leave no hole between them but their own call overhead."""
+
+    __slots__ = ("telemetry", "name", "bucket", "step", "on_loop", "annotation", "start")
+
+    def __init__(self, telemetry: "Telemetry", name: str, bucket: str | None, step: int | None):
+        self.telemetry, self.name, self.bucket, self.step = telemetry, name, bucket, step
+
+    def __enter__(self) -> "_Span":
+        self.start = time.perf_counter()
+        telemetry = self.telemetry
+        self.on_loop = threading.get_ident() == telemetry._loop_thread
+        if self.on_loop:
+            telemetry._span_depth += 1
+        if self.step is not None:
+            telemetry._step_in_flight = self.step
+        self.annotation = _annotation(self.name, self.step)
+        self.annotation.__enter__()
+        return self
+
+    def __exit__(self, *exc_info) -> None:
+        self.annotation.__exit__(*exc_info)
+        elapsed = time.perf_counter() - self.start
+        telemetry = self.telemetry
+        if self.on_loop:
+            telemetry._span_depth -= 1
+            if telemetry._span_depth == 0:
+                telemetry._split[self.name] = telemetry._split.get(self.name, 0.0) + elapsed
+        if self.bucket is not None:
+            with telemetry._lock:
+                telemetry._buckets[self.bucket] = telemetry._buckets.get(self.bucket, 0.0) + elapsed
 
 
 class Telemetry:
@@ -614,6 +686,13 @@ class Telemetry:
         self._window_start = time.perf_counter()
         self._seen_first_step = False
         self._last_step = 0
+        # the current iteration's split (begin_iterations .. record_step): only the loop's
+        # thread writes it, and only its outermost spans — those tile the iteration
+        self._loop_thread: int | None = None
+        self._iteration_start = 0.0
+        self._span_depth = 0
+        self._split: dict[str, float] = {}
+        self._step_in_flight = 0  # the newest dispatched step: where a compile event fell
 
         self._file = None
         if sink_path is not None:
@@ -738,35 +817,51 @@ class Telemetry:
         record.update(fields)
         self._emit(record)
 
-    @contextmanager
-    def timer(self, bucket: str):
-        """Accumulate a wall-clock scope into a goodput bucket (checkpoint saves, eval)."""
-        start = time.perf_counter()
-        try:
-            yield
-        finally:
-            elapsed = time.perf_counter() - start
-            with self._lock:
-                self._buckets[bucket] = self._buckets.get(bucket, 0.0) + elapsed
+    def span(self, name: str, bucket: str | None = None, step: int | None = None) -> "_Span":
+        """Cut one boundary of the program, once, on both clocks: a ``TraceAnnotation`` in a
+        captured profile (``StepTraceAnnotation`` when `step` is given: the dispatch of that
+        train step) and ``perf_counter`` for the sink. The duration goes to the current
+        iteration's split when this is an outermost span on the loop's thread, and to the
+        goodput `bucket` when one is named. Outside a train loop (no
+        :meth:`begin_iterations`) it only annotates — nothing is kept or written."""
+        return _Span(self, name, bucket, step)
 
     # ------------------------------------------------------------------ goodput
 
+    def begin_iterations(self) -> None:
+        """The calling thread's train loop starts here: from now on every
+        :meth:`record_step` closes one iteration, and the spans in between are its split."""
+        self._loop_thread = threading.get_ident()
+        self._iteration_start = time.perf_counter()
+        self._span_depth = 0
+        self._split = {}
+
     def record_step(self, step: int, data_seconds: float, step_seconds: float) -> None:
         """Per-step accounting from the train loops: dataloader wait + jitted-step wall
-        time. Writes a step record and feeds the window buckets."""
+        time. Writes a step record and feeds the window buckets. In a train loop
+        (:meth:`begin_iterations`) this is the iteration's last call: the record also
+        carries the iteration's wall time and its split, and the write itself is the
+        first span (``loop.record``) of the next iteration."""
         self._last_step = step
         timings: dict[str, float] = {"data": round(data_seconds, 6)}
-        with self._lock:
-            self._buckets["data"] += data_seconds
-            if not self._seen_first_step:
-                self._seen_first_step = True
-                self._buckets["compile"] += step_seconds
-                timings["compile"] = round(step_seconds, 6)
-            else:
-                self._buckets["step"] += step_seconds
-                self._step_times.append(step_seconds)
-                timings["step"] = round(step_seconds, 6)
-        self._emit({"kind": "step", "step": step, "t": timings})
+        if self._loop_thread is not None:
+            boundary = time.perf_counter()
+            timings["wall"] = round(boundary - self._iteration_start, 6)
+            timings["split"] = {k: round(v, 6) for k, v in self._split.items()}
+            self._iteration_start = boundary
+            self._split = {}
+        with self.span("loop.record"):
+            with self._lock:
+                self._buckets["data"] += data_seconds
+                if not self._seen_first_step:
+                    self._seen_first_step = True
+                    self._buckets["compile"] += step_seconds
+                    timings["compile"] = round(step_seconds, 6)
+                else:
+                    self._buckets["step"] += step_seconds
+                    self._step_times.append(step_seconds)
+                    timings["step"] = round(step_seconds, 6)
+            self._emit({"kind": "step", "step": step, "t": timings})
 
     def current_mfu(self) -> float | None:
         """Steady-state MFU %% over the current window: analytic model TFLOPs per step
@@ -844,9 +939,11 @@ class Telemetry:
 
     # ------------------------------------------------------------------ profiler
 
-    def poll_profiler(self, step: int) -> None:
+    def poll_profiler(self, step: int, last_outputs=None) -> None:
+        """`last_outputs`: the outputs of the step just dispatched (a capture waits for
+        them before it starts or stops: it holds whole steps)."""
         if self.profiler is not None:
-            self.profiler.poll(step, telemetry=self)
+            self.profiler.poll(step, telemetry=self, last_outputs=last_outputs)
 
     # ------------------------------------------------------------------ lifecycle
 
@@ -907,8 +1004,12 @@ class _NullTelemetry:
     def snapshot(self) -> dict:
         return {"counters": {}, "gauges": {}, "quantiles": {}}
 
-    def timer(self, bucket):
-        return nullcontext()
+    def span(self, name, bucket=None, step=None):
+        # the profiler's clock is still cut (an inactive TraceMe costs nanoseconds)
+        return _annotation(name, step)
+
+    def begin_iterations(self) -> None:
+        pass
 
     def record_step(self, step, data_seconds, step_seconds) -> None:
         pass
@@ -919,7 +1020,7 @@ class _NullTelemetry:
     def emit_window(self, step):
         return None
 
-    def poll_profiler(self, step) -> None:
+    def poll_profiler(self, step, last_outputs=None) -> None:
         pass
 
     def close(self, status: str = "ok") -> None:
@@ -930,10 +1031,33 @@ _NULL = _NullTelemetry()
 _ACTIVE: Telemetry | None = None
 
 
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_COMPILE_LISTENER_REGISTERED = False
+
+
+def _on_jax_duration_event(event: str, duration_secs: float, **kwargs) -> None:
+    """The process's one `jax.monitoring` listener: a backend compilation (a persistent
+    cache load counts: the program was not in memory) while a train loop's telemetry is
+    installed (`begin_iterations`; the serving engines' record streams stay as they are)."""
+    telemetry = _ACTIVE
+    if telemetry is None or telemetry._loop_thread is None or event != _COMPILE_EVENT:
+        return
+    telemetry.count("compiles")
+    telemetry.event(
+        "compile",
+        step=telemetry._step_in_flight,
+        seconds=round(duration_secs, 6),
+        program=kwargs.get("fun_name"),
+    )
+
+
 def install_telemetry(telemetry: Telemetry) -> None:
     """Make `telemetry` the process-wide instance cross-module counters report to."""
-    global _ACTIVE
+    global _ACTIVE, _COMPILE_LISTENER_REGISTERED
     _ACTIVE = telemetry
+    if not _COMPILE_LISTENER_REGISTERED:  # jax.monitoring has no way to take one back
+        _COMPILE_LISTENER_REGISTERED = True
+        jax.monitoring.register_event_duration_secs_listener(_on_jax_duration_event)
 
 
 def uninstall_telemetry() -> None:

@@ -424,7 +424,7 @@ def test_steady_state_data_bucket_shrinks_under_prefetch(tmp_path, monkeypatch):
     to <10%% of its depth-0 value in the same test."""
 
     @contextmanager
-    def slow_profiler_context(path, step):
+    def slow_profiler_context(path, step, last_outputs=None):
         # a deterministic stand-in for the jitted step's wall time: 80 ms the prefetch
         # worker can overlap, independent of CI machine speed
         time.sleep(0.08)

@@ -127,7 +127,7 @@ def test_goodput_window_accounting_and_mfu(tmp_path):
     # steady mean step = 0.4s -> 25 TFLOPs/group achieved vs 200 peak -> 12.5% MFU
     assert telemetry.current_mfu() == pytest.approx(12.5)
 
-    with telemetry.timer("checkpoint"):
+    with telemetry.span("loop.checkpoint", bucket="checkpoint"):
         pass
     window = telemetry.emit_window(3)
     goodput = window["goodput"]
@@ -286,6 +286,61 @@ def test_failed_capture_start_never_kills_training(tmp_path, monkeypatch):
     assert not profiler.active
 
 
+class _Pending:
+    """Stands for a dispatched step's output: `jax.block_until_ready` waits on it."""
+
+    def __init__(self, calls):
+        self.calls = calls
+
+    def block_until_ready(self):
+        self.calls.append(("wait", None))
+        return self
+
+
+def test_both_capture_paths_stop_only_after_the_wait(tmp_path, monkeypatch, _fake_profiler):
+    """A capture holds whole steps: the on-demand path and the fixed schedule start and stop
+    the profiler only after a wait on the newest dispatched step's outputs, through one
+    helper (dispatch is asynchronous: a trace stopped right after it loses the step)."""
+    pending = _Pending(_fake_profiler)
+    profiler = OnDemandProfiler(
+        str(tmp_path / "trigger"), str(tmp_path / "traces"), num_steps=1, use_signal=False
+    )
+    (tmp_path / "trigger").touch()
+    profiler.poll(1, last_outputs={"loss": pending})
+    profiler.poll(2, last_outputs={"loss": pending})
+    assert [c[0] for c in _fake_profiler] == ["wait", "start", "wait", "stop"]
+
+    # the fixed schedule enters and leaves `jax.profiler.trace` (start_trace / stop_trace)
+    del _fake_profiler[:]
+
+    class _Trace:
+        def __init__(self, path):
+            self.path = path
+
+        def __enter__(self):
+            _fake_profiler.append(("start", self.path))
+
+        def __exit__(self, *exc_info):
+            _fake_profiler.append(("stop", None))
+
+    monkeypatch.setattr(jax.profiler, "trace", _Trace)
+    outputs = {"step": None}  # the loop's `metrics`: None before the first dispatch
+    with get_profiler_context(str(tmp_path / "fixed"), 6, lambda: outputs["step"]):
+        assert [c[0] for c in _fake_profiler] == ["start"]  # nothing dispatched: no wait
+        outputs["step"] = {"loss": pending}  # the step dispatched inside the context
+    assert [c[0] for c in _fake_profiler] == ["start", "wait", "stop"]
+
+    # a capture never raises into training: a wait or a stop that fails is logged
+    def boom():
+        raise RuntimeError("profiler backend gone")
+
+    monkeypatch.setattr(jax.profiler, "stop_trace", boom)
+    (tmp_path / "trigger").touch()
+    profiler.poll(3, last_outputs=None)
+    profiler.poll(4, last_outputs=None)
+    assert not profiler.active
+
+
 # --------------------------------------------------------------------------- counter wiring
 
 
@@ -375,11 +430,110 @@ def test_checkpoint_save_and_prune_counters(tmp_path):
     telemetry.close()
 
 
+def test_step_record_split_sums_to_wall_time(tmp_path):
+    """Inside a train loop the loop thread's outermost spans tile the iteration: the step
+    record's split sums to its wall time; nested spans and other threads' spans stay out;
+    `t.data` and `t.step` stay what the caller measured."""
+    import time
+
+    sink = tmp_path / "t.jsonl"
+    telemetry = Telemetry(sink_path=str(sink), rank=0)
+    telemetry.begin_iterations()
+    for step in (1, 2, 3):
+        with telemetry.span("loop.data_wait"):
+            time.sleep(0.002)
+            with telemetry.span("data_fetch"):  # nested: inside its parent, not a part
+                time.sleep(0.001)
+        with telemetry.span("train_step", step=step):
+            time.sleep(0.003)
+            # another thread's span (the prefetch worker's) is no part of the loop's split
+            worker = threading.Thread(target=telemetry.span("prefetch_assemble").__enter__)
+            worker.start()
+            worker.join()
+        with telemetry.span("loop.checkpoint", bucket="checkpoint"):
+            time.sleep(0.001)
+        telemetry.record_step(step, data_seconds=0.002, step_seconds=0.003)
+    window = telemetry.emit_window(3)
+    telemetry.close()
+
+    steps = [r for r in _read_sink(sink) if r["kind"] == "step"]
+    assert [r["step"] for r in steps] == [1, 2, 3]
+    for record in steps:
+        t = record["t"]
+        assert t["data"] == 0.002 and t.get("step", t.get("compile")) == 0.003
+        assert set(t["split"]) <= {"loop.record", "loop.data_wait", "train_step", "loop.checkpoint"}
+        assert abs(sum(t["split"].values()) - t["wall"]) < max(0.01 * t["wall"], 2e-4)
+        assert t["split"]["loop.data_wait"] >= 0.003  # the nested span is inside it
+    # the write of a step's record is the head of the next iteration
+    assert "loop.record" not in steps[0]["t"]["split"]
+    assert list(steps[1]["t"]["split"])[0] == "loop.record"
+    assert window["goodput"]["checkpoint"] >= 0.003  # a span's bucket is fed by the same cut
+
+
+def test_span_outside_a_train_loop_costs_no_write(tmp_path):
+    """Without `begin_iterations` a span only annotates: no record, no split — tools and
+    the serving engine can cut their boundaries through the same primitive for free."""
+    sink = tmp_path / "t.jsonl"
+    telemetry = Telemetry(sink_path=str(sink), rank=0)
+    before = len(_read_sink(sink))
+    with telemetry.span("data_fetch"):
+        pass
+    with telemetry.span("eval", bucket="eval"):
+        pass
+    assert len(_read_sink(sink)) == before
+    assert telemetry._split == {}
+    telemetry.record_step(1, 0.1, 0.1)
+    (record,) = [r for r in _read_sink(sink) if r["kind"] == "step"]
+    assert set(record["t"]) == {"data", "compile"}  # no wall, no split outside a loop
+    telemetry.close()
+    with get_telemetry().span("data_fetch"):  # the null registry annotates too
+        pass
+
+
+def test_compiles_counter_names_the_step_of_a_recompile(tmp_path):
+    """A run that recompiles mid-training says so: the `compiles` counter and a `compile`
+    event with the seconds, the program and the step it fell in."""
+    sink = tmp_path / "t.jsonl"
+    telemetry = Telemetry(sink_path=str(sink), rank=0)
+    install_telemetry(telemetry)
+    jax.jit(lambda x: x - 1.0)(jnp.ones((11,))).block_until_ready()  # before the loop: not counted
+    assert "compiles" not in telemetry.counters
+    telemetry.begin_iterations()
+
+    @jax.jit
+    def scale_for_compile_test(x):
+        return x * 3.0
+
+    with telemetry.span("train_step", step=7):
+        scale_for_compile_test(jnp.ones((3,))).block_until_ready()
+    first = telemetry.counters["compiles"]
+    assert first >= 1
+    with telemetry.span("train_step", step=8):
+        scale_for_compile_test(jnp.ones((3,))).block_until_ready()  # cached: no compile
+    assert telemetry.counters["compiles"] == first
+    with telemetry.span("train_step", step=9):
+        scale_for_compile_test(jnp.ones((5,))).block_until_ready()  # a new shape: recompile
+    assert telemetry.counters["compiles"] > first
+    uninstall_telemetry()
+    jax.jit(lambda x: x + 1.0)(jnp.ones((7,))).block_until_ready()  # nobody installed: not counted
+    counted = telemetry.counters["compiles"]
+    telemetry.close()
+
+    events = [
+        r for r in _read_sink(sink)
+        if r["kind"] == "event" and r["event"] == "compile"
+        and "scale_for_compile_test" in str(r["program"])
+    ]
+    assert [e["step"] for e in events] == [7, 9]
+    assert all(e["seconds"] > 0 for e in events)
+    assert _read_sink(sink)[-1]["counters"]["compiles"] == counted
+
+
 def test_null_registry_is_safe_without_install():
     null = get_telemetry()
     null.count("anything", event=True, step=1)
     null.record_step(1, 0.1, 0.1)
-    with null.timer("checkpoint"):
+    with null.span("loop.checkpoint", bucket="checkpoint"):
         pass
     assert null.emit_window(1) is None
     assert null.current_mfu() is None

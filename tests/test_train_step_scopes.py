@@ -1,0 +1,144 @@
+"""The train step names its phases (docs/OBSERVABILITY.md "Phases of the train step").
+
+Lowers `make_train_step` for a tiny scanned `gpt_dolomite` with the chunked loss and reads
+the framework names of the lowered operations (`jit(train_step)/.../op`, what a profile
+calls `tf_op`): every phase scope is there, every scan sits in a scope of its own, the
+loss's backward matmuls are under `head_loss`, and no matmul is left with JAX's bare
+`transpose(jvp())` — a backward rule or scan body with no name of its own. Scopes are
+metadata: the compile-cache key strips them, so the compiled program is what it was.
+"""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import optax
+import pytest
+from flax import linen as nn
+
+from dolomite_engine_tpu.models import config_from_dict
+from dolomite_engine_tpu.models.gpt_dolomite import GPTDolomiteForCausalLM
+from dolomite_engine_tpu.train_utils import TrainState, make_train_step
+
+
+def _lowered(accumulation: int = 1, collect_health: bool = False, scan_layers: bool = True):
+    config = config_from_dict(
+        dict(
+            model_type="gpt_dolomite", vocab_size=256, n_positions=64, n_embd=32, n_layer=4,
+            n_head=4, num_key_value_heads=2, attention_head_type="gqa",
+            position_embedding_type="rope", activation_function="swiglu",
+            normalization_function="rmsnorm", add_bias=False, resid_pdrop=0.0,
+            embd_pdrop=0.0, attn_pdrop=0.0, bos_token_id=0, eos_token_id=1, pad_token_id=2,
+            tie_word_embeddings=True, fused_lm_head_loss=True, loss_chunk_size=16,
+            z_loss_coef=1e-4,
+        )
+    )
+    model = GPTDolomiteForCausalLM(
+        config=config, scan_layers=scan_layers, checkpoint_every=2,
+        checkpoint_policy="save_dots", dtype=jnp.bfloat16,
+    )
+    params = nn.unbox(model.init(jax.random.PRNGKey(0), jnp.zeros((1, 32), jnp.int32))["params"])
+    optimizer = optax.adamw(1e-3)
+    state = TrainState(
+        step=jnp.zeros((), jnp.int32), params=params, opt_state=optimizer.init(params)
+    )
+
+    def loss_fn(params, micro, rng):
+        return model.apply({"params": params}, micro["text"], compute_loss=True).loss
+
+    step = make_train_step(
+        loss_fn, optimizer, gradient_accumulation_steps=accumulation, skip_nonfinite=True,
+        collect_health=collect_health,
+    )
+    batch = {"text": jnp.zeros((accumulation, 1, 32), jnp.int32)}
+    return jax.jit(step).lower(state, batch, jax.random.PRNGKey(0))
+
+
+def _operation_names(lowered) -> list:
+    """[(stablehlo op, framework name)] of every operation with a named location. Inside a
+    function the lowering made (a scan's body is one: `closed_call`) names are relative to
+    the call; the call site's prefix is added when the program is compiled."""
+    text = lowered.as_text(debug_info=True)
+    named = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+    found, open_regions = [], []  # an operation with regions prints its location after them
+    for line in text.splitlines():
+        indent = len(line) - len(line.lstrip())
+        op = re.search(r'= "?stablehlo\.(\w+)', line)
+        ref = re.search(r"loc\((#loc\d+)\)\s*$", line)
+        if op and ref is None:
+            open_regions.append((indent, op.group(1)))
+            continue
+        if op is None and ref and open_regions and open_regions[-1][0] == indent and line.lstrip().startswith("}"):
+            op_name = open_regions.pop()[1]
+        elif op and ref:
+            op_name = op.group(1)
+        else:
+            continue
+        if ref.group(1) in named:
+            found.append((op_name, named[ref.group(1)]))
+    return found
+
+
+@pytest.fixture(scope="module")
+def names():
+    return _operation_names(_lowered())
+
+
+@pytest.mark.parametrize(
+    "scope", ["embed", "blocks", "final_norm", "head_loss", "loss_chunks", "grad_clip", "optimizer"]
+)
+def test_phase_scope_is_in_the_lowered_step(names, scope):
+    assert any(scope in name.split("/") for _, name in names), scope
+
+
+def test_every_scan_and_the_cond_sit_in_a_scope_of_their_own(names):
+    whiles = sorted({name for op, name in names if op == "while"})
+    assert len(whiles) == 4  # the layer scan and the loss's chunk scan, forward and backward
+    for name in whiles:
+        assert ("/blocks/while" in name) != ("/head_loss/loss_chunks/while" in name), name
+    assert sum("transpose(" in name for name in whiles) == 2
+    conds = {name for op, name in names if op == "case"}
+    assert conds and all(name.endswith("optimizer/cond") for name in conds), conds
+
+
+def test_the_loss_backward_matmuls_are_under_head_loss(names):
+    # the backward rule of the chunked loss (a custom_vjp) is traced under the scopes of
+    # its call and opens its forward's own: its scan is head_loss/loss_chunks ...
+    assert any(
+        op == "while" and "transpose(" in name and name.endswith("head_loss/loss_chunks/while")
+        for op, name in names
+    )
+    # ... and inside the scan's body its matmuls carry the chunk's name, forward
+    # (`ce_chunk`), replayed (`jvp(ce_chunk)`) and backward (`transpose(jvp(ce_chunk))`)
+    dots = {name for op, name in names if op == "dot_general"}
+    assert {"ce_chunk/dot_general", "jvp(ce_chunk)/dot_general", "transpose(jvp(ce_chunk))/dot_general"} <= dots
+
+
+def test_no_matmul_is_left_without_an_owner(names):
+    dots = [name for op, name in names if op == "dot_general"]
+    assert len(dots) >= 8
+    bare = [n for n in dots if n in ("dot_general", "jvp()/dot_general", "transpose(jvp())/dot_general")]
+    assert not bare, bare
+    for name in dots:
+        assert "ce_chunk" in name or "h_scan" in name, name
+
+
+def test_accumulation_and_health_have_scopes_too():
+    names = _operation_names(_lowered(accumulation=2, collect_health=True))
+    whiles = {name for op, name in names if op == "while"}
+    assert any(name.endswith("accumulate/while") for name in whiles), whiles
+    assert any("health" in name.split("/") for _, name in names)
+
+
+def test_the_unrolled_model_carries_the_same_phases():
+    names = _operation_names(_lowered(scan_layers=False))
+    for scope in ("embed", "blocks", "final_norm", "head_loss", "optimizer"):
+        assert any(scope in name.split("/") for _, name in names), scope
+    dots = [name for op, name in names if op == "dot_general" and "ce_chunk" not in name]
+    assert dots and all("/blocks/" in name for name in dots), dots[:3]
+
+
+def test_scopes_are_debug_locations_only():
+    """Names live in debug locations alone: the lowering printed without them names no phase."""
+    text = _lowered().as_text()
+    assert "head_loss" not in text and "blocks" not in text

@@ -286,6 +286,31 @@ def summarize(records: list[dict]) -> str:
             )
         lines.append("")
 
+    # ---------------------------------------------------------------- an iteration's split
+    # step records written from a train loop carry the loop's spans (t.split, in the order
+    # they ran) and the iteration's wall time: the median of each part over the steady
+    # steps, and the slowest iteration with its own split — which part stalled
+    split_steps = [r["t"] for r in steps if "split" in r.get("t", {}) and "step" in r["t"]]
+    if split_steps:
+        names = list(dict.fromkeys(name for t in split_steps for name in t["split"]))
+        walls = sorted(t["wall"] for t in split_steps)
+        slowest = max(split_steps, key=lambda t: t["wall"])
+        lines.append(
+            f"| iteration split (ms) | median of {len(split_steps)} | slowest iteration "
+            f"({1e3 * slowest['wall']:.4g} ms) |"
+        )
+        lines.append("|---|---|---|")
+        for name in names:
+            values = sorted(t["split"].get(name, 0.0) for t in split_steps)
+            lines.append(
+                f"| {name} | {1e3 * percentile(values, 50):.4g} "
+                f"| {1e3 * slowest['split'].get(name, 0.0):.4g} |"
+            )
+        lines.append(
+            f"| (whole iteration) | {1e3 * percentile(walls, 50):.4g} | {1e3 * slowest['wall']:.4g} |"
+        )
+        lines.append("")
+
     # ---------------------------------------------------------------- goodput
     if windows:
         totals = {
