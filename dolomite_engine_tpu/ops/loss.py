@@ -16,11 +16,39 @@ Parity:
 from __future__ import annotations
 
 import functools
+import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec
 
 IGNORE_INDEX = -100
+
+
+def _ce_terms(
+    logits: jax.Array, labels: jax.Array, upcast: bool, want_z: bool, with_lse: bool = False
+) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array | None]:
+    """(loss_sum, z_sum, num_tokens, lse): `lse` is the per-token log-sum-exp in float32 —
+    all the chunked loss's backward rule keeps of the logits — or None unless `with_lse`."""
+    if upcast:
+        logits = logits.astype(jnp.float32)
+
+    mask = labels != IGNORE_INDEX
+    safe_labels = jnp.where(mask, labels, 0)
+
+    logprobs = jax.nn.log_softmax(logits, axis=-1)
+    token_logprobs = jnp.take_along_axis(logprobs, safe_labels[..., None], axis=-1)[..., 0]
+
+    loss_sum = -jnp.sum(jnp.where(mask, token_logprobs, 0.0))
+    num_tokens = jnp.sum(mask.astype(jnp.float32))
+    z_sum = jnp.zeros((), jnp.float32)
+    lse = None
+    if want_z or with_lse:
+        lse = jax.scipy.special.logsumexp(logits, axis=-1).astype(jnp.float32)
+    if want_z:
+        z_sum = jnp.sum(jnp.where(mask, jnp.square(lse), 0.0))
+    return loss_sum, z_sum, num_tokens, lse if with_lse else None
 
 
 def cross_entropy_terms(
@@ -36,22 +64,7 @@ def cross_entropy_terms(
     vocab axis otherwise). The single formula shared by the unchunked and chunked loss
     paths, so their parity is summation-order-only (1-2 float32 ulp).
     """
-    if upcast:
-        logits = logits.astype(jnp.float32)
-
-    mask = labels != IGNORE_INDEX
-    safe_labels = jnp.where(mask, labels, 0)
-
-    logprobs = jax.nn.log_softmax(logits, axis=-1)
-    token_logprobs = jnp.take_along_axis(logprobs, safe_labels[..., None], axis=-1)[..., 0]
-
-    loss_sum = -jnp.sum(jnp.where(mask, token_logprobs, 0.0))
-    num_tokens = jnp.sum(mask.astype(jnp.float32))
-    z_sum = jnp.zeros((), jnp.float32)
-    if want_z:
-        lse = jax.scipy.special.logsumexp(logits, axis=-1)
-        z_sum = jnp.sum(jnp.where(mask, jnp.square(lse.astype(jnp.float32)), 0.0))
-    return loss_sum, z_sum, num_tokens
+    return _ce_terms(logits, labels, upcast, want_z)[:3]
 
 
 def cross_entropy_loss(
@@ -121,6 +134,101 @@ def causal_lm_loss(
     return loss
 
 
+class LossTiling(NamedTuple):
+    """How the chunked loss's backward rule cuts the ``[tokens, vocab]`` logits it recomputes
+    (:func:`plan_loss_backward`). Static and hashable: it is resolved where the forward is
+    traced — a `custom_vjp`'s backward rule is traced later, outside the model's
+    logical-axis rules — and handed to the rule as a non-differentiable argument."""
+
+    token_blocks: int  # outer loop: blocks of whole forward chunks (every batch row of each)
+    vocab_tiles: int  # inner scan: tiles of the vocabulary
+    vocab_shards: int  # shards of "act_vocab"; a tile takes `tile_rows` rows of EVERY shard
+    tile_rows: int
+    batch_axes: tuple | str | None  # mesh axes of "act_batch" / "act_vocab" for the rule's
+    vocab_axes: tuple | str | None  # constraints; `constrain` False: no mesh, no constraint
+    constrain: bool
+
+    def logits_tile(self, batch: int, n_chunks: int, chunk: int) -> tuple[int, ...]:
+        """Shape of the logits the backward rule holds at a time (the forward holds
+        ``[batch, chunk, V]``): ``[chunks a block, batch, chunk, shards, tile_rows]``."""
+        return (n_chunks // self.token_blocks, batch, chunk, self.vocab_shards, self.tile_rows)
+
+
+def _divisors(n: int) -> list[int]:
+    return [d for d in range(1, n + 1) if n % d == 0]
+
+
+def plan_loss_backward(
+    batch: int, n_chunks: int, chunk: int, vocab: int, hidden_size: int
+) -> tuple[LossTiling, dict]:
+    """Choose the backward rule's tiling from the shapes the loss sees, and price it.
+
+    The rule has two gradients to accumulate: the table's ``[V, H]``, summed over tokens,
+    and the hidden states' ``[T, H]``, summed over the vocabulary. A loop over token blocks
+    carries the first, a loop over vocabulary tiles the second, and a carried float32
+    accumulator is read and written once an iteration. With the live logits held at the
+    forward's budget (``batch x chunk x V`` elements: ``token_blocks x vocab_tiles >=
+    n_chunks``) the float32 bytes a device moves for its accumulators are about
+
+        token_blocks x 2 x V_local x H x 4   +   vocab_tiles x 2 x T_local x H x 4
+
+    (once, not twice, for a loop of one iteration: nothing is carried). The plan is the
+    pair that makes this smallest over the divisors of ``n_chunks``; one packed row of 4096
+    tokens against a 49152-row table gives 1 x 16 (tiles of 3072 rows), four rows a device
+    with the table over tp 4 gives 4 x 4. Local sizes follow the ambient mesh and rules:
+    tokens are sharded as "act_batch", the vocabulary as "act_vocab", and a tile is cut
+    INSIDE each vocabulary shard's rows (never across shards: the partitioner would gather
+    the table every tile). A vocabulary the tile count does not divide is padded with rows
+    that the rule masks out of the softmax.
+
+    Returns the tiling and the record of it the telemetry event ``loss_tiling`` carries.
+    """
+    from ..parallel.sharding import logical_spec
+
+    batch_axes = vocab_axes = None
+    batch_shards = vocab_shards = 1
+    resolved = logical_spec((batch, vocab), ("act_batch", "act_vocab"))
+    if resolved is not None:
+        mesh, (batch_axes, vocab_axes) = resolved
+
+        def size(axes) -> int:
+            names = () if axes is None else (axes,) if isinstance(axes, str) else axes
+            return math.prod(mesh.shape[a] for a in names)
+
+        batch_shards, vocab_shards = size(batch_axes), size(vocab_axes)
+    tokens_local = batch * n_chunks * chunk // batch_shards
+    vocab_local = vocab // vocab_shards
+
+    def accumulator_elements(token_blocks: int) -> int:
+        vocab_tiles = min(-(-n_chunks // token_blocks), vocab_local)
+        table_trips = 1 if token_blocks == 1 else 2 * token_blocks
+        hidden_trips = 1 if vocab_tiles == 1 else 2 * vocab_tiles
+        return table_trips * vocab_local + hidden_trips * tokens_local
+
+    token_blocks = min(_divisors(n_chunks), key=accumulator_elements)
+    vocab_tiles = min(-(-n_chunks // token_blocks), vocab_local)
+    tile_rows = -(-vocab_local // vocab_tiles)
+    if vocab_local % vocab_tiles and tile_rows > 128:
+        tile_rows = -(-tile_rows // 128) * 128  # padded anyway: keep the tile lane-aligned
+        vocab_tiles = -(-vocab_local // tile_rows)
+    tiling = LossTiling(
+        token_blocks, vocab_tiles, vocab_shards, tile_rows, batch_axes, vocab_axes,
+        resolved is not None,
+    )
+    record = dict(
+        token_blocks=token_blocks,
+        vocab_tiles=vocab_tiles,
+        tile_rows=tile_rows,
+        vocab_shards=vocab_shards,
+        tokens_per_device=tokens_local,
+        # float32 bytes of the accumulators the rule's loops carry, a device
+        hidden_carry_bytes=4 * hidden_size * tokens_local // token_blocks if vocab_tiles > 1 else 0,
+        table_carry_bytes=4 * hidden_size * vocab_tiles * tile_rows if token_blocks > 1 else 0,
+        accumulator_bytes_moved=4 * hidden_size * accumulator_elements(token_blocks),
+    )
+    return tiling, record
+
+
 @jax.named_scope("ce_chunk")  # a scan body starts with no name of its own in a profile
 def _chunk_ce_terms(
     h: jax.Array,
@@ -130,22 +238,19 @@ def _chunk_ce_terms(
     upcast: bool,
     compute_dtype,
     want_z: bool,
-) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """One chunk's LM-head matmul + CE reduction, XLA reference lowering.
+) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+    """One chunk's LM-head matmul + CE reduction, XLA reference lowering: ``(loss_sum,
+    z_sum, num_tokens, lse)`` with ``lse`` the chunk's per-token log-sum-exp in float32.
 
-    The chunk's ``[B, chunk, V]`` logits exist only inside this function — forward AND
-    backward (the `_chunked_ce_terms` custom_vjp re-runs it under `jax.vjp` per chunk).
+    The chunk's ``[B, chunk, V]`` logits exist only inside this function.
     """
     from ..parallel.sharding import logical_constraint
 
-    # Pin the table to its ACTIVATION layout (vocab over tp only; replicated otherwise)
-    # INSIDE the per-chunk body so the backward replay sees it too. Under ZeRO-3 the tied
-    # table arrives fsdp-sharded along vocab; without this boundary the partitioner
-    # propagates that layout into the chunk's log_softmax backward where it collides
-    # with the batch-sharded logits constraint below — XLA then falls back to
-    # "involuntary full rematerialization" (full replication) of the logits-sized
-    # gradient. With it, the table is gathered at a clean boundary and grad_emb leaves
-    # as a reduce-scatter — exactly ZeRO-3's gather/compute/scatter contract.
+    # The table arrives pinned to its ACTIVATION layout (`fused_linear_cross_entropy`);
+    # pinned again INSIDE the per-chunk body: without this boundary the partitioner has
+    # propagated a ZeRO-3 table's fsdp-sharded vocabulary into the chunk's log_softmax,
+    # where it collides with the batch-sharded logits constraint below — XLA then falls
+    # back to "involuntary full rematerialization" (full replication) of the logits.
     table = logical_constraint(table, ("act_vocab", None))
     logits = jnp.dot(h.astype(compute_dtype), table.T)
     # keep the CE vocab-parallel ("act_vocab" -> tp) instead of all-gathering the table
@@ -155,10 +260,38 @@ def _chunk_ce_terms(
     logits = logical_constraint(logits, ("act_batch", None, "act_vocab"))
     if logit_scale is not None:
         logits = logits * logit_scale
-    return cross_entropy_terms(logits, y, upcast=upcast, want_z=want_z)
+    return _ce_terms(logits, y, upcast, want_z, with_lse=True)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _chunked_ce_forward(hidden_c, labels_c, table, logit_scale, upcast, compute_dtype, want_z):
+    """The forward scan over chunks: ``((loss_sum, z_sum, num_tokens), lse_c)`` with
+    ``lse_c [n_chunks, B, chunk]`` the float32 log-sum-exp of every token — all the
+    backward rule keeps of the logits."""
+    from ..ops.pallas import use_pallas
+
+    if use_pallas("fused_ce"):
+        from ..ops.pallas.fused_ce import fused_ce_chunk
+
+        def chunk_terms(h, y):
+            return fused_ce_chunk(
+                h, table, y, logit_scale=logit_scale, upcast=upcast,
+                compute_dtype=compute_dtype, return_lse=True,
+            )
+    else:
+
+        def chunk_terms(h, y):
+            return _chunk_ce_terms(h, table, y, logit_scale, upcast, compute_dtype, want_z)
+
+    def body(carry, xs):
+        loss_sum, z_sum, num, lse = chunk_terms(*xs)
+        return (carry[0] + loss_sum, carry[1] + z_sum, carry[2] + num), lse
+
+    zero = jnp.zeros((), jnp.float32)
+    with jax.named_scope("loss_chunks"):
+        return jax.lax.scan(body, (zero, zero, zero), (hidden_c, labels_c))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def _chunked_ce_terms(
     hidden_c: jax.Array,  # [n_chunks, B, chunk, H]
     labels_c: jax.Array,  # [n_chunks, B, chunk]
@@ -167,80 +300,175 @@ def _chunked_ce_terms(
     upcast: bool,
     compute_dtype,
     want_z: bool,
+    tiling: LossTiling,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """(loss_sum, z_sum, num_tokens) over all chunks; at most one chunk's logits live.
 
-    The ``fused_ce`` kernel family dispatches here: with the family on Pallas the
-    per-chunk reduction runs `ops/pallas/fused_ce.fused_ce_chunk` (vocab-tiled online
-    logsumexp — the chunk logits never leave VMEM); the XLA reference scans
-    `_chunk_ce_terms`. The custom_vjp below makes BOTH backwards the same per-chunk
-    recompute + autodiff of the reference body, so gradients cannot depend on the
-    forward backend.
+    Forward: a scan over sequence chunks (`_chunked_ce_forward`). The ``fused_ce`` kernel
+    family dispatches there: on Pallas the per-chunk reduction runs
+    `ops/pallas/fused_ce.fused_ce_chunk` (vocab-tiled online logsumexp — the chunk logits
+    never leave VMEM); the XLA reference scans `_chunk_ce_terms`.
+
+    Backward: ONE rule for both backends (`_chunked_ce_terms_bwd`), so gradients depend on
+    the forward's backend only through the log-sum-exp it saved (1-2 float32 ulp apart).
+    Residuals are ``(hidden, labels, table, lse)``: the inputs plus one float per token,
+    nothing logits-sized. The rule recomputes the logits a tile at a time, forms
+    ``softmax - onehot`` from the saved log-sum-exp (no second reduction over the
+    vocabulary) and runs the two gradient matmuls. Which accumulator its loops carry is
+    `tiling`'s choice (:func:`plan_loss_backward`): the inner scan runs over vocabulary
+    tiles and carries the hidden states' gradient of one token block — each tile's table
+    gradient leaves one matmul that contracts over the block's every token, accumulated in
+    float32 inside the MXU as the unchunked reference's is, and is written once (the
+    scan's `ys`); only where one block may not hold all tokens does an outer loop over
+    token blocks carry the table's float32 gradient, once a block. (Before PR 25 the rule
+    scanned the forward's chunks and carried the whole float32 ``[V, H]`` gradient through
+    HBM once per `chunk` tokens: 1 GB a chunk at V 49152, H 2560.)
     """
-    from ..ops.pallas import use_pallas
-
-    if use_pallas("fused_ce"):
-        from ..ops.pallas.fused_ce import fused_ce_chunk
-
-        def body(carry, xs):
-            h, y = xs
-            loss_sum, z_sum, num = fused_ce_chunk(
-                h, table, y, logit_scale=logit_scale, upcast=upcast,
-                compute_dtype=compute_dtype,
-            )
-            return (carry[0] + loss_sum, carry[1] + z_sum, carry[2] + num), None
-    else:
-
-        def body(carry, xs):
-            h, y = xs
-            loss_sum, z_sum, num = _chunk_ce_terms(
-                h, table, y, logit_scale, upcast, compute_dtype, want_z
-            )
-            return (carry[0] + loss_sum, carry[1] + z_sum, carry[2] + num), None
-
-    zero = jnp.zeros((), jnp.float32)
-    with jax.named_scope("loss_chunks"):
-        (loss_sum, z_sum, num_tokens), _ = jax.lax.scan(
-            body, (zero, zero, zero), (hidden_c, labels_c)
-        )
-    return loss_sum, z_sum, num_tokens
+    return _chunked_ce_forward(
+        hidden_c, labels_c, table, logit_scale, upcast, compute_dtype, want_z
+    )[0]
 
 
-def _chunked_ce_terms_fwd(hidden_c, labels_c, table, logit_scale, upcast, compute_dtype, want_z):
-    out = _chunked_ce_terms(
+def _chunked_ce_terms_fwd(
+    hidden_c, labels_c, table, logit_scale, upcast, compute_dtype, want_z, tiling
+):
+    terms, lse_c = _chunked_ce_forward(
         hidden_c, labels_c, table, logit_scale, upcast, compute_dtype, want_z
     )
-    # residuals are exactly the inputs — O(B*S*H + V*H), nothing logits-sized is saved
-    return out, (hidden_c, labels_c, table)
+    # O(B*S*H + V*H + B*S): nothing logits-sized is saved
+    return terms, (hidden_c, labels_c, table, lse_c)
 
 
-def _chunked_ce_terms_bwd(logit_scale, upcast, compute_dtype, want_z, residuals, cts):
-    hidden_c, labels_c, table = residuals
-
-    def body(dtable_acc, xs):
-        h, y = xs
-        terms, chunk_vjp = jax.vjp(
-            lambda h_, t_: _chunk_ce_terms(
-                h_, t_, y, logit_scale, upcast, compute_dtype, want_z
-            ),
-            h,
-            table,
+@jax.named_scope("ce_tile")  # the backward scan's body: one vocabulary tile of one token block
+def _tile_grads(
+    h: jax.Array,  # [c, B, chunk, H] compute dtype: a token block (c forward chunks)
+    y: jax.Array,  # [c, B, chunk]
+    lse: jax.Array,  # [c, B, chunk] float32, saved by the forward
+    softmax_coef: jax.Array,  # [c, B, chunk]: cotangent of a token's softmax row ...
+    label_coef: jax.Array,  # ... and of its label's logit; both 0 on IGNORE_INDEX rows
+    w: jax.Array,  # [shards, tile_rows, H] compute dtype: the tile's rows of every shard
+    vocab_ids: jax.Array,  # [shards, tile_rows] int32; -1 on padded rows
+    logit_scale: float | None,
+    upcast: bool,
+    constrain,
+) -> tuple[jax.Array, jax.Array]:
+    """One tile's ``(d hidden [shards, c, B, chunk, H]`` — a partial sum a vocabulary shard —
+    ``, d tile [shards, tile_rows, H])``, both float32: the logits recomputed as the
+    forward computed them, ``d logits = softmax_coef x softmax - label_coef x onehot`` in
+    the precision `upcast` states, and the two gradient matmuls on compute-dtype operands
+    with float32 accumulation."""
+    with jax.named_scope("logits"):
+        logits = jax.lax.dot_general(h, w, (((3,), (2,)), ((), ())))  # [c, B, chunk, g, v]
+    logits = constrain(logits, "logits")
+    if logit_scale is not None:
+        logits = logits * logit_scale
+    if upcast:
+        logits = logits.astype(jnp.float32)
+    col = (..., None, None)
+    lse, softmax_coef, label_coef = (
+        x.astype(logits.dtype)[col] for x in (lse, softmax_coef, label_coef)
+    )
+    dlogits = jnp.exp(logits - lse) * softmax_coef - jnp.where(vocab_ids == y[col], label_coef, 0)
+    dlogits = jnp.where(vocab_ids >= 0, dlogits, 0).astype(h.dtype)
+    if logit_scale is not None:
+        dlogits = dlogits * logit_scale
+    dlogits = constrain(dlogits, "logits")
+    with jax.named_scope("grad_hidden"):
+        # the vocabulary shard is a BATCH dimension: each shard keeps the partial sum over
+        # its own rows, and the shards are summed once, after the scan — contracted here,
+        # every tile would end in an all-reduce of the block's d hidden over tp
+        dh = jax.lax.dot_general(
+            dlogits, w, (((4,), (1,)), ((3,), (0,))), preferred_element_type=jnp.float32
+        )  # [g, c, B, chunk, H]
+    with jax.named_scope("grad_table"):
+        dw = jax.lax.dot_general(
+            dlogits, h, (((0, 1, 2), (0, 1, 2)), ((), ())), preferred_element_type=jnp.float32
         )
-        # the scan carry sums the chunk terms in fp32, but without `upcast` a bf16 chunk
-        # emits bf16 terms — and jax.vjp takes cotangents only in its outputs' own dtype
-        dh, dt = chunk_vjp(tuple(ct.astype(term.dtype) for ct, term in zip(cts, terms)))
-        # fp32 accumulation across chunks regardless of the table's compute dtype (the
-        # unchunked reference accumulates its table grad inside one fp32 matmul)
-        return dtable_acc + dt.astype(jnp.float32), dh
+    return constrain(dh, "hidden"), constrain(dw, "tile")
+
+
+def _chunked_ce_terms_bwd(logit_scale, upcast, compute_dtype, want_z, tiling, residuals, cts):
+    hidden_c, labels_c, table, lse_c = residuals
+    n_chunks, _, _, hidden_size = hidden_c.shape
+    blocks, tiles = tiling.token_blocks, tiling.vocab_tiles
+    shards, rows = tiling.vocab_shards, tiling.tile_rows
+    vocab_local = table.shape[0] // shards
+
+    batch_axes, vocab_axes = tiling.batch_axes, tiling.vocab_axes
+    specs = {
+        "hidden": PartitionSpec(vocab_axes, None, batch_axes, None, None),
+        "logits": PartitionSpec(None, batch_axes, None, vocab_axes, None),
+        "tile": PartitionSpec(vocab_axes, None, None),
+        "tiles": PartitionSpec(None, vocab_axes, None, None),
+        "table": PartitionSpec(vocab_axes, None),
+    }
+
+    def constrain(x, what):
+        return jax.lax.with_sharding_constraint(x, specs[what]) if tiling.constrain else x
+
+    # the table as the forward pinned it, in its ACTIVATION layout (`fused_linear_cross_
+    # entropy`: the gather is the forward's), as [tiles, shards, rows, H]: tile j holds
+    # rows [j*rows, (j+1)*rows) of EVERY vocabulary shard, so a tile is cut inside each shard
+    w = constrain(table, "table").reshape(shards, vocab_local, hidden_size)
+    w = jnp.pad(w, ((0, 0), (0, tiles * rows - vocab_local), (0, 0)))
+    w_tiles = constrain(jnp.moveaxis(w.reshape(shards, tiles, rows, hidden_size), 1, 0), "tiles")
+    row = jnp.arange(rows, dtype=jnp.int32)
+    shard_start = vocab_local * jnp.arange(shards, dtype=jnp.int32)[:, None]
+
+    # d(loss_sum)/d(logits) = softmax - onehot and d(z_sum)/d(logits) = 2 lse softmax on
+    # valid rows (z_sum = sum lse^2); num_tokens has no gradient. Without `upcast` the
+    # forward's terms are compute-dtype: the coefficients are cast where they are used
+    ct_loss, ct_z = (ct.astype(jnp.float32) for ct in cts[:2])
+    valid = labels_c != IGNORE_INDEX
+    label_coef = jnp.where(valid, ct_loss, 0.0)
+    softmax_coef = label_coef
+    if want_z:
+        softmax_coef = jnp.where(valid, ct_loss + 2.0 * ct_z * lse_c, 0.0)
+
+    def block_grads(h, y, lse, s_coef, l_coef):
+        """One token block: scan the vocabulary tiles, carrying the block's d hidden."""
+
+        def body(dh_acc, xs):
+            j, w_tile = xs
+            local = j * rows + row  # row index inside a shard; >= vocab_local: padding
+            vocab_ids = jnp.where(local < vocab_local, shard_start + local, -1)
+            dh, dw = _tile_grads(
+                h, y, lse, s_coef, l_coef, constrain(w_tile, "tile"), vocab_ids,
+                logit_scale, upcast, constrain,
+            )
+            return dh_acc + dh, dw
+
+        dh, dw_tiles = jax.lax.scan(
+            body,
+            constrain(jnp.zeros((shards, *h.shape), jnp.float32), "hidden"),
+            (jnp.arange(tiles, dtype=jnp.int32), w_tiles),
+        )
+        return dh.sum(axis=0).astype(hidden_c.dtype), dw_tiles
 
     # a custom_vjp's backward rule is traced under the scopes of the call (the model's
     # `head_loss`) but not under those its forward opened: it opens the forward's own, so
     # that a profile tells the two scans apart by JAX's `transpose(...)` wrapper alone
     with jax.named_scope("loss_chunks"):
-        dtable, dhidden_c = jax.lax.scan(
-            body, jnp.zeros(table.shape, jnp.float32), (hidden_c, labels_c)
-        )
-        return dhidden_c, None, dtable.astype(table.dtype)
+        per_block = [
+            x.reshape(blocks, n_chunks // blocks, *x.shape[1:])
+            for x in (hidden_c, labels_c, lse_c, softmax_coef, label_coef)
+        ]
+        if blocks == 1:
+            dh, dw_tiles = block_grads(*(x[0] for x in per_block))
+        else:
+            # more tokens than one block may hold: the table's float32 gradient is carried,
+            # once a block instead of once a chunk
+            def outer(dw_acc, xs):
+                dh, dw_tiles = block_grads(*xs)
+                return dw_acc + dw_tiles, dh
+
+            with jax.named_scope("token_blocks"):
+                dw_tiles, dh = jax.lax.scan(
+                    outer, constrain(jnp.zeros(w_tiles.shape, jnp.float32), "tiles"), per_block
+                )
+        dtable = jnp.moveaxis(dw_tiles, 0, 1).reshape(shards, tiles * rows, hidden_size)
+        dtable = dtable[:, :vocab_local].reshape(table.shape).astype(table.dtype)
+        return dh.reshape(hidden_c.shape), None, constrain(dtable, "table")
 
 
 _chunked_ce_terms.defvjp(_chunked_ce_terms_fwd, _chunked_ce_terms_bwd)
@@ -259,26 +487,37 @@ def fused_linear_cross_entropy(
 ) -> jax.Array:
     """LM-head matmul + CE without ever materializing the [B, S, V] logits.
 
-    The sequence axis is cut into chunks of `chunk_size`; a `lax.scan` computes each
-    chunk's logits ([B, chunk, V]), reduces them to (loss_sum, z_sum, count), and
-    discards them. The whole reduction sits behind a `custom_vjp` whose residuals are
-    just (hidden, labels, table): backward re-runs each chunk's forward under `jax.vjp`
-    and accumulates the table grad in fp32, so peak logits memory is O(chunk) in both
-    directions. Peak logits memory drops S/chunk_size-fold (at seq 2048 / vocab 50k the
-    full tensor is the single largest allocation in a train step). The reference has no
-    counterpart (it materializes logits and calls F.cross_entropy,
+    Forward: the sequence axis is cut into chunks of `chunk_size`; a `lax.scan` computes
+    each chunk's logits ([B, chunk, V]), reduces them to (loss_sum, z_sum, count) and the
+    tokens' log-sum-exp, and discards them. The whole reduction sits behind a `custom_vjp`
+    whose residuals are (hidden, labels, table) and that one float per token.
+
+    Backward: the rule recomputes logits under the same budget — `chunk_size` x V elements
+    a batch row — but cuts them the other way where that is cheaper: all tokens (or a
+    block of them) against a TILE of the vocabulary, so that the loop carries the hidden
+    states' float32 gradient ([tokens, H]) instead of the table's ([V, H], read and
+    written once an iteration). The tiling follows the shapes — tokens and vocabulary rows
+    a device holds, `chunk_size` — through :func:`plan_loss_backward`; there is no knob.
+    When a telemetry is installed the choice is written once as a ``loss_tiling`` event.
+
+    Peak logits memory is O(chunk) in both directions and drops S/chunk_size-fold (at seq
+    2048 / vocab 50k the full tensor is the single largest allocation in a train step).
+    The reference has no counterpart (it materializes logits and calls F.cross_entropy,
     `model_wrapper/pretraining.py:89-127`); this is the TPU/HBM-side answer to that cost
     — the same move as Liger-kernel's chunked fused CE on GPU.
 
-    With the ``fused_ce`` kernel family on Pallas the per-chunk reduction additionally
-    runs as a vocab-tiled online-logsumexp kernel (`ops/pallas/fused_ce.py`) whose
-    logits tiles never leave VMEM; gradients are backend-independent by construction
-    (see `_chunked_ce_terms`).
+    With the ``fused_ce`` kernel family on Pallas the per-chunk forward reduction
+    additionally runs as a vocab-tiled online-logsumexp kernel (`ops/pallas/fused_ce.py`)
+    whose logits tiles never leave VMEM; the backward rule is the same for both (see
+    `_chunked_ce_terms`).
 
     hidden: [B, S, H]; embedding: [V, H] (tied-embedding layout); labels: [B, S] with
     IGNORE_INDEX. Chunking is along sequence, so dp/fsdp/ep batch sharding is untouched.
     ``z_loss_coef`` adds ``coef * mean(logsumexp^2)`` exactly like `causal_lm_loss`.
     """
+    from ..parallel.sharding import logical_constraint
+    from ..utils.telemetry import get_telemetry
+
     B, S, H = hidden.shape
     chunk_size = min(chunk_size, S)
     if S % chunk_size != 0:
@@ -293,9 +532,16 @@ def fused_linear_cross_entropy(
     hidden_c = hidden.reshape(B, n_chunks, chunk_size, H).swapaxes(0, 1)
     labels_c = labels.reshape(B, n_chunks, chunk_size).swapaxes(0, 1)
 
-    emb = embedding.astype(compute_dtype)
+    # Pin the table to its ACTIVATION layout (vocab over tp only; replicated otherwise)
+    # once, here: under ZeRO-3 the tied table arrives fsdp-sharded, and both scans and the
+    # backward rule (whose residual this is: it gathers nothing again) compute with the
+    # gathered one — ZeRO-3's gather/compute/scatter contract, the scatter being the
+    # transpose of this constraint.
+    emb = logical_constraint(embedding.astype(compute_dtype), ("act_vocab", None))
+    tiling, record = plan_loss_backward(B, n_chunks, chunk_size, emb.shape[0], H)
+    get_telemetry().event_once("loss_tiling", **record)
     loss_sum, z_sum, num_tokens = _chunked_ce_terms(
-        hidden_c, labels_c, emb, logit_scale, upcast, compute_dtype, z_loss_coef != 0.0
+        hidden_c, labels_c, emb, logit_scale, upcast, compute_dtype, z_loss_coef != 0.0, tiling
     )
     denom = jnp.maximum(num_tokens, 1.0)
     loss = loss_sum / denom

@@ -13,9 +13,10 @@ Numerics: the tile matmul accumulates fp32 (``preferred_element_type``); a non-f
 ``compute_dtype`` is round-tripped through that dtype after the dot so the tile sees the
 same quantized logits as the XLA reference's ``compute_dtype`` matmul. The online
 max/sum recurrence reassociates the reduction, so parity vs the reference is 1-2 float32
-ulp (asserted in tier-1), not bitwise. Gradients never touch this kernel: the chunked
-loss's `custom_vjp` backward recomputes through the XLA reference body regardless of the
-forward backend (`ops/loss._chunked_ce_terms`).
+ulp (asserted in tier-1), not bitwise. Gradients never run this kernel: the chunked
+loss's `custom_vjp` has one backward rule for both forward backends, which takes from the
+forward only the per-token log-sum-exp this kernel also returns
+(`ops/loss._chunked_ce_terms`).
 """
 
 from __future__ import annotations
@@ -176,8 +177,11 @@ def fused_ce_chunk(
     upcast: bool,
     compute_dtype,
     interpret: bool | None = None,
-) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """One chunk's (loss_sum, z_sum, num_tokens) via the vocab-tiled kernel.
+    return_lse: bool = False,
+) -> tuple:
+    """One chunk's (loss_sum, z_sum, num_tokens) via the vocab-tiled kernel and,
+    `return_lse`, the tokens' float32 log-sum-exp ``[B, chunk]`` as a fourth (the
+    residual the chunked loss's backward rule forms its softmax from).
 
     Drop-in for `ops/loss._chunk_ce_terms` on the forward pass: h [B, chunk, H],
     table [V, H], y [B, chunk]. The kernel always reduces in fp32, which matches the
@@ -200,4 +204,4 @@ def fused_ce_chunk(
     loss_sum = jnp.sum(jnp.where(mask, lse - lab, 0.0))
     z_sum = jnp.sum(jnp.where(mask, jnp.square(lse), 0.0))
     num = jnp.sum(mask.astype(jnp.float32))
-    return loss_sum, z_sum, num
+    return (loss_sum, z_sum, num) + ((lse.reshape(y.shape),) if return_lse else ())

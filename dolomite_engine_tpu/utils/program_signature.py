@@ -154,6 +154,29 @@ def hlo_tpu_kernels(compiled_text: str) -> dict[str, int]:
     return dict(sorted(counts.items()))
 
 
+def hlo_collectives(compiled_text: str) -> list[tuple[str, tuple[int, ...], bool]]:
+    """``[(collective, result dims, inside a while body?)]`` of a compiled program's text:
+    its all-gathers, all-reduces, reduce-scatters and all-to-alls (async ``-start`` forms
+    included; a tuple-shaped result reports its first member). The flag says whether the
+    instruction sits in a computation some ``while`` names as its body — a collective a
+    loop repeats every trip."""
+    bodies = set(re.findall(r"body=%?([\w.\-]+)", compiled_text))
+    found: list[tuple[str, tuple[int, ...], bool]] = []
+    computation = None
+    for line in compiled_text.splitlines():
+        header = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
+        if header:
+            computation = header.group(1)
+        op = re.search(
+            r"= \(?\w+\[([\d,]*)\]\S* (all-gather|all-reduce|reduce-scatter|all-to-all)(?:-start)?\(",
+            line,
+        )
+        if op:
+            dims = tuple(int(d) for d in op.group(1).split(",") if d)
+            found.append((op.group(2), dims, computation in bodies))
+    return found
+
+
 def _count_donated_inputs(lowered_text: str) -> int:
     """Donated inputs, from the lowering's argument attributes: ``tf.aliasing_output``
     marks an input aliased onto an output, ``jax.buffer_donor`` a donation the aliaser

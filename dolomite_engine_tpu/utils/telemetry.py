@@ -294,6 +294,10 @@ KNOWN_EVENTS: tuple[str, ...] = (
     "anomaly",
     # one XLA backend compilation: seconds, program (the jitted function's name), step
     "compile",
+    # the tiling the chunked loss's backward rule chose where the step was traced
+    # (ops/loss.plan_loss_backward): token_blocks x vocab_tiles, tile_rows, vocab_shards,
+    # tokens_per_device, and the float32 bytes of the accumulators its loops carry
+    "loss_tiling",
     # serving-fleet fault tolerance (serving/cluster/health.py + router.py): one event
     # per downward health edge, per completed drain/rejoin, and when a threaded
     # Router.wait timed out with work still pending (fields name who/why)
@@ -693,6 +697,7 @@ class Telemetry:
         self._span_depth = 0
         self._split: dict[str, float] = {}
         self._step_in_flight = 0  # the newest dispatched step: where a compile event fell
+        self._events_once: set = set()  # what `event_once` has written
 
         self._file = None
         if sink_path is not None:
@@ -806,6 +811,16 @@ class Telemetry:
             record["step"] = step
         record.update(fields)
         self._emit(record)
+
+    def event_once(self, name: str, **fields) -> None:
+        """:meth:`event`, unless this instance already wrote the same one: for facts of a
+        trace (a program may be traced more than once: shape evaluation, remat, re-jit)."""
+        key = (name, tuple(sorted(fields.items())))
+        with self._lock:
+            if key in self._events_once:
+                return
+            self._events_once.add(key)
+        self.event(name, **fields)
 
     def emit_record(self, kind: str, step: int | None = None, **fields) -> None:
         """Write a record of an additional declared kind (``health``, ``model_report`` —
@@ -984,6 +999,9 @@ class _NullTelemetry:
         pass
 
     def event(self, name, step=None, **fields) -> None:
+        pass
+
+    def event_once(self, name, **fields) -> None:
         pass
 
     def emit_record(self, kind, step=None, **fields) -> None:

@@ -172,26 +172,31 @@ def test_power_scheduler():
     assert float(f(50)) <= 1.0
 
 
-def test_fused_linear_cross_entropy_matches_plain():
+@pytest.mark.parametrize(
+    "B, S, V, chunk",
+    [(2, 8, 32, 4), (2, 8, 33, 4), (4, 16, 8, 2), (1, 10, 32, 5)],
+    ids=["tiles", "padded_vocab", "token_blocks", "one_row"],
+)
+def test_fused_linear_cross_entropy_matches_plain(B, S, V, chunk):
     """Fused chunked LM-head loss == materialized logits path, values AND grads."""
     import numpy as np
 
     from dolomite_engine_tpu.ops.loss import IGNORE_INDEX, fused_linear_cross_entropy
 
     rng = np.random.RandomState(0)
-    B, S, H, V, chunk = 2, 8, 16, 32, 4
+    H = 16
     hidden = jnp.asarray(rng.randn(B, S, H), jnp.float32)
     emb = jnp.asarray(rng.randn(V, H) * 0.02, jnp.float32)
     labels = jnp.asarray(rng.randint(0, V, size=(B, S)), jnp.int32)
-    labels = labels.at[0, -1].set(IGNORE_INDEX).at[1, 0].set(IGNORE_INDEX)
+    labels = labels.at[0, -1].set(IGNORE_INDEX).at[-1, 0].set(IGNORE_INDEX)
 
     def plain(h, e):
         logits = jnp.dot(h, e.T)
         return causal_lm_loss(logits, jnp.zeros((B, S), jnp.int32), labels=labels)
 
-    def fused(h, e):
+    def fused(h, e, chunk_size=chunk):
         return fused_linear_cross_entropy(
-            h, e, labels, chunk_size=chunk, compute_dtype=jnp.float32
+            h, e, labels, chunk_size=chunk_size, compute_dtype=jnp.float32
         )
 
     lp, (ghp, gep) = jax.value_and_grad(plain, argnums=(0, 1))(hidden, emb)
@@ -201,8 +206,42 @@ def test_fused_linear_cross_entropy_matches_plain():
     np.testing.assert_allclose(gep, gef, rtol=1e-5, atol=1e-6)
 
     # non-divisible seq pads up to a chunk multiple with IGNORE labels, still exact
-    lf2 = fused_linear_cross_entropy(hidden, emb, labels, chunk_size=5, compute_dtype=jnp.float32)
+    lf2, (ghf2, gef2) = jax.value_and_grad(
+        lambda h, e: fused(h, e, chunk_size=chunk + 1), argnums=(0, 1)
+    )(hidden, emb)
     np.testing.assert_allclose(lp, lf2, rtol=1e-6)
+    np.testing.assert_allclose(ghp, ghf2, rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(gep, gef2, rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize(
+    "batch, n_chunks, chunk, vocab, expected",
+    [
+        (1, 16, 256, 49152, (1, 16, 3072)),  # the benchmark's cells: one packed row of 4096
+        (4, 16, 256, 49152, (2, 8, 6144)),  # the shipped job's micro batch on one device
+        (1, 128, 256, 49152, (8, 16, 3072)),  # one row of 32768 tokens: long context
+        (1, 16, 256, 50257, (1, 16, 3200)),  # a prime vocabulary: padded, lane-aligned tiles
+        (2, 4, 7, 211, (1, 4, 53)),
+        (8, 1, 64, 50257, (1, 1, 50257)),  # one chunk: one block, one tile, no loop carries
+    ],
+)
+def test_plan_loss_backward_follows_the_shapes(batch, n_chunks, chunk, vocab, expected):
+    """The backward rule's tiling is chosen from the shapes alone: live logits stay at the
+    forward's budget, and the float32 bytes its loops carry never exceed those of the
+    corner it used to sit in (a token block a chunk, one tile: the whole table gradient
+    through HBM once a chunk)."""
+    from dolomite_engine_tpu.ops.loss import plan_loss_backward
+
+    hidden_size = 64
+    tiling, record = plan_loss_backward(batch, n_chunks, chunk, vocab, hidden_size)
+    assert (tiling.token_blocks, tiling.vocab_tiles, tiling.tile_rows) == expected
+    assert not tiling.constrain and tiling.vocab_shards == 1  # no mesh here
+    assert n_chunks % tiling.token_blocks == 0
+    assert tiling.token_blocks * tiling.vocab_tiles >= n_chunks  # the live-logits budget
+    assert vocab <= tiling.vocab_tiles * tiling.tile_rows < vocab + 128 * tiling.vocab_tiles
+    old_corner = 4 * hidden_size * (2 * n_chunks * vocab + batch * n_chunks * chunk)
+    assert record["accumulator_bytes_moved"] <= (old_corner if n_chunks > 1 else old_corner + 4 * hidden_size * vocab)
+    assert record["table_carry_bytes"] == (0 if tiling.token_blocks == 1 else 4 * hidden_size * tiling.vocab_tiles * tiling.tile_rows)
 
 
 def test_fused_lm_head_loss_model_parity():

@@ -916,37 +916,73 @@ def _ce_fixtures(seed=0, B=2, S=24, H=32, V=211):
     return hidden, table, labels
 
 
-@pytest.mark.parametrize("z_coef", [0.0, 1e-3])
-def test_fused_ce_chunked_matches_unchunked_to_ulp(z_coef):
-    """Acceptance: chunked-vs-unchunked loss AND grads within 1-2 float32 ulp, with
-    the per-chunk reduction on the XLA reference and on the fused_ce kernel."""
-    from dolomite_engine_tpu.ops.loss import causal_lm_loss, fused_linear_cross_entropy
+# (B, S, V, chunk) -> the (token_blocks, vocab_tiles) `plan_loss_backward` makes of them:
+# the shapes that choose different loop orders for the backward rule
+_CE_TILINGS = {
+    "few_tokens_one_block_padded_vocab": ((2, 24, 211, 7), (1, 4)),  # T 56 < V 211 = 4 x 53 - 1
+    "blocks_and_tiles": ((2, 64, 96, 8), (4, 2)),  # T 128 > V 96
+    "blocks_and_tiles_padded_vocab": ((2, 64, 97, 8), (4, 2)),  # ... and 97 = 2 x 49 - 1
+    "many_tokens_one_tile": ((4, 64, 32, 8), (8, 1)),  # T 256 >> V 32: the table is carried
+}
+# what the configuration may state besides the shapes; fp32 rows keep the ulp tolerances
+_CE_NUMERICS = {
+    "fp32": dict(),
+    "fp32_logit_scale": dict(logit_scale=0.125),
+    "bf16_logit_scale": dict(compute_dtype=jnp.bfloat16, logit_scale=0.5),
+    "bf16_no_upcast": dict(compute_dtype=jnp.bfloat16, upcast=False),
+}
 
-    hidden, table, labels = _ce_fixtures()
-    B, S, _ = hidden.shape
+
+@pytest.mark.parametrize("numerics", list(_CE_NUMERICS))
+@pytest.mark.parametrize("z_coef", [0.0, 1e-3])
+@pytest.mark.parametrize("tiling", list(_CE_TILINGS))
+def test_fused_ce_chunked_matches_unchunked_to_ulp(tiling, z_coef, numerics):
+    """Acceptance: chunked-vs-unchunked loss AND grads within 1-2 float32 ulp, with the
+    per-chunk reduction on the XLA reference and on the fused_ce kernel — over the shapes
+    that make the backward rule choose each loop order (one token block or several, one
+    vocabulary tile or several, a vocabulary no tile count divides), with IGNORE_INDEX
+    rows, z-loss on and off, `logit_scale`, bf16 operands and `upcast` both ways (the bf16
+    rows within 2 bf16 ulp of the gradient's largest entry: the unchunked reference
+    rounds its own gradients to bf16)."""
+    from dolomite_engine_tpu.ops.loss import (
+        causal_lm_loss, fused_linear_cross_entropy, plan_loss_backward,
+    )
+
+    (B, S, V, chunk), expected = _CE_TILINGS[tiling]
+    options = dict(compute_dtype=jnp.float32, logit_scale=None, upcast=True)
+    options.update(_CE_NUMERICS[numerics])
+    dtype, scale, upcast = options["compute_dtype"], options["logit_scale"], options["upcast"]
+    hidden, table, labels = _ce_fixtures(B=B, S=S, V=V)
+    plan = plan_loss_backward(B, -(-S // chunk), chunk, V, hidden.shape[-1])[0]
+    assert (plan.token_blocks, plan.vocab_tiles) == expected
 
     def unchunked(h, t):
-        logits = jnp.dot(h, t.T)
+        logits = jnp.dot(h.astype(dtype), t.astype(dtype).T)
+        if scale is not None:
+            logits = logits * scale
         return causal_lm_loss(
-            logits, jnp.zeros((B, S), jnp.int32), labels=labels, z_loss_coef=z_coef
+            logits, jnp.zeros((B, S), jnp.int32), labels=labels, z_loss_coef=z_coef,
+            upcast=upcast,
         )
 
     def chunked(h, t):
         return fused_linear_cross_entropy(
-            h, t, labels, chunk_size=7, compute_dtype=jnp.float32, z_loss_coef=z_coef
+            h, t, labels, chunk_size=chunk, z_loss_coef=z_coef, **options
         )
 
     ref_loss, ref_grads = jax.value_and_grad(unchunked, argnums=(0, 1))(hidden, table)
+    fp32 = dtype == jnp.float32
     for backend in ("xla", "pallas"):
         with kernel_overrides(fused_ce=backend):
             loss, grads = jax.value_and_grad(chunked, argnums=(0, 1))(hidden, table)
-        # loss: summation-order only -> 1-2 fp32 ulp around ~5.3
-        np.testing.assert_allclose(float(loss), float(ref_loss), rtol=0, atol=2e-6)
+        # loss: summation-order only -> 1-2 fp32 ulp around ~5.3 (bf16 logits without
+        # upcast: the unchunked loss itself is a bf16 sum)
+        loss_atol = 2e-6 if upcast else 0.1
+        np.testing.assert_allclose(float(loss), float(ref_loss), rtol=0, atol=loss_atol)
         for g, r in zip(grads, ref_grads):
             # same atol style as the remat-policy matrix: ~1 fp32 ulp at magnitude 1
-            np.testing.assert_allclose(
-                np.asarray(g), np.asarray(r), rtol=0, atol=1.2e-7
-            )
+            atol = 1.2e-7 if fp32 else 2 * 2.0**-7 * float(jnp.max(jnp.abs(r)))
+            np.testing.assert_allclose(np.asarray(g), np.asarray(r), rtol=0, atol=atol)
 
 
 def test_fused_ce_kernel_rowwise_terms():
@@ -1046,20 +1082,50 @@ def test_fused_ce_moe_aux_loss_combination():
         np.testing.assert_allclose(float(out.loss), float(out_ref.loss), rtol=0, atol=2e-6)
 
 
-def test_fused_ce_peak_logits_memory_is_o_chunk():
+def _loop_carries(jaxpr) -> list:
+    """[(primitive, trip count or None, [carry avals])] of every scan / while in `jaxpr`,
+    nested ones included."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            first = eqn.params["num_consts"]
+            carry = eqn.invars[first : first + eqn.params["num_carry"]]
+            found.append(("scan", eqn.params["length"], [v.aval for v in carry]))
+        elif eqn.primitive.name == "while":
+            first = eqn.params["cond_nconsts"] + eqn.params["body_nconsts"]
+            found.append(("while", None, [v.aval for v in eqn.invars[first:]]))
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    found.extend(_loop_carries(inner))
+    return found
+
+
+@pytest.mark.parametrize("V, blocks", [(1999, 1), (199, 2)], ids=["one_block", "two_blocks"])
+def test_fused_ce_peak_logits_memory_is_o_chunk(V, blocks):
     """Acceptance: the chunked lowering never materializes a [B*S, V]-sized logits
     buffer — asserted through the shared perf-signature HLO-feature API
     (utils/program_signature.py, the same checks `tools/perf_ledger.py` gates on):
     the unchunked grad program must contain the full [B, S, V] tile, the chunked one
-    must not (at most the [B, chunk, V] scan tile)."""
-    from dolomite_engine_tpu.ops.loss import causal_lm_loss, fused_linear_cross_entropy
+    must not (at most the forward's [B, chunk, V] scan tile and the backward rule's
+    [chunks a block, B, chunk, shards, tile rows] tile, no larger) — and no loop of the
+    backward program carries a table-shaped float32 buffer where one token block holds
+    all tokens; where there are several, one loop does, once a block, not once a chunk."""
+    from dolomite_engine_tpu.ops.loss import (
+        causal_lm_loss, fused_linear_cross_entropy, plan_loss_backward,
+    )
     from dolomite_engine_tpu.utils.program_signature import capture_program_signature
 
-    B, S, H, V = 2, 64, 16, 199
+    B, S, H = 2, 64, 16
     hidden = jax.random.normal(jax.random.PRNGKey(0), (B, S, H), jnp.float32)
     table = jax.random.normal(jax.random.PRNGKey(1), (V, H), jnp.float32)
     labels = jax.random.randint(jax.random.PRNGKey(2), (B, S), 0, V)
     chunk = 8
+    plan = plan_loss_backward(B, S // chunk, chunk, V, H)[0]
+    assert plan.token_blocks == blocks
+    tile = plan.logits_tile(B, S // chunk, chunk)
+    assert np.prod(tile) <= 1.1 * B * chunk * V  # the forward's budget (a padded tile's worth over)
 
     def unchunked(h, t):
         return causal_lm_loss(jnp.dot(h, t.T), jnp.zeros((B, S), jnp.int32), labels=labels)
@@ -1069,7 +1135,11 @@ def test_fused_ce_peak_logits_memory_is_o_chunk():
             h, t, labels, chunk_size=chunk, compute_dtype=jnp.float32
         )
 
-    checks = {"full_logits": ((B, S, V), "f32"), "chunk_logits": ((B, chunk, V), "f32")}
+    checks = {
+        "full_logits": ((B, S, V), "f32"),
+        "chunk_logits": ((B, chunk, V), "f32"),
+        "tile_logits": (tile, "f32"),
+    }
     # forward AND backward: grad of the loss is where remat pressure lives.
     # compile=False: the assertion is about the lowering, not the buffer assignment
     sig_unchunked = capture_program_signature(
@@ -1081,8 +1151,20 @@ def test_fused_ce_peak_logits_memory_is_o_chunk():
         name="ce_chunked_grad", compile=False, shape_checks=checks,
     )
     assert sig_unchunked.hlo["checks"]["full_logits"]  # the reference builds full logits
+    assert not sig_unchunked.hlo["checks"]["tile_logits"]
     assert not sig_chunked.hlo["checks"]["full_logits"]
-    assert sig_chunked.hlo["checks"]["chunk_logits"]  # ...while the chunk tile exists
+    assert sig_chunked.hlo["checks"]["chunk_logits"]  # ...while the forward's chunk tile
+    assert sig_chunked.hlo["checks"]["tile_logits"]  # and the backward's vocabulary tile exist
+
+    loops = _loop_carries(jax.make_jaxpr(jax.grad(chunked, argnums=(0, 1)))(hidden, table).jaxpr)
+    assert len(loops) >= 2  # the forward's scan and the backward's
+    table_sized = [
+        (kind, trips) for kind, trips, carry in loops
+        if any(a.dtype == jnp.float32 and a.size >= V * H for a in carry)
+    ]
+    # one block: every tile's table gradient leaves its matmul once (the scan's ys);
+    # two blocks: the outer loop alone carries it, for 2 trips and not S // chunk = 8
+    assert table_sized == ([] if blocks == 1 else [("scan", blocks)])
 
 
 # ------------------------------------------------------------------- fused_rope_qkv

@@ -182,3 +182,48 @@ def test_sharded_block_compiles_for_v5e_2x2(v5e, width):
     with mesh, nn.logical_axis_rules(get_logical_axis_rules(stage=3, sequence_parallel=True)):
         text = jax.jit(jax.grad(block, argnums=(0, 3))).lower(*args).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') >= 6  # 1 + 2 + 3 kernels, fwd + bwd
+
+
+def test_sharded_fused_loss_compiles_for_v5e_2x2(v5e):
+    """The chunked loss, forward and backward, at the flagship's head (2560 x 49152, one
+    packed row of 4096 a device) under fsdp 2 x tp 2 with the table over tp — the layout
+    `chip_smoke.py --chips 4` trains on. The backward rule tiles the vocabulary inside each
+    tp shard: the table is gathered (its embed axis, over fsdp) once, outside every loop,
+    and no loop gathers a tile of it."""
+    from dolomite_engine_tpu.ops.loss import fused_linear_cross_entropy
+    from dolomite_engine_tpu.utils.program_signature import hlo_collectives
+
+    embd, vocab, batch, seq = 2560, 49152, 2, 4096
+    mesh = Mesh(np.asarray(v5e).reshape(1, 2, 1, 2, 1), MESH_AXES)
+
+    def loss(hidden, table, labels):
+        return fused_linear_cross_entropy(
+            hidden, table, labels, chunk_size=256, compute_dtype=jnp.bfloat16, z_loss_coef=1e-4
+        )
+
+    def spec(shape, dtype, *axes):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=NamedSharding(mesh, P(*axes)))
+
+    args = (
+        spec((batch, seq, embd), jnp.bfloat16, "fsdp", "tp"),
+        spec((vocab, embd), jnp.float32, "tp", "fsdp"),
+        spec((batch, seq), jnp.int32, "fsdp"),
+    )
+    rules = get_logical_axis_rules(
+        stage=3, sequence_parallel=True, tensor_parallel_word_embeddings=True
+    )
+    with mesh, nn.logical_axis_rules(rules):
+        compiled = jax.jit(
+            jax.value_and_grad(loss, argnums=(0, 1)),
+            out_shardings=(None, (args[0].sharding, args[1].sharding)),
+        ).lower(*args).compile()
+    gathers = [
+        (dims, in_loop)
+        for kind, dims, in_loop in hlo_collectives(compiled.as_text())
+        if kind == "all-gather"
+    ]
+    table_rows = {vocab, vocab // 2, 1536}  # the table, a tp shard of it, a tile's rows
+    of_table = [g for g in gathers if g[0][-1] == embd and table_rows & set(g[0])]
+    assert of_table == [((vocab // 2, embd), False)], gathers
+    # a device's temporaries: the gathered bf16 shard (126 MB) and its gradient's tiles
+    assert compiled.memory_analysis().temp_size_in_bytes < 300 * 2**20

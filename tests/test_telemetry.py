@@ -529,6 +529,40 @@ def test_compiles_counter_names_the_step_of_a_recompile(tmp_path):
     assert _read_sink(sink)[-1]["counters"]["compiles"] == counted
 
 
+def test_loss_tiling_event_is_written_once_a_trace(tmp_path):
+    """The chunked loss's backward rule says how it engaged: one `loss_tiling` event where
+    the step is traced (token blocks x vocabulary tiles, the bytes its loops carry), not
+    one a trace of the same shapes, and a new one when the shapes choose another tiling."""
+    from dolomite_engine_tpu.ops.loss import fused_linear_cross_entropy
+
+    sink = tmp_path / "t.jsonl"
+    telemetry = Telemetry(sink_path=str(sink), rank=0)
+    install_telemetry(telemetry)
+
+    def grads(batch, seq, vocab):
+        hidden = jnp.ones((batch, seq, 16), jnp.float32)
+        table = jnp.ones((vocab, 16), jnp.float32)
+        labels = jnp.zeros((batch, seq), jnp.int32)
+        loss = lambda h, t: fused_linear_cross_entropy(  # noqa: E731
+            h, t, labels, chunk_size=8, compute_dtype=jnp.float32
+        )
+        return jax.jit(jax.grad(loss, argnums=(0, 1))).lower(hidden, table)
+
+    grads(2, 64, 1999)
+    grads(2, 64, 1999)  # traced again (another jit of the same step): nothing new to say
+    grads(2, 64, 199)
+    uninstall_telemetry()
+    get_telemetry().event_once("loss_tiling", token_blocks=1)  # the no-op registry has the method too
+    telemetry.close()
+
+    events = [r for r in _read_sink(sink) if r["kind"] == "event" and r["event"] == "loss_tiling"]
+    assert [(e["token_blocks"], e["vocab_tiles"], e["tile_rows"]) for e in events] == [(1, 8, 256), (2, 4, 50)]
+    one_block, two_blocks = events
+    assert one_block["table_carry_bytes"] == 0 and one_block["hidden_carry_bytes"] == 4 * 16 * 128
+    assert two_blocks["table_carry_bytes"] == 4 * 16 * 200 and two_blocks["tokens_per_device"] == 128
+    assert "step" not in one_block and one_block["vocab_shards"] == 1
+
+
 def test_null_registry_is_safe_without_install():
     null = get_telemetry()
     null.count("anything", event=True, step=1)

@@ -3,7 +3,8 @@
 Lowers `make_train_step` for a tiny scanned `gpt_dolomite` with the chunked loss and reads
 the framework names of the lowered operations (`jit(train_step)/.../op`, what a profile
 calls `tf_op`): every phase scope is there, every scan sits in a scope of its own, the
-loss's backward matmuls are under `head_loss`, and no matmul is left with JAX's bare
+loss's backward matmuls are under `head_loss` (`ce_tile`: recomputed logits, hidden and
+table gradients), and no matmul is left with JAX's bare
 `transpose(jvp())` — a backward rule or scan body with no name of its own. Scopes are
 metadata: the compile-cache key strips them, so the compiled program is what it was.
 """
@@ -108,10 +109,16 @@ def test_the_loss_backward_matmuls_are_under_head_loss(names):
         op == "while" and "transpose(" in name and name.endswith("head_loss/loss_chunks/while")
         for op, name in names
     )
-    # ... and inside the scan's body its matmuls carry the chunk's name, forward
-    # (`ce_chunk`), replayed (`jvp(ce_chunk)`) and backward (`transpose(jvp(ce_chunk))`)
+    # ... and inside the scans' bodies the matmuls carry names that tell them apart: the
+    # forward's chunk (`ce_chunk`), and in the backward rule's vocabulary tile (`ce_tile`)
+    # the recomputed logits and the two gradient matmuls
     dots = {name for op, name in names if op == "dot_general"}
-    assert {"ce_chunk/dot_general", "jvp(ce_chunk)/dot_general", "transpose(jvp(ce_chunk))/dot_general"} <= dots
+    assert {
+        "ce_chunk/dot_general", "ce_tile/logits/dot_general",
+        "ce_tile/grad_hidden/dot_general", "ce_tile/grad_table/dot_general",
+    } <= dots
+    # the rule differentiates nothing: no replayed forward, no transposed chunk
+    assert not [name for name in dots if "jvp(ce_chunk)" in name]
 
 
 def test_no_matmul_is_left_without_an_owner(names):
@@ -120,7 +127,7 @@ def test_no_matmul_is_left_without_an_owner(names):
     bare = [n for n in dots if n in ("dot_general", "jvp()/dot_general", "transpose(jvp())/dot_general")]
     assert not bare, bare
     for name in dots:
-        assert "ce_chunk" in name or "h_scan" in name, name
+        assert "ce_chunk" in name or "ce_tile" in name or "h_scan" in name, name
 
 
 def test_accumulation_and_health_have_scopes_too():
@@ -134,7 +141,10 @@ def test_the_unrolled_model_carries_the_same_phases():
     names = _operation_names(_lowered(scan_layers=False))
     for scope in ("embed", "blocks", "final_norm", "head_loss", "optimizer"):
         assert any(scope in name.split("/") for _, name in names), scope
-    dots = [name for op, name in names if op == "dot_general" and "ce_chunk" not in name]
+    dots = [
+        name for op, name in names
+        if op == "dot_general" and "ce_chunk" not in name and "ce_tile" not in name
+    ]
     assert dots and all("/blocks/" in name for name in dots), dots[:3]
 
 

@@ -109,7 +109,7 @@ def scan_tree(tree: ast.AST, filename: str, tables: dict) -> tuple[list[tuple[in
         if not (isinstance(node, ast.Call) and isinstance(node.func, ast.Attribute)):
             continue
         method = node.func.attr
-        if method not in ("count", "event", "gauge", "emit_record"):
+        if method not in ("count", "event", "event_once", "gauge", "emit_record"):
             continue
         if not _is_telemetry_receiver(node, filename):
             continue
@@ -137,7 +137,7 @@ def scan_tree(tree: ast.AST, filename: str, tables: dict) -> tuple[list[tuple[in
                             "KNOWN_EVENTS",
                         )
                     )
-        elif method == "event":
+        elif method in ("event", "event_once"):
             usage.events.add(name)
             if name not in events:
                 errors.append((node.lineno, f"event '{name}' not in KNOWN_EVENTS"))

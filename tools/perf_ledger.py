@@ -122,18 +122,34 @@ def _train_step_suite(model_type: str):
                 )
             }
             jit_step = jax.jit(step_fn, donate_argnums=0)
-            # fused CE: the [micro_bs, seq, vocab] fp32 logits must not exist, the
-            # [micro_bs, chunk, vocab] scan tile must
-            checks = {
-                "full_logits": ((t["micro_bs"], t["seq"], t["vocab"]), "f32"),
-                "chunk_logits": ((t["micro_bs"], t["loss_chunk"], t["vocab"]), "f32"),
-            }
+            with wrapper.apply_scope():  # the rules the step's own trace resolves under
+                checks = _logits_checks(
+                    t["micro_bs"], t["seq"], t["n_embd"], t["vocab"], t["loss_chunk"]
+                )
             yield f"train_step[{model_type},policy={policy}]", capture_jit_signature(
                 jit_step,
                 (state, batch, jax.random.PRNGKey(1)),
                 name=f"train_step[{model_type},policy={policy}]",
                 shape_checks=checks,
             )
+
+
+def _logits_checks(batch: int, seq: int, hidden: int, vocab: int, chunk: int) -> dict:
+    """Fused CE: the [batch, seq, vocab] fp32 logits must not exist; the forward's
+    [batch, chunk, vocab] scan tile must, and so must the tile the backward rule recomputes
+    — all tokens of a block against a slice of the vocabulary, as `ops/loss.
+    plan_loss_backward` cuts it under the ambient mesh (the same budget the other way)."""
+    from dolomite_engine_tpu.ops.loss import plan_loss_backward
+
+    n_chunks = seq // chunk
+    tile = plan_loss_backward(batch, n_chunks, chunk, vocab, hidden)[0].logits_tile(
+        batch, n_chunks, chunk
+    )
+    return {
+        "full_logits": ((batch, seq, vocab), "f32"),
+        "chunk_logits": ((batch, chunk, vocab), "f32"),
+        "tile_logits": (tile, "f32"),
+    }
 
 
 def _fused_ce_suite():
@@ -150,10 +166,7 @@ def _fused_ce_suite():
     hidden = jax.ShapeDtypeStruct((c["B"], c["S"], c["H"]), jnp.float32)
     table = jax.ShapeDtypeStruct((c["V"], c["H"]), jnp.float32)
     labels = jax.ShapeDtypeStruct((c["B"], c["S"]), jnp.int32)
-    checks = {
-        "full_logits": ((c["B"], c["S"], c["V"]), "f32"),
-        "chunk_logits": ((c["B"], c["chunk"], c["V"]), "f32"),
-    }
+    checks = _logits_checks(c["B"], c["S"], c["H"], c["V"], c["chunk"])
 
     def fwd(h, t, y):
         return fused_linear_cross_entropy(
