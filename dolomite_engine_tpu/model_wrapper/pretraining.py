@@ -103,7 +103,16 @@ class ModelWrapperForPretraining(ModelWrapper):
             )
         # output.loss already includes the scaled router aux loss (models/gpt_dolomite.py
         # compute_aux_loss hook) — do not add it again
+        if self.step_counter_names:
+            return output.loss, output.counters
         return output.loss
+
+    @property
+    def step_counter_names(self) -> tuple:
+        """Names of what the family's forward pass counts (models/nemotron_h.py); where
+        there are any, :meth:`loss` returns ``(loss, counters)`` and the train step is
+        built with ``has_aux``."""
+        return tuple(getattr(self.model, "step_counter_names", ()))
 
 
 class ModelWrapperForFinetuning(ModelWrapper):

@@ -13,6 +13,7 @@ from .config import (
     EncDecDolomiteConfig,
     GPTCrossLayerConfig,
     MoEConfig,
+    NemotronHConfig,
     RNNDolomiteConfig,
 )
 from .gpt_dolomite import CausalLMOutput, GPTDolomiteForCausalLM, GPTDolomiteModel
@@ -24,6 +25,7 @@ from .gpt_crosslayer import (
     convert_gpt_dolomite_to_gpt_crosslayer,
 )
 from .moe_dolomite import MoEDolomiteForCausalLM, MoEDolomiteModel
+from .nemotron_h import NemotronHForCausalLM, NemotronHModel
 from .rnn_dolomite import RNNDolomiteForCausalLM, RNNDolomiteModel
 
 _CONFIG_CLASSES: dict[str, type] = {
@@ -33,6 +35,7 @@ _CONFIG_CLASSES: dict[str, type] = {
     "dense_moe": DenseMoEConfig,
     "rnn_dolomite": RNNDolomiteConfig,
     "enc_dec_dolomite": EncDecDolomiteConfig,
+    "nemotron_h": NemotronHConfig,
 }
 
 _MODEL_CLASSES: dict[str, type] = {
@@ -42,6 +45,7 @@ _MODEL_CLASSES: dict[str, type] = {
     "dense_moe": DenseMoEForCausalLM,
     "rnn_dolomite": RNNDolomiteForCausalLM,
     "enc_dec_dolomite": EncDecDolomiteForSeq2SeqLM,
+    "nemotron_h": NemotronHForCausalLM,
 }
 
 # families trained/driven through the seq2seq (AutoModelForSeq2SeqLM) surface

@@ -70,7 +70,11 @@ class CommonConfig:
         if self.n_inner is None:
             self.n_inner = 4 * self.n_embd
 
-        if self.fused_lm_head_loss and not self.tie_word_embeddings:
+        if (
+            self.fused_lm_head_loss
+            and not self.tie_word_embeddings
+            and not self.fused_loss_reads_untied_head()
+        ):
             raise ValueError(
                 "fused_lm_head_loss requires tie_word_embeddings (the chunked loss reads the "
                 "tied embedding table; an untied lm_head would silently fall back to "
@@ -112,6 +116,12 @@ class CommonConfig:
             assert self.n_head % self.num_key_value_heads == 0, (
                 "GroupedQueryAttention needs n_head divisible by num_key_value_heads"
             )
+
+    @classmethod
+    def fused_loss_reads_untied_head(cls) -> bool:
+        """Whether the family's model hands the chunked loss an untied head's table
+        (`nemotron_h` does); the others read the tied embedding table only."""
+        return False
 
     @classmethod
     def supported_position_embeddings(cls) -> frozenset[PositionEmbeddingType]:
@@ -282,3 +292,129 @@ class RNNDolomiteConfig(CommonConfig):
             self.attention_pattern = "d" * self.n_layer
         assert len(self.attention_pattern) == self.n_layer
         assert set(self.attention_pattern) <= {"a", "d"}
+
+
+@dataclass
+class NemotronHConfig(CommonConfig):
+    """The `nemotron_h` tower (Nemotron-H / Nemotron-Labs-TwoTower's first tower): every
+    layer is ONE mixer behind a pre-norm and a residual, chosen by `hybrid_override_pattern`
+    over ``M`` (Mamba-2), ``E`` (routed experts + a shared expert) and ``*`` (attention
+    without positions). The repo's names carry the widths they always carried (`n_embd`,
+    `n_head`, `num_key_value_heads`, `attention_head_dim`); the rest are the public
+    `config.json`'s keys.
+
+    `experts_held` = (first, count): the chip's share of a layer's experts under expert
+    parallelism. The router keeps `num_experts` outputs and `num_experts_per_tok` choices;
+    the banks hold `count` experts and the layer adds what those give (`ops/moe.py`
+    `experts_held_ragged`). None holds all. `deployment` is free text and numbers about the
+    cut (published depth and vocabulary, chips sharing a layer) for the run's `model_layout`
+    event; the program reads nothing from it."""
+
+    model_type: str = "nemotron_h"
+    attention_head_type: str = "gqa"
+    position_embedding_type: str = "nope"
+    normalization_function: str = "rmsnorm"
+    activation_function: str = "relu2"
+    add_bias: bool = False
+    tie_word_embeddings: bool = False
+    hybrid_override_pattern: str | None = None
+    # Mamba-2 mixer
+    mamba_num_heads: int = 64
+    mamba_head_dim: int = 64
+    mamba_n_groups: int = 8
+    ssm_state_size: int = 128
+    conv_kernel: int = 4
+    chunk_size: int = 128
+    use_conv_bias: bool = True
+    time_step_min: float = 0.001
+    time_step_max: float = 0.1
+    time_step_floor: float = 1e-4
+    # experts
+    num_experts: int = 128
+    num_experts_per_tok: int = 6
+    moe_intermediate_size: int = 1856
+    moe_shared_expert_intermediate_size: int = 3712
+    routed_scaling_factor: float = 2.5
+    norm_topk_prob: bool = True
+    experts_held: list[int] | None = None
+    deployment: dict | None = None
+
+    # parameter leaves the optimizer must leave as they are (buffers of the public model)
+    buffer_names = ("e_score_correction_bias",)
+
+    def __post_init__(self) -> None:
+        if self.hybrid_override_pattern is None:
+            self.hybrid_override_pattern = "M" * self.n_layer
+        super().__post_init__()
+        if len(self.hybrid_override_pattern) != self.n_layer:
+            raise ValueError(
+                f"hybrid_override_pattern {self.hybrid_override_pattern!r} names "
+                f"{len(self.hybrid_override_pattern)} layers, n_layer is {self.n_layer}"
+            )
+        unknown = set(self.hybrid_override_pattern) - set("ME*")
+        if unknown:
+            raise ValueError(
+                f"hybrid_override_pattern knows M (Mamba-2), E (experts) and * (attention), "
+                f"not {sorted(unknown)}"
+            )
+        if self.mamba_num_heads % self.mamba_n_groups:
+            raise ValueError("mamba_num_heads must be a multiple of mamba_n_groups")
+        if self.experts_held is not None:
+            first, count = self.experts_held
+            if first < 0 or count < 1 or first + count > self.num_experts:
+                raise ValueError(
+                    f"experts_held {self.experts_held} lies outside 0..{self.num_experts}"
+                )
+            self.experts_held = [int(first), int(count)]
+
+    @classmethod
+    def fused_loss_reads_untied_head(cls) -> bool:
+        return True
+
+    @classmethod
+    def supported_position_embeddings(cls) -> frozenset[PositionEmbeddingType]:
+        return frozenset({PositionEmbeddingType.nope})
+
+    @property
+    def mamba_inner(self) -> int:
+        return self.mamba_num_heads * self.mamba_head_dim
+
+    @property
+    def mamba_conv_dim(self) -> int:
+        return self.mamba_inner + 2 * self.mamba_n_groups * self.ssm_state_size
+
+    def held_experts(self) -> tuple[int, int]:
+        return tuple(self.experts_held) if self.experts_held else (0, self.num_experts)
+
+    def forward_block_flops(self, b: int, s: int) -> float:
+        """Forward matmul FLOPs of all the blocks for `b` rows of `s` tokens, under
+        `train_utils.get_model_tflops`' conventions (2 per multiply-add; attention's score
+        and value products at the full square; the routed experts by the even share of
+        token-slots the experts held here get; the scan's and the convolution's own work
+        left out). A family whose block is not attention + MLP has this method, and
+        `get_model_tflops` takes its blocks from it."""
+        h = self.n_embd
+        heads, kv, d = self.n_head, self.num_key_value_heads, self.head_dim
+        held = self.held_experts()[1]
+        per_kind = {
+            "M": 2 * b * s * (h * (self.mamba_inner + self.mamba_conv_dim + self.mamba_num_heads) + self.mamba_inner * h),
+            "*": 2 * b * s * (h * (heads + 2 * kv) * d + heads * d * h) + 4 * b * s * s * heads * d,
+            "E": 2 * b * s * (
+                h * self.num_experts
+                + 2 * h * self.moe_shared_expert_intermediate_size
+                + self.num_experts_per_tok * held / self.num_experts * 2 * h * self.moe_intermediate_size
+            ),
+        }
+        return float(sum(per_kind[kind] for kind in self.hybrid_override_pattern))
+
+    def layout_record(self) -> dict:
+        """What the run's one `model_layout` telemetry event says."""
+        first, count = self.held_experts()
+        return dict(
+            pattern=self.hybrid_override_pattern,
+            experts_held=count,
+            first_expert_held=first,
+            experts_published=self.num_experts,
+            vocabulary_rows_held=self.vocab_size,
+            **(self.deployment or {}),
+        )

@@ -45,6 +45,9 @@ class CausalLMOutput:
     kv_caches: list[KVCache] | None = None
     hidden_states: jax.Array | None = None
     aux_loss: jax.Array | None = None
+    # what a family counts in its forward pass for the train step to return beside the loss
+    # (`step_counters`; nemotron_h: token-slots routed to held / absent experts)
+    counters: dict | None = None
 
 
 # the zero-arg members of jax.checkpoint_policies that ARE policies; the rest are policy
@@ -506,7 +509,7 @@ class GPTDolomiteForCausalLM(nn.Module):
         use_fused = (
             want_loss
             and self.config.fused_lm_head_loss
-            and self.config.tie_word_embeddings
+            and (self.config.tie_word_embeddings or self.config.fused_loss_reads_untied_head())
             and kv_caches is None
         )
 
@@ -550,7 +553,18 @@ class GPTDolomiteForCausalLM(nn.Module):
             if aux_loss is not None:
                 loss = loss + aux_loss
 
-        return CausalLMOutput(logits=logits, loss=loss, kv_caches=new_caches, aux_loss=aux_loss)
+        return CausalLMOutput(
+            logits=logits,
+            loss=loss,
+            kv_caches=new_caches,
+            aux_loss=aux_loss,
+            counters=self.step_counters(extras),
+        )
+
+    def step_counters(self, extras: list) -> dict | None:
+        """Hook: counters of this forward pass from the per-block extras (None: the family
+        counts nothing, and the train step returns what it always returned)."""
+        return None
 
     def compute_aux_loss(
         self,

@@ -1,0 +1,419 @@
+"""`nemotron_h`: the hybrid tower of Nemotron-H / Nemotron-Labs-TwoTower (first tower only).
+
+Every layer is ``x + mixer(RMSNorm(x))`` with ONE mixer, chosen by the config's pattern:
+
+  - ``M``  `Mamba2Mixer`: in-projection to ``[z | xBC | dt]``, a causal depthwise
+    convolution with silu over ``xBC``, the selective scan in chunks (`ops/mamba2.py`), a
+    gated grouped RMSNorm, out-projection;
+  - ``E``  `SharedExpertMoE`: a router that scores ALL experts (sigmoid, chosen with a
+    correction bias, weighed without it, renormalised, scaled), the chip's share of the
+    routed experts (`ops/moe.experts_held_ragged`) and a shared expert every token passes;
+  - ``*``  the repo's `Attention` without position embedding.
+
+After the last layer a norm and an untied head; the loss is the repo's chunked one, which
+reads the head's ``[V, H]`` table. No second tower, no adaLN conditioning, no in-block
+bidirectional attention, no diffusion objective: the public ``config.json`` has no key for
+them (PERF.md section 7).
+
+Packed rows (``segment_ids``): attention, the convolution's taps and the Mamba state all
+reset at document boundaries. Training path only: a KV/state cache raises. `scan_layers`
+raises — the layers differ, and a scan over whole periods is not built. tp > 1 and ep > 1
+raise rather than replicate the Mamba heads or the experts silently.
+
+Scopes inside the jitted step (docs/OBSERVABILITY.md "Phases of the train step"):
+``mamba_mixer`` (``mamba_in_proj``, ``mamba_conv``, ``mamba2_scan``, ``mamba_gated_norm``,
+``mamba_out_proj``), ``moe`` (``moe_router``, ``moe_dispatch``, ``moe_experts``,
+``moe_shared_expert``, ``moe_combine``), ``attention``. Everything is differentiated by
+JAX, so the backward pass carries them under ``transpose(...)``.
+"""
+
+from __future__ import annotations
+
+import math
+from typing import Any
+
+import jax
+import jax.numpy as jnp
+from flax import linen as nn
+
+from ..enums import AttentionImplementation
+from ..ops.activations import get_activation_function
+from ..ops.mamba2 import causal_conv1d, gated_group_rmsnorm, mamba2_chunked
+from ..ops.moe import experts_held_ragged, route_sigmoid_bias
+from ..parallel.sharding import logical_constraint
+from .config import NemotronHConfig
+from .gpt_dolomite import GPTDolomiteForCausalLM, resolve_remat_policy
+from .modeling_utils import (
+    Attention,
+    ParameterizedEmbedding,
+    ParameterizedLinear,
+    _normal_init,
+    depth_scaled_init_std,
+    get_norm,
+)
+from .moe_dolomite import ParameterizedExperts
+
+# what the step returns beside the loss, one number a layer of experts (`E`)
+STEP_COUNTERS = ("routed_slots", "absent_slots", "fullest_expert_rows", "held_expert_rows")
+
+
+def _inverse_softplus(x: jax.Array) -> jax.Array:
+    return x + jnp.log(-jnp.expm1(-x))
+
+
+class Mamba2Mixer(nn.Module):
+    config: NemotronHConfig
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, hidden_states: jax.Array, segment_ids: jax.Array | None = None) -> jax.Array:
+        config = self.config
+        heads, width = config.mamba_num_heads, config.mamba_head_dim
+        groups, state = config.mamba_n_groups, config.ssm_state_size
+        inner, conv_dim = config.mamba_inner, config.mamba_conv_dim
+        batch, seq = hidden_states.shape[:2]
+
+        with jax.named_scope("mamba_in_proj"):
+            projected = ParameterizedLinear(
+                features=inner + conv_dim + heads,
+                use_bias=False,
+                std=config.initializer_range,
+                kernel_axes=("embed", "mamba_inner"),
+                dtype=self.dtype,
+                name="in_proj",
+            )(hidden_states)
+            gate, xbc, dt = jnp.split(projected, [inner, inner + conv_dim], axis=-1)
+
+        def conv_init(key, shape, dtype=jnp.float32):  # torch's Conv1d default
+            bound = 1.0 / math.sqrt(config.conv_kernel)
+            return jax.random.uniform(key, shape, dtype, -bound, bound)
+
+        with jax.named_scope("mamba_conv"):
+            conv_weight = self.param(
+                "conv_weight",
+                nn.with_logical_partitioning(conv_init, (None, None)),
+                (conv_dim, config.conv_kernel),
+                jnp.float32,
+            )
+            conv_bias = None
+            if config.use_conv_bias:
+                conv_bias = self.param(
+                    "conv_bias",
+                    nn.with_logical_partitioning(conv_init, (None,)),
+                    (conv_dim,),
+                    jnp.float32,
+                ).astype(self.dtype)
+            xbc = jax.nn.silu(
+                causal_conv1d(xbc, conv_weight.astype(self.dtype), conv_bias, segment_ids)
+            )
+            x, b, c = jnp.split(xbc, [inner, inner + groups * state], axis=-1)
+
+        def init_dt_bias(key, shape, dtype=jnp.float32):
+            low, high = math.log(config.time_step_min), math.log(config.time_step_max)
+            dt0 = jnp.exp(jax.random.uniform(key, shape, dtype) * (high - low) + low)
+            return _inverse_softplus(jnp.maximum(dt0, config.time_step_floor))
+
+        def init_a_log(key, shape, dtype=jnp.float32):
+            return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
+
+        per_head = lambda init: nn.with_logical_partitioning(init, (None,))  # noqa: E731
+        dt_bias = self.param("dt_bias", per_head(init_dt_bias), (heads,), jnp.float32)
+        a_log = self.param("A_log", per_head(init_a_log), (heads,), jnp.float32)
+        d_skip = self.param("D", per_head(nn.initializers.ones_init()), (heads,), jnp.float32)
+
+        with jax.named_scope("mamba2_scan"):
+            seq_chunk = config.chunk_size if seq % config.chunk_size == 0 else seq
+            y = jax.checkpoint(mamba2_chunked, static_argnums=(7,))(
+                x.reshape(batch, seq, heads, width),
+                jax.nn.softplus(dt.astype(jnp.float32) + dt_bias),
+                -jnp.exp(a_log),
+                b.reshape(batch, seq, groups, state),
+                c.reshape(batch, seq, groups, state),
+                d_skip,
+                segment_ids,
+                seq_chunk,
+            ).reshape(batch, seq, inner)
+
+        with jax.named_scope("mamba_gated_norm"):
+            norm_weight = self.param(
+                "norm_weight",
+                nn.with_logical_partitioning(nn.initializers.ones_init(), (None,)),
+                (inner,),
+                jnp.float32,
+            )
+            y = gated_group_rmsnorm(y, gate, norm_weight, groups, config.layer_norm_epsilon)
+
+        with jax.named_scope("mamba_out_proj"):
+            return ParameterizedLinear(
+                features=config.n_embd,
+                use_bias=False,
+                std=depth_scaled_init_std(config),
+                kernel_axes=("mamba_inner", "embed"),
+                dtype=self.dtype,
+                name="out_proj",
+            )(y)
+
+
+class SharedExpertMoE(nn.Module):
+    """Routed experts (the share held here) plus a shared expert. Returns the layer's
+    output and its counters (`STEP_COUNTERS`: int32 scalars, and the rows of each held expert)."""
+
+    config: NemotronHConfig
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, hidden_states: jax.Array) -> tuple[jax.Array, dict]:
+        config = self.config
+        hidden = config.n_embd
+        act = get_activation_function(config.activation_function)
+        first, held = config.held_experts()
+        batch, seq, _ = hidden_states.shape
+        x = hidden_states.reshape(-1, hidden)
+
+        with jax.named_scope("moe_router"):
+            gate = self.param(
+                "gate",
+                nn.with_logical_partitioning(_normal_init(config.initializer_range), (None, None)),
+                (hidden, config.num_experts),
+                jnp.float32,
+            )
+            # the router's scores are float32 whatever the model's dtype (the public model's)
+            logits = jnp.dot(
+                x.astype(jnp.float32), gate, precision=jax.lax.Precision.HIGHEST
+            )
+            # the family's routing rule (`ops/moe.route_sigmoid_bias`); the bias is a buffer
+            correction_bias = self.param(
+                "e_score_correction_bias",
+                nn.with_logical_partitioning(nn.initializers.zeros_init(), (None,)),
+                (config.num_experts,),
+                jnp.float32,
+            )
+            weights, selected = route_sigmoid_bias(
+                logits,
+                config.num_experts_per_tok,
+                correction_bias,
+                config.routed_scaling_factor,
+                config.norm_topk_prob,
+            )
+
+        c_fc, _ = ParameterizedExperts(
+            num_experts=held,
+            features=config.moe_intermediate_size,
+            use_bias=False,
+            std=config.initializer_range,
+            kernel_axes=("experts", "embed", "expert_mlp"),
+            dtype=self.dtype,
+            name="c_fc",
+        )(hidden)
+        c_proj, _ = ParameterizedExperts(
+            num_experts=held,
+            features=hidden,
+            use_bias=False,
+            std=depth_scaled_init_std(config),
+            kernel_axes=("experts", "expert_mlp", "embed"),
+            dtype=self.dtype,
+            name="c_proj",
+        )(config.moe_intermediate_size)
+
+        # `moe_dispatch` (the sort, the gather), `moe_experts` (the grouped products) and
+        # `moe_combine` (the weighted scatter-add) are opened inside: one function, so that
+        # the overflow path is the same code at more rows
+        routed, counters = experts_held_ragged(
+            x.astype(self.dtype),
+            weights,
+            selected,
+            c_fc.astype(self.dtype),
+            c_proj.astype(self.dtype),
+            act,
+            config.num_experts,
+            first,
+        )
+
+        with jax.named_scope("moe_shared_expert"):
+            h = ParameterizedLinear(
+                features=config.moe_shared_expert_intermediate_size,
+                use_bias=False,
+                std=config.initializer_range,
+                kernel_axes=("embed", "mlp"),
+                dtype=self.dtype,
+                name="shared_c_fc",
+            )(x)
+            shared = ParameterizedLinear(
+                features=hidden,
+                use_bias=False,
+                std=depth_scaled_init_std(config),
+                kernel_axes=("mlp", "embed"),
+                dtype=self.dtype,
+                name="shared_c_proj",
+            )(act(h))
+
+        with jax.named_scope("moe_combine"):
+            out = (routed.astype(self.dtype) + shared).reshape(batch, seq, hidden)
+        return out, counters
+
+
+class NemotronHBlock(nn.Module):
+    """``x + mixer(norm(x))``; `mixer` is one letter of the pattern."""
+
+    config: NemotronHConfig
+    mixer: str
+    attention_implementation: AttentionImplementation = AttentionImplementation.sdpa
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(
+        self, hidden_states: jax.Array, attention_mask=None, segment_ids=None, deterministic: bool = True
+    ) -> tuple[jax.Array, dict | None]:
+        config = self.config
+        h = get_norm(config, self.dtype, "ln_1")(hidden_states)
+        counters = None
+        if self.mixer == "M":
+            with jax.named_scope("mamba_mixer"):
+                out = Mamba2Mixer(config=config, dtype=self.dtype, name="mixer")(h, segment_ids)
+        elif self.mixer == "E":
+            with jax.named_scope("moe"):
+                out, counters = SharedExpertMoE(config=config, dtype=self.dtype, name="moe")(h)
+        else:
+            with jax.named_scope("attention"):
+                out, _ = Attention(
+                    config=config,
+                    attention_implementation=self.attention_implementation,
+                    dtype=self.dtype,
+                    name="attn",
+                )(h, attention_mask=attention_mask, segment_ids=segment_ids, deterministic=deterministic)
+        hidden_states = hidden_states + out.astype(hidden_states.dtype)
+        hidden_states = logical_constraint(hidden_states, ("act_batch", "act_seq", "act_embed"))
+        return hidden_states, counters
+
+
+class NemotronHModel(nn.Module):
+    config: NemotronHConfig
+    attention_implementation: AttentionImplementation = AttentionImplementation.sdpa
+    dtype: Any = jnp.float32
+    checkpoint_every: int = 0
+    checkpoint_policy: str | None = None
+    scan_layers: bool = False
+
+    def setup(self) -> None:
+        config = self.config
+        if self.scan_layers:
+            raise ValueError(
+                "scan_layers with nemotron_h: the layers of a pattern differ and a scan over "
+                "whole periods is not built; run it unrolled (scan_layers: false)"
+            )
+        from ..parallel.mesh import MeshManager
+
+        if MeshManager.is_initialized():
+            for axis, what in (("tp", "the Mamba-2 heads"), ("ep", "the experts held")):
+                if MeshManager.axis_size(axis) > 1:
+                    raise ValueError(
+                        f"nemotron_h on a mesh with {axis} > 1: {what} would be replicated, "
+                        f"not sharded; {axis} for this family is not built"
+                    )
+        self.wte = ParameterizedEmbedding(
+            num_embeddings=config.vocab_size,
+            features=config.n_embd,
+            std=config.initializer_range,
+            dtype=self.dtype,
+        )
+        remat_policy = resolve_remat_policy(self.checkpoint_policy)
+        blocks = []
+        for i, mixer in enumerate(config.hybrid_override_pattern):
+            cls = NemotronHBlock
+            if self.checkpoint_every and i % self.checkpoint_every == 0:
+                # flax counts the module instance as argument 0; deterministic is arg 4.
+                # prevent_cse stays on: the layers are unrolled, and XLA would merge a
+                # layer's replay with its forward pass and keep every layer's activations
+                cls = nn.remat(cls, static_argnums=(4,), policy=remat_policy)
+            blocks.append(
+                cls(
+                    config=config,
+                    mixer=mixer,
+                    attention_implementation=self.attention_implementation,
+                    dtype=self.dtype,
+                )
+            )
+        self.h = blocks
+        self.ln_f = get_norm(config, self.dtype)
+
+    def __call__(
+        self,
+        input_ids: jax.Array,
+        position_ids: jax.Array | None = None,
+        attention_mask: jax.Array | None = None,
+        segment_ids: jax.Array | None = None,
+        kv_caches: list | None = None,
+        cache_index: jax.Array | None = None,
+        deterministic: bool = True,
+        inputs_embeds: jax.Array | None = None,
+    ) -> tuple[jax.Array, None, list]:
+        if kv_caches is not None:
+            raise NotImplementedError(
+                "nemotron_h has no generation cache (Mamba state and convolution taps are not "
+                "in the serving engine's cache: ROADMAP M2); the training path only"
+            )
+        with jax.named_scope("embed"):
+            hidden_states = self.wte(input_ids) if inputs_embeds is None else inputs_embeds
+            hidden_states = logical_constraint(hidden_states, ("act_batch", "act_seq", "act_embed"))
+        if segment_ids is None and attention_mask is not None:
+            # padded rows: the pad tokens are a document of their own
+            segment_ids = attention_mask.astype(jnp.int32)
+        extras = []
+        with jax.named_scope("blocks"):
+            for block in self.h:
+                hidden_states, counters = block(hidden_states, attention_mask, segment_ids, deterministic)
+                if counters is not None:
+                    extras.append(counters)
+        with jax.named_scope("final_norm"):
+            hidden_states = self.ln_f(hidden_states)
+        return hidden_states, None, extras
+
+
+class HeadTable(nn.Module):
+    """The untied head as a ``[V, H]`` table (the public checkpoint's layout, and what the
+    chunked loss reads)."""
+
+    num_embeddings: int
+    features: int
+    std: float = 0.02
+
+    @nn.compact
+    def __call__(self) -> jax.Array:
+        return self.param(
+            "kernel",
+            nn.with_logical_partitioning(_normal_init(self.std), ("vocab", "embed")),
+            (self.num_embeddings, self.features),
+            jnp.float32,
+        )
+
+
+class NemotronHForCausalLM(GPTDolomiteForCausalLM):
+    """The tower under the repo's head and loss (`GPTDolomiteForCausalLM`)."""
+
+    base_model_cls: type = NemotronHModel
+    step_counter_names = STEP_COUNTERS
+
+    def setup(self) -> None:
+        self.transformer = self.base_model_cls(**self._transformer_kwargs())
+        self.lm_head = HeadTable(
+            num_embeddings=self.config.vocab_size,
+            features=self.config.n_embd,
+            std=self.config.initializer_range,
+        )
+
+    def _lm_head_operands(self, hidden_states: jax.Array) -> tuple[jax.Array, jax.Array]:
+        return hidden_states.astype(self.dtype), self.lm_head().astype(self.dtype)
+
+    def compute_logits(self, hidden_states: jax.Array) -> jax.Array:
+        head_in, table = self._lm_head_operands(hidden_states)
+        logits = jnp.dot(head_in, table.T)
+        return logical_constraint(logits, ("act_batch", "act_seq_inner", "act_vocab"))
+
+    def step_counters(self, extras: list) -> dict | None:
+        """``{name: int32[layers of experts, ...]}`` from the blocks' counters."""
+        if not extras:
+            return None
+        return {name: jnp.stack([layer[name] for layer in extras]) for name in STEP_COUNTERS}
+
+    def init_kv_caches(self, batch_size: int, max_length: int, dtype=None) -> list:
+        raise NotImplementedError("nemotron_h has no generation cache (ROADMAP M2)")

@@ -40,6 +40,29 @@ def route(
     return weights.astype(router_logits.dtype), selected.astype(jnp.int32)
 
 
+def route_sigmoid_bias(
+    router_logits: jax.Array,
+    top_k: int,
+    correction_bias: jax.Array,
+    scaling_factor: float = 1.0,
+    normalize: bool = True,
+) -> tuple[jax.Array, jax.Array]:
+    """The `nemotron_h` family's routing rule (DeepSeek-V3's): sigmoid scores, chosen with a
+    bias and weighed without it. ``s = sigmoid(logits)`` in float32 over all experts, the
+    top-k of ``s + correction_bias`` are chosen, and the weights are the chosen experts'
+    ``s``, divided by their sum (+1e-20) where `normalize`, times `scaling_factor`. The bias
+    is a buffer: it chooses, and no gradient reaches it.
+
+    Returns (router_weights [T, k] float32, selected_experts [T, k] int32)."""
+    scores = jax.nn.sigmoid(router_logits.astype(jnp.float32))
+    biased = scores + jax.lax.stop_gradient(correction_bias.astype(jnp.float32))
+    _, selected = jax.lax.top_k(biased, top_k)
+    weights = jnp.take_along_axis(scores, selected, axis=-1)
+    if normalize:
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+    return weights * scaling_factor, selected.astype(jnp.int32)
+
+
 def load_balancing_loss(
     router_logits: jax.Array, num_experts: int, top_k: int, token_mask: jax.Array | None = None
 ) -> jax.Array:
@@ -152,6 +175,133 @@ def experts_ragged(
     gates = jnp.take(router_weights.reshape(-1), order).astype(y.dtype)  # [T*k]
     out = jnp.zeros((tokens, hidden), dtype=y.dtype)
     return out.at[token_index].add(y * gates[:, None])
+
+
+def _share_grouped_product(rows: jax.Array) -> Callable:
+    """``product(rows, bank, group_sizes)``: ``rows[i] @ bank[g]`` for the rows of group ``g``
+    (``group_sizes`` in order; rows past their sum belong to no group and come out
+    undefined). On a TPU, in a trace with no multi-device mesh, jax's megablox grouped matmul
+    (`ops/pallas/moe.held_grouped_product`), else `jax.lax.ragged_dot`. Nothing a user sets:
+    on a v5e the TPU compiler's own `ragged_dot` kernel ran these shapes at 23-33 TFLOP/s and
+    megablox at 50-90 (PERF.md, PR 26); under a mesh the Mosaic kernel would have to go
+    through `parallel.sharding.shard_kernel` with the share's layout across chips (experts
+    over ``ep``), which is not built, and off the TPU it would run interpreted."""
+    from ..parallel.sharding import kernel_sharding
+
+    layout = ((rows.shape, (None, None)),)
+    if jax.default_backend() == "tpu" and kernel_sharding(layout, layout) is None:
+        from .pallas.moe import held_grouped_product
+
+        return held_grouped_product
+    return jax.lax.ragged_dot
+
+
+def experts_held_ragged(
+    x: jax.Array,
+    router_weights: jax.Array,
+    selected_experts: jax.Array,
+    w_fc: jax.Array,
+    w_proj: jax.Array,
+    act: Callable,
+    num_experts: int,
+    first_expert: int,
+    capacity: int | None = None,
+) -> tuple[jax.Array, dict]:
+    """One chip's share of an expert layer: the banks hold experts ``first_expert ..
+    first_expert + E_held - 1`` of a router that scores all `num_experts`.
+
+    Token-slots of held experts are sorted to the front, grouped by expert, and go through
+    two grouped products (`_share_grouped_product`); slots of absent experts are dropped before
+    them — no dummy bank, nothing stands in for the other chips — and what those experts
+    would have added is left out of the result. Shapes are static, the routed rows are not:
+    `capacity` rows are gathered (default: four times the even share ``T k E_held /
+    num_experts``, rounded up to 512) and the grouped products run over the routed ones
+    among them. A step that routes more here than `capacity` walks the sorted slots in
+    chunks of `capacity` rows instead (a scan whose chunks past the last routed row are
+    skipped, each chunk re-computed in the backward pass): slower, one chunk's rows in
+    memory, and no row dropped however uneven the routing. `lax.cond` chooses; the usual
+    step pays for the rows it routes.
+
+    x ``[T, d]``; router_weights / selected_experts ``[T, k]`` (ids over all experts);
+    w_fc ``[E_held, d, f]``, w_proj ``[E_held, f, d]`` (no biases, no GLU: `act` is applied
+    to the whole of ``f``). Returns (``[T, d]``, counters): ``routed_slots``,
+    ``absent_slots`` and ``fullest_expert_rows`` (int32 scalars) and ``held_expert_rows``
+    (int32 ``[E_held]``: the rows of each held expert, whose sum and maximum the others are)."""
+    tokens, hidden = x.shape
+    top_k = selected_experts.shape[-1]
+    held = w_fc.shape[0]
+    slots = tokens * top_k
+    if capacity is None:
+        capacity = -(-4 * slots * held // num_experts // 512) * 512
+    capacity = min(max(capacity, 1), slots)
+    chunks = -(-slots // capacity)
+    grouped_product = _share_grouped_product(x)  # asked here, where the model is traced
+
+    with jax.named_scope("moe_dispatch"):
+        local = selected_experts.reshape(-1) - first_expert
+        key = jnp.where((local >= 0) & (local < held), local, held)  # absent experts sort last
+        order = jnp.argsort(key, stable=True)
+        group_sizes = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
+        group_ends = jnp.cumsum(group_sizes)
+        group_starts = group_ends - group_sizes
+        routed = group_ends[-1]
+        order = jnp.pad(order, (0, chunks * capacity - slots))  # whole chunks to slice
+
+    def add_rows(out, start, x, w_fc, w_proj, gates):
+        """Add what the sorted slots ``start .. start + capacity`` give to `out`."""
+        with jax.named_scope("moe_dispatch"):
+            slot = jax.lax.dynamic_slice_in_dim(order, start, capacity)
+            token_index = slot // top_k
+            valid = start + jnp.arange(capacity) < routed
+            # (the select also stops what a grouped product's backward leaves in the rows of
+            # no group from reaching x's gradient through the gather's transpose)
+            xs = jnp.where(valid[:, None], jnp.take(x, token_index, axis=0), 0)
+            # the part of every expert's group that lies in these rows
+            sizes = jnp.clip(
+                jnp.minimum(group_ends, start + capacity) - jnp.maximum(group_starts, start), 0
+            )
+        with jax.named_scope("moe_experts"):
+            h = act(grouped_product(xs, w_fc, sizes))
+            y = grouped_product(h, w_proj, sizes)
+        with jax.named_scope("moe_combine"):
+            # rows past the routed ones belong to no group: whatever the product left
+            # there is not a number of this layer
+            scale = jnp.where(valid, jnp.take(gates, slot), 0.0).astype(y.dtype)
+            y = jnp.where(valid[:, None], y, 0) * scale[:, None]
+            return out.at[token_index].add(y)
+
+    def at_once(x, w_fc, w_proj, gates):
+        return add_rows(jnp.zeros((tokens, hidden), x.dtype), 0, x, w_fc, w_proj, gates)
+
+    def in_chunks(x, w_fc, w_proj, gates):
+        @jax.checkpoint
+        def chunk(out, start):
+            return jax.lax.cond(
+                start < routed,
+                lambda out: add_rows(out, start, x, w_fc, w_proj, gates),
+                lambda out: out,
+                out,
+            )
+
+        out, _ = jax.lax.scan(
+            lambda out, start: (chunk(out, start), None),
+            jnp.zeros((tokens, hidden), x.dtype),
+            jnp.arange(chunks, dtype=jnp.int32) * capacity,
+        )
+        return out
+
+    operands = (x, w_fc, w_proj, router_weights.reshape(-1))
+    if chunks == 1:
+        out = at_once(*operands)
+    else:
+        out = jax.lax.cond(routed <= capacity, at_once, in_chunks, *operands)
+    counters = {
+        "routed_slots": routed,
+        "absent_slots": slots - routed,
+        "fullest_expert_rows": jnp.max(group_sizes),
+        "held_expert_rows": group_sizes,
+    }
+    return out, counters
 
 
 def combine_weights(

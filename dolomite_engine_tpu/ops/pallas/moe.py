@@ -255,3 +255,38 @@ def experts_grouped(
     gates = jnp.take(router_weights.reshape(-1), order).astype(ys.dtype)
     out = jnp.zeros((tokens, hidden), dtype=ys.dtype)
     return out.at[token_index].add(ys * gates[:, None])
+
+
+# ---------------------------------------------------------- a share's grouped product
+
+
+def held_grouped_product(
+    rows: jax.Array, bank: jax.Array, group_sizes: jax.Array, *, interpret: bool | None = None
+) -> jax.Array:
+    """``rows[i] @ bank[g]`` for the rows of group ``g``: jax's megablox grouped matmul
+    (`jax.experimental.pallas.ops.tpu.megablox`, with its own backward: the same kernel on
+    the transposed bank for the rows' gradient, a transposed grouped product for the
+    bank's). The drop-in for `jax.lax.ragged_dot` in `ops/moe.experts_held_ragged`: the
+    grid walks the tiles of the groups that have rows, so rows past ``sum(group_sizes)``
+    cost nothing — and are left as they were: the caller masks them.
+
+    rows ``[m, a]``, bank ``[groups, a, b]``. One device's kernel: under a multi-device mesh
+    it would have to go through `parallel.sharding.shard_kernel`, and the share's layout
+    across chips (experts over ``ep``) is not built — the caller takes `jax.lax.ragged_dot`
+    there (`ops/moe._share_grouped_product`)."""
+    import math
+
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    m, a = rows.shape
+    b = bank.shape[-1]
+    tiling = (math.gcd(m, 512), min(a, 1024), min(b, 1024))
+    with jax.named_scope("pallas_moe_grouped_product"):
+        return gmm(
+            rows,
+            bank,
+            group_sizes,
+            preferred_element_type=rows.dtype,
+            tiling=tiling,
+            interpret=_interpret_default(interpret),
+        )

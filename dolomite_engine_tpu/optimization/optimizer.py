@@ -172,6 +172,20 @@ def get_mup_label_tree(params: Any) -> Any:
     return jax.tree_util.tree_map_with_path(label, params)
 
 
+def _hold_leaves(names: tuple[str, ...]) -> optax.GradientTransformation:
+    """Zero the update of every leaf whose last path key is in `names`."""
+
+    def update(updates, state, params=None):
+        del params
+
+        def hold(path, u):
+            return jax.numpy.zeros_like(u) if getattr(path[-1], "key", None) in names else u
+
+        return jax.tree_util.tree_map_with_path(hold, updates), state
+
+    return optax.GradientTransformation(lambda params: optax.EmptyState(), update)
+
+
 def get_optimizer(
     optimizer_class_name: str,
     optimizer_class_args: dict,
@@ -186,6 +200,15 @@ def get_optimizer(
     factory = _OPTIMIZER_FACTORIES[optimizer_class_name]
     if factory is None:
         raise ValueError(f"optimizer '{optimizer_class_name}' is not supported on TPU")
+
+    buffers = tuple(getattr(model_config, "buffer_names", ()))
+    if buffers:
+        # leaves the public model keeps as buffers (nemotron_h's router correction bias):
+        # no gradient reaches them, and weight decay must not move them either
+        inner = factory
+
+        def factory(schedule, args):
+            return optax.chain(inner(schedule, args), _hold_leaves(buffers))
 
     if params_group_method is None:
         return factory(lr_schedule, optimizer_class_args)

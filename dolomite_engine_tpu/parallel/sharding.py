@@ -60,6 +60,9 @@ def get_logical_axis_rules(
         ("mlp", "tp"),
         ("experts", "ep"),
         ("expert_mlp", "tp"),
+        # Mamba-2's d_inner (in/out projections of models/nemotron_h): not sharded — tp over
+        # the Mamba heads is not built, and the model raises on a mesh with tp > 1
+        ("mamba_inner", None),
         # activation axes
         ("act_batch", ("dp", "fsdp", "ep")),
         ("act_seq", act_seq),

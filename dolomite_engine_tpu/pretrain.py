@@ -227,10 +227,14 @@ def train(
             offload_optimizer=offload,
             skip_nonfinite=ft_args.skip_nonfinite_steps,
             collect_health=monitor.wants_step_metrics,
+            has_aux=bool(model.step_counter_names),
         ),
         donate_argnums=(0,),
         **jit_kwargs,
     )
+    if hasattr(model.config, "layout_record"):
+        # a model cut to a chip's share says once a run what it holds of what was published
+        telemetry.event_once("model_layout", **model.config.layout_record())
     eval_step_fn = jax.jit(
         make_eval_step(
             lambda params, text, rng, fp8_state=None: model.loss(
@@ -410,6 +414,14 @@ def train(
                     # below, so window goodput stays honest without a per-step host sync
                     loss = float(metrics["loss"])
                     grad_norm = float(metrics["grad_norm"])
+                    if "counters" in metrics:
+                        # what the step's forward pass counted (a layer of experts each
+                        # entry), read where the loss is read: no program of its own
+                        telemetry.event(
+                            "step_counters",
+                            step=global_step,
+                            **{k: v.tolist() for k, v in jax.device_get(metrics["counters"]).items()},
+                        )
             step_seconds = time.perf_counter() - step_start
 
             with telemetry.span("loop.account"):

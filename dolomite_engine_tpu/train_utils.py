@@ -83,10 +83,13 @@ def make_train_step(
     offload_optimizer: bool = False,
     skip_nonfinite: bool = False,
     collect_health: bool = False,
+    has_aux: bool = False,
 ):
     """Build the jitted train step.
 
-    `loss_fn(params, micro_batch, rng) -> scalar loss`. `batch` passed to the returned step has
+    `loss_fn(params, micro_batch, rng) -> scalar loss` — or, with `has_aux`, ``(loss,
+    counters)``: a dict of integer arrays the forward pass counted, summed over the
+    micro-batches and returned as ``metrics["counters"]`` by the same program. `batch` passed to the returned step has
     a leading [gradient_accumulation_steps] axis on every leaf.
 
     `offload_optimizer` (cpu_offload, TPU only): the incoming opt state lives in pinned host
@@ -120,7 +123,9 @@ def make_train_step(
 
         # fp8 state is differentiated too: its "gradient" is the NEXT delayed-scaling state
         # (flax overwrite-with-gradient contract, ops/fp8.py) — overwritten, never optimized
-        grad_fn = jax.value_and_grad(micro_loss, argnums=(0, 1) if use_fp8 else 0)
+        grad_fn = jax.value_and_grad(
+            micro_loss, argnums=(0, 1) if use_fp8 else 0, has_aux=has_aux
+        )
 
         # Phase scopes (docs/OBSERVABILITY.md "Phases of the train step"): with the model's
         # own (`embed`, `blocks`, `final_norm`, `head_loss`) they give every operation of
@@ -130,6 +135,9 @@ def make_train_step(
         if gradient_accumulation_steps == 1:
             micro = jax.tree.map(lambda x: x[0], batch)
             loss, grads = grad_fn(state.params, state.fp8, micro, rng)
+            counters = None
+            if has_aux:
+                loss, counters = loss
             if use_fp8:
                 grads, new_fp8 = grads
             with jax.named_scope("grad_clip"):
@@ -142,6 +150,9 @@ def make_train_step(
                 # thread the scaling state through the micro-steps so every micro-batch's
                 # amax observation enters the history (not just the last one's)
                 loss, grads = grad_fn(state.params, fp8_carry, micro_batch, micro_rng)
+                micro_counters = None
+                if has_aux:
+                    loss, micro_counters = loss
                 if use_fp8:
                     grads, fp8_carry = grads
                 grads_acc = jax.tree.map(
@@ -149,16 +160,22 @@ def make_train_step(
                     grads_acc,
                     grads,
                 )
-                return (grads_acc, loss_acc + loss / gradient_accumulation_steps, fp8_carry), None
+                return (
+                    grads_acc,
+                    loss_acc + loss / gradient_accumulation_steps,
+                    fp8_carry,
+                ), micro_counters
 
             zero_grads = jax.tree.map(
                 lambda p: jnp.zeros(p.shape, jnp.float32), state.params
             )
             rngs = jax.random.split(rng, gradient_accumulation_steps)
             with jax.named_scope("accumulate"):
-                (grads, loss, new_fp8), _ = jax.lax.scan(
+                (grads, loss, new_fp8), counters = jax.lax.scan(
                     accum_fn, (zero_grads, jnp.zeros((), jnp.float32), state.fp8), (batch, rngs)
                 )
+                if has_aux:
+                    counters = jax.tree.map(lambda c: jnp.sum(c, axis=0), counters)
 
         with jax.named_scope("grad_clip"):
             grads, grad_norm = clip_grad_norm(grads, gradient_clipping)
@@ -189,6 +206,8 @@ def make_train_step(
             step=state.step + 1, params=new_params, opt_state=new_opt_state, fp8=new_fp8
         )
         metrics = {"loss": loss, "grad_norm": grad_norm}
+        if has_aux:
+            metrics["counters"] = counters
         if step_ok is not None:
             metrics["skipped"] = (~step_ok).astype(jnp.int32)
         if collect_health:
@@ -415,6 +434,11 @@ def get_model_tflops(
         active_experts = config.num_experts
     if active_experts:
         mlp_flops *= active_experts
+
+    if hasattr(config, "forward_block_flops"):
+        # a family whose block is not attention + MLP counts its own blocks, all of them at
+        # once, under this function's conventions (`NemotronHConfig.forward_block_flops`)
+        attention_flops, mlp_flops, l = config.forward_block_flops(b, s), 0.0, 1
 
     forward = l * (attention_flops + mlp_flops)
     backward = 2 * forward

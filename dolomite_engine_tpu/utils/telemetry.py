@@ -298,6 +298,15 @@ KNOWN_EVENTS: tuple[str, ...] = (
     # (ops/loss.plan_loss_backward): token_blocks x vocab_tiles, tile_rows, vocab_shards,
     # tokens_per_device, and the float32 bytes of the accumulators its loops carry
     "loss_tiling",
+    # what a model cut to one chip's share holds of what was published (models/config.py
+    # NemotronHConfig.layout_record: pattern, experts held of published, vocabulary rows
+    # held, the deployment's numbers), once a run
+    "model_layout",
+    # what the step's forward pass counted, returned by the train step beside the loss
+    # (train_utils.make_train_step has_aux) and read where the loss is read: for nemotron_h
+    # one entry a layer of experts — routed_slots (token-slots of held experts: the rows
+    # the grouped products multiply), absent_slots, fullest_expert_rows
+    "step_counters",
     # serving-fleet fault tolerance (serving/cluster/health.py + router.py): one event
     # per downward health edge, per completed drain/rejoin, and when a threaded
     # Router.wait timed out with work still pending (fields name who/why)

@@ -227,3 +227,73 @@ def test_sharded_fused_loss_compiles_for_v5e_2x2(v5e):
     assert of_table == [((vocab // 2, embd), False)], gathers
     # a device's temporaries: the gathered bf16 shard (126 MB) and its gradient's tiles
     assert compiled.memory_analysis().temp_size_in_bytes < 300 * 2**20
+
+
+def test_nemotron_h_tower_step_compiles_for_v5e_at_published_widths(v5e, capsys):
+    """The benchmark cell's whole train step — the 9 layers MEMEM*EME of the `nemotron_h`
+    tower at published widths, 2 packed rows of 8192 tokens, AdamW, as
+    `benchmark/drivers/train_packed_tower.py` builds its arguments — for one described v5e:
+    the chunked scan, the grouped products, splash at GQA 16:1 and the chunked loss on the
+    untied head all lower, and the program fits the chip. The estimate of its temporaries is
+    printed; it is an estimate (PERF.md section 6: it has differed from the chip's reading)."""
+    import types
+
+    from benchmark.drivers.train_packed import build_training_args
+    from benchmark.drivers.train_packed_tower import model_config
+    from benchmark.spec import Spec
+    from dolomite_engine_tpu import pretrain
+    from dolomite_engine_tpu.distributed import TrainState
+    from dolomite_engine_tpu.enums import Mode
+    from dolomite_engine_tpu.ops.pallas import config as pallas_config
+    from dolomite_engine_tpu.ops.pallas import install_kernel_config
+    from dolomite_engine_tpu.train_utils import make_train_step
+    from dolomite_engine_tpu.utils import packages
+
+    one_chip = SingleDeviceSharding(v5e[0])
+    cell = Spec.load().cell("train-nemotron-tower-packed8k")
+    ctx = types.SimpleNamespace(cell=cell, tiny=False, seed=1, out_dir="/nonexistent")
+    cfg = model_config(ctx)
+    args = build_training_args(ctx, cfg, "/nonexistent/corpus", 10)
+    rows, seq = args.training_parameters.micro_batch_size, cfg["n_positions"]
+
+    saved = (jax.default_backend, pallas_config._PLATFORM_KEY, packages.pallas_interpret_mode)
+    jax.default_backend = lambda: "tpu"  # ops/attention asks it before choosing splash
+    pallas_config._PLATFORM_KEY = "tpu:v5e"
+    packages.pallas_interpret_mode = lambda: False
+    try:
+        args.kernel_args.install()
+        model = pretrain.get_model(args, Mode.training)
+        optimizer, _ = pretrain.build_optimizer_from_args(args, model)
+
+        def init():
+            params = nn.unbox(model.model.init(jax.random.PRNGKey(0), **model.get_dummy_inputs())["params"])
+            return TrainState(step=jnp.zeros((), jnp.int32), params=params, opt_state=optimizer.init(params), fp8=None)
+
+        place = lambda tree: jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)  # noqa: E731
+        step = jax.jit(
+            make_train_step(
+                lambda params, micro, rng: model.loss(params, micro["text"], rngs={"dropout": rng}, train=True),
+                optimizer, gradient_clipping=1.0, skip_nonfinite=True, has_aux=bool(model.step_counter_names),
+            ),
+            donate_argnums=(0,),
+        )
+        compiled = step.lower(
+            place(jax.eval_shape(init)),
+            {"text": jax.ShapeDtypeStruct((1, rows, seq + 1), jnp.int32, sharding=one_chip)},
+            jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip),
+        ).compile()
+    finally:
+        jax.default_backend, pallas_config._PLATFORM_KEY, packages.pallas_interpret_mode = saved
+        install_kernel_config(None)
+    memory, text = compiled.memory_analysis(), compiled.as_text()
+    gib = 2.0**30
+    with capsys.disabled():
+        print(
+            f"\nnemotron_h tower step for a described v5e: state {memory.argument_size_in_bytes / gib:.2f} GiB, "
+            f"temporaries (estimate) {memory.temp_size_in_bytes / gib:.2f} GiB"
+        )
+    # on one TPU the experts' grouped products are megablox kernels (`ops/moe._share_grouped_product`): 4 layers
+    # x (forward, replay, three in the backward) x 2 products, beside splash and the norms
+    assert "ragged-dot" not in text and text.count('custom_call_target="tpu_custom_call"') >= 40
+    assert 6.0 * gib < memory.argument_size_in_bytes < 6.5 * gib  # 667M parameters x 10 B of state
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 14.5 * gib

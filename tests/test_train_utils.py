@@ -156,3 +156,27 @@ def test_val_group_names_from_weighted_split_paths():
     assert get_group_names(args, "val_weighted_split_paths") == ["books", "web"]
     assert get_group_names(args, "test_weighted_split_paths") is None
     assert get_group_names(SimpleNamespace(datasets=[]), "val_weighted_split_paths") is None
+
+
+def test_a_family_that_counts_its_own_blocks_is_asked_for_them():
+    """`nemotron_h`'s block is one mixer, not attention + MLP: `get_model_tflops` takes the
+    blocks' forward FLOPs from the config's `forward_block_flops` (it tests no model's
+    name), adds the backward as twice that, one more forward per re-computed block, and
+    the head; here each kind of layer against a hand sum."""
+    from dolomite_engine_tpu.models.config import NemotronHConfig
+
+    config = NemotronHConfig(
+        vocab_size=512, n_positions=64, n_embd=32, n_layer=3, hybrid_override_pattern="ME*", n_head=4,
+        num_key_value_heads=2, attention_head_dim=8, mamba_num_heads=4, mamba_head_dim=16, mamba_n_groups=2,
+        ssm_state_size=8, num_experts=16, num_experts_per_tok=2, experts_held=[4, 4], moe_intermediate_size=24,
+        moe_shared_expert_intermediate_size=48,
+    )
+    b, s, h = 2, 64, 32
+    mamba = 2 * b * s * (h * (64 + (64 + 2 * 2 * 8) + 4) + 64 * h)  # in-projection [z | xBC | dt], out-projection
+    experts = 2 * b * s * (h * 16 + 2 * h * 48 + 2 * (4 / 16) * 2 * h * 24)  # router, shared, the held quarter of 2 slots
+    attention = 2 * b * s * (h * (4 + 2 * 2) * 8 + 4 * 8 * h) + 4 * b * s * s * 4 * 8
+    assert config.forward_block_flops(b, s) == mamba + experts + attention
+    head = 6 * b * s * h * 512
+    assert get_model_tflops(config, b, s) == (3 * (mamba + experts + attention) + head) / 1e12
+    full = get_model_tflops(config, b, s, "block", {"checkpoint_every": 1, "policy": "full"})
+    assert full == (4 * (mamba + experts + attention) + head) / 1e12
