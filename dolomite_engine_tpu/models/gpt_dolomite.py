@@ -13,21 +13,25 @@ Gradient checkpointing: `checkpoint_every` wraps every k-th block in `jax.checkp
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
+from jax.ad_checkpoint import checkpoint_name
 
 from ..parallel.sharding import logical_constraint
 
 from ..enums import AttentionImplementation
+from ..ops.attention import watch_kernel_residuals
 from ..ops.loss import causal_lm_loss, derive_causal_labels, fused_linear_cross_entropy
 from ..ops.rope import RoPEParams
 from .config import CommonConfig
 from .enums import PositionEmbeddingType
 from .modeling_utils import (
+    ATTENTION_KERNEL_RESIDUALS_CHECKPOINT_NAME,
     ATTENTION_OUT_CHECKPOINT_NAME,
     Block,
     KVCache,
@@ -71,32 +75,51 @@ REMAT_POLICY_NAMES = ("full", "save_dots", "save_attention_out", "offload_dots")
 
 
 
+def _and_these_names(policy, *names: str):
+    """`policy`, and the values tagged with `names` kept on the device: jax's
+    `save_from_both_policies`, for a policy that may answer with an `Offloadable`."""
+    named = jax.checkpoint_policies.save_only_these_names(*names)
+
+    def both(prim, *args, **params):
+        return True if named(prim, *args, **params) else policy(prim, *args, **params)
+
+    return both
+
+
 def resolve_named_remat_policy(policy: str):
     """Map a `gradient_checkpointing_args.policy` name to a jax policy fn.
 
     - ``full``: save nothing inside the block (jax's default) — the all-or-nothing remat
       the `checkpoint_every` knob always had; maximum recompute, minimum memory.
-    - ``save_dots``: save every matmul output, recompute only elementwise ops
-      (`dots_saveable`) — near-zero recompute FLOPs at the cost of keeping the big
-      activations.
+    - ``save_dots``: save every matmul output (`dots_saveable`) and, where attention
+      lowered through the Pallas kernel, the kernel's output and log-sum-exp (by their
+      `checkpoint_name`: a `pallas_call` is no dot, and `dots_saveable` alone runs the whole
+      forward kernel again in the backward pass). What replays is elementwise, the norms
+      and rope. On the XLA `sdpa` path the attention's products are dots and were always
+      saved.
     - ``save_attention_out``: save only the attention sublayer output
       (`save_only_these_names` over the `Block`'s checkpoint_name tag) — the named
       middle ground: one [B, S, H] tensor per block survives, the MLP backward starts
-      from it instead of waiting on an attention recompute.
+      from it instead of waiting on an attention recompute (the attention itself still
+      replays: its own backward needs q, k, v and the row statistics).
     - ``offload_dots``: `save_dots`' recompute point with the saved dot outputs parked
-      in pinned host memory instead of HBM (``offload_dot_with_no_batch_dims``).
+      in pinned host memory instead of HBM (``offload_dot_with_no_batch_dims``); the
+      kernel's output and log-sum-exp stay on the device.
     """
     if policy == "full":
         return None
     if policy == "save_dots":
-        return jax.checkpoint_policies.dots_saveable
+        return _and_these_names(
+            jax.checkpoint_policies.dots_saveable, ATTENTION_KERNEL_RESIDUALS_CHECKPOINT_NAME
+        )
     if policy == "save_attention_out":
         return jax.checkpoint_policies.save_only_these_names(
             ATTENTION_OUT_CHECKPOINT_NAME
         )
     if policy == "offload_dots":
-        return jax.checkpoint_policies.offload_dot_with_no_batch_dims(
-            "device", "pinned_host"
+        return _and_these_names(
+            jax.checkpoint_policies.offload_dot_with_no_batch_dims("device", "pinned_host"),
+            ATTENTION_KERNEL_RESIDUALS_CHECKPOINT_NAME,
         )
     raise ValueError(
         f"unknown remat policy '{policy}' (expected one of {REMAT_POLICY_NAMES})"
@@ -131,6 +154,68 @@ def resolve_remat_policy(name: str | None):
             f"{REMAT_POLICY_NAMES} or one of {_REMAT_POLICIES})"
         )
     return getattr(jax.checkpoint_policies, name)
+
+
+def names_kept_on_device(policy_fn) -> tuple[str, ...]:
+    """Which of the repo's `checkpoint_name` tags a policy keeps on the device, asked of the
+    policy itself (so a raw `everything_saveable` answers for both)."""
+    if policy_fn is None:
+        return ()
+    return tuple(
+        name
+        for name in (ATTENTION_OUT_CHECKPOINT_NAME, ATTENTION_KERNEL_RESIDUALS_CHECKPOINT_NAME)
+        if policy_fn(_name_primitive(), name=name) in (True, jax.ad_checkpoint.Saveable)
+    )
+
+
+@functools.cache
+def _name_primitive():
+    """The primitive behind `checkpoint_name`, which jax exports under no public name."""
+    return jax.make_jaxpr(lambda x: checkpoint_name(x, "tag"))(0.0).eqns[0].primitive
+
+
+def remat_plan(
+    checkpoint_policy: str | None,
+    checkpoint_every: int,
+    rematerialized: list[bool],
+    kernel_residual_bytes: list[int],
+) -> dict:
+    """What the telemetry event ``remat_plan`` says, once a traced model: how the remat
+    policy engaged. A block an entry: whether it sits under `jax.checkpoint`, and the bytes a
+    batch row of the residuals its attention kernel tagged in this trace
+    (`ops.attention.watch_kernel_residuals`; 0 where attention lowered through XLA or the
+    block holds none). A kernel whose residuals the policy does not keep runs its forward
+    again in the backward pass."""
+    names = names_kept_on_device(resolve_remat_policy(checkpoint_policy))
+    through_kernel = [b for r, b in zip(rematerialized, kernel_residual_bytes) if r and b]
+    kept = ATTENTION_KERNEL_RESIDUALS_CHECKPOINT_NAME in names
+    return {
+        "policy": checkpoint_policy or "full",
+        "checkpoint_every": checkpoint_every,
+        "saved_names": names,
+        "blocks": len(rematerialized),
+        "blocks_rematerialized": sum(rematerialized),
+        "attention_kernel_blocks": len(through_kernel),
+        "attention_kernel_residuals_saved": len(through_kernel) if kept else 0,
+        "attention_kernel_residual_bytes_per_block_row": max(through_kernel, default=0),
+    }
+
+
+def say_remat_plan(model: nn.Module, kernel_residual_bytes: list[int]) -> None:
+    """Write the ``remat_plan`` event of a model that rematerializes (its
+    `checkpoint_policy`, `checkpoint_every`, `rematerialized`), once a distinct plan."""
+    if model.checkpoint_every:
+        from ..utils.telemetry import get_telemetry
+
+        get_telemetry().event_once(
+            "remat_plan",
+            **remat_plan(
+                model.checkpoint_policy,
+                model.checkpoint_every,
+                model.rematerialized,
+                kernel_residual_bytes,
+            ),
+        )
 
 
 class GPTDolomiteModel(nn.Module):
@@ -208,6 +293,7 @@ class GPTDolomiteModel(nn.Module):
                 )
             if self.checkpoint_every:
                 cls = nn.remat(cls, static_argnums=(8,), prevent_cse=False, policy=remat_policy)
+            self.rematerialized = (self.checkpoint_every > 0,) * self.num_blocks
             self.h_scan = nn.scan(
                 cls,
                 variable_axes={"params": 0},
@@ -217,10 +303,14 @@ class GPTDolomiteModel(nn.Module):
                 metadata_params={nn.PARTITION_NAME: "layers"},
             )(**inst_kwargs)
         else:
+            self.rematerialized = tuple(
+                self.checkpoint_every > 0 and i % self.checkpoint_every == 0
+                for i in range(self.num_blocks)
+            )
             blocks = []
             for i in range(self.num_blocks):
                 cls = self.block_cls
-                if self.checkpoint_every and i % self.checkpoint_every == 0:
+                if self.rematerialized[i]:
                     # flax counts the module instance as argument 0; deterministic is arg 8
                     cls = nn.remat(
                         cls, static_argnums=(8,), prevent_cse=False, policy=remat_policy
@@ -315,7 +405,7 @@ class GPTDolomiteModel(nn.Module):
                 "scan_layers is a training-path feature; for generation convert the "
                 "checkpoint with unstack_block_params and rebuild without scan_layers"
             )
-            with jax.named_scope("blocks"):
+            with jax.named_scope("blocks"), watch_kernel_residuals() as seen:
                 hidden_states, _ = self.h_scan(
                     hidden_states,
                     attention_mask,
@@ -326,13 +416,17 @@ class GPTDolomiteModel(nn.Module):
                     None,
                     deterministic,
                 )
+            # one body for every block: what its trace tagged, every block tagged
+            say_remat_plan(self, [seen[0] if seen else 0] * self.num_blocks)
             with jax.named_scope("final_norm"):
                 return self.ln_f(hidden_states), None, []
 
         new_caches = [] if kv_caches is not None else None
         extras = []  # per-block extra outputs (MoE router logits etc.)
-        with jax.named_scope("blocks"):
+        kernel_residual_bytes = []
+        with jax.named_scope("blocks"), watch_kernel_residuals() as seen:
             for i, block in enumerate(self.h):
+                calls_before = len(seen)
                 out = block(
                     hidden_states,
                     attention_mask,
@@ -343,11 +437,14 @@ class GPTDolomiteModel(nn.Module):
                     cache_index,
                     deterministic,
                 )
+                kernel_residual_bytes.append(sum(seen[calls_before:]))
                 hidden_states, cache = out[0], out[1]
                 if len(out) > 2 and out[2] is not None:
                     extras.append(out[2])
                 if new_caches is not None:
                     new_caches.append(cache)
+
+        say_remat_plan(self, kernel_residual_bytes)
 
         with jax.named_scope("final_norm"):
             hidden_states = self.ln_f(hidden_states)

@@ -49,6 +49,12 @@ KVCache = dict[str, jax.Array]  # {"k": [B, L, Hkv, D], "v": [B, L, Hkv, D]}
 # save_attention_out remat policy (models/gpt_dolomite.resolve_named_remat_policy)
 # saves exactly these tensors
 ATTENTION_OUT_CHECKPOINT_NAME = "attention_out"
+# checkpoint_name tag on what the attention kernel hands its own backward rule: its output
+# [rows, heads, S, head] and the rows' log-sum-exp [rows, heads, S] (ops/attention.
+# _splash_attention_local). A pallas_call is no dot, so `dots_saveable` alone replays the
+# whole forward kernel in the backward pass; the save_dots and offload_dots policies keep
+# these two by name
+ATTENTION_KERNEL_RESIDUALS_CHECKPOINT_NAME = "attention_kernel_residuals"
 
 
 def _normal_init(std: float) -> Callable:

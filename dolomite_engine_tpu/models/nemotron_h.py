@@ -38,11 +38,12 @@ from flax import linen as nn
 
 from ..enums import AttentionImplementation
 from ..ops.activations import get_activation_function
+from ..ops.attention import watch_kernel_residuals
 from ..ops.mamba2 import causal_conv1d, gated_group_rmsnorm, mamba2_chunked
 from ..ops.moe import experts_held_ragged, route_sigmoid_bias
 from ..parallel.sharding import logical_constraint
 from .config import NemotronHConfig
-from .gpt_dolomite import GPTDolomiteForCausalLM, resolve_remat_policy
+from .gpt_dolomite import GPTDolomiteForCausalLM, resolve_remat_policy, say_remat_plan
 from .modeling_utils import (
     Attention,
     ParameterizedEmbedding,
@@ -317,10 +318,14 @@ class NemotronHModel(nn.Module):
             dtype=self.dtype,
         )
         remat_policy = resolve_remat_policy(self.checkpoint_policy)
+        self.rematerialized = tuple(
+            self.checkpoint_every > 0 and i % self.checkpoint_every == 0
+            for i in range(len(config.hybrid_override_pattern))
+        )
         blocks = []
         for i, mixer in enumerate(config.hybrid_override_pattern):
             cls = NemotronHBlock
-            if self.checkpoint_every and i % self.checkpoint_every == 0:
+            if self.rematerialized[i]:
                 # flax counts the module instance as argument 0; deterministic is arg 4.
                 # prevent_cse stays on: the layers are unrolled, and XLA would merge a
                 # layer's replay with its forward pass and keep every layer's activations
@@ -359,11 +364,15 @@ class NemotronHModel(nn.Module):
             # padded rows: the pad tokens are a document of their own
             segment_ids = attention_mask.astype(jnp.int32)
         extras = []
-        with jax.named_scope("blocks"):
+        kernel_residual_bytes = []
+        with jax.named_scope("blocks"), watch_kernel_residuals() as seen:
             for block in self.h:
+                calls_before = len(seen)
                 hidden_states, counters = block(hidden_states, attention_mask, segment_ids, deterministic)
+                kernel_residual_bytes.append(sum(seen[calls_before:]))
                 if counters is not None:
                     extras.append(counters)
+        say_remat_plan(self, kernel_residual_bytes)
         with jax.named_scope("final_norm"):
             hidden_states = self.ln_f(hidden_states)
         return hidden_states, None, extras

@@ -16,6 +16,7 @@ is [B, S] tokens + segment_ids [B, S] (0 = padding, 1.. = documents); this also 
 
 from __future__ import annotations
 
+from contextlib import contextmanager
 from functools import partial
 
 import jax
@@ -326,6 +327,18 @@ def _use_splash_kernel() -> bool:
     return use_pallas("splash_attention")
 
 
+def splash_expected(implementation: AttentionImplementation | None) -> bool:
+    """Whether a model's causal self-attention is expected to lower through the splash
+    kernel: asked for as ``flash_attention_2``, on a TPU, the family on Pallas. `attention`
+    may still drop the kernel for a call and says why (dropout, a mask that is no padding
+    mask, a kv cache, a length off 128, alibi); what a trace did is in its ``remat_plan``."""
+    return (
+        implementation == AttentionImplementation.flash_attention_2
+        and jax.default_backend() == "tpu"
+        and _use_splash_kernel()
+    )
+
+
 def _tpu_splash_attention(
     q: jax.Array,
     k: jax.Array,
@@ -361,11 +374,31 @@ def _tpu_splash_attention(
     return out
 
 
+# lists that `watch_kernel_residuals` opened, innermost last
+_RESIDUAL_WATCHERS: list[list[int]] = []
+
+
+@contextmanager
+def watch_kernel_residuals():
+    """What the attention kernel tagged for the remat policy in the trace inside: one entry a
+    kernel call, the bytes a batch row (on a device) of its output and log-sum-exp. Empty
+    where attention lowered through XLA — its products are dots there. The model reads it for
+    its ``remat_plan`` event (`models/gpt_dolomite.remat_plan`)."""
+    seen: list[int] = []
+    _RESIDUAL_WATCHERS.append(seen)
+    try:
+        yield seen
+    finally:
+        _RESIDUAL_WATCHERS.pop()
+
+
 def _splash_attention_local(q, k, v, segment_ids, softmax_scale: float, interpret: bool):
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as _sk,
         splash_attention_mask as _sm,
     )
+
+    from ..models.modeling_utils import ATTENTION_KERNEL_RESIDUALS_CHECKPOINT_NAME
 
     qt = jnp.swapaxes(q, 1, 2)  # [B, Hq, S, D]
     kt = jnp.swapaxes(k, 1, 2)  # [B, Hkv, S, D]
@@ -385,9 +418,18 @@ def _splash_attention_local(q, k, v, segment_ids, softmax_scale: float, interpre
         block_kv_dq=bkv,
     )
     mask = _sm.MultiHeadMask([_sm.CausalMask((sq, skv)) for _ in range(num_q_heads)])
+    # the name makes jax's kernel tag its output and log-sum-exp with `checkpoint_name`, so a
+    # remat policy can keep them (save_dots does) and the backward pass need not run the
+    # forward kernel again; outside a remat and under a policy without the name the tag is
+    # an identity that does not reach the HLO
     kernel = _sk.make_splash_mha_single_device(
-        mask, block_sizes=block_sizes, interpret=interpret
+        mask,
+        block_sizes=block_sizes,
+        residual_checkpoint_name=ATTENTION_KERNEL_RESIDUALS_CHECKPOINT_NAME,
+        interpret=interpret,
     )
+    for seen in _RESIDUAL_WATCHERS:
+        seen.append(num_q_heads * sq * (qt.shape[3] * qt.dtype.itemsize + 4))
 
     qs = qt * softmax_scale  # splash has no sm_scale argument
     if segment_ids is None:

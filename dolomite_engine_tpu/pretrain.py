@@ -198,6 +198,7 @@ def train(
     install_telemetry(telemetry)
     monitor = build_health_monitor(args, telemetry)
     register_crash_hook(monitor.dump_flight_record)
+    from .ops.attention import splash_expected
     from .train_utils import estimate_remat_activation_bytes
 
     emit_model_report(
@@ -211,6 +212,7 @@ def train(
             gradient_checkpointing_method=args.distributed_args.gradient_checkpointing_method,
             gradient_checkpointing_args=args.distributed_args.gradient_checkpointing_args,
             dtype_bytes=jnp.dtype(model.dtype).itemsize,
+            attention_kernel=splash_expected(model.attention_implementation),
         ),
     )
 

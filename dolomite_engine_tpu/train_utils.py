@@ -483,9 +483,16 @@ def estimate_remat_activation_bytes(
     gradient_checkpointing_method=None,
     gradient_checkpointing_args: dict | None = None,
     dtype_bytes: int = 4,
+    attention_kernel: bool = False,
 ) -> dict:
     """Analytic per-replica estimate of the activation bytes each remat policy keeps
     live between forward and backward, and the delta vs the ``full`` policy.
+
+    ``attention_kernel``: attention lowers through the Pallas kernel
+    (`ops.attention.splash_expected`). Its score and context products are then no dots and
+    no policy sees them; ``save_dots`` and ``offload_dots`` keep, by name, the kernel's
+    output and the rows' float32 log-sum-exp on the device (what the ``remat_plan``
+    telemetry event counts a block and row), the raw ``*saveable`` names keep neither.
 
     Counts only what the policy SAVES (block-boundary carries plus the policy's
     selected residuals per checkpointed block); XLA scratch, attention workspace, and
@@ -506,7 +513,7 @@ def estimate_remat_activation_bytes(
     token_bytes = b * s * dtype_bytes
     boundary = l // max(every, 1) * token_bytes * h if every else l * token_bytes * h
 
-    per_block_extra = 0.0
+    per_block_extra = kernel_residuals = 0.0
     if every:
         if policy in ("save_dots", "offload_dots") or "saveable" in policy:
             # every dot output: fused qkv + attention scores + context + out proj +
@@ -514,18 +521,22 @@ def estimate_remat_activation_bytes(
             glu = 2 if "glu" in str(config.activation_function) else 1
             per_block_extra = token_bytes * (
                 h * (1 + 2 * kvh / n)  # qkv projection output
-                + n * s  # attention scores [b, n, s, s]
-                + 3 * h  # context + attention out proj + mlp c_proj
+                + (0 if attention_kernel else n * s + h)  # scores [b, n, s, s] + context
+                + 2 * h  # attention out proj + mlp c_proj
                 + glu * f  # c_fc output
             )
+            if attention_kernel and policy in ("save_dots", "offload_dots"):
+                # the kernel's output [b, n, s, head] and log-sum-exp [b, n, s] float32
+                kernel_residuals = token_bytes * h + b * s * n * 4
         elif policy == "save_attention_out":
             per_block_extra = token_bytes * h
     checkpointed_blocks = (l // max(every, 1)) if every else 0
     extra = checkpointed_blocks * per_block_extra
+    kept_on_device = checkpointed_blocks * kernel_residuals
 
     # offload parks the saved dots in pinned host memory: device HBM sees only the
-    # boundaries, the host pays `extra`
-    device_bytes = boundary + (0.0 if policy == "offload_dots" else extra)
+    # boundaries and the kernel's residuals, the host pays `extra`
+    device_bytes = boundary + kept_on_device + (0.0 if policy == "offload_dots" else extra)
     host_bytes = extra if policy == "offload_dots" else 0.0
     full_bytes = float(boundary)  # the full policy saves boundaries only
 

@@ -298,6 +298,12 @@ KNOWN_EVENTS: tuple[str, ...] = (
     # (ops/loss.plan_loss_backward): token_blocks x vocab_tiles, tile_rows, vocab_shards,
     # tokens_per_device, and the float32 bytes of the accumulators its loops carry
     "loss_tiling",
+    # how the remat policy engaged where the model was traced (models/gpt_dolomite.remat_plan):
+    # policy, checkpoint_every, the checkpoint_name tags it keeps, blocks and how many sit
+    # under jax.checkpoint, how many of those ran attention through the Pallas kernel, how
+    # many of them keep the kernel's output and log-sum-exp (the others run the forward
+    # kernel again in the backward pass), and those bytes a block and batch row
+    "remat_plan",
     # what a model cut to one chip's share holds of what was published (models/config.py
     # NemotronHConfig.layout_record: pattern, experts held of published, vocabulary rows
     # held, the deployment's numbers), once a run

@@ -14,6 +14,7 @@ in the table. Nothing runs, so nothing here says anything about results or speed
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or libtpu logs under /tmp
 
@@ -141,12 +142,9 @@ def test_kernel_compiles_for_v5e(v5e, family, case, width):
     assert "tpu_custom_call" in jax.jit(fn).lower(*args).compile().as_text()
 
 
-@pytest.mark.parametrize("width", WIDTHS)
-def test_sharded_block_compiles_for_v5e_2x2(v5e, width):
-    """Norm, rope+QKV and splash, forward and backward, under fsdp 2 x tp 2 with
-    sequence parallelism — the layout `chip_smoke.py --chips 4` trains on. GSPMD refuses
-    to partition a Mosaic kernel, so each has to arrive inside a `shard_map`
-    (`parallel.sharding.shard_kernel`), also where the backward is traced."""
+def _sharded_block_gradient_text(v5e, width, wrap=lambda block: block) -> str:
+    """The compiled value and gradient of norm, rope+QKV and splash under fsdp 2 x tp 2 with
+    sequence parallelism; `wrap` puts the block under a `jax.checkpoint`."""
     embd, heads, kv_heads, head_dim, batch, seq = WIDTHS[width]
     mesh = Mesh(np.asarray(v5e).reshape(1, 2, 1, 2, 1), MESH_AXES)
     fused = (heads + 2 * kv_heads) * head_dim
@@ -180,8 +178,31 @@ def test_sharded_block_compiles_for_v5e_2x2(v5e, width):
         spec((batch, seq), jnp.int32, "fsdp"),
     )
     with mesh, nn.logical_axis_rules(get_logical_axis_rules(stage=3, sequence_parallel=True)):
-        text = jax.jit(jax.grad(block, argnums=(0, 3))).lower(*args).compile().as_text()
+        return jax.jit(jax.value_and_grad(wrap(block), argnums=(0, 3))).lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("width", WIDTHS)
+def test_sharded_block_compiles_for_v5e_2x2(v5e, width):
+    """Norm, rope+QKV and splash, forward and backward, under fsdp 2 x tp 2 with
+    sequence parallelism — the layout `chip_smoke.py --chips 4` trains on. GSPMD refuses
+    to partition a Mosaic kernel, so each has to arrive inside a `shard_map`
+    (`parallel.sharding.shard_kernel`), also where the backward is traced."""
+    text = _sharded_block_gradient_text(v5e, width)
     assert text.count('custom_call_target="tpu_custom_call"') >= 6  # 1 + 2 + 3 kernels, fwd + bwd
+
+
+@pytest.mark.parametrize("policy, forward_kernels", [("save_dots", 1), ("dots_saveable", 2), ("full", 2)])
+def test_remat_policy_reaches_the_kernel_inside_its_shard_map_for_v5e_2x2(v5e, policy, forward_kernels):
+    """The same block under `jax.checkpoint`, as a remat'ed layer is: `save_dots` keeps the
+    splash kernel's output and log-sum-exp by their name, through the `shard_map` the mesh
+    puts the kernel in, and the compiled gradient holds the forward kernel once; a policy
+    without the name runs it again in the backward pass."""
+    from dolomite_engine_tpu.models.gpt_dolomite import resolve_remat_policy
+
+    remat = lambda block: jax.checkpoint(block, policy=resolve_remat_policy(policy))  # noqa: E731
+    text = _sharded_block_gradient_text(v5e, "smoke_2560_32x80_s4096", remat)
+    assert len(re.findall(r"%splash_mha_fwd\S* = ", text)) == forward_kernels
+    assert len(re.findall(r"%splash_mha_dkv\S* = ", text)) == 1
 
 
 def test_sharded_fused_loss_compiles_for_v5e_2x2(v5e):

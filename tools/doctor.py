@@ -147,6 +147,7 @@ def main(argv: list[str] | None = None) -> int:
     remat = None
     sequence_length = getattr(model, "sequence_length", None)
     if args.training_parameters is not None and sequence_length:
+        from dolomite_engine_tpu.ops.attention import splash_expected
         from dolomite_engine_tpu.train_utils import estimate_remat_activation_bytes
 
         model_tflops = get_model_tflops(
@@ -167,6 +168,7 @@ def main(argv: list[str] | None = None) -> int:
             gradient_checkpointing_method=args.distributed_args.gradient_checkpointing_method,
             gradient_checkpointing_args=args.distributed_args.gradient_checkpointing_args,
             dtype_bytes=jnp.dtype(model.dtype).itemsize,
+            attention_kernel=splash_expected(model.attention_implementation),
         )
 
     report = build_model_report(
