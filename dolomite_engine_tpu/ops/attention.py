@@ -320,8 +320,7 @@ def _use_splash_kernel() -> bool:
     MaxText kernel: GQA without KV-head repetition, fused bwd option) or the legacy
     flash kernel. Numerics are pinned by tests in interpret mode; splash against legacy
     flash on hardware: not measured. Selection lives in the central KernelConfig
-    (`ops/pallas/config.py` — ``kernel_args`` block / ``DOLOMITE_KERNELS``; the legacy
-    ``DOLOMITE_SPLASH_ATTENTION=1`` spelling still works as an env alias)."""
+    (`ops/pallas/config.py` — ``kernel_args`` block / ``DOLOMITE_KERNELS``)."""
     from .pallas import use_pallas
 
     return use_pallas("splash_attention")

@@ -4,8 +4,7 @@ Every hand-written kernel in this package sits behind a per-op-family switch wit
 plain-XLA lowering as the numerical reference:
 
 - ``splash_attention``: the GQA-native Pallas splash kernel for full-sequence causal
-  attention (`ops/attention.py` — previously the ad-hoc ``DOLOMITE_SPLASH_ATTENTION`` env
-  sniff, still honored as a legacy alias).
+  attention (`ops/attention.py`).
 - ``paged_attention``: the ragged paged-attention decode kernel (`paged_attention.py`) —
   serving decode/verify reads K/V straight through the page table instead of
   gather-then-mask.
@@ -188,9 +187,6 @@ def _config_from_env() -> KernelConfig:
                 f"(expected one of {KERNEL_FAMILIES})"
             )
         overrides[family] = _coerce_backend(backend.strip()) if sep else KernelBackend.pallas
-    # legacy opt-in spelling from the splash-attention PR, kept working
-    if os.environ.get("DOLOMITE_SPLASH_ATTENTION", "0") == "1":
-        overrides.setdefault("splash_attention", KernelBackend.pallas)
     return KernelConfig(**overrides)
 
 

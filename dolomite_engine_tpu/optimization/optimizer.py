@@ -20,6 +20,7 @@ import jax
 import optax
 
 from ..enums import ParamsGroupMethod
+from .scheduler import get_scheduler
 
 # every reference optimizer name -> optax factory(lr_schedule, args)
 # kernel-variant aliases collapse to their mathematical equivalent
@@ -233,3 +234,27 @@ def get_optimizer(
         )
 
     raise ValueError(f"unexpected params_group_method ({params_group_method})")
+
+
+def build_optimizer_from_args(args, model) -> tuple[optax.GradientTransformation, Callable]:
+    """(optimizer, lr schedule) as a `TrainingArgs` describes them, for `model`'s parameters."""
+    lr_scheduler_args = args.lr_scheduler_args
+    lr_schedule = get_scheduler(
+        num_warmup_steps=lr_scheduler_args.num_warmup_steps,
+        num_constant_steps=lr_scheduler_args.num_constant_steps,
+        num_decay_steps=lr_scheduler_args.num_decay_steps,
+        num_training_steps=args.training_parameters.num_training_steps,
+        lr_decay_style=lr_scheduler_args.lr_decay_style,
+        lr_decay_factor=lr_scheduler_args.lr_decay_factor,
+        extra_lr_scheduler_args=lr_scheduler_args.extra_lr_scheduler_args,
+        base_lr=args.optimizer_args.class_args.get("lr", 1e-5),
+    )
+    optimizer = get_optimizer(
+        optimizer_class_name=args.optimizer_args.class_name,
+        optimizer_class_args=args.optimizer_args.class_args,
+        lr_schedule=lr_schedule,
+        params_group_method=args.optimizer_args.params_group_method,
+        model_config=model.config,
+        params=model.abstract_params(),
+    )
+    return optimizer, lr_schedule

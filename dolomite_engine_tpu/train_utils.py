@@ -245,29 +245,11 @@ def handle_nonfinite_step(
     return consecutive
 
 
-def run_timed_windows(jit_step, state, batch, rng: jax.Array, steps: int, windows: int):
-    """Median-of-windows step timing shared by `bench.py` and `tools/bench_sweep.py`: run
-    `windows` blocks of `steps` steps, syncing once per block. Returns (final_state,
-    per-step window times); callers take the median and report the spread."""
-    import time as _time
+def make_eval_step(model):
+    """`eval_step(params, batch, fp8_state=None)`: the model's loss with dropout off."""
 
-    window_times: list[float] = []
-    i = 0
-    for _ in range(max(windows, 1)):
-        t0 = _time.perf_counter()
-        for _ in range(steps):
-            state, metrics = jit_step(state, batch, jax.random.fold_in(rng, i))
-            i += 1
-        jax.block_until_ready(metrics["loss"])
-        window_times.append((_time.perf_counter() - t0) / steps)
-    return state, window_times
-
-
-def make_eval_step(loss_fn: Callable):
     def eval_step(params, batch, fp8_state=None):
-        if fp8_state is not None:
-            return loss_fn(params, batch, None, fp8_state)
-        return loss_fn(params, batch, None)
+        return model.loss(params, batch, rngs=None, train=False, fp8_state=fp8_state)
 
     return eval_step
 

@@ -10,9 +10,7 @@ from .diagnostics import (
     FlightRecorder,
     HealthMonitor,
     StragglerDetector,
-    build_health_monitor,
     build_model_report,
-    crash_reason,
     emit_model_report,
     per_group_health,
 )
@@ -20,12 +18,10 @@ from .fault_tolerance import (
     StallWatchdog,
     install_preemption_handler,
     preemption_requested,
-    register_crash_hook,
     request_preemption,
     reset_preemption,
     run_crash_hooks,
     uninstall_preemption_handler,
-    unregister_crash_hook,
 )
 from .logger import (
     get_logger,
@@ -52,7 +48,6 @@ from .safetensors import SafeTensorsWeightsManager
 from .telemetry import (
     OnDemandProfiler,
     Telemetry,
-    build_telemetry,
     collect_memory_gauges,
     detect_peak_tflops_per_device,
     get_telemetry,

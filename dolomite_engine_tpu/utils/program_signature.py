@@ -15,8 +15,8 @@ Three consumers share this one extraction path (no private ``memory_analysis()``
 - ``tools/perf_ledger.py`` captures a canonical program suite into ``PERF_LEDGER.json``
   and diffs the current tree against it with per-metric tolerances — the CPU-tier
   regression gate (docs/OBSERVABILITY.md "Perf ledger").
-- ``tools/bench_sweep.py`` / ``tools/scaling_report.py`` / ``tools/doctor.py`` read their
-  HBM/flops columns from signatures.
+- ``tools/scaling_report.py`` / ``tools/doctor.py`` read their HBM/flops columns from
+  signatures.
 - ``ServingEngine.program_signatures()`` and the train loops' flagged capture self-report
   what compiled into the telemetry sink (``program_signature`` record kind).
 

@@ -430,7 +430,7 @@ def test_steady_state_data_bucket_shrinks_under_prefetch(tmp_path, monkeypatch):
         time.sleep(0.08)
         yield
 
-    monkeypatch.setattr(finetune, "get_profiler_context", slow_profiler_context)
+    monkeypatch.setattr("dolomite_engine_tpu.train_loop.get_profiler_context", slow_profiler_context)
 
     def run(depth, where):
         prefetcher = StepPrefetcher(

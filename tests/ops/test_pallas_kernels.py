@@ -62,7 +62,6 @@ def _clean_kernel_selection(monkeypatch):
     from dolomite_engine_tpu.ops.pallas import config as kernel_config_module
 
     monkeypatch.delenv("DOLOMITE_KERNELS", raising=False)
-    monkeypatch.delenv("DOLOMITE_SPLASH_ATTENTION", raising=False)
     previous = kernel_config_module._INSTALLED
     install_kernel_config(None)
     yield
@@ -91,14 +90,6 @@ def test_env_override_parsing(monkeypatch):
     assert config.moe_dispatch is KernelBackend.xla
     assert config.splash_attention is KernelBackend.auto  # untouched families stay auto
     assert resolved_kernel_backend("splash_attention") is KernelBackend.xla  # ...cpu
-
-
-def test_env_override_legacy_splash_alias(monkeypatch):
-    monkeypatch.setenv("DOLOMITE_SPLASH_ATTENTION", "1")
-    assert get_kernel_config().splash_attention is KernelBackend.pallas
-    # explicit DOLOMITE_KERNELS beats the legacy alias
-    monkeypatch.setenv("DOLOMITE_KERNELS", "splash_attention=xla")
-    assert get_kernel_config().splash_attention is KernelBackend.xla
 
 
 def test_env_override_unknown_family_raises(monkeypatch):

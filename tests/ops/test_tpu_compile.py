@@ -7,7 +7,7 @@ here and compiles for a chip that is described, not attached
 (`jax.experimental.topologies`), so every Pallas family the promotion table turns on for
 a TPU (`ops/pallas/config._PLATFORM_PROMOTIONS`) is compiled with ``interpret=False`` at
 the widths that run: the flagship `chip_smoke.py` drives (n_embd 2560, 32 heads of 80,
-seq 4096, 16-token pages, the engine's 512-token prefill chunk) and the `bench.py`
+seq 4096, 16-token pages, the engine's 512-token prefill chunk) and a GQA
 configuration (n_embd 1024, 16 query / 8 kv heads of 64, seq 2048). A family that stops
 compiling fails here, at no chip time; one that is demoted leaves this file with its row
 in the table. Nothing runs, so nothing here says anything about results or speed.

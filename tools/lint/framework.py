@@ -31,7 +31,6 @@ DEFAULT_ROOTS = (
     "dolomite_engine_tpu",
     "tools",
     "scripts",
-    "bench.py",
     "chip_smoke.py",
     "__graft_entry__.py",
 )

@@ -52,7 +52,7 @@ _SERVE = dict(num_slots=2, max_len=64, page_size=8, prefill_chunk_tokens=16,
 
 def _train_step_suite(model_type: str):
     """One capture per remat policy of the full jitted train step (ZeRO-3-style state,
-    donated, fused chunked CE) — the programs `bench_sweep.py --remat` times."""
+    donated, fused chunked CE)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
