@@ -23,8 +23,10 @@ raise rather than replicate the Mamba heads or the experts silently.
 Scopes inside the jitted step (docs/OBSERVABILITY.md "Phases of the train step"):
 ``mamba_mixer`` (``mamba_in_proj``, ``mamba_conv``, ``mamba2_scan``, ``mamba_gated_norm``,
 ``mamba_out_proj``), ``moe`` (``moe_router``, ``moe_dispatch``, ``moe_experts``,
-``moe_shared_expert``, ``moe_combine``), ``attention``. Everything is differentiated by
-JAX, so the backward pass carries them under ``transpose(...)``.
+``moe_shared_expert``, ``moe_combine``), ``attention``. The backward pass carries them
+under ``transpose(...)``: nearly everything is differentiated by JAX, and the backward rules
+of the kernels (splash, the scan's where `ops/mamba2.mamba2_scan` takes it) inherit the
+scopes of their calls.
 """
 
 from __future__ import annotations
@@ -39,7 +41,7 @@ from flax import linen as nn
 from ..enums import AttentionImplementation
 from ..ops.activations import get_activation_function
 from ..ops.attention import watch_kernel_residuals
-from ..ops.mamba2 import causal_conv1d, gated_group_rmsnorm, mamba2_chunked
+from ..ops.mamba2 import causal_conv1d, gated_group_rmsnorm, mamba2_scan, watch_scan_lowerings
 from ..ops.moe import experts_held_ragged, route_sigmoid_bias
 from ..parallel.sharding import logical_constraint
 from .config import NemotronHConfig
@@ -60,6 +62,23 @@ STEP_COUNTERS = ("routed_slots", "absent_slots", "fullest_expert_rows", "held_ex
 
 def _inverse_softplus(x: jax.Array) -> jax.Array:
     return x + jnp.log(-jnp.expm1(-x))
+
+
+def scan_plan(scans: list[dict]) -> dict:
+    """What the telemetry event ``mamba2_scan_plan`` says, once a traced model: which lowering
+    of the chunked scan each ``M`` layer took (`ops/mamba2.scan_lowering`'s record of each call,
+    in the order of the layers), and of the `jnp` ones why."""
+    kernel = [plan for plan in scans if plan["form"] == "kernel"]
+    return {
+        "layers": len(scans),
+        "kernel_layers": tuple(i for i, plan in enumerate(scans) if plan["form"] == "kernel"),
+        "jnp_layers": tuple(i for i, plan in enumerate(scans) if plan["form"] == "jnp"),
+        "jnp_reasons": tuple(sorted({plan["reason"] for plan in scans if plan["form"] == "jnp"})),
+        "chunk": max(plan["chunk"] for plan in scans),
+        "kernel_launches_per_layer_and_pass": max((plan["launches_per_pass"] for plan in kernel), default=0),
+        "kept_bytes_per_layer": max((plan["kept_bytes"] for plan in kernel), default=0),
+        "kept_state_bytes_per_layer": max((plan["kept_state_bytes"] for plan in kernel), default=0),
+    }
 
 
 class Mamba2Mixer(nn.Module):
@@ -123,8 +142,8 @@ class Mamba2Mixer(nn.Module):
         d_skip = self.param("D", per_head(nn.initializers.ones_init()), (heads,), jnp.float32)
 
         with jax.named_scope("mamba2_scan"):
-            seq_chunk = config.chunk_size if seq % config.chunk_size == 0 else seq
-            y = jax.checkpoint(mamba2_chunked, static_argnums=(7,))(
+            # one Pallas kernel a pass or the `jnp` form, by what the trace observes
+            y = mamba2_scan(
                 x.reshape(batch, seq, heads, width),
                 jax.nn.softplus(dt.astype(jnp.float32) + dt_bias),
                 -jnp.exp(a_log),
@@ -132,7 +151,7 @@ class Mamba2Mixer(nn.Module):
                 c.reshape(batch, seq, groups, state),
                 d_skip,
                 segment_ids,
-                seq_chunk,
+                config.chunk_size,
             ).reshape(batch, seq, inner)
 
         with jax.named_scope("mamba_gated_norm"):
@@ -365,7 +384,7 @@ class NemotronHModel(nn.Module):
             segment_ids = attention_mask.astype(jnp.int32)
         extras = []
         kernel_residual_bytes = []
-        with jax.named_scope("blocks"), watch_kernel_residuals() as seen:
+        with jax.named_scope("blocks"), watch_kernel_residuals() as seen, watch_scan_lowerings() as scans:
             for block in self.h:
                 calls_before = len(seen)
                 hidden_states, counters = block(hidden_states, attention_mask, segment_ids, deterministic)
@@ -373,6 +392,10 @@ class NemotronHModel(nn.Module):
                 if counters is not None:
                     extras.append(counters)
         say_remat_plan(self, kernel_residual_bytes)
+        if scans:
+            from ..utils.telemetry import get_telemetry
+
+            get_telemetry().event_once("mamba2_scan_plan", **scan_plan(scans))
         with jax.named_scope("final_norm"):
             hidden_states = self.ln_f(hidden_states)
         return hidden_states, None, extras

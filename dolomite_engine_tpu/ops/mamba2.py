@@ -14,10 +14,20 @@ size; ``B`` and ``C`` are shared by the heads of a group, head ``h`` reads group
 `mamba2_chunked` is the training form ("Transformers are SSMs", Dao & Gu 2024, the minimal
 SSD algorithm): inside a chunk of ``L`` tokens the output is a masked ``[L, L]`` matrix
 product ``(decay o C B^T) X``, each chunk leaves a state, and the states are carried from
-chunk to chunk by a short scan. The decay's logarithms and their cumulative sums stay in
-float32; the matrix products take the operands' dtype and accumulate in float32. Plain
-`jnp`, differentiated by JAX: no `custom_vjp`, so the backward pass carries the scopes of
-the forward.
+chunk to chunk by a short scan. The decay's logarithms, their cumulative sums and the
+exponentials stay in float32; the matrix products take the operands' dtype and accumulate
+in float32; the state is carried in float32; ``scores`` is rounded to the operands' dtype
+before its product. That is the contract, and it has two lowerings, chosen by `mamba2_scan`
+(the entry the mixer calls) from what the trace observes:
+
+  - `mamba2_chunked`, plain `jnp`, differentiated by JAX under a `jax.checkpoint`: what runs
+    off the TPU, under a mesh of several devices and at shapes the kernel does not tile, and
+    what the kernel is tested against. Its ``[L, L]`` tensors and their cotangents go
+    through HBM.
+  - `ops/pallas/mamba2.mamba2_chunked_kernel`: one Pallas kernel a pass with a backward
+    rule of its own (`jax.custom_vjp`), which keeps every ``[L, L]`` tensor in VMEM. A
+    `custom_vjp`'s backward inherits the scopes of its call, so both launches are found
+    under the mixer's ``mamba2_scan`` scope.
 
 Packed rows: ``segment_ids`` ``[B, T]`` (equal ids = one document, non-decreasing along a
 row). A pair of tokens of different documents has decay zero, a chunk's incoming state
@@ -27,6 +37,8 @@ crosses into the next document is a wrong model, not a slow one.
 """
 
 from __future__ import annotations
+
+from contextlib import contextmanager
 
 import jax
 import jax.numpy as jnp
@@ -180,6 +192,86 @@ def mamba2_chunked(
     y = y + from_state * reach[..., None]
     y = y + xc.astype(f32) * d.astype(f32).reshape(groups, per_group)[..., None]
     return y.reshape(batch, length, heads, width).astype(dtype)
+
+
+# lists that `watch_scan_lowerings` opened, innermost last
+_LOWERING_WATCHERS: list[list[dict]] = []
+
+
+@contextmanager
+def watch_scan_lowerings():
+    """What `mamba2_scan` chose in the trace inside: one entry a call (`scan_lowering`'s
+    record). The model reads it for its ``mamba2_scan_plan`` event."""
+    seen: list[dict] = []
+    _LOWERING_WATCHERS.append(seen)
+    try:
+        yield seen
+    finally:
+        _LOWERING_WATCHERS.pop()
+
+
+def scan_lowering(x_shape: tuple, bc_shape: tuple, chunk_size: int, itemsize: int = 2) -> dict:
+    """Which lowering of the chunked scan a trace takes, and why: ``form`` (``kernel`` or
+    ``jnp``), ``reason``, the ``chunk``, the kernel launches a pass, and the bytes the
+    kernel's backward rule keeps (of them the states entering the chunks). The rule
+    `ops/moe._share_grouped_product` uses for megablox: on a TPU, in a trace with no
+    multi-device mesh, at shapes the kernel tiles (`ops/pallas/mamba2.tiles`), the kernel;
+    anything else the `jnp` form (``reason`` ``backend``, ``mesh`` or ``shape``). Nothing a
+    user sets. Under a mesh the Mosaic kernel would have to go through
+    `parallel.sharding.shard_kernel` with the scan's layout across chips (rows over the data
+    axes), which is not built; off the TPU it would run interpreted. A row the chunk does
+    not divide is one chunk of the whole row, in `jnp`."""
+    from ..parallel.sharding import kernel_sharding
+
+    length = x_shape[1]
+    chunk = chunk_size if length % chunk_size == 0 else length
+    plan = {"form": "jnp", "chunk": chunk, "launches_per_pass": 0, "kept_bytes": 0, "kept_state_bytes": 0}
+    layout = ((tuple(x_shape), (None,) * len(x_shape)),)
+    if jax.default_backend() != "tpu":
+        return dict(plan, reason="backend")
+    if kernel_sharding(layout, layout) is not None:
+        return dict(plan, reason="mesh")
+    from .pallas import mamba2 as kernel
+
+    shapes = (*x_shape, *bc_shape[-2:], chunk)
+    if not kernel.tiles(*shapes):
+        return dict(plan, reason="shape")
+    kept = kernel.kept_bytes(*shapes, itemsize)
+    return dict(
+        plan,
+        form="kernel",
+        reason="one TPU, shapes that tile",
+        launches_per_pass=1,
+        kept_bytes=sum(kept.values()),
+        kept_state_bytes=kept["entering_states"],
+    )
+
+
+def mamba2_scan(
+    x: jax.Array,
+    dt: jax.Array,
+    a_log_decay: jax.Array,
+    b: jax.Array,
+    c: jax.Array,
+    d: jax.Array,
+    segment_ids: jax.Array | None = None,
+    chunk_size: int = 128,
+) -> jax.Array:
+    """The chunked scan as the mixer runs it (arguments and result as `mamba2_chunked`; a
+    row the chunk does not divide is taken as one chunk): the kernel where `scan_lowering`
+    says so, else the `jnp` form under a `jax.checkpoint` — its ``[L, L]`` residuals are
+    cheaper to build again than to keep; the kernel's rule keeps none."""
+    plan = scan_lowering(x.shape, b.shape, chunk_size, x.dtype.itemsize)
+    operands = (x, dt, a_log_decay, b, c, d, segment_ids, plan["chunk"])
+    if plan["form"] == "kernel":
+        from .pallas.mamba2 import mamba2_chunked_kernel
+
+        y = mamba2_chunked_kernel(*operands)
+    else:
+        y = jax.checkpoint(mamba2_chunked, static_argnums=(7,))(*operands)
+    if _LOWERING_WATCHERS:
+        _LOWERING_WATCHERS[-1].append(plan)
+    return y
 
 
 def gated_group_rmsnorm(

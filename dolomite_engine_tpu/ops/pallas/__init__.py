@@ -17,6 +17,8 @@ on detected TPU generations and stays XLA everywhere else):
   scatter-combine) replacing the dense all-experts einsum;
 - :mod:`.fused_ce` — vocab-tiled online-logsumexp chunk reduction for the chunked fused
   LM-head loss (the chunk's logits tiles never leave VMEM);
+- :mod:`.mamba2` — the Mamba-2 chunked selective scan, forward and backward, with every
+  ``[L, L]`` tensor in VMEM (chosen by `ops/mamba2.mamba2_scan`, not by a family switch);
 - :mod:`.rope_qkv` — fused QKV-split + rotary embedding behind the one rope+QKV call
   site shared by training and the serving prefill/decode/verify programs.
 

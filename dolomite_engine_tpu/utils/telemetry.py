@@ -308,6 +308,11 @@ KNOWN_EVENTS: tuple[str, ...] = (
     # NemotronHConfig.layout_record: pattern, experts held of published, vocabulary rows
     # held, the deployment's numbers), once a run
     "model_layout",
+    # which lowering of the Mamba-2 chunked scan each M layer of a traced model took
+    # (models/nemotron_h.scan_plan, from ops/mamba2.scan_lowering): the layers on the Pallas
+    # kernel and on the jnp form (and why: backend, mesh or shape), the chunk, the kernel's
+    # launches a layer and pass, and the bytes a layer its backward rule keeps
+    "mamba2_scan_plan",
     # what the step's forward pass counted, returned by the train step beside the loss
     # (train_utils.make_train_step has_aux) and read where the loss is read: for nemotron_h
     # one entry a layer of experts — routed_slots (token-slots of held experts: the rows

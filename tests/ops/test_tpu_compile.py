@@ -142,6 +142,37 @@ def test_kernel_compiles_for_v5e(v5e, family, case, width):
     assert "tpu_custom_call" in jax.jit(fn).lower(*args).compile().as_text()
 
 
+@pytest.mark.parametrize("case", ["fwd", "grad"])
+def test_mamba2_scan_kernel_compiles_for_v5e_at_the_cell_s_shapes(v5e, case):
+    """The Mamba-2 scan's kernels (`ops/pallas/mamba2.py`; not a family of the promotion
+    table: `ops/mamba2.mamba2_scan` chooses them from the trace) at the shapes of
+    `train-nemotron-tower-packed8k`: 2 rows of 8192, 64 heads of 64 in 8 groups, state 128,
+    chunk 128, bfloat16. ``grad`` holds the forward launch that keeps the entering states
+    and the backward launch."""
+    from dolomite_engine_tpu.ops.pallas.mamba2 import mamba2_chunked_kernel
+
+    batch, seq, heads, width, groups, state = 2, 8192, 64, 64, 8, 128
+    one_chip = SingleDeviceSharding(v5e[0])
+    spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
+    args = (
+        spec((batch, seq, heads, width), jnp.bfloat16),
+        spec((batch, seq, heads), jnp.float32),
+        spec((heads,), jnp.float32),
+        spec((batch, seq, groups, state), jnp.bfloat16),
+        spec((batch, seq, groups, state), jnp.bfloat16),
+        spec((heads,), jnp.float32),
+        spec((batch, seq), jnp.int32),
+    )
+    scan = lambda *a: mamba2_chunked_kernel(*a, 128, interpret=False)  # noqa: E731
+    launches = 1
+    if case == "grad":
+        scan, launches = jax.grad(lambda *a: _sum_sq(mamba2_chunked_kernel(*a, 128, interpret=False)), argnums=tuple(range(6))), 2
+    text = jax.jit(scan).lower(*args).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == launches
+    # no [L, L] tensor over chunks and heads outside the kernels
+    assert not re.search(r"\[2,64,(8,8|64),128,128\]", text)
+
+
 def _sharded_block_gradient_text(v5e, width, wrap=lambda block: block) -> str:
     """The compiled value and gradient of norm, rope+QKV and splash under fsdp 2 x tp 2 with
     sequence parallelism; `wrap` puts the block under a `jax.checkpoint`."""
@@ -316,5 +347,9 @@ def test_nemotron_h_tower_step_compiles_for_v5e_at_published_widths(v5e, capsys)
     # on one TPU the experts' grouped products are megablox kernels (`ops/moe._share_grouped_product`): 4 layers
     # x (forward, replay, three in the backward) x 2 products, beside splash and the norms
     assert "ragged-dot" not in text and text.count('custom_call_target="tpu_custom_call"') >= 40
+    # and the scans are the Pallas kernels (`ops/mamba2.mamba2_scan`): 4 M layers x (forward, replay, backward)
+    kernels = [line.split(" = ")[0] for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert sum("mamba2_scan_fwd" in name for name in kernels) == 8, kernels
+    assert sum("mamba2_scan_bwd" in name for name in kernels) == 4, kernels
     assert 6.0 * gib < memory.argument_size_in_bytes < 6.5 * gib  # 667M parameters x 10 B of state
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 14.5 * gib
