@@ -12,6 +12,7 @@ from .config import (
     DenseMoEConfig,
     EncDecDolomiteConfig,
     GPTCrossLayerConfig,
+    JoyAIFlashConfig,
     MoEConfig,
     NemotronHConfig,
     RNNDolomiteConfig,
@@ -24,6 +25,7 @@ from .gpt_crosslayer import (
     GPTCrossLayerModel,
     convert_gpt_dolomite_to_gpt_crosslayer,
 )
+from .joyai_flash import JoyAIFlashForCausalLM, JoyAIFlashModel
 from .moe_dolomite import MoEDolomiteForCausalLM, MoEDolomiteModel
 from .nemotron_h import NemotronHForCausalLM, NemotronHModel
 from .rnn_dolomite import RNNDolomiteForCausalLM, RNNDolomiteModel
@@ -36,6 +38,7 @@ _CONFIG_CLASSES: dict[str, type] = {
     "rnn_dolomite": RNNDolomiteConfig,
     "enc_dec_dolomite": EncDecDolomiteConfig,
     "nemotron_h": NemotronHConfig,
+    "joyai_llm_flash": JoyAIFlashConfig,
 }
 
 _MODEL_CLASSES: dict[str, type] = {
@@ -46,6 +49,7 @@ _MODEL_CLASSES: dict[str, type] = {
     "rnn_dolomite": RNNDolomiteForCausalLM,
     "enc_dec_dolomite": EncDecDolomiteForSeq2SeqLM,
     "nemotron_h": NemotronHForCausalLM,
+    "joyai_llm_flash": JoyAIFlashForCausalLM,
 }
 
 # families trained/driven through the seq2seq (AutoModelForSeq2SeqLM) surface

@@ -294,6 +294,16 @@ class RNNDolomiteConfig(CommonConfig):
         assert set(self.attention_pattern) <= {"a", "d"}
 
 
+def _checked_experts_held(experts_held, num_experts: int) -> list[int] | None:
+    """`experts_held` = (first, count) as two ints, or None (all held)."""
+    if experts_held is None:
+        return None
+    first, count = experts_held
+    if first < 0 or count < 1 or first + count > num_experts:
+        raise ValueError(f"experts_held {experts_held} lies outside 0..{num_experts}")
+    return [int(first), int(count)]
+
+
 @dataclass
 class NemotronHConfig(CommonConfig):
     """The `nemotron_h` tower (Nemotron-H / Nemotron-Labs-TwoTower's first tower): every
@@ -359,13 +369,7 @@ class NemotronHConfig(CommonConfig):
             )
         if self.mamba_num_heads % self.mamba_n_groups:
             raise ValueError("mamba_num_heads must be a multiple of mamba_n_groups")
-        if self.experts_held is not None:
-            first, count = self.experts_held
-            if first < 0 or count < 1 or first + count > self.num_experts:
-                raise ValueError(
-                    f"experts_held {self.experts_held} lies outside 0..{self.num_experts}"
-                )
-            self.experts_held = [int(first), int(count)]
+        self.experts_held = _checked_experts_held(self.experts_held, self.num_experts)
 
     @classmethod
     def fused_loss_reads_untied_head(cls) -> bool:
@@ -412,6 +416,123 @@ class NemotronHConfig(CommonConfig):
         first, count = self.held_experts()
         return dict(
             pattern=self.hybrid_override_pattern,
+            experts_held=count,
+            first_expert_held=first,
+            experts_published=self.num_experts,
+            vocabulary_rows_held=self.vocab_size,
+            **(self.deployment or {}),
+        )
+
+
+@dataclass
+class JoyAIFlashConfig(CommonConfig):
+    """`joyai_llm_flash` (JoyAI-LLM-Flash; its keys are the DeepSeek-V3 family's): every block
+    is latent attention (`modeling_utils.LatentAttention`) and then a feed-forward sublayer —
+    a dense SwiGLU MLP of `n_inner` in the first `first_k_dense_replace` blocks, routed
+    experts plus a shared expert (`shared_expert_moe.SharedExpertMoE`) in the others. With
+    `num_nextn_predict_layers` 1 a multi-token-prediction module — one more expert block over
+    ``W [norm(embedding of the next token) ; norm(last block's output)]`` — sends a second
+    pass through the head, and the loss is ``main + mtp_loss_coef x mtp``.
+
+    The repo's names carry the widths they always carried (`n_embd`, `n_head`, `n_inner`);
+    the rest are the public `config.json`'s keys. `experts_held` and `deployment` are
+    `NemotronHConfig`'s."""
+
+    model_type: str = "joyai_llm_flash"
+    attention_head_type: str = "mha"
+    position_embedding_type: str = "rope"
+    normalization_function: str = "rmsnorm"
+    activation_function: str = "swiglu"
+    layer_norm_epsilon: float = 1e-6
+    add_bias: bool = False
+    tie_word_embeddings: bool = False
+    # latent attention
+    q_lora_rank: int = 1536
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    rope_interleave: bool = True
+    # feed-forward sublayers
+    first_k_dense_replace: int = 1
+    num_experts: int = 256
+    num_experts_per_tok: int = 8
+    moe_intermediate_size: int = 768
+    n_shared_experts: int = 1
+    routed_scaling_factor: float = 2.5
+    norm_topk_prob: bool = True
+    experts_held: list[int] | None = None
+    # multi-token prediction
+    num_nextn_predict_layers: int = 0
+    mtp_loss_coef: float = 0.3
+    deployment: dict | None = None
+
+    buffer_names = ("e_score_correction_bias",)
+
+    def __post_init__(self) -> None:
+        if self.attention_head_dim is None:
+            self.attention_head_dim = self.qk_nope_head_dim + self.qk_rope_head_dim
+        super().__post_init__()
+        if self.num_nextn_predict_layers not in (0, 1):
+            raise ValueError("num_nextn_predict_layers > 1: multi-token prediction is built at depth 1")
+        if self.qk_rope_head_dim % 2:
+            raise ValueError("qk_rope_head_dim must be even (rotary pairs)")
+        if not 0 <= self.first_k_dense_replace <= self.n_layer:
+            raise ValueError(f"first_k_dense_replace {self.first_k_dense_replace} lies outside 0..{self.n_layer}")
+        self.experts_held = _checked_experts_held(self.experts_held, self.num_experts)
+
+    @classmethod
+    def fused_loss_reads_untied_head(cls) -> bool:
+        return True
+
+    @classmethod
+    def supported_position_embeddings(cls) -> frozenset[PositionEmbeddingType]:
+        return frozenset({PositionEmbeddingType.rope})
+
+    @property
+    def moe_shared_expert_intermediate_size(self) -> int:
+        return self.n_shared_experts * self.moe_intermediate_size
+
+    @property
+    def expert_layers(self) -> int:
+        """Layers of experts whose counters a step returns: the blocks after the dense ones,
+        and the multi-token-prediction module's."""
+        return self.n_layer - self.first_k_dense_replace + self.num_nextn_predict_layers
+
+    def held_experts(self) -> tuple[int, int]:
+        return tuple(self.experts_held) if self.experts_held else (0, self.num_experts)
+
+    def forward_block_flops(self, b: int, s: int) -> float:
+        """`NemotronHConfig.forward_block_flops` for this family: the blocks and the
+        multi-token-prediction module (its projection and its block; the head's second
+        pass is the caller's)."""
+        h, heads = self.n_embd, self.n_head
+        qk, v = self.qk_nope_head_dim + self.qk_rope_head_dim, self.v_head_dim
+        held = self.held_experts()[1]
+        attention = 2 * b * s * (
+            h * self.q_lora_rank + self.q_lora_rank * heads * qk
+            + h * (self.kv_lora_rank + self.qk_rope_head_dim)
+            + self.kv_lora_rank * heads * (self.qk_nope_head_dim + v)
+            + heads * v * h
+        ) + 2 * b * s * s * heads * (qk + v)
+        dense = 2 * b * s * 3 * h * self.n_inner
+        experts = 2 * b * s * (
+            h * self.num_experts
+            + 3 * h * self.moe_shared_expert_intermediate_size
+            + self.num_experts_per_tok * held / self.num_experts * 3 * h * self.moe_intermediate_size
+        )
+        dense_blocks = self.first_k_dense_replace
+        expert_blocks = self.expert_layers
+        mtp = self.num_nextn_predict_layers * 2 * b * s * 2 * h * h
+        return float((dense_blocks + expert_blocks) * attention + dense_blocks * dense + expert_blocks * experts + mtp)
+
+    def layout_record(self) -> dict:
+        """What the run's one `model_layout` telemetry event says."""
+        first, count = self.held_experts()
+        return dict(
+            blocks_dense=self.first_k_dense_replace,
+            blocks_experts=self.n_layer - self.first_k_dense_replace,
+            blocks_mtp=self.num_nextn_predict_layers,
             experts_held=count,
             first_expert_held=first,
             experts_published=self.num_experts,

@@ -34,6 +34,7 @@ from .modeling_utils import (
     ATTENTION_KERNEL_RESIDUALS_CHECKPOINT_NAME,
     ATTENTION_OUT_CHECKPOINT_NAME,
     Block,
+    HeadTable,
     KVCache,
     ParameterizedEmbedding,
     ParameterizedLinear,
@@ -620,17 +621,7 @@ class GPTDolomiteForCausalLM(nn.Module):
             with jax.named_scope("head_loss"):
                 if labels is None:
                     labels = derive_causal_labels(input_ids, attention_mask, segment_ids)
-                head_in, head_table = self._lm_head_operands(hidden_states)
-                loss = fused_linear_cross_entropy(
-                    head_in,
-                    head_table,
-                    labels,
-                    chunk_size=self.config.loss_chunk_size,
-                    upcast=self.config.upcast_logits_for_loss,
-                    logit_scale=None if self.config.m_width is None else 1.0 / self.config.m_width,
-                    compute_dtype=self.dtype,
-                    z_loss_coef=self.config.z_loss_coef,
-                )
+                loss = self.fused_head_loss(hidden_states, labels)
         else:
             with jax.named_scope("head_loss"):
                 logits = self.compute_logits(hidden_states)
@@ -656,6 +647,22 @@ class GPTDolomiteForCausalLM(nn.Module):
             kv_caches=new_caches,
             aux_loss=aux_loss,
             counters=self.step_counters(extras),
+        )
+
+    @nn.nowrap  # no scope of the method's own: the operations keep the names they had in `__call__`
+    def fused_head_loss(self, hidden_states: jax.Array, labels: jax.Array) -> jax.Array:
+        """The chunked head matmul and cross-entropy of `hidden_states` against `labels`
+        (`ops/loss.fused_linear_cross_entropy` on the head's ``[V, H]`` table)."""
+        head_in, head_table = self._lm_head_operands(hidden_states)
+        return fused_linear_cross_entropy(
+            head_in,
+            head_table,
+            labels,
+            chunk_size=self.config.loss_chunk_size,
+            upcast=self.config.upcast_logits_for_loss,
+            logit_scale=None if self.config.m_width is None else 1.0 / self.config.m_width,
+            compute_dtype=self.dtype,
+            z_loss_coef=self.config.z_loss_coef,
         )
 
     def step_counters(self, extras: list) -> dict | None:
@@ -702,3 +709,25 @@ class GPTDolomiteForCausalLM(nn.Module):
             {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
             for _ in range(config.n_layer)
         ]
+
+
+class HeadTableForCausalLM(GPTDolomiteForCausalLM):
+    """A family's blocks (`base_model_cls`) under an untied head kept as a ``[V, H]`` table
+    (`modeling_utils.HeadTable`: the public checkpoints' layout, and what the chunked loss
+    reads) — `nemotron_h`, `joyai_llm_flash`."""
+
+    def setup(self) -> None:
+        self.transformer = self.base_model_cls(**self._transformer_kwargs())
+        self.lm_head = HeadTable(
+            num_embeddings=self.config.vocab_size,
+            features=self.config.n_embd,
+            std=self.config.initializer_range,
+        )
+
+    def _lm_head_operands(self, hidden_states: jax.Array) -> tuple[jax.Array, jax.Array]:
+        return hidden_states.astype(self.dtype), self.lm_head().astype(self.dtype)
+
+    def compute_logits(self, hidden_states: jax.Array) -> jax.Array:
+        head_in, table = self._lm_head_operands(hidden_states)
+        logits = jnp.dot(head_in, table.T)
+        return logical_constraint(logits, ("act_batch", "act_seq_inner", "act_vocab"))

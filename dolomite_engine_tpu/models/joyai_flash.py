@@ -1,0 +1,323 @@
+"""`joyai_llm_flash`: JoyAI-LLM-Flash (the DeepSeek-V3 family's layers), training path.
+
+Every block is ``a = x + MLA(RMSNorm(x))``, ``y = a + F(RMSNorm(a))``: latent attention
+(`modeling_utils.LatentAttention`) and then a dense SwiGLU MLP in the first
+`first_k_dense_replace` blocks, the routed experts with a shared expert
+(`shared_expert_moe.SharedExpertMoE`: sigmoid scores chosen with a correction bias, the chip's
+share of the experts) in the others. After the last block a norm and an untied head.
+
+Multi-token prediction (`num_nextn_predict_layers` 1), when a loss is asked for: with ``h_i``
+the last block's output (before the final norm) and ``t`` the tokens,
+``h'_i = W [RMSNorm(Emb(t_{i+1})) ; RMSNorm(h_i)]``, one more expert block over ``h'`` under
+the masks and positions of the main blocks, a norm, and the main model's head — the same
+``[V, H]`` table, read by the chunked loss a second time — against ``t_{i+2}``, over the
+positions whose ``t_{i+1}`` and ``t_{i+2}`` lie in ``t_i``'s document. The loss is
+``main + mtp_loss_coef x mtp``; both come back beside the experts' counters
+(`STEP_COUNTERS`), with the count of MTP targets.
+
+Training path only: a KV cache raises (the latent page and the absorbed decode form are not
+built: ROADMAP M5), `scan_layers` raises (the first block differs from the others), tp > 1
+and ep > 1 raise rather than replicate the heads or the experts silently.
+
+Scopes inside the jitted step (docs/OBSERVABILITY.md "Phases of the train step"):
+``latent_attention`` (``mla_q_down``, ``mla_q_up``, ``mla_kv_down``, ``mla_kv_up``,
+``mla_rope``, the splash kernels' own, ``mla_out_proj``), ``dense_mlp``, ``moe`` (the five
+sub-scopes of `SharedExpertMoE`), and ``mtp`` (``mtp_combine``, the block's scopes,
+``mtp_final_norm``, ``mtp_head_loss``). The module's block runs as ``blocks/mtp/...`` and its
+pass through the head as ``head_loss/mtp/mtp_head_loss/...``, so a reader of the step's
+phases counts them with the blocks and with the head.
+"""
+
+from __future__ import annotations
+
+from typing import Any
+
+import jax
+import jax.numpy as jnp
+from flax import linen as nn
+from jax.ad_checkpoint import checkpoint_name
+
+from ..enums import AttentionImplementation
+from ..ops.attention import watch_kernel_residuals
+from ..ops.loss import IGNORE_INDEX, causal_lm_loss, derive_causal_labels
+from ..ops.rope import RoPEParams, get_cos_sin
+from ..parallel.sharding import logical_constraint
+from .config import JoyAIFlashConfig
+from .gpt_dolomite import CausalLMOutput, HeadTableForCausalLM, resolve_remat_policy, say_remat_plan
+from .modeling_utils import (
+    ATTENTION_OUT_CHECKPOINT_NAME,
+    MLP,
+    LatentAttention,
+    ParameterizedEmbedding,
+    ParameterizedLinear,
+    get_norm,
+)
+from .shared_expert_moe import STEP_COUNTERS, SharedExpertMoE, stack_step_counters
+
+# beside the experts' counters: both parts of the loss and the positions the second had
+LOSS_PARTS = ("main_loss", "mtp_loss", "mtp_targets")
+
+
+class JoyAIFlashBlock(nn.Module):
+    """Latent attention, then the dense MLP (`dense`) or the experts."""
+
+    config: JoyAIFlashConfig
+    dense: bool
+    attention_implementation: AttentionImplementation = AttentionImplementation.sdpa
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(
+        self, hidden_states: jax.Array, attention_mask=None, segment_ids=None, rope_cos_sin=None, deterministic: bool = True
+    ) -> tuple[jax.Array, dict | None]:
+        config = self.config
+        residual = hidden_states
+        h = get_norm(config, self.dtype, "ln_1")(hidden_states)
+        with jax.named_scope("latent_attention"):
+            attn_out = LatentAttention(
+                config=config, attention_implementation=self.attention_implementation, dtype=self.dtype, name="attn"
+            )(h, attention_mask, segment_ids, rope_cos_sin, deterministic)
+        attn_out = checkpoint_name(attn_out, ATTENTION_OUT_CHECKPOINT_NAME)
+        h, hidden_states = get_norm(config, self.dtype, "ln_2")(attn_out, residual=residual)
+        counters = None
+        if self.dense:
+            with jax.named_scope("dense_mlp"):
+                out = MLP(config=config, dtype=self.dtype, name="mlp")(h, deterministic=deterministic)
+        else:
+            with jax.named_scope("moe"):
+                out, counters = SharedExpertMoE(config=config, dtype=self.dtype, name="moe")(h)
+        hidden_states = hidden_states + out.astype(hidden_states.dtype)
+        hidden_states = logical_constraint(hidden_states, ("act_batch", "act_seq", "act_embed"))
+        return hidden_states, counters
+
+
+class MultiTokenPrediction(nn.Module):
+    """``block(W [norm(next token's embedding) ; norm(hidden)])`` and a norm: what the head
+    reads for the token after the next."""
+
+    config: JoyAIFlashConfig
+    block_cls: type
+    attention_implementation: AttentionImplementation = AttentionImplementation.sdpa
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(
+        self, hidden_states, next_embeds, attention_mask=None, segment_ids=None, rope_cos_sin=None, deterministic: bool = True
+    ) -> tuple[jax.Array, dict]:
+        config = self.config
+        with jax.named_scope("mtp_combine"):
+            both = jnp.concatenate(
+                [get_norm(config, self.dtype, "enorm")(next_embeds), get_norm(config, self.dtype, "hnorm")(hidden_states)],
+                axis=-1,
+            )
+            hidden_states = ParameterizedLinear(
+                features=config.n_embd,
+                use_bias=False,
+                std=config.initializer_range,
+                kernel_axes=("embed", None),
+                dtype=self.dtype,
+                name="eh_proj",
+            )(both)
+        hidden_states, counters = self.block_cls(
+            config=config, dense=False, attention_implementation=self.attention_implementation, dtype=self.dtype, name="block"
+        )(hidden_states, attention_mask, segment_ids, rope_cos_sin, deterministic)
+        with jax.named_scope("mtp_final_norm"):
+            return get_norm(config, self.dtype, "norm")(hidden_states), counters
+
+
+class JoyAIFlashModel(nn.Module):
+    config: JoyAIFlashConfig
+    attention_implementation: AttentionImplementation = AttentionImplementation.sdpa
+    dtype: Any = jnp.float32
+    checkpoint_every: int = 0
+    checkpoint_policy: str | None = None
+    scan_layers: bool = False
+
+    def setup(self) -> None:
+        config = self.config
+        if self.scan_layers:
+            raise ValueError(
+                "scan_layers with joyai_llm_flash: the dense first block differs from the expert "
+                "blocks and a scan over the like ones is not built; run it unrolled (scan_layers: false)"
+            )
+        from ..parallel.mesh import MeshManager
+
+        if MeshManager.is_initialized():
+            for axis, what in (("tp", "the latent attention's heads"), ("ep", "the experts held")):
+                if MeshManager.axis_size(axis) > 1:
+                    raise ValueError(
+                        f"joyai_llm_flash on a mesh with {axis} > 1: {what} would be replicated, "
+                        f"not sharded; {axis} for this family is not built"
+                    )
+        self.wte = ParameterizedEmbedding(
+            num_embeddings=config.vocab_size, features=config.n_embd, std=config.initializer_range, dtype=self.dtype
+        )
+        self.rope_params = RoPEParams.from_config(
+            config.qk_rope_head_dim, config.rope_theta, config.rope_scaling, config.n_positions
+        )
+        remat_policy = resolve_remat_policy(self.checkpoint_policy)
+        blocks = config.n_layer + config.num_nextn_predict_layers
+        self.rematerialized = tuple(
+            self.checkpoint_every > 0 and i % self.checkpoint_every == 0 for i in range(blocks)
+        )
+
+        def block_cls(i: int) -> type:
+            # flax counts the module instance as argument 0; deterministic is arg 5.
+            # prevent_cse stays on, as for the other unrolled families
+            if self.rematerialized[i]:
+                return nn.remat(JoyAIFlashBlock, static_argnums=(5,), policy=remat_policy)
+            return JoyAIFlashBlock
+
+        self.h = [
+            block_cls(i)(
+                config=config,
+                dense=i < config.first_k_dense_replace,
+                attention_implementation=self.attention_implementation,
+                dtype=self.dtype,
+            )
+            for i in range(config.n_layer)
+        ]
+        self.ln_f = get_norm(config, self.dtype)
+        if config.num_nextn_predict_layers:
+            self.mtp = MultiTokenPrediction(
+                config=config,
+                block_cls=block_cls(config.n_layer),
+                attention_implementation=self.attention_implementation,
+                dtype=self.dtype,
+            )
+
+    def __call__(
+        self,
+        input_ids: jax.Array,
+        position_ids: jax.Array | None = None,
+        attention_mask: jax.Array | None = None,
+        segment_ids: jax.Array | None = None,
+        kv_caches: list | None = None,
+        cache_index: jax.Array | None = None,
+        deterministic: bool = True,
+        inputs_embeds: jax.Array | None = None,
+        predict_second: bool = False,
+    ) -> tuple[jax.Array, None, list, jax.Array | None]:
+        """(normed hidden states, None, the expert layers' counters, and — `predict_second`,
+        with a multi-token-prediction module — its normed hidden states, else None)."""
+        if kv_caches is not None:
+            raise NotImplementedError(
+                "joyai_llm_flash has no generation cache (a latent page and the absorbed decode "
+                "form are not built: ROADMAP M5); the training path only"
+            )
+        batch, seq = input_ids.shape
+        with jax.named_scope("embed"):
+            hidden_states = self.wte(input_ids) if inputs_embeds is None else inputs_embeds
+            hidden_states = logical_constraint(hidden_states, ("act_batch", "act_seq", "act_embed"))
+            if position_ids is None:
+                position_ids = jnp.broadcast_to(jnp.arange(seq, dtype=jnp.int32)[None], (batch, seq))
+            rope_cos_sin = get_cos_sin(self.rope_params, position_ids, dtype=self.dtype)
+        if segment_ids is None and attention_mask is not None:
+            segment_ids = attention_mask.astype(jnp.int32)  # the pad tokens are a document of their own
+        extras, kernel_residual_bytes, second = [], [], None
+        with watch_kernel_residuals() as seen:
+            with jax.named_scope("blocks"):
+                for block in self.h:
+                    calls_before = len(seen)
+                    hidden_states, counters = block(hidden_states, attention_mask, segment_ids, rope_cos_sin, deterministic)
+                    kernel_residual_bytes.append(sum(seen[calls_before:]))
+                    if counters is not None:
+                        extras.append(counters)
+            # (at initialization too, whatever the call asks for: the module's parameters exist)
+            if self.config.num_nextn_predict_layers and (predict_second or self.is_initializing()):
+                with jax.named_scope("blocks"), jax.named_scope("mtp"):
+                    calls_before = len(seen)
+                    # the last position's next token is not in `input_ids`; it has no target either
+                    next_embeds = self.wte(jnp.roll(input_ids, -1, axis=1))
+                    second, counters = self.mtp(
+                        hidden_states, next_embeds, attention_mask, segment_ids, rope_cos_sin, deterministic
+                    )
+                    kernel_residual_bytes.append(sum(seen[calls_before:]))
+                    extras.append(counters)
+        if len(kernel_residual_bytes) == len(self.rematerialized):
+            say_remat_plan(self, kernel_residual_bytes)
+        with jax.named_scope("final_norm"):
+            hidden_states = self.ln_f(hidden_states)
+        return hidden_states, None, extras, second
+
+
+def second_token_labels(labels: jax.Array, segment_ids: jax.Array | None) -> jax.Array:
+    """Targets of the multi-token-prediction pass from the main pass's: position i's is
+    ``t_{i+2}``, the main label of position i + 1 (which already is no label where ``t_{i+2}``
+    leaves ``t_{i+1}``'s document), and no label where ``t_{i+1}`` leaves ``t_i``'s document
+    or the row ends."""
+    shifted = jnp.concatenate([labels[:, 1:], jnp.full_like(labels[:, :1], IGNORE_INDEX)], axis=1)
+    if segment_ids is None:
+        return shifted
+    next_segment = jnp.concatenate([segment_ids[:, 1:], jnp.zeros_like(segment_ids[:, :1])], axis=1)
+    return jnp.where(next_segment == segment_ids, shifted, IGNORE_INDEX)
+
+
+class JoyAIFlashForCausalLM(HeadTableForCausalLM):
+    """The blocks under the repo's untied head table and chunked loss, the loss read twice
+    where the model predicts a second token."""
+
+    base_model_cls: type = JoyAIFlashModel
+    step_counter_names = STEP_COUNTERS + LOSS_PARTS
+
+    @nn.nowrap  # (as `fused_head_loss`: the scopes are the caller's)
+    def head_loss(self, hidden_states: jax.Array, labels: jax.Array) -> jax.Array:
+        """Mean cross-entropy (+ z-loss) of the head over `hidden_states` against `labels`."""
+        if self.config.fused_lm_head_loss:
+            return self.fused_head_loss(hidden_states, labels)
+        return causal_lm_loss(
+            self.compute_logits(hidden_states),
+            labels,  # (unread: the labels are given)
+            upcast=self.config.upcast_logits_for_loss,
+            labels=labels,
+            z_loss_coef=self.config.z_loss_coef,
+        )
+
+    def __call__(
+        self,
+        input_ids: jax.Array,
+        position_ids: jax.Array | None = None,
+        attention_mask: jax.Array | None = None,
+        segment_ids: jax.Array | None = None,
+        labels: jax.Array | None = None,
+        kv_caches: list | None = None,
+        cache_index: jax.Array | None = None,
+        deterministic: bool = True,
+        compute_loss: bool = False,
+        inputs_embeds: jax.Array | None = None,
+    ) -> CausalLMOutput:
+        want_loss = compute_loss or labels is not None
+        hidden_states, _, extras, second = self.transformer(
+            input_ids,
+            position_ids=position_ids,
+            attention_mask=attention_mask,
+            segment_ids=segment_ids,
+            kv_caches=kv_caches,
+            cache_index=cache_index,
+            deterministic=deterministic,
+            inputs_embeds=inputs_embeds,
+            predict_second=want_loss,
+        )
+        if not want_loss:
+            with jax.named_scope("head_loss"):
+                return CausalLMOutput(logits=self.compute_logits(hidden_states))
+
+        with jax.named_scope("head_loss"):
+            if labels is None:
+                labels = derive_causal_labels(input_ids, attention_mask, segment_ids)
+            main_loss = self.head_loss(hidden_states, labels)
+        mtp_loss = jnp.zeros((), jnp.float32)
+        mtp_targets = jnp.zeros((), jnp.int32)
+        loss = main_loss
+        if second is not None:
+            with jax.named_scope("head_loss"), jax.named_scope("mtp"), jax.named_scope("mtp_head_loss"):
+                second_labels = second_token_labels(labels, segment_ids)
+                mtp_loss = self.head_loss(second, second_labels)
+                mtp_targets = jnp.sum(second_labels != IGNORE_INDEX).astype(jnp.int32)
+            loss = main_loss + self.config.mtp_loss_coef * mtp_loss
+        counters = stack_step_counters(extras) or {}
+        counters.update(main_loss=main_loss, mtp_loss=mtp_loss, mtp_targets=mtp_targets)
+        return CausalLMOutput(loss=loss, counters=counters)
+
+    def init_kv_caches(self, batch_size: int, max_length: int, dtype=None) -> list:
+        raise NotImplementedError("joyai_llm_flash has no generation cache (ROADMAP M5)")

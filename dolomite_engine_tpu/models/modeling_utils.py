@@ -37,7 +37,13 @@ from ..ops.activations import get_activation_function, is_glu
 from ..ops.attention import attention as attention_op
 from ..ops.normalization import check_normalization_function, layernorm, rmsnorm
 from ..ops.pallas import use_pallas
-from ..ops.rope import RoPEParams, get_cos_sin, split_qkv_apply_rope
+from ..ops.rope import (
+    RoPEParams,
+    apply_rotary_pos_emb,
+    deinterleave_pairs,
+    get_cos_sin,
+    split_qkv_apply_rope,
+)
 from .config import CommonConfig
 from .enums import InitMethod, PositionEmbeddingType
 
@@ -168,6 +174,24 @@ class ParameterizedEmbedding(nn.Module):
         if hasattr(embedding, "unbox"):
             embedding = embedding.unbox()
         return embedding
+
+
+class HeadTable(nn.Module):
+    """The untied head as a ``[V, H]`` table (the public checkpoint's layout, and what the
+    chunked loss reads)."""
+
+    num_embeddings: int
+    features: int
+    std: float = 0.02
+
+    @nn.compact
+    def __call__(self) -> jax.Array:
+        return self.param(
+            "kernel",
+            nn.with_logical_partitioning(_normal_init(self.std), ("vocab", "embed")),
+            (self.num_embeddings, self.features),
+            jnp.float32,
+        )
 
 
 class Norm(nn.Module):
@@ -704,6 +728,84 @@ class Attention(nn.Module):
         out = c_proj(out)
         out = nn.Dropout(rate=config.resid_pdrop)(out, deterministic=deterministic)
         return out, kv_cache
+
+
+class LatentAttention(nn.Module):
+    """Multi-head latent attention (the DeepSeek-V3 family's), in its training form: queries
+    and keys/values come from low-rank latents, each behind an RMSNorm, and are expanded per
+    head; the rotary part of a head (``qk_rope_head_dim`` columns) is projected apart — one
+    key rope part for all heads — and scores run over ``[nope | rope]`` while the values
+    keep ``v_head_dim`` columns. The absorbed decode form and a latent KV cache are not
+    built. No bias anywhere.
+
+    Scopes: ``mla_q_down``, ``mla_q_up``, ``mla_kv_down``, ``mla_kv_up``, ``mla_rope``, the
+    attention op's own (the splash kernels on a TPU), ``mla_out_proj``."""
+
+    config: CommonConfig
+    attention_implementation: AttentionImplementation = AttentionImplementation.sdpa
+    dtype: Dtype = jnp.float32
+
+    @nn.compact
+    def __call__(
+        self,
+        hidden_states: jax.Array,
+        attention_mask: jax.Array | None = None,
+        segment_ids: jax.Array | None = None,
+        rope_cos_sin: tuple[jax.Array, jax.Array] | None = None,
+        deterministic: bool = True,
+    ) -> jax.Array:
+        config = self.config
+        heads, nope, rope, v_dim = config.n_head, config.qk_nope_head_dim, config.qk_rope_head_dim, config.v_head_dim
+        batch, seq = hidden_states.shape[:2]
+
+        def linear(features, name, axes, std=config.initializer_range):
+            return ParameterizedLinear(
+                features=features, use_bias=False, std=std, kernel_axes=axes, dtype=self.dtype, name=name
+            )
+
+        with jax.named_scope("mla_q_down"):
+            latent_q = linear(config.q_lora_rank, "q_a_proj", ("embed", None))(hidden_states)
+            latent_q = get_norm(config, self.dtype, "q_a_layernorm")(latent_q)
+        with jax.named_scope("mla_q_up"):
+            query = linear(heads * (nope + rope), "q_b_proj", ("embed", "heads"))(latent_q)
+            query = query.reshape(batch, seq, heads, nope + rope)
+        with jax.named_scope("mla_kv_down"):
+            compressed = linear(config.kv_lora_rank + rope, "kv_a_proj_with_mqa", ("embed", None))(hidden_states)
+            latent_kv, key_rope = jnp.split(compressed, [config.kv_lora_rank], axis=-1)
+            latent_kv = get_norm(config, self.dtype, "kv_a_layernorm")(latent_kv)
+        with jax.named_scope("mla_kv_up"):
+            expanded = linear(heads * (nope + v_dim), "kv_b_proj", ("embed", "heads"))(latent_kv)
+            key_nope, value = jnp.split(expanded.reshape(batch, seq, heads, nope + v_dim), [nope], axis=-1)
+        with jax.named_scope("mla_rope"):
+            query_nope, query_rope = jnp.split(query, [nope], axis=-1)
+            key_rope = key_rope[:, :, None, :]
+            if config.rope_interleave:
+                query_rope, key_rope = deinterleave_pairs(query_rope), deinterleave_pairs(key_rope)
+            if rope_cos_sin is not None:
+                query_rope = apply_rotary_pos_emb(query_rope, *rope_cos_sin)
+                key_rope = apply_rotary_pos_emb(key_rope, *rope_cos_sin)
+            query = jnp.concatenate([query_nope, query_rope], axis=-1)
+            key = jnp.concatenate([key_nope, jnp.broadcast_to(key_rope, (batch, seq, heads, rope))], axis=-1)
+
+        attn_pdrop = 0.0 if deterministic else config.attn_pdrop
+        out = attention_op(
+            query,
+            key,
+            value,
+            implementation=self.attention_implementation,
+            causal=True,
+            softmax_scale=get_softmax_scale(config, nope + rope),
+            attention_mask=attention_mask,
+            segment_ids=segment_ids,
+            softmax_in_fp32=config.attention_softmax_in_fp32,
+            dropout=attn_pdrop,
+            dropout_rng=self.make_rng("dropout") if attn_pdrop > 0.0 else None,
+        )
+        with jax.named_scope("mla_out_proj"):
+            out = linear(config.n_embd, "o_proj", ("heads", "embed"), depth_scaled_init_std(config))(
+                out.reshape(batch, seq, heads * v_dim)
+            )
+        return nn.Dropout(rate=config.resid_pdrop)(out, deterministic=deterministic)
 
 
 class MLP(nn.Module):

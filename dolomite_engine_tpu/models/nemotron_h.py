@@ -5,7 +5,7 @@ Every layer is ``x + mixer(RMSNorm(x))`` with ONE mixer, chosen by the config's 
   - ``M``  `Mamba2Mixer`: in-projection to ``[z | xBC | dt]``, a causal depthwise
     convolution with silu over ``xBC``, the selective scan in chunks (`ops/mamba2.py`), a
     gated grouped RMSNorm, out-projection;
-  - ``E``  `SharedExpertMoE`: a router that scores ALL experts (sigmoid, chosen with a
+  - ``E``  `shared_expert_moe.SharedExpertMoE`: a router that scores ALL experts (sigmoid, chosen with a
     correction bias, weighed without it, renormalised, scaled), the chip's share of the
     routed experts (`ops/moe.experts_held_ragged`) and a shared expert every token passes;
   - ``*``  the repo's `Attention` without position embedding.
@@ -39,25 +39,19 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from ..enums import AttentionImplementation
-from ..ops.activations import get_activation_function
 from ..ops.attention import watch_kernel_residuals
 from ..ops.mamba2 import causal_conv1d, gated_group_rmsnorm, mamba2_scan, watch_scan_lowerings
-from ..ops.moe import experts_held_ragged, route_sigmoid_bias
 from ..parallel.sharding import logical_constraint
 from .config import NemotronHConfig
-from .gpt_dolomite import GPTDolomiteForCausalLM, resolve_remat_policy, say_remat_plan
+from .gpt_dolomite import HeadTableForCausalLM, resolve_remat_policy, say_remat_plan
 from .modeling_utils import (
     Attention,
     ParameterizedEmbedding,
     ParameterizedLinear,
-    _normal_init,
     depth_scaled_init_std,
     get_norm,
 )
-from .moe_dolomite import ParameterizedExperts
-
-# what the step returns beside the loss, one number a layer of experts (`E`)
-STEP_COUNTERS = ("routed_slots", "absent_slots", "fullest_expert_rows", "held_expert_rows")
+from .shared_expert_moe import STEP_COUNTERS, SharedExpertMoE, stack_step_counters
 
 
 def _inverse_softplus(x: jax.Array) -> jax.Array:
@@ -172,104 +166,6 @@ class Mamba2Mixer(nn.Module):
                 dtype=self.dtype,
                 name="out_proj",
             )(y)
-
-
-class SharedExpertMoE(nn.Module):
-    """Routed experts (the share held here) plus a shared expert. Returns the layer's
-    output and its counters (`STEP_COUNTERS`: int32 scalars, and the rows of each held expert)."""
-
-    config: NemotronHConfig
-    dtype: Any = jnp.float32
-
-    @nn.compact
-    def __call__(self, hidden_states: jax.Array) -> tuple[jax.Array, dict]:
-        config = self.config
-        hidden = config.n_embd
-        act = get_activation_function(config.activation_function)
-        first, held = config.held_experts()
-        batch, seq, _ = hidden_states.shape
-        x = hidden_states.reshape(-1, hidden)
-
-        with jax.named_scope("moe_router"):
-            gate = self.param(
-                "gate",
-                nn.with_logical_partitioning(_normal_init(config.initializer_range), (None, None)),
-                (hidden, config.num_experts),
-                jnp.float32,
-            )
-            # the router's scores are float32 whatever the model's dtype (the public model's)
-            logits = jnp.dot(
-                x.astype(jnp.float32), gate, precision=jax.lax.Precision.HIGHEST
-            )
-            # the family's routing rule (`ops/moe.route_sigmoid_bias`); the bias is a buffer
-            correction_bias = self.param(
-                "e_score_correction_bias",
-                nn.with_logical_partitioning(nn.initializers.zeros_init(), (None,)),
-                (config.num_experts,),
-                jnp.float32,
-            )
-            weights, selected = route_sigmoid_bias(
-                logits,
-                config.num_experts_per_tok,
-                correction_bias,
-                config.routed_scaling_factor,
-                config.norm_topk_prob,
-            )
-
-        c_fc, _ = ParameterizedExperts(
-            num_experts=held,
-            features=config.moe_intermediate_size,
-            use_bias=False,
-            std=config.initializer_range,
-            kernel_axes=("experts", "embed", "expert_mlp"),
-            dtype=self.dtype,
-            name="c_fc",
-        )(hidden)
-        c_proj, _ = ParameterizedExperts(
-            num_experts=held,
-            features=hidden,
-            use_bias=False,
-            std=depth_scaled_init_std(config),
-            kernel_axes=("experts", "expert_mlp", "embed"),
-            dtype=self.dtype,
-            name="c_proj",
-        )(config.moe_intermediate_size)
-
-        # `moe_dispatch` (the sort, the gather), `moe_experts` (the grouped products) and
-        # `moe_combine` (the weighted scatter-add) are opened inside: one function, so that
-        # the overflow path is the same code at more rows
-        routed, counters = experts_held_ragged(
-            x.astype(self.dtype),
-            weights,
-            selected,
-            c_fc.astype(self.dtype),
-            c_proj.astype(self.dtype),
-            act,
-            config.num_experts,
-            first,
-        )
-
-        with jax.named_scope("moe_shared_expert"):
-            h = ParameterizedLinear(
-                features=config.moe_shared_expert_intermediate_size,
-                use_bias=False,
-                std=config.initializer_range,
-                kernel_axes=("embed", "mlp"),
-                dtype=self.dtype,
-                name="shared_c_fc",
-            )(x)
-            shared = ParameterizedLinear(
-                features=hidden,
-                use_bias=False,
-                std=depth_scaled_init_std(config),
-                kernel_axes=("mlp", "embed"),
-                dtype=self.dtype,
-                name="shared_c_proj",
-            )(act(h))
-
-        with jax.named_scope("moe_combine"):
-            out = (routed.astype(self.dtype) + shared).reshape(batch, seq, hidden)
-        return out, counters
 
 
 class NemotronHBlock(nn.Module):
@@ -401,51 +297,15 @@ class NemotronHModel(nn.Module):
         return hidden_states, None, extras
 
 
-class HeadTable(nn.Module):
-    """The untied head as a ``[V, H]`` table (the public checkpoint's layout, and what the
-    chunked loss reads)."""
-
-    num_embeddings: int
-    features: int
-    std: float = 0.02
-
-    @nn.compact
-    def __call__(self) -> jax.Array:
-        return self.param(
-            "kernel",
-            nn.with_logical_partitioning(_normal_init(self.std), ("vocab", "embed")),
-            (self.num_embeddings, self.features),
-            jnp.float32,
-        )
-
-
-class NemotronHForCausalLM(GPTDolomiteForCausalLM):
-    """The tower under the repo's head and loss (`GPTDolomiteForCausalLM`)."""
+class NemotronHForCausalLM(HeadTableForCausalLM):
+    """The tower under the repo's untied head table and chunked loss."""
 
     base_model_cls: type = NemotronHModel
     step_counter_names = STEP_COUNTERS
 
-    def setup(self) -> None:
-        self.transformer = self.base_model_cls(**self._transformer_kwargs())
-        self.lm_head = HeadTable(
-            num_embeddings=self.config.vocab_size,
-            features=self.config.n_embd,
-            std=self.config.initializer_range,
-        )
-
-    def _lm_head_operands(self, hidden_states: jax.Array) -> tuple[jax.Array, jax.Array]:
-        return hidden_states.astype(self.dtype), self.lm_head().astype(self.dtype)
-
-    def compute_logits(self, hidden_states: jax.Array) -> jax.Array:
-        head_in, table = self._lm_head_operands(hidden_states)
-        logits = jnp.dot(head_in, table.T)
-        return logical_constraint(logits, ("act_batch", "act_seq_inner", "act_vocab"))
-
     def step_counters(self, extras: list) -> dict | None:
         """``{name: int32[layers of experts, ...]}`` from the blocks' counters."""
-        if not extras:
-            return None
-        return {name: jnp.stack([layer[name] for layer in extras]) for name in STEP_COUNTERS}
+        return stack_step_counters(extras)
 
     def init_kv_caches(self, batch_size: int, max_length: int, dtype=None) -> list:
         raise NotImplementedError("nemotron_h has no generation cache (ROADMAP M2)")

@@ -9,7 +9,8 @@ Pallas flash kernel; "sdpa" maps to XLA's fused `jax.nn.dot_product_attention`; 
 fp32-softmax debug/parity path. GQA/MQA head broadcast replaces `repeat_key_value`
 (`attention/utils.py:5-118`).
 
-All shapes are batch-first: q [B, Sq, Hq, D]; k, v [B, Skv, Hkv, D]. Packed (padding-free) input
+All shapes are batch-first: q [B, Sq, Hq, D]; k [B, Skv, Hkv, D]; v [B, Skv, Hkv, Dv] (Dv is
+D everywhere but in latent attention, whose values are narrower than its scores). Packed (padding-free) input
 is [B, S] tokens + segment_ids [B, S] (0 = padding, 1.. = documents); this also implements
 `reset_attention_mask` document isolation (reference `model_wrapper/pretraining.py:129-160`).
 """
@@ -309,7 +310,11 @@ def sdpa_attention(
     bias: jax.Array | None,
     softmax_scale: float,
 ) -> jax.Array:
-    """XLA fused attention; GQA/MQA handled natively by `jax.nn.dot_product_attention`."""
+    """XLA fused attention; GQA/MQA handled natively by `jax.nn.dot_product_attention`.
+    It refuses values narrower than the scores' head (latent attention): those take the
+    explicit products of `eager_attention`, which XLA fuses as it can."""
+    if v.shape[-1] != q.shape[-1]:
+        return eager_attention(q, k, v, mask, bias, softmax_scale)
     return jax.nn.dot_product_attention(
         q, k, v, bias=bias, mask=mask, scale=softmax_scale, implementation="xla"
     )
@@ -428,7 +433,8 @@ def _splash_attention_local(q, k, v, segment_ids, softmax_scale: float, interpre
         interpret=interpret,
     )
     for seen in _RESIDUAL_WATCHERS:
-        seen.append(num_q_heads * sq * (qt.shape[3] * qt.dtype.itemsize + 4))
+        # the output is as wide as the values (latent attention scores over a wider head)
+        seen.append(num_q_heads * sq * (vt.shape[3] * qt.dtype.itemsize + 4))
 
     qs = qt * softmax_scale  # splash has no sm_scale argument
     if segment_ids is None:

@@ -129,6 +129,14 @@ def split_qkv_apply_rope(
     return query, key, value
 
 
+def deinterleave_pairs(x: jax.Array) -> jax.Array:
+    """``[x0 x1 x2 x3 ...] -> [x0 x2 ... | x1 x3 ...]`` over the last axis: a checkpoint whose
+    rotary pairs are neighbours (``rope_interleave``, the DeepSeek-V3 family's layout) brought
+    to the rotate-half layout the rest of this file rotates. Scores do not change with the
+    order of the columns as long as queries and keys share it."""
+    return jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
+
+
 def _rotate_half(x: jax.Array) -> jax.Array:
     x1, x2 = jnp.split(x, 2, axis=-1)
     return jnp.concatenate([-x2, x1], axis=-1)

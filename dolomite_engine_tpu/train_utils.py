@@ -88,8 +88,9 @@ def make_train_step(
     """Build the jitted train step.
 
     `loss_fn(params, micro_batch, rng) -> scalar loss` — or, with `has_aux`, ``(loss,
-    counters)``: a dict of integer arrays the forward pass counted, summed over the
-    micro-batches and returned as ``metrics["counters"]`` by the same program. `batch` passed to the returned step has
+    counters)``: a dict of what the forward pass counted — integer arrays, summed over the
+    micro-batches, and float scalars (a loss's parts), averaged over them — returned as
+    ``metrics["counters"]`` by the same program. `batch` passed to the returned step has
     a leading [gradient_accumulation_steps] axis on every leaf.
 
     `offload_optimizer` (cpu_offload, TPU only): the incoming opt state lives in pinned host
@@ -175,7 +176,11 @@ def make_train_step(
                     accum_fn, (zero_grads, jnp.zeros((), jnp.float32), state.fp8), (batch, rngs)
                 )
                 if has_aux:
-                    counters = jax.tree.map(lambda c: jnp.sum(c, axis=0), counters)
+                    # counts add up over the micro-batches; a loss's part is their mean, as the loss is
+                    counters = jax.tree.map(
+                        lambda c: (jnp.sum if jnp.issubdtype(c.dtype, jnp.integer) else jnp.mean)(c, axis=0),
+                        counters,
+                    )
 
         with jax.named_scope("grad_clip"):
             grads, grad_norm = clip_grad_norm(grads, gradient_clipping)

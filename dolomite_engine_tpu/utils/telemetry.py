@@ -306,7 +306,8 @@ KNOWN_EVENTS: tuple[str, ...] = (
     "remat_plan",
     # what a model cut to one chip's share holds of what was published (models/config.py
     # NemotronHConfig.layout_record: pattern, experts held of published, vocabulary rows
-    # held, the deployment's numbers), once a run
+    # held, the deployment's numbers; JoyAIFlashConfig.layout_record: blocks by kind in the
+    # pattern's place), once a run
     "model_layout",
     # which lowering of the Mamba-2 chunked scan each M layer of a traced model took
     # (models/nemotron_h.scan_plan, from ops/mamba2.scan_lowering): the layers on the Pallas
@@ -316,7 +317,9 @@ KNOWN_EVENTS: tuple[str, ...] = (
     # what the step's forward pass counted, returned by the train step beside the loss
     # (train_utils.make_train_step has_aux) and read where the loss is read: for nemotron_h
     # one entry a layer of experts — routed_slots (token-slots of held experts: the rows
-    # the grouped products multiply), absent_slots, fullest_expert_rows
+    # the grouped products multiply), absent_slots, fullest_expert_rows, held_expert_rows;
+    # for joyai_llm_flash the same (its multi-token-prediction module's layer last) and the
+    # loss's two parts main_loss and mtp_loss with mtp_targets, the positions the second had
     "step_counters",
     # serving-fleet fault tolerance (serving/cluster/health.py + router.py): one event
     # per downward health edge, per completed drain/rejoin, and when a threaded

@@ -127,6 +127,46 @@ def test_the_trainer_s_step_follows_the_reference():
         np.testing.assert_allclose(mine, np.asarray(facts["held_expert_rows"]), atol=2)  # a near-tie may fall either way
 
 
+def test_the_tower_is_what_it_was_before_its_expert_layer_moved():
+    """`SharedExpertMoE` serves a second family from `models/shared_expert_moe.py` (PR 30); the
+    tower's parameter tree and its lowered train step (bfloat16, `full` remat every layer,
+    `skip_nonfinite`, counters beside the loss) at this file's size are, letter for letter, what
+    the commit before the move lowered: the hashes were taken there, on this installation (jax
+    0.9.0). A change of the tower's program on purpose takes them anew, and says so."""
+    import hashlib
+
+    from dolomite_engine_tpu.distributed import TrainState
+
+    wrapper = ModelWrapperForPretraining(
+        mode=Mode.training, pretrained_config=CFG, dtype="bf16", sequence_length=CFG["n_positions"],
+        reset_attention_mask=True, zero_stage=0, gradient_checkpointing_args={"checkpoint_every": 1},
+    )
+    schedule = get_scheduler(0, 0, None, 10, LRDecaySchedule.constant, 0.1, base_lr=OPTIMIZER["lr"])
+    optimizer = get_optimizer(
+        "TorchAdamW", {k: OPTIMIZER[k] for k in ("weight_decay", "betas", "eps")}, schedule, model_config=wrapper.config,
+    )
+
+    def init():
+        params = nn.unbox(wrapper.model.init(jax.random.PRNGKey(0), jnp.zeros((1, 16), jnp.int32))["params"])
+        return TrainState(step=jnp.zeros((), jnp.int32), params=params, opt_state=optimizer.init(params), fp8=None)
+
+    state = jax.eval_shape(init)
+    step = jax.jit(make_train_step(
+        lambda p, micro, rng: wrapper.loss(p, micro["text"], rngs=None, train=True), optimizer,
+        gradient_clipping=1.0, skip_nonfinite=True, has_aux=True,
+    ))
+    text = step.lower(
+        state, {"text": jax.ShapeDtypeStruct((1, 2, CFG["n_positions"] + 1), jnp.int32)}, jax.ShapeDtypeStruct((2,), jnp.uint32)
+    ).as_text()
+    tree = str(jax.tree.map(lambda a: (a.shape, str(a.dtype)), state.params))
+    assert hashlib.sha256(tree.encode()).hexdigest() == "36990d468b39e5c040180d1e45a25dac74eeeb1d513dc6bc37c03b12fe9fca5b"
+    assert len(text.splitlines()) == 6527
+    assert hashlib.sha256(text.encode()).hexdigest() == "5bb24700eea949b767ed0617596dcdfb41be3c9a5d1603c0b849c64c82301b37"
+    from dolomite_engine_tpu.models import nemotron_h, shared_expert_moe
+
+    assert nemotron_h.SharedExpertMoE is shared_expert_moe.SharedExpertMoE is SharedExpertMoE
+
+
 def test_the_shares_add_up_to_the_reference_s_uncut_layer():
     """Four shares of 8 experts: the routed parts of all shares plus the shared expert, once,
     are the reference's layer with all 32 experts."""

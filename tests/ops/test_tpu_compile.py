@@ -281,13 +281,9 @@ def test_sharded_fused_loss_compiles_for_v5e_2x2(v5e):
     assert compiled.memory_analysis().temp_size_in_bytes < 300 * 2**20
 
 
-def test_nemotron_h_tower_step_compiles_for_v5e_at_published_widths(v5e, capsys):
-    """The benchmark cell's whole train step — the 9 layers MEMEM*EME of the `nemotron_h`
-    tower at published widths, 2 packed rows of 8192 tokens, AdamW, as
-    `benchmark/drivers/train_packed_tower.py` builds its arguments — for one described v5e:
-    the chunked scan, the grouped products, splash at GQA 16:1 and the chunked loss on the
-    untied head all lower, and the program fits the chip. The estimate of its temporaries is
-    printed; it is an estimate (PERF.md section 6: it has differed from the chip's reading)."""
+def _compiled_cell_step(v5e, cell_name: str):
+    """The whole train step of a benchmark cell driven by `train_packed_tower`, built from the
+    cell's files as that driver builds its arguments, compiled for one described v5e."""
     import types
 
     from benchmark.drivers.train_packed import build_training_args
@@ -302,7 +298,7 @@ def test_nemotron_h_tower_step_compiles_for_v5e_at_published_widths(v5e, capsys)
     from dolomite_engine_tpu.utils import packages
 
     one_chip = SingleDeviceSharding(v5e[0])
-    cell = Spec.load().cell("train-nemotron-tower-packed8k")
+    cell = Spec.load().cell(cell_name)
     ctx = types.SimpleNamespace(cell=cell, tiny=False, seed=1, out_dir="/nonexistent")
     cfg = model_config(ctx)
     args = build_training_args(ctx, cfg, "/nonexistent/corpus", 10)
@@ -337,6 +333,17 @@ def test_nemotron_h_tower_step_compiles_for_v5e_at_published_widths(v5e, capsys)
     finally:
         jax.default_backend, pallas_config._PLATFORM_KEY, packages.pallas_interpret_mode = saved
         install_kernel_config(None)
+    return compiled
+
+
+def test_nemotron_h_tower_step_compiles_for_v5e_at_published_widths(v5e, capsys):
+    """The benchmark cell's whole train step — the 9 layers MEMEM*EME of the `nemotron_h`
+    tower at published widths, 2 packed rows of 8192 tokens, AdamW, as
+    `benchmark/drivers/train_packed_tower.py` builds its arguments — for one described v5e:
+    the chunked scan, the grouped products, splash at GQA 16:1 and the chunked loss on the
+    untied head all lower, and the program fits the chip. The estimate of its temporaries is
+    printed; it is an estimate (PERF.md section 6: it has differed from the chip's reading)."""
+    compiled = _compiled_cell_step(v5e, "train-nemotron-tower-packed8k")
     memory, text = compiled.memory_analysis(), compiled.as_text()
     gib = 2.0**30
     with capsys.disabled():
@@ -353,3 +360,28 @@ def test_nemotron_h_tower_step_compiles_for_v5e_at_published_widths(v5e, capsys)
     assert sum("mamba2_scan_bwd" in name for name in kernels) == 4, kernels
     assert 6.0 * gib < memory.argument_size_in_bytes < 6.5 * gib  # 667M parameters x 10 B of state
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 14.5 * gib
+
+
+def test_joyai_flash_step_compiles_for_v5e_at_published_widths(v5e, capsys):
+    """The cell `train-joyai-flash-mtp-packed8k`'s whole train step — a dense block, 4 expert
+    blocks and the MTP module of `joyai_llm_flash` at published widths, 2 packed rows of 8192
+    tokens, AdamW — for one described v5e: splash at scores over 192 and values of 128 (Mosaic
+    takes the 192 as it is: no padding), the megablox products on gated banks and the chunked
+    loss read twice all lower, and the program fits the chip (an estimate: the chip's reading is
+    in PERF.md)."""
+    compiled = _compiled_cell_step(v5e, "train-joyai-flash-mtp-packed8k")
+    memory, text = compiled.memory_analysis(), compiled.as_text()
+    gib = 2.0**30
+    with capsys.disabled():
+        print(
+            f"\njoyai_llm_flash step for a described v5e: state {memory.argument_size_in_bytes / gib:.2f} GiB, "
+            f"temporaries (estimate) {memory.temp_size_in_bytes / gib:.2f} GiB"
+        )
+    kernels = [line.split(" = ")[0] for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    count = lambda prefix: sum(name.strip().lstrip("%").startswith(prefix) for name in kernels)  # noqa: E731
+    # 6 blocks x (forward, its replay under `full` remat, dkv, dq) of splash: no attention is left to XLA's products
+    assert (count("splash_mha_fwd"), count("splash_mha_dkv"), count("splash_mha_dq")) == (12, 6, 6), kernels
+    # 5 layers of experts x (forward and its replay: 2 products each; backward: 2 for the rows, 2 for the banks)
+    assert "ragged-dot" not in text and (count("gmm"), count("tgmm")) == (60, 20), kernels
+    assert 6.2 * gib < memory.argument_size_in_bytes < 6.5 * gib  # 680.4M parameters x 10 B of state
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.0 * gib

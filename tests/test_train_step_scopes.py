@@ -152,3 +152,48 @@ def test_scopes_are_debug_locations_only():
     """Names live in debug locations alone: the lowering printed without them names no phase."""
     text = _lowered().as_text()
     assert "head_loss" not in text and "blocks" not in text
+
+
+def test_joyai_flash_names_its_layers_and_both_passes_through_the_head():
+    """`joyai_llm_flash`'s lowered train step carries the scopes docs/OBSERVABILITY.md lists:
+    latent attention's, the dense MLP's, the experts', and the multi-token-prediction module
+    under `blocks/mtp` and `head_loss/mtp/mtp_head_loss` — forward and backward — so that
+    `benchmark/phases.py` counts the module with the blocks and the head."""
+    from dolomite_engine_tpu.models import get_model_class
+    from tests.models.test_joyai_flash import CFG
+
+    model = get_model_class("joyai_llm_flash")(config=config_from_dict(CFG), checkpoint_every=1, dtype=jnp.bfloat16)
+    ids = jnp.zeros((1, 32), jnp.int32)
+    params = nn.unbox(model.init(jax.random.PRNGKey(0), ids)["params"])
+    assert "mtp" in params["transformer"]  # the module's parameters exist whatever the first call asked for
+    optimizer = optax.adamw(1e-3)
+    state = TrainState(step=jnp.zeros((), jnp.int32), params=params, opt_state=optimizer.init(params))
+
+    def loss_fn(params, micro, rng):
+        out = model.apply({"params": params}, micro["text"], compute_loss=True)
+        return out.loss, out.counters
+
+    lowered = jax.jit(make_train_step(loss_fn, optimizer, skip_nonfinite=True, has_aux=True)).lower(
+        state, {"text": jnp.zeros((1, 1, 32), jnp.int32)}, jax.random.PRNGKey(0)
+    )
+    names = [name for _, name in _operation_names(lowered)]
+    dots = [name for op, name in _operation_names(lowered) if op == "dot_general"]
+    for scope in (
+        "mla_q_down", "mla_q_up", "mla_kv_down", "mla_kv_up", "mla_rope", "mla_out_proj", "dense_mlp",
+        "moe_router", "moe_dispatch", "moe_experts", "moe_shared_expert", "moe_combine", "mtp_combine", "mtp_final_norm",
+    ):
+        assert any(f"/{scope}/" in name and "transpose(" not in name for name in names), scope
+        assert any(f"/{scope}/" in name and "transpose(" in name for name in names), scope
+    in_blocks = [name for name in names if "/blocks/" in name]
+    assert any("/blocks/mtp/" in name and "/latent_attention/" in name for name in in_blocks)
+    assert any("/blocks/mtp/" in name and "/moe/" in name for name in in_blocks)
+    # (a scan's body is named relative to its call: the loss's two passes are told apart by their scans)
+    plain = lambda name: re.sub(r"(transpose|jvp)\(|\)", "", name)  # noqa: E731
+    loss_scans = sorted(plain(name) for op, name in _operation_names(lowered) if op == "while" and "loss_chunks" in name)
+    assert len(loss_scans) == 4, loss_scans  # each pass's forward scan and its backward rule's
+    assert sum(name.endswith("head_loss/mtp/mtp_head_loss/loss_chunks/while") for name in loss_scans) == 2, loss_scans
+    assert sum(name.endswith("head_loss/loss_chunks/while") and "mtp" not in name for name in loss_scans) == 2, loss_scans
+    # no matmul of the blocks outside a layer's scope
+    for name in dots:
+        if "/blocks/" in name:
+            assert re.search(r"/(latent_attention|dense_mlp|moe|mtp_combine)/", name), name
