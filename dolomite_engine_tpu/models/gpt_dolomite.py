@@ -25,7 +25,7 @@ from jax.ad_checkpoint import checkpoint_name
 from ..parallel.sharding import logical_constraint
 
 from ..enums import AttentionImplementation
-from ..ops.attention import watch_kernel_residuals
+from ..ops.attention import SPLASH_COUNTERS, splash_block_counters, splash_expected, watch_kernel_residuals
 from ..ops.loss import causal_lm_loss, derive_causal_labels, fused_linear_cross_entropy
 from ..ops.rope import RoPEParams
 from .config import CommonConfig
@@ -51,7 +51,8 @@ class CausalLMOutput:
     hidden_states: jax.Array | None = None
     aux_loss: jax.Array | None = None
     # what a family counts in its forward pass for the train step to return beside the loss
-    # (`step_counters`; nemotron_h: token-slots routed to held / absent experts)
+    # (`step_counters`; nemotron_h: token-slots routed to held / absent experts; every family
+    # whose attention runs the splash kernel: the blocks its tables visited)
     counters: dict | None = None
 
 
@@ -641,12 +642,14 @@ class GPTDolomiteForCausalLM(nn.Module):
             if aux_loss is not None:
                 loss = loss + aux_loss
 
+        counters = self.step_counters(extras) or {}
+        counters.update(self.splash_step_counters(hidden_states, segment_ids, attention_mask))
         return CausalLMOutput(
             logits=logits,
             loss=loss,
             kv_caches=new_caches,
             aux_loss=aux_loss,
-            counters=self.step_counters(extras),
+            counters=counters or None,
         )
 
     @nn.nowrap  # no scope of the method's own: the operations keep the names they had in `__call__`
@@ -665,10 +668,32 @@ class GPTDolomiteForCausalLM(nn.Module):
             z_loss_coef=self.config.z_loss_coef,
         )
 
+    # names of what the family's blocks count (`step_counters`)
+    family_counter_names = ()
+
+    @property
+    def step_counter_names(self) -> tuple:
+        """Names of what a forward pass counts for the train step to return beside the loss:
+        the family's own and, where the blocks' attention is expected through the splash
+        kernel, `ops.attention.SPLASH_COUNTERS`. Empty: the train step returns what it
+        always returned."""
+        return self.family_counter_names + (SPLASH_COUNTERS if splash_expected(self.attention_implementation) else ())
+
     def step_counters(self, extras: list) -> dict | None:
-        """Hook: counters of this forward pass from the per-block extras (None: the family
-        counts nothing, and the train step returns what it always returned)."""
+        """Hook: the family's counters of this forward pass from the per-block extras (None:
+        it counts nothing)."""
         return None
+
+    def splash_step_counters(self, hidden_states: jax.Array, segment_ids: jax.Array | None, attention_mask: jax.Array | None) -> dict:
+        """`SPLASH_COUNTERS` of these rows (`hidden_states`: ``[rows, S, ...]``), one attention layer's worth (the layers share the
+        ids): the blocks the splash kernel's tables make it run and those under the diagonal
+        (`ops.attention.splash_block_counters`). A key-side padding mask reaches the kernel
+        as ids 1 / 0, so it counts as that. Nothing where the kernel is not expected."""
+        if not splash_expected(self.attention_implementation):
+            return {}
+        if segment_ids is None and attention_mask is not None and attention_mask.ndim == 2:
+            segment_ids = attention_mask
+        return splash_block_counters(hidden_states.shape[0], hidden_states.shape[1], segment_ids)
 
     def compute_aux_loss(
         self,

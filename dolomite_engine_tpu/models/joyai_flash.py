@@ -258,7 +258,7 @@ class JoyAIFlashForCausalLM(HeadTableForCausalLM):
     where the model predicts a second token."""
 
     base_model_cls: type = JoyAIFlashModel
-    step_counter_names = STEP_COUNTERS + LOSS_PARTS
+    family_counter_names = STEP_COUNTERS + LOSS_PARTS
 
     @nn.nowrap  # (as `fused_head_loss`: the scopes are the caller's)
     def head_loss(self, hidden_states: jax.Array, labels: jax.Array) -> jax.Array:
@@ -317,6 +317,7 @@ class JoyAIFlashForCausalLM(HeadTableForCausalLM):
             loss = main_loss + self.config.mtp_loss_coef * mtp_loss
         counters = stack_step_counters(extras) or {}
         counters.update(main_loss=main_loss, mtp_loss=mtp_loss, mtp_targets=mtp_targets)
+        counters.update(self.splash_step_counters(hidden_states, segment_ids, attention_mask))
         return CausalLMOutput(loss=loss, counters=counters)
 
     def init_kv_caches(self, batch_size: int, max_length: int, dtype=None) -> list:

@@ -301,7 +301,7 @@ class NemotronHForCausalLM(HeadTableForCausalLM):
     """The tower under the repo's untied head table and chunked loss."""
 
     base_model_cls: type = NemotronHModel
-    step_counter_names = STEP_COUNTERS
+    family_counter_names = STEP_COUNTERS
 
     def step_counters(self, extras: list) -> dict | None:
         """``{name: int32[layers of experts, ...]}`` from the blocks' counters."""

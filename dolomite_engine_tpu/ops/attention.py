@@ -13,6 +13,13 @@ All shapes are batch-first: q [B, Sq, Hq, D]; k [B, Skv, Hkv, D]; v [B, Skv, Hkv
 D everywhere but in latent attention, whose values are narrower than its scores). Packed (padding-free) input
 is [B, S] tokens + segment_ids [B, S] (0 = padding, 1.. = documents); this also implements
 `reset_attention_mask` document isolation (reference `model_wrapper/pretraining.py:129-160`).
+
+How documents reach the splash kernel: twice. Inside a block the kernel compares the segment
+ids of its queries and keys, as it always did. Which blocks it runs at all it reads from block
+tables in scalar memory, and those are built each step from the rows' segment ids
+(`document_block_pairs`, `_document_block_tables`): a (query block, key block) pair under the
+diagonal that no document spans is neither fetched nor computed, in the forward pass, in dkv and
+in dq. A call without segment ids runs jax's static causal tables, as before.
 """
 
 from __future__ import annotations
@@ -396,19 +403,96 @@ def watch_kernel_residuals():
         _RESIDUAL_WATCHERS.pop()
 
 
+# what a step's forward pass counts of the kernel's block tables (`splash_block_counters`)
+SPLASH_COUNTERS = ("splash_blocks_visited", "splash_blocks_causal")
+
+
+def document_block_pairs(segment_ids: jax.Array, block: int) -> jax.Array:
+    """bool ``[B, n, n]`` (``n = S // block``): whether (query block i, key block j) of a row
+    holds a pair the causal, per-document mask lets through — j on or under the diagonal and
+    the two blocks' ids meet. The test is each block's ``[min, max]`` of the ids: ranges that
+    do not meet share no id, whatever the order of the ids, so a needed pair is never dropped;
+    on ids that do not decrease along the row it is exact. Padding (id 0, at a row's tail)
+    is given the largest id first, so that it too is in order."""
+    batch, seq = segment_ids.shape
+    n = seq // block
+    ids = segment_ids.astype(jnp.int32)
+    ids = jnp.where(ids == 0, jnp.iinfo(jnp.int32).max, ids).reshape(batch, n, block)
+    low, high = ids.min(-1), ids.max(-1)  # [B, n]
+    meet = (low[:, :, None] <= high[:, None, :]) & (low[:, None, :] <= high[:, :, None])
+    return meet & jnp.tril(jnp.ones((n, n), bool))
+
+
+def _document_block_tables(needed: jax.Array):
+    """The tables jax's splash launches read by scalar prefetch, from `document_block_pairs`,
+    for the rows laid end to end as one sequence of ``B * n`` blocks (a row's blocks only ever
+    name blocks of the same row, so rows never meet). ``block_mask`` says whether a grid step
+    runs; ``data_next`` names the block its index maps fetch — its own where it runs, the next
+    one that runs where it does not, so a skipped step moves nothing the next does not need.
+
+    Forward and dq (grid: heads, query blocks, key slots) get ``[1, B * n, n]``: the grid is
+    as wide as a row, and slot j of query block (b, i) is key block (b, j). dkv (grid: key
+    blocks, heads, query slots) gets ``[1, n, B * n]``: slot i of key block (b, j) is query
+    block (b, i). That is the shape jax's own shrunk tables have (`_shrink_mask_info`)."""
+    batch, n, _ = needed.shape
+    first = jnp.arange(batch, dtype=jnp.int32)[:, None, None] * n  # a row's first block
+    slot = jnp.arange(n, dtype=jnp.int32)
+
+    # forward, dq: the next needed (row, slot) in grid order. The last slot of the last row
+    # is a diagonal block, so there always is one; a query block's run ends at its diagonal
+    # and goes on with the first needed block of the next query block.
+    positions = jnp.arange(batch * n * n, dtype=jnp.int32)
+    following = jax.lax.cummin(jnp.where(needed.reshape(-1), positions, batch * n * n), reverse=True)
+    data_next = following // (n * n) * n + following % n  # its row's first block + its slot
+    forward = (needed.astype(jnp.int32).reshape(1, batch * n, n), data_next.reshape(1, batch * n, n))
+
+    # dkv: the next needed query block of the same key block, else its diagonal — where the
+    # next head starts
+    following = jax.lax.cummin(jnp.where(needed, slot[None, :, None], n), axis=1, reverse=True)
+    following = jnp.where(following == n, slot[None, None, :], following) + first
+    key_major = lambda t: jnp.swapaxes(t, 0, 1).reshape(1, n, batch * n)
+    return forward, (key_major(needed.astype(jnp.int32)), key_major(following))
+
+
+def splash_block_counters(batch: int, seq: int, segment_ids: jax.Array | None = None) -> dict:
+    """`SPLASH_COUNTERS` of one attention layer over these rows: the (query block, key block)
+    pairs the kernel's tables make it run, and those under the diagonal. Their ratio is what
+    the documents left of the work; 1.0 says the tables skipped nothing (no segment ids).
+    Zeros where the kernel does not take the length."""
+    if seq % 128 != 0:
+        return dict.fromkeys(SPLASH_COUNTERS, jnp.zeros((), jnp.int32))
+    block = _pick_block(seq)
+    n = seq // block
+    causal = jnp.asarray(batch * n * (n + 1) // 2, jnp.int32)
+    visited = causal if segment_ids is None else document_block_pairs(segment_ids, block).sum(dtype=jnp.int32)
+    return {"splash_blocks_visited": visited, "splash_blocks_causal": causal}
+
+
+def _rows_end_to_end(x: jax.Array) -> jax.Array:
+    """``[B, S, H, D] -> [H, B * S, D]``: each head's rows one after the other."""
+    return jnp.transpose(x, (2, 0, 1, 3)).reshape(x.shape[2], x.shape[0] * x.shape[1], x.shape[3])
+
+
 def _splash_attention_local(q, k, v, segment_ids, softmax_scale: float, interpret: bool):
+    """jax's splash kernel over one device's rows, causal, blocks of `_pick_block`.
+
+    Without segment ids: the static causal kernel (`make_splash_mha_single_device`), whose
+    tables know the diagonal, under `jax.vmap` over the rows. With segment ids: the same
+    kernel functions on tables built from the ids (`_document_block_tables`), so the blocks
+    no document spans are not run; the rows go in end to end as one sequence (Pallas would
+    batch a per-row scalar-prefetch operand with a loop of slices and copies over the rows).
+    The mask inside a block — causal on positions, equality on segment ids — is the same
+    on both paths, and a skipped block is one whose every entry it masked."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as _sk,
         splash_attention_mask as _sm,
     )
 
     from ..models.modeling_utils import ATTENTION_KERNEL_RESIDUALS_CHECKPOINT_NAME
+    from ..utils.telemetry import get_telemetry
 
-    qt = jnp.swapaxes(q, 1, 2)  # [B, Hq, S, D]
-    kt = jnp.swapaxes(k, 1, 2)  # [B, Hkv, S, D]
-    vt = jnp.swapaxes(v, 1, 2)
-    num_q_heads = qt.shape[1]
-    sq, skv = qt.shape[2], kt.shape[2]
+    batch, sq, num_q_heads, _ = q.shape
+    skv = k.shape[1]
 
     bq, bkv = _pick_block(sq), _pick_block(skv)
     block_sizes = _sk.BlockSizes(
@@ -421,30 +505,64 @@ def _splash_attention_local(q, k, v, segment_ids, softmax_scale: float, interpre
         block_q_dq=bq,
         block_kv_dq=bkv,
     )
+    for seen in _RESIDUAL_WATCHERS:
+        # the output is as wide as the values (latent attention scores over a wider head)
+        seen.append(num_q_heads * sq * (v.shape[3] * q.dtype.itemsize + 4))
+    get_telemetry().event_once(
+        "splash_block_plan",
+        block_q=bq,
+        block_kv=bkv,
+        rows=batch,
+        # a launch's grid: heads x query blocks x key slots (dkv: key blocks x heads x query slots)
+        grid=(num_q_heads, batch * (sq // bq), skv // bkv),
+        launches_per_call=1 if segment_ids is not None else batch,
+        tables="segment_ids" if segment_ids is not None else "static",
+        why_static=None if segment_ids is not None else "the call has no segment ids",
+    )
+
+    # jax's static causal kernel of one row: its tables know the diagonal. The name makes it
+    # tag its output and log-sum-exp with `checkpoint_name`, so a remat policy can keep them
+    # (save_dots does) and the backward pass need not run the forward kernel again; outside
+    # a remat and under a policy without the name the tag is an identity that does not
+    # reach the HLO
     mask = _sm.MultiHeadMask([_sm.CausalMask((sq, skv)) for _ in range(num_q_heads)])
-    # the name makes jax's kernel tag its output and log-sum-exp with `checkpoint_name`, so a
-    # remat policy can keep them (save_dots does) and the backward pass need not run the
-    # forward kernel again; outside a remat and under a policy without the name the tag is
-    # an identity that does not reach the HLO
     kernel = _sk.make_splash_mha_single_device(
         mask,
         block_sizes=block_sizes,
         residual_checkpoint_name=ATTENTION_KERNEL_RESIDUALS_CHECKPOINT_NAME,
         interpret=interpret,
     )
-    for seen in _RESIDUAL_WATCHERS:
-        # the output is as wide as the values (latent attention scores over a wider head)
-        seen.append(num_q_heads * sq * (vt.shape[3] * qt.dtype.itemsize + 4))
-
-    qs = qt * softmax_scale  # splash has no sm_scale argument
     if segment_ids is None:
+        qt = jnp.swapaxes(q, 1, 2)  # [B, Hq, S, D]
+        kt = jnp.swapaxes(k, 1, 2)  # [B, Hkv, S, D]
+        vt = jnp.swapaxes(v, 1, 2)
+        qs = qt * softmax_scale  # splash has no sm_scale argument
         out = jax.vmap(lambda a, b, c: kernel(a, b, c))(qs, kt, vt)
-    else:
-        seg = segment_ids.astype(jnp.int32)
-        out = jax.vmap(
-            lambda a, b, c, s: kernel(a, b, c, segment_ids=_sk.SegmentIds(q=s, kv=s))
-        )(qs, kt, vt, seg)
-    return jnp.swapaxes(out, 1, 2)
+        return jnp.swapaxes(out, 1, 2)
+
+    # the same kernel (its functions, mask value, in-block causal function) on the documents'
+    # tables; the positions are those of the rows laid end to end, where a row's causal
+    # order is what it was
+    (block_mask, data_next), (block_mask_dkv, data_next_dkv) = _document_block_tables(
+        document_block_pairs(segment_ids, bq)
+    )
+    tables = kernel.fwd_mask_info._replace(
+        data_next=data_next, block_mask=block_mask, q_sequence=jnp.arange(batch * sq, dtype=jnp.int32)
+    )
+    kernel = _sk.SplashAttentionKernel(
+        tables,
+        tables,  # dq: the forward's block sizes, so the forward's tables
+        tables._replace(data_next=data_next_dkv, block_mask=block_mask_dkv),
+        **kernel.kwargs,
+    )
+    ids = segment_ids.astype(jnp.int32).reshape(-1)
+    out = kernel(
+        _rows_end_to_end(q) * softmax_scale,  # splash has no sm_scale argument
+        _rows_end_to_end(k),
+        _rows_end_to_end(v),
+        segment_ids=_sk.SegmentIds(q=ids, kv=ids),
+    )
+    return jnp.transpose(out.reshape(num_q_heads, batch, sq, v.shape[3]), (1, 2, 0, 3))
 
 
 def _tpu_flash_attention(

@@ -25,13 +25,19 @@ import jax.numpy as jnp
 import numpy as np
 
 _DEFAULT_BLOCK_ROWS = 256
+# the most bytes a block of rows may hold. The kernel with a residual double-buffers two
+# inputs and two outputs and keeps float32 copies of a block: at 2 MiB a block (256 rows of
+# 4096 in bfloat16) that is 17.8 MiB, over the 16 MiB of scoped VMEM a kernel gets, and it
+# compiled only where XLA chose to keep both outputs in VMEM itself — which any change to
+# the operations around it can undo (PR 31 did). 1.5 MiB leaves 256 rows to widths up to 3072.
+_MAX_BLOCK_BYTES = 3 * 2**19
 
 
-def _pick_block_rows(rows: int) -> int:
+def _pick_block_rows(rows: int, row_bytes: int = 0) -> int:
     for block in (_DEFAULT_BLOCK_ROWS, 128, 64, 32, 16, 8):
-        if rows >= block:
+        if rows >= block and block * row_bytes <= _MAX_BLOCK_BYTES:
             return block
-    return max(rows, 1)
+    return max(min(rows, 8), 1)
 
 
 def _rmsnorm_kernel(x_ref, w_ref, o_ref, *, eps: float):
@@ -84,7 +90,7 @@ def _rmsnorm_local(x, weight, eps: float, residual, interpret: bool | None):
     interpret = _interpret_default(interpret)
     rows2d, shape = _flatten_rows(x)
     rows, dim = rows2d.shape
-    block_rows = _pick_block_rows(rows)
+    block_rows = _pick_block_rows(rows, dim * rows2d.dtype.itemsize)
     padded = -(-rows // block_rows) * block_rows
     if padded != rows:
         rows2d = jnp.pad(rows2d, ((0, padded - rows), (0, 0)))

@@ -254,7 +254,9 @@ def make_eval_step(model):
     """`eval_step(params, batch, fp8_state=None)`: the model's loss with dropout off."""
 
     def eval_step(params, batch, fp8_state=None):
-        return model.loss(params, batch, rngs=None, train=False, fp8_state=fp8_state)
+        loss = model.loss(params, batch, rngs=None, train=False, fp8_state=fp8_state)
+        # a family whose forward pass counts returns (loss, counters): `step_counter_names`
+        return loss[0] if isinstance(loss, tuple) else loss
 
     return eval_step
 

@@ -304,6 +304,12 @@ KNOWN_EVENTS: tuple[str, ...] = (
     # many of them keep the kernel's output and log-sum-exp (the others run the forward
     # kernel again in the backward pass), and those bytes a block and batch row
     "remat_plan",
+    # what the splash kernel's launches run where attention was traced
+    # (ops/attention._splash_attention_local): block_q, block_kv, the rows of a call, a
+    # launch's grid (heads, query blocks, key slots), launches a call, and whether the block
+    # tables come from the rows' segment ids ("segment_ids": blocks no document spans are
+    # skipped) or are jax's static causal ones ("static", with why_static)
+    "splash_block_plan",
     # what a model cut to one chip's share holds of what was published (models/config.py
     # NemotronHConfig.layout_record: pattern, experts held of published, vocabulary rows
     # held, the deployment's numbers; JoyAIFlashConfig.layout_record: blocks by kind in the
@@ -319,7 +325,10 @@ KNOWN_EVENTS: tuple[str, ...] = (
     # one entry a layer of experts — routed_slots (token-slots of held experts: the rows
     # the grouped products multiply), absent_slots, fullest_expert_rows, held_expert_rows;
     # for joyai_llm_flash the same (its multi-token-prediction module's layer last) and the
-    # loss's two parts main_loss and mtp_loss with mtp_targets, the positions the second had
+    # loss's two parts main_loss and mtp_loss with mtp_targets, the positions the second had;
+    # for every family whose attention runs the splash kernel splash_blocks_visited and
+    # splash_blocks_causal (ops/attention.splash_block_counters: the block pairs one
+    # attention layer's tables ran over the step's rows, and those under the diagonal)
     "step_counters",
     # serving-fleet fault tolerance (serving/cluster/health.py + router.py): one event
     # per downward health edge, per completed drain/rejoin, and when a threaded

@@ -37,6 +37,10 @@ from dolomite_engine_tpu.parallel.sharding import get_logical_axis_rules, logica
 WIDTHS = {
     "smoke_2560_32x80_s4096": (2560, 32, 32, 80, 2, 4096),
     "bench_1024_16-8x64_s2048": (1024, 16, 8, 64, 8, 2048),
+    # the 8B cell's: at 4096 wide a 256-row block of the norm with a residual is over the
+    # scoped VMEM a kernel gets (PR 31: it had compiled only inside the whole step, where XLA
+    # kept its outputs in VMEM)
+    "granite8b_4096_32-8x128_s4096": (4096, 32, 8, 128, 2, 4096),
 }
 DECODE_SLOTS, PREFILL_CHUNK, PAGE_SIZE = 8, 512, 16
 
