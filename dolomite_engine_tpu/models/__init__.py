@@ -13,6 +13,7 @@ from .config import (
     EncDecDolomiteConfig,
     GPTCrossLayerConfig,
     JoyAIFlashConfig,
+    Lfm2MoeConfig,
     MoEConfig,
     NemotronHConfig,
     RNNDolomiteConfig,
@@ -26,6 +27,7 @@ from .gpt_crosslayer import (
     convert_gpt_dolomite_to_gpt_crosslayer,
 )
 from .joyai_flash import JoyAIFlashForCausalLM, JoyAIFlashModel
+from .lfm2_moe import Lfm2MoeForCausalLM, Lfm2MoeModel
 from .moe_dolomite import MoEDolomiteForCausalLM, MoEDolomiteModel
 from .nemotron_h import NemotronHForCausalLM, NemotronHModel
 from .rnn_dolomite import RNNDolomiteForCausalLM, RNNDolomiteModel
@@ -39,6 +41,7 @@ _CONFIG_CLASSES: dict[str, type] = {
     "enc_dec_dolomite": EncDecDolomiteConfig,
     "nemotron_h": NemotronHConfig,
     "joyai_llm_flash": JoyAIFlashConfig,
+    "lfm2_moe": Lfm2MoeConfig,
 }
 
 _MODEL_CLASSES: dict[str, type] = {
@@ -50,6 +53,7 @@ _MODEL_CLASSES: dict[str, type] = {
     "enc_dec_dolomite": EncDecDolomiteForSeq2SeqLM,
     "nemotron_h": NemotronHForCausalLM,
     "joyai_llm_flash": JoyAIFlashForCausalLM,
+    "lfm2_moe": Lfm2MoeForCausalLM,
 }
 
 # families trained/driven through the seq2seq (AutoModelForSeq2SeqLM) surface

@@ -65,6 +65,10 @@ class CommonConfig:
     # per-head width when it differs from n_embd // n_head (HF T5's d_kv: flan-t5-small is
     # 512 wide with 6 heads of 64); None derives it from n_embd
     attention_head_dim: int | None = None
+    # an RMSNorm of every query and key head over its columns (one weight of head_dim each,
+    # eps `layer_norm_epsilon`) between the QKV split and the rotation
+    # (`ops/rope.split_qkv_apply_rope`)
+    qk_norm: bool = False
 
     def __post_init__(self) -> None:
         if self.n_inner is None:
@@ -351,6 +355,8 @@ class NemotronHConfig(CommonConfig):
 
     # parameter leaves the optimizer must leave as they are (buffers of the public model)
     buffer_names = ("e_score_correction_bias",)
+    # the literal of the family's public code in the renormalisation's denominator (a third family's differs)
+    norm_topk_prob_epsilon = 1e-20
 
     def __post_init__(self) -> None:
         if self.hybrid_override_pattern is None:
@@ -468,6 +474,7 @@ class JoyAIFlashConfig(CommonConfig):
     deployment: dict | None = None
 
     buffer_names = ("e_score_correction_bias",)
+    norm_topk_prob_epsilon = 1e-20  # (as `NemotronHConfig`'s)
 
     def __post_init__(self) -> None:
         if self.attention_head_dim is None:
@@ -533,6 +540,113 @@ class JoyAIFlashConfig(CommonConfig):
             blocks_dense=self.first_k_dense_replace,
             blocks_experts=self.n_layer - self.first_k_dense_replace,
             blocks_mtp=self.num_nextn_predict_layers,
+            experts_held=count,
+            first_expert_held=first,
+            experts_published=self.num_experts,
+            vocabulary_rows_held=self.vocab_size,
+            **(self.deployment or {}),
+        )
+
+
+@dataclass
+class Lfm2MoeConfig(CommonConfig):
+    """`lfm2_moe` (LFM2-24B-A2B's family): every block is ONE operator behind a pre-norm and a
+    residual — by `layer_types[i]` a gated short convolution (``conv``: `models/lfm2_moe.ShortConv`)
+    or grouped-query attention with per-head QK norms and rope (``full_attention``) — and then
+    a feed-forward sublayer behind another: a dense SwiGLU MLP of `n_inner` in the first
+    `num_dense_layers` blocks, sigmoid-routed SwiGLU experts WITHOUT a shared expert
+    (`shared_expert_moe.SharedExpertMoE`) in the others. The embedding is the head's table.
+
+    The repo's names carry the widths they always carried (`n_embd`, `n_head`,
+    `num_key_value_heads`, `n_inner`); the rest are the public `config.json`'s keys
+    (`norm_topk_prob_epsilon` is the public modeling code's literal). `experts_held` and
+    `deployment` are `NemotronHConfig`'s."""
+
+    model_type: str = "lfm2_moe"
+    attention_head_type: str = "gqa"
+    position_embedding_type: str = "rope"
+    normalization_function: str = "rmsnorm"
+    activation_function: str = "swiglu"
+    layer_norm_epsilon: float = 1e-5
+    rope_theta: float = 1000000.0
+    add_bias: bool = False
+    tie_word_embeddings: bool = True
+    qk_norm: bool = True
+    # the operator of every block, and its short convolution
+    layer_types: list[str] | None = None
+    conv_L_cache: int = 3
+    conv_bias: bool = False
+    # feed-forward sublayers
+    num_dense_layers: int = 2
+    num_experts: int = 64
+    num_experts_per_tok: int = 4
+    moe_intermediate_size: int = 1536
+    use_expert_bias: bool = True
+    routed_scaling_factor: float = 1.0
+    norm_topk_prob: bool = True
+    norm_topk_prob_epsilon: float = 1e-6
+    experts_held: list[int] | None = None
+    deployment: dict | None = None
+
+    buffer_names = ("e_score_correction_bias",)
+    # the family has no shared expert (`SharedExpertMoE` then builds none)
+    moe_shared_expert_intermediate_size = 0
+
+    def __post_init__(self) -> None:
+        if self.layer_types is None:
+            self.layer_types = ["full_attention"] * self.n_layer
+        super().__post_init__()
+        if len(self.layer_types) != self.n_layer:
+            raise ValueError(f"layer_types names {len(self.layer_types)} layers, n_layer is {self.n_layer}")
+        unknown = set(self.layer_types) - {"conv", "full_attention"}
+        if unknown:
+            raise ValueError(f"layer_types knows conv and full_attention, not {sorted(unknown)}")
+        if self.conv_bias:
+            raise ValueError("conv_bias true: the short convolution is built without biases (the published models')")
+        if not self.use_expert_bias:
+            raise ValueError("use_expert_bias false: the router is built with its bias (the published models')")
+        if not 0 <= self.num_dense_layers <= self.n_layer:
+            raise ValueError(f"num_dense_layers {self.num_dense_layers} lies outside 0..{self.n_layer}")
+        self.experts_held = _checked_experts_held(self.experts_held, self.num_experts)
+
+    @classmethod
+    def supported_position_embeddings(cls) -> frozenset[PositionEmbeddingType]:
+        return frozenset({PositionEmbeddingType.rope})
+
+    @property
+    def expert_layers(self) -> int:
+        """Layers of experts whose counters a step returns: the blocks after the dense ones."""
+        return self.n_layer - self.num_dense_layers
+
+    def held_experts(self) -> tuple[int, int]:
+        return tuple(self.experts_held) if self.experts_held else (0, self.num_experts)
+
+    def forward_block_flops(self, b: int, s: int) -> float:
+        """`NemotronHConfig.forward_block_flops` for this family (the convolution's taps and
+        gates, no matmuls, left out as the tower's are)."""
+        h, heads, kv, d = self.n_embd, self.n_head, self.num_key_value_heads, self.head_dim
+        held = self.held_experts()[1]
+        per_operator = {
+            "conv": 2 * b * s * (3 * h * h + h * h),
+            "full_attention": 2 * b * s * (h * (heads + 2 * kv) * d + heads * d * h) + 4 * b * s * s * heads * d,
+        }
+        dense = 2 * b * s * 3 * h * self.n_inner
+        experts = 2 * b * s * (
+            h * self.num_experts
+            + self.num_experts_per_tok * held / self.num_experts * 3 * h * self.moe_intermediate_size
+        )
+        operators = sum(per_operator[kind] for kind in self.layer_types)
+        return float(operators + self.num_dense_layers * dense + self.expert_layers * experts)
+
+    def layout_record(self) -> dict:
+        """What the run's one `model_layout` telemetry event says."""
+        first, count = self.held_experts()
+        return dict(
+            layer_types=",".join(self.layer_types),
+            blocks_conv=self.layer_types.count("conv"),
+            blocks_attention=self.layer_types.count("full_attention"),
+            blocks_dense=self.num_dense_layers,
+            blocks_experts=self.expert_layers,
             experts_held=count,
             first_expert_held=first,
             experts_published=self.num_experts,

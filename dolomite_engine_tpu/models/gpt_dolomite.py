@@ -737,11 +737,14 @@ class GPTDolomiteForCausalLM(nn.Module):
 
 
 class HeadTableForCausalLM(GPTDolomiteForCausalLM):
-    """A family's blocks (`base_model_cls`) under an untied head kept as a ``[V, H]`` table
-    (`modeling_utils.HeadTable`: the public checkpoints' layout, and what the chunked loss
-    reads) — `nemotron_h`, `joyai_llm_flash`."""
+    """A family's blocks (`base_model_cls`) under a head kept as a ``[V, H]`` table — what the
+    chunked loss reads: an untied one (`modeling_utils.HeadTable`: the public checkpoints'
+    layout; `nemotron_h`, `joyai_llm_flash`) or, where the config ties, the embedding's own
+    (`lfm2_moe`)."""
 
     def setup(self) -> None:
+        if self.config.tie_word_embeddings:
+            return super().setup()  # the embedding table is the head's: no parameter of its own
         self.transformer = self.base_model_cls(**self._transformer_kwargs())
         self.lm_head = HeadTable(
             num_embeddings=self.config.vocab_size,
@@ -750,6 +753,8 @@ class HeadTableForCausalLM(GPTDolomiteForCausalLM):
         )
 
     def _lm_head_operands(self, hidden_states: jax.Array) -> tuple[jax.Array, jax.Array]:
+        if self.config.tie_word_embeddings:
+            return super()._lm_head_operands(hidden_states)
         return hidden_states.astype(self.dtype), self.lm_head().astype(self.dtype)
 
     def compute_logits(self, hidden_states: jax.Array) -> jax.Array:

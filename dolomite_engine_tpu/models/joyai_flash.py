@@ -52,7 +52,13 @@ from .modeling_utils import (
     ParameterizedLinear,
     get_norm,
 )
-from .shared_expert_moe import STEP_COUNTERS, SharedExpertMoE, stack_step_counters
+from .shared_expert_moe import (
+    STEP_COUNTERS,
+    SharedExpertMoE,
+    refuse_generation_cache,
+    refuse_what_is_not_built,
+    stack_step_counters,
+)
 
 # beside the experts' counters: both parts of the loss and the positions the second had
 LOSS_PARTS = ("main_loss", "mtp_loss", "mtp_targets")
@@ -135,20 +141,12 @@ class JoyAIFlashModel(nn.Module):
 
     def setup(self) -> None:
         config = self.config
-        if self.scan_layers:
-            raise ValueError(
-                "scan_layers with joyai_llm_flash: the dense first block differs from the expert "
-                "blocks and a scan over the like ones is not built; run it unrolled (scan_layers: false)"
-            )
-        from ..parallel.mesh import MeshManager
-
-        if MeshManager.is_initialized():
-            for axis, what in (("tp", "the latent attention's heads"), ("ep", "the experts held")):
-                if MeshManager.axis_size(axis) > 1:
-                    raise ValueError(
-                        f"joyai_llm_flash on a mesh with {axis} > 1: {what} would be replicated, "
-                        f"not sharded; {axis} for this family is not built"
-                    )
+        refuse_what_is_not_built(
+            "joyai_llm_flash",
+            self.scan_layers,
+            "the dense first block differs from the expert blocks and a scan over the like ones is not built",
+            {"tp": "the latent attention's heads", "ep": "the experts held"},
+        )
         self.wte = ParameterizedEmbedding(
             num_embeddings=config.vocab_size, features=config.n_embd, std=config.initializer_range, dtype=self.dtype
         )
@@ -201,9 +199,8 @@ class JoyAIFlashModel(nn.Module):
         """(normed hidden states, None, the expert layers' counters, and — `predict_second`,
         with a multi-token-prediction module — its normed hidden states, else None)."""
         if kv_caches is not None:
-            raise NotImplementedError(
-                "joyai_llm_flash has no generation cache (a latent page and the absorbed decode "
-                "form are not built: ROADMAP M5); the training path only"
+            refuse_generation_cache(
+                "joyai_llm_flash", "a latent page and the absorbed decode form are not built: ROADMAP M5"
             )
         batch, seq = input_ids.shape
         with jax.named_scope("embed"):
@@ -321,4 +318,4 @@ class JoyAIFlashForCausalLM(HeadTableForCausalLM):
         return CausalLMOutput(loss=loss, counters=counters)
 
     def init_kv_caches(self, batch_size: int, max_length: int, dtype=None) -> list:
-        raise NotImplementedError("joyai_llm_flash has no generation cache (ROADMAP M5)")
+        refuse_generation_cache("joyai_llm_flash", "ROADMAP M5")

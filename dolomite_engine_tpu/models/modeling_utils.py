@@ -644,8 +644,22 @@ class Attention(nn.Module):
         # forward, serving prefill chunks, decode, and the speculative verify window all
         # split + rotate here, so the XLA reference and the fused Pallas kernel
         # (`fused_rope_qkv` family, ops/pallas/rope_qkv.py) swap for all of them at once
+        qk_norm = None
+        if config.qk_norm:
+            # a config that asks for it: one RMSNorm weight of `head_dim` each for the query
+            # and the key heads, applied per head between the split and the rotation
+            q_norm_weight, k_norm_weight = (
+                self.param(
+                    name,
+                    nn.with_logical_partitioning(nn.initializers.ones_init(), (None,)),
+                    (head_dim,),
+                    jnp.float32,
+                )
+                for name in ("q_norm_weight", "k_norm_weight")
+            )
+            qk_norm = (q_norm_weight, k_norm_weight, config.layer_norm_epsilon)
         query, key, value = split_qkv_apply_rope(
-            qkv, num_heads, num_kv_heads, head_dim, rope_cos_sin
+            qkv, num_heads, num_kv_heads, head_dim, rope_cos_sin, qk_norm
         )
 
         softmax_scale = get_softmax_scale(config, head_dim)

@@ -40,7 +40,8 @@ from flax import linen as nn
 
 from ..enums import AttentionImplementation
 from ..ops.attention import watch_kernel_residuals
-from ..ops.mamba2 import causal_conv1d, gated_group_rmsnorm, mamba2_scan, watch_scan_lowerings
+from ..ops.causal_conv import causal_conv1d
+from ..ops.mamba2 import gated_group_rmsnorm, mamba2_scan, watch_scan_lowerings
 from ..parallel.sharding import logical_constraint
 from .config import NemotronHConfig
 from .gpt_dolomite import HeadTableForCausalLM, resolve_remat_policy, say_remat_plan
@@ -51,7 +52,13 @@ from .modeling_utils import (
     depth_scaled_init_std,
     get_norm,
 )
-from .shared_expert_moe import STEP_COUNTERS, SharedExpertMoE, stack_step_counters
+from .shared_expert_moe import (
+    STEP_COUNTERS,
+    SharedExpertMoE,
+    refuse_generation_cache,
+    refuse_what_is_not_built,
+    stack_step_counters,
+)
 
 
 def _inverse_softplus(x: jax.Array) -> jax.Array:
@@ -212,20 +219,12 @@ class NemotronHModel(nn.Module):
 
     def setup(self) -> None:
         config = self.config
-        if self.scan_layers:
-            raise ValueError(
-                "scan_layers with nemotron_h: the layers of a pattern differ and a scan over "
-                "whole periods is not built; run it unrolled (scan_layers: false)"
-            )
-        from ..parallel.mesh import MeshManager
-
-        if MeshManager.is_initialized():
-            for axis, what in (("tp", "the Mamba-2 heads"), ("ep", "the experts held")):
-                if MeshManager.axis_size(axis) > 1:
-                    raise ValueError(
-                        f"nemotron_h on a mesh with {axis} > 1: {what} would be replicated, "
-                        f"not sharded; {axis} for this family is not built"
-                    )
+        refuse_what_is_not_built(
+            "nemotron_h",
+            self.scan_layers,
+            "the layers of a pattern differ and a scan over whole periods is not built",
+            {"tp": "the Mamba-2 heads", "ep": "the experts held"},
+        )
         self.wte = ParameterizedEmbedding(
             num_embeddings=config.vocab_size,
             features=config.n_embd,
@@ -268,9 +267,8 @@ class NemotronHModel(nn.Module):
         inputs_embeds: jax.Array | None = None,
     ) -> tuple[jax.Array, None, list]:
         if kv_caches is not None:
-            raise NotImplementedError(
-                "nemotron_h has no generation cache (Mamba state and convolution taps are not "
-                "in the serving engine's cache: ROADMAP M2); the training path only"
+            refuse_generation_cache(
+                "nemotron_h", "Mamba state and convolution taps are not in the serving engine's cache: ROADMAP M2"
             )
         with jax.named_scope("embed"):
             hidden_states = self.wte(input_ids) if inputs_embeds is None else inputs_embeds
@@ -308,4 +306,4 @@ class NemotronHForCausalLM(HeadTableForCausalLM):
         return stack_step_counters(extras)
 
     def init_kv_caches(self, batch_size: int, max_length: int, dtype=None) -> list:
-        raise NotImplementedError("nemotron_h has no generation cache (ROADMAP M2)")
+        refuse_generation_cache("nemotron_h", "ROADMAP M2")

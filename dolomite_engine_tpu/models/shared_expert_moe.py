@@ -1,6 +1,8 @@
 """The expert layer of the families routed by sigmoid scores with a correction bias
-(`nemotron_h`, `joyai_llm_flash`): a router that scores ALL experts, the chip's share of the
-routed experts (`ops/moe.experts_held_ragged`) and a shared expert every token passes."""
+(`nemotron_h`, `joyai_llm_flash`, `lfm2_moe`): a router that scores ALL experts, the chip's
+share of the routed experts (`ops/moe.experts_held_ragged`) and — where the family has one — a
+shared expert every token passes. Also what those families refuse, said once
+(`refuse_what_is_not_built`, `refuse_generation_cache`)."""
 
 from __future__ import annotations
 
@@ -20,6 +22,31 @@ from .moe_dolomite import ParameterizedExperts
 STEP_COUNTERS = ("routed_slots", "absent_slots", "fullest_expert_rows", "held_expert_rows")
 
 
+def refuse_what_is_not_built(family: str, scan_layers: bool, why_no_scan: str, replicated_under: dict) -> None:
+    """What the training-path-only expert families raise when a model is set up: `scan_layers`
+    (`why_no_scan`: how the family's layers differ and which scan is not built) and a mesh axis
+    of `replicated_under` (``{"tp": what would be replicated, "ep": ...}``) above 1 — rather
+    than replicate heads or experts silently."""
+    if scan_layers:
+        raise ValueError(f"scan_layers with {family}: {why_no_scan}; run it unrolled (scan_layers: false)")
+    from ..parallel.mesh import MeshManager
+
+    if MeshManager.is_initialized():
+        for axis, what in replicated_under.items():
+            if MeshManager.axis_size(axis) > 1:
+                raise ValueError(
+                    f"{family} on a mesh with {axis} > 1: {what} would be replicated, "
+                    f"not sharded; {axis} for this family is not built"
+                )
+
+
+def refuse_generation_cache(family: str, why: str | None = None) -> None:
+    """The same families have no generation cache (`why`: what is missing, and the ROADMAP
+    item that says so)."""
+    detail = f" ({why})" if why else ""
+    raise NotImplementedError(f"{family} has no generation cache{detail}; the training path only")
+
+
 def stack_step_counters(extras: list) -> dict | None:
     """``{name: int32[layers of experts, ...]}`` from the blocks' counters (None: no layer of
     experts ran)."""
@@ -29,15 +56,19 @@ def stack_step_counters(extras: list) -> dict | None:
 
 
 class SharedExpertMoE(nn.Module):
-    """Routed experts (the share held here) plus a shared expert. Returns the layer's
-    output and its counters (`STEP_COUNTERS`: int32 scalars, and the rows of each held expert).
+    """Routed experts (the share held here) plus a shared expert, where the family has one.
+    Returns the layer's output and its counters (`STEP_COUNTERS`: int32 scalars, and the rows
+    of each held expert).
 
-    Every width comes from the family's config (`NemotronHConfig`, `JoyAIFlashConfig`):
-    `num_experts`, `num_experts_per_tok`, `moe_intermediate_size`,
+    Every width comes from the family's config (`NemotronHConfig`, `JoyAIFlashConfig`,
+    `Lfm2MoeConfig`): `num_experts`, `num_experts_per_tok`, `moe_intermediate_size`,
     `moe_shared_expert_intermediate_size`, `routed_scaling_factor`, `norm_topk_prob`,
     `held_experts()` and `activation_function` — with a gated one (``swiglu``) the up
     banks and the shared expert's up projection are twice as wide, ``[up | gate]`` as
-    everywhere in the repo, and the activation folds them."""
+    everywhere in the repo, and the activation folds them. A shared width of 0 is no shared
+    expert: no parameters, no ``moe_shared_expert`` scope, and the routed experts' weighted
+    scatter-add is the layer's output. `norm_topk_prob_epsilon` stands in the renormalisation's
+    denominator."""
 
     config: CommonConfig
     dtype: Any = jnp.float32
@@ -76,6 +107,7 @@ class SharedExpertMoE(nn.Module):
                 correction_bias,
                 config.routed_scaling_factor,
                 config.norm_topk_prob,
+                config.norm_topk_prob_epsilon,
             )
 
         c_fc, _ = ParameterizedExperts(
@@ -110,6 +142,9 @@ class SharedExpertMoE(nn.Module):
             config.num_experts,
             first,
         )
+
+        if not config.moe_shared_expert_intermediate_size:
+            return routed.astype(self.dtype).reshape(batch, seq, hidden), counters
 
         with jax.named_scope("moe_shared_expert"):
             h = ParameterizedLinear(

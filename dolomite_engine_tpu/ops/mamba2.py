@@ -1,6 +1,7 @@
-"""Mamba-2 (state-space duality) mixer ops: the selective scan in chunks, its token-by-token
-form, and the causal depthwise convolution in front of it — all three reset at document
-boundaries of a packed row.
+"""Mamba-2 (state-space duality) mixer ops: the selective scan in chunks and its token-by-token
+form; the causal depthwise convolution in front of them lives in `ops/causal_conv.py` since a
+second family uses it (re-exported here) — all three reset at document boundaries of a packed
+row.
 
 Recurrence, per head ``h`` of width ``P`` with a state ``S`` of ``[P, N]`` (``N`` the state
 size; ``B`` and ``C`` are shared by the heads of a group, head ``h`` reads group
@@ -43,30 +44,7 @@ from contextlib import contextmanager
 import jax
 import jax.numpy as jnp
 
-
-def causal_conv1d(
-    x: jax.Array,
-    weight: jax.Array,
-    bias: jax.Array | None = None,
-    segment_ids: jax.Array | None = None,
-) -> jax.Array:
-    """Causal depthwise convolution over time: ``y_t = b + sum_k w[:, k] x_{t-(K-1-k)}``
-    (torch ``Conv1d(groups=C, padding=K-1)`` cut to ``T``: the last tap reads ``x_t``).
-
-    x ``[B, T, C]``, weight ``[C, K]``, bias ``[C]``. A tap whose token lies before the row
-    or in another document (``segment_ids`` differ) contributes nothing."""
-    length = x.shape[1]
-    taps = weight.shape[-1]
-    y = x * weight[:, taps - 1]
-    for back in range(1, taps):
-        shifted = jnp.pad(x, ((0, 0), (back, 0), (0, 0)))[:, :length]
-        if segment_ids is not None:
-            earlier = jnp.pad(segment_ids, ((0, 0), (back, 0)), constant_values=-1)[:, :length]
-            shifted = jnp.where((earlier == segment_ids)[..., None], shifted, 0)
-        y = y + shifted * weight[:, taps - 1 - back]
-    if bias is not None:
-        y = y + bias
-    return y
+from .causal_conv import causal_conv1d  # noqa: F401  (two families' convolution now: its own module)
 
 
 def mamba2_recurrent(

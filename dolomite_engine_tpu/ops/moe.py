@@ -46,12 +46,14 @@ def route_sigmoid_bias(
     correction_bias: jax.Array,
     scaling_factor: float = 1.0,
     normalize: bool = True,
+    epsilon: float = 1e-20,
 ) -> tuple[jax.Array, jax.Array]:
-    """The `nemotron_h` family's routing rule (DeepSeek-V3's): sigmoid scores, chosen with a
-    bias and weighed without it. ``s = sigmoid(logits)`` in float32 over all experts, the
+    """The routing rule of the families routed by sigmoid scores (DeepSeek-V3's): chosen with
+    a bias and weighed without it. ``s = sigmoid(logits)`` in float32 over all experts, the
     top-k of ``s + correction_bias`` are chosen, and the weights are the chosen experts'
-    ``s``, divided by their sum (+1e-20) where `normalize`, times `scaling_factor`. The bias
-    is a buffer: it chooses, and no gradient reaches it.
+    ``s``, divided by their sum + `epsilon` where `normalize` (the family's own: 1e-20 in
+    `nemotron_h` and `joyai_llm_flash`'s public code, 1e-6 in `lfm2_moe`'s), times
+    `scaling_factor`. The bias is a buffer: it chooses, and no gradient reaches it.
 
     Returns (router_weights [T, k] float32, selected_experts [T, k] int32)."""
     scores = jax.nn.sigmoid(router_logits.astype(jnp.float32))
@@ -59,7 +61,7 @@ def route_sigmoid_bias(
     _, selected = jax.lax.top_k(biased, top_k)
     weights = jnp.take_along_axis(scores, selected, axis=-1)
     if normalize:
-        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + epsilon)
     return weights * scaling_factor, selected.astype(jnp.int32)
 
 

@@ -87,6 +87,7 @@ def split_qkv_apply_rope(
     num_kv_heads: int,
     head_dim: int,
     rope_cos_sin: tuple[jax.Array, jax.Array] | None,
+    qk_norm: tuple[jax.Array, jax.Array, float] | None = None,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Split the fused QKV projection output and apply rotary embeddings to Q and K.
 
@@ -101,6 +102,12 @@ def split_qkv_apply_rope(
     rope_cos_sin: ([..., S, head_dim], [..., S, head_dim]) from `get_cos_sin`, or None
     for rope-free position embeddings (split only). Returns (query [B, S, Hq, D],
     key [B, S, Hkv, D], value [B, S, Hkv, D]) with rope already applied to Q/K.
+
+    `qk_norm` = (query weight [D], key weight [D], eps): an RMSNorm of every query and key
+    head over its D columns, BEFORE the rotation (a config with `qk_norm`; scope
+    ``qk_norm``). The seam is then split -> norm -> rotate, and the fused kernel — which
+    rotates the flat [Q | K | V] before any split — steps aside for the call; the
+    ``rope_qkv_plan`` event says so once a model (`why_xla`).
     """
     batch, seq = qkv.shape[:2]
 
@@ -108,12 +115,23 @@ def split_qkv_apply_rope(
         from .pallas import use_pallas
 
         if use_pallas("fused_rope_qkv"):
-            from .pallas.rope_qkv import fused_rope_qkv
+            if qk_norm is None:
+                from .pallas.rope_qkv import fused_rope_qkv
 
-            qkv = fused_rope_qkv(
-                qkv, rope_cos_sin[0], rope_cos_sin[1], num_heads, num_kv_heads, head_dim
-            )
-            rope_cos_sin = None  # rotated in-kernel; plain split below
+                qkv = fused_rope_qkv(
+                    qkv, rope_cos_sin[0], rope_cos_sin[1], num_heads, num_kv_heads, head_dim
+                )
+                rope_cos_sin = None  # rotated in-kernel; plain split below
+            else:
+                from ..utils.telemetry import get_telemetry
+
+                get_telemetry().event_once(
+                    "rope_qkv_plan",
+                    form="xla",
+                    why_xla="q and k are normed per head between the split and the rotation; "
+                    "the fused kernel rotates the flat [Q | K | V] before any split",
+                    heads=(num_heads, num_kv_heads, head_dim),
+                )
 
     query, key, value = jnp.split(
         qkv, [num_heads * head_dim, (num_heads + num_kv_heads) * head_dim], axis=-1
@@ -121,6 +139,14 @@ def split_qkv_apply_rope(
     query = query.reshape(batch, seq, num_heads, head_dim)
     key = key.reshape(batch, seq, num_kv_heads, head_dim)
     value = value.reshape(batch, seq, num_kv_heads, head_dim)
+
+    if qk_norm is not None:
+        from .normalization import rmsnorm
+
+        query_weight, key_weight, eps = qk_norm
+        with jax.named_scope("qk_norm"):
+            query = rmsnorm(query, query_weight, eps)
+            key = rmsnorm(key, key_weight, eps)
 
     if rope_cos_sin is not None:
         cos, sin = rope_cos_sin

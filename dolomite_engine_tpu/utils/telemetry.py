@@ -313,8 +313,13 @@ KNOWN_EVENTS: tuple[str, ...] = (
     # what a model cut to one chip's share holds of what was published (models/config.py
     # NemotronHConfig.layout_record: pattern, experts held of published, vocabulary rows
     # held, the deployment's numbers; JoyAIFlashConfig.layout_record: blocks by kind in the
-    # pattern's place), once a run
+    # pattern's place; Lfm2MoeConfig.layout_record: layer_types and the blocks by operator
+    # and by feed-forward), once a run
     "model_layout",
+    # the one rope+QKV seam where a call norms q and k per head (ops/rope.split_qkv_apply_rope,
+    # a config with qk_norm) and the fused rope+QKV kernel, promoted there, steps aside: form
+    # ("xla"), why_xla, heads (query heads, key/value heads, head width), once a traced model
+    "rope_qkv_plan",
     # which lowering of the Mamba-2 chunked scan each M layer of a traced model took
     # (models/nemotron_h.scan_plan, from ops/mamba2.scan_lowering): the layers on the Pallas
     # kernel and on the jnp form (and why: backend, mesh or shape), the chunk, the kernel's
@@ -326,6 +331,7 @@ KNOWN_EVENTS: tuple[str, ...] = (
     # the grouped products multiply), absent_slots, fullest_expert_rows, held_expert_rows;
     # for joyai_llm_flash the same (its multi-token-prediction module's layer last) and the
     # loss's two parts main_loss and mtp_loss with mtp_targets, the positions the second had;
+    # for lfm2_moe the four counters of its layers of experts;
     # for every family whose attention runs the splash kernel splash_blocks_visited and
     # splash_blocks_causal (ops/attention.splash_block_counters: the block pairs one
     # attention layer's tables ran over the step's rows, and those under the diagonal)

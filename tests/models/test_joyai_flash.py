@@ -349,3 +349,41 @@ def test_what_the_family_refuses(eight_devices):
                 model.init(jax.random.PRNGKey(0), ids)
         finally:
             MeshManager.destroy()
+
+
+def test_the_lowered_step_is_what_it_was_before_a_third_family_shared_its_expert_layer():
+    """`SharedExpertMoE`, `route_sigmoid_bias`, the rope+QKV seam and the families' raises serve a
+    third family since PR 33 (no shared expert, an epsilon of its own, QK norms); this family's
+    parameter tree and its lowered train step (bfloat16, `full` remat every block, `skip_nonfinite`,
+    counters beside the loss) at this file's size are, letter for letter, what the commit before
+    lowered: the hashes were taken there, on this installation (jax 0.9.0). A change of this
+    family's program on purpose takes them anew, and says so."""
+    import hashlib
+
+    from dolomite_engine_tpu.distributed import TrainState
+
+    wrapper = ModelWrapperForPretraining(
+        mode=Mode.training, pretrained_config=CFG, dtype="bf16", sequence_length=CFG["n_positions"],
+        reset_attention_mask=True, reset_position_ids=True, zero_stage=0, gradient_checkpointing_args={"checkpoint_every": 1},
+    )
+    schedule = get_scheduler(0, 0, None, 10, LRDecaySchedule.constant, 0.1, base_lr=OPTIMIZER["lr"])
+    optimizer = get_optimizer(
+        "TorchAdamW", {k: OPTIMIZER[k] for k in ("weight_decay", "betas", "eps")}, schedule, model_config=wrapper.config,
+    )
+
+    def init():
+        params = nn.unbox(wrapper.model.init(jax.random.PRNGKey(0), jnp.zeros((1, 16), jnp.int32), compute_loss=True)["params"])
+        return TrainState(step=jnp.zeros((), jnp.int32), params=params, opt_state=optimizer.init(params), fp8=None)
+
+    state = jax.eval_shape(init)
+    step = jax.jit(make_train_step(
+        lambda p, micro, rng: wrapper.loss(p, micro["text"], rngs=None, train=True), optimizer,
+        gradient_clipping=1.0, skip_nonfinite=True, has_aux=True,
+    ))
+    text = step.lower(
+        state, {"text": jax.ShapeDtypeStruct((1, 2, CFG["n_positions"] + 1), jnp.int32)}, jax.ShapeDtypeStruct((2,), jnp.uint32)
+    ).as_text()
+    tree = str(jax.tree.map(lambda a: (a.shape, str(a.dtype)), state.params))
+    assert hashlib.sha256(tree.encode()).hexdigest() == "4f3c47802e75a87cbdc4288b995f51a06c50485f568ee733f27eb4296b8d833b"
+    assert len(text.splitlines()) == 8214
+    assert hashlib.sha256(text.encode()).hexdigest() == "6254ade890d4808203a6d0bf29d6f6750a81a16ef99d5de57e56c566e048a726"

@@ -389,3 +389,30 @@ def test_joyai_flash_step_compiles_for_v5e_at_published_widths(v5e, capsys):
     assert "ragged-dot" not in text and (count("gmm"), count("tgmm")) == (60, 20), kernels
     assert 6.2 * gib < memory.argument_size_in_bytes < 6.5 * gib  # 680.4M parameters x 10 B of state
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.0 * gib
+
+
+def test_lfm2_moe_step_compiles_for_v5e_at_published_widths(v5e, capsys):
+    """The cell `train-lfm2-moe-packed8k`'s whole train step — a conv block with the dense MLP,
+    an attention block and three conv blocks before experts, of `lfm2_moe` at published widths, 4
+    packed rows of 8192 tokens, AdamW, built as `pretrain.main` builds it — for one described
+    v5e: splash at head 64 and GQA 4:1 under the document block tables, the QK norms ahead of an
+    XLA rotation (the fused rope+QKV kernel steps aside), the megablox products on gated banks of
+    1536 with no shared expert beside them, and the chunked loss on the tied table all lower, and
+    the program fits the chip (an estimate: the chip's reading is in PERF.md)."""
+    compiled = _compiled_cell_step(v5e, "train-lfm2-moe-packed8k")
+    memory, text = compiled.memory_analysis(), compiled.as_text()
+    gib = 2.0**30
+    with capsys.disabled():
+        print(
+            f"\nlfm2_moe step for a described v5e: state {memory.argument_size_in_bytes / gib:.2f} GiB, "
+            f"temporaries (estimate) {memory.temp_size_in_bytes / gib:.2f} GiB"
+        )
+    kernels = [line.split(" = ")[0] for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    count = lambda prefix: sum(name.strip().lstrip("%").startswith(prefix) for name in kernels)  # noqa: E731
+    # 1 attention block x (forward, its replay under `full` remat, dkv, dq) of splash
+    assert (count("splash_mha_fwd"), count("splash_mha_dkv"), count("splash_mha_dq")) == (2, 1, 1), kernels
+    # 4 layers of experts x (forward and its replay: 2 products each; backward: 2 for the rows, 2 for the banks)
+    assert "ragged-dot" not in text and (count("gmm"), count("tgmm")) == (48, 16), kernels
+    assert not any("rope_qkv" in name for name in kernels), kernels  # the norms sit before the rotation: XLA's form
+    assert 4.3 * gib < memory.argument_size_in_bytes < 4.5 * gib  # 469.3M parameters x 10 B of state
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.5 * gib

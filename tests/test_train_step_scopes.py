@@ -197,3 +197,42 @@ def test_joyai_flash_names_its_layers_and_both_passes_through_the_head():
     for name in dots:
         if "/blocks/" in name:
             assert re.search(r"/(latent_attention|dense_mlp|moe|mtp_combine)/", name), name
+
+
+def test_lfm2_moe_names_its_operators_and_its_experts_without_a_shared_one():
+    """`lfm2_moe`'s lowered train step carries the scopes docs/OBSERVABILITY.md lists — the short
+    convolution's three, attention with `qk_norm` inside it, the dense MLP's, the experts' four —
+    forward and backward, has no `moe_shared_expert` anywhere, and leaves no matmul of the blocks
+    outside an operator's or a feed-forward's scope."""
+    from dolomite_engine_tpu.models import get_model_class
+    from tests.models.test_lfm2_moe import CFG
+
+    model = get_model_class("lfm2_moe")(config=config_from_dict(CFG), checkpoint_every=1, dtype=jnp.bfloat16)
+    ids = jnp.zeros((1, 32), jnp.int32)
+    params = nn.unbox(model.init(jax.random.PRNGKey(0), ids)["params"])
+    optimizer = optax.adamw(1e-3)
+    state = TrainState(step=jnp.zeros((), jnp.int32), params=params, opt_state=optimizer.init(params))
+
+    def loss_fn(params, micro, rng):
+        out = model.apply({"params": params}, micro["text"], compute_loss=True)
+        return out.loss, out.counters
+
+    lowered = jax.jit(make_train_step(loss_fn, optimizer, skip_nonfinite=True, has_aux=True)).lower(
+        state, {"text": jnp.zeros((1, 1, 32), jnp.int32)}, jax.random.PRNGKey(0)
+    )
+    names = [name for _, name in _operation_names(lowered)]
+    dots = [name for op, name in _operation_names(lowered) if op == "dot_general"]
+    for scope in (
+        "short_conv_in_proj", "short_conv_gates_taps", "short_conv_out_proj", "qk_norm", "dense_mlp",
+        "moe_router", "moe_dispatch", "moe_experts", "moe_combine",
+    ):
+        assert any(f"/{scope}/" in name and "transpose(" not in name for name in names), scope
+        assert any(f"/{scope}/" in name and "transpose(" in name for name in names), scope
+    assert not any("moe_shared_expert" in name for name in names)
+    assert all("/attention/" in name for name in names if "/qk_norm/" in name)
+    assert all("/short_conv/" in name for name in names if "/short_conv_gates_taps/" in name)
+    # the gates and the taps hold no matmul: the memory-bound part
+    assert not any("/short_conv_gates_taps/" in name for name in dots)
+    for name in dots:
+        if "/blocks/" in name:
+            assert re.search(r"/(short_conv|attention|dense_mlp|moe)/", name), name
