@@ -57,6 +57,7 @@ from .shared_expert_moe import (
     SharedExpertMoE,
     refuse_generation_cache,
     refuse_what_is_not_built,
+    say_dispatch_plan,
     stack_step_counters,
 )
 
@@ -212,7 +213,7 @@ class JoyAIFlashModel(nn.Module):
         if segment_ids is None and attention_mask is not None:
             segment_ids = attention_mask.astype(jnp.int32)  # the pad tokens are a document of their own
         extras, kernel_residual_bytes, second = [], [], None
-        with watch_kernel_residuals() as seen:
+        with watch_kernel_residuals() as seen, say_dispatch_plan():
             with jax.named_scope("blocks"):
                 for block in self.h:
                     calls_before = len(seen)

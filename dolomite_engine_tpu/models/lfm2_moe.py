@@ -61,6 +61,7 @@ from .shared_expert_moe import (
     SharedExpertMoE,
     refuse_generation_cache,
     refuse_what_is_not_built,
+    say_dispatch_plan,
     stack_step_counters,
 )
 
@@ -215,7 +216,7 @@ class Lfm2MoeModel(nn.Module):
         if segment_ids is None and attention_mask is not None:
             segment_ids = attention_mask.astype(jnp.int32)  # the pad tokens are a document of their own
         extras, kernel_residual_bytes = [], []
-        with jax.named_scope("blocks"), watch_kernel_residuals() as seen:
+        with jax.named_scope("blocks"), watch_kernel_residuals() as seen, say_dispatch_plan():
             for block in self.h:
                 calls_before = len(seen)
                 hidden_states, counters = block(hidden_states, attention_mask, segment_ids, rope_cos_sin, deterministic)

@@ -57,6 +57,7 @@ from .shared_expert_moe import (
     SharedExpertMoE,
     refuse_generation_cache,
     refuse_what_is_not_built,
+    say_dispatch_plan,
     stack_step_counters,
 )
 
@@ -278,7 +279,12 @@ class NemotronHModel(nn.Module):
             segment_ids = attention_mask.astype(jnp.int32)
         extras = []
         kernel_residual_bytes = []
-        with jax.named_scope("blocks"), watch_kernel_residuals() as seen, watch_scan_lowerings() as scans:
+        with (
+            jax.named_scope("blocks"),
+            watch_kernel_residuals() as seen,
+            watch_scan_lowerings() as scans,
+            say_dispatch_plan(),
+        ):
             for block in self.h:
                 calls_before = len(seen)
                 hidden_states, counters = block(hidden_states, attention_mask, segment_ids, deterministic)

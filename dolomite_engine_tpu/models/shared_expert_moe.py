@@ -6,6 +6,8 @@ shared expert every token passes. Also what those families refuse, said once
 
 from __future__ import annotations
 
+from collections import Counter
+from contextlib import contextmanager
 from typing import Any
 
 import jax
@@ -13,7 +15,7 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from ..ops.activations import get_activation_function, is_glu
-from ..ops.moe import experts_held_ragged, route_sigmoid_bias
+from ..ops.moe import experts_held_ragged, route_sigmoid_bias, watch_dispatch_plans
 from .config import CommonConfig
 from .modeling_utils import ParameterizedLinear, _normal_init, depth_scaled_init_std
 from .moe_dolomite import ParameterizedExperts
@@ -53,6 +55,22 @@ def stack_step_counters(extras: list) -> dict | None:
     if not extras:
         return None
     return {name: jnp.stack([layer[name] for layer in extras]) for name in STEP_COUNTERS}
+
+
+@contextmanager
+def say_dispatch_plan():
+    """Round a model's blocks: write the ``moe_dispatch_plan`` event, once a traced model, for
+    the layers of experts traced inside — how many `layers`, and what
+    `ops/moe.experts_held_ragged` planned for them from their shapes (the buffers' `capacity`,
+    the `block_rows` a loop step of its row movements takes, `blocks_per_capacity`, the
+    `form`). With `routed_slots` of the ``step_counters`` event a layer ran
+    ``ceil(routed_slots / block_rows)`` of `blocks_per_capacity` blocks that step."""
+    from ..utils.telemetry import get_telemetry
+
+    with watch_dispatch_plans() as plans:
+        yield
+    for plan, layers in Counter(tuple(plan.items()) for plan in plans).items():
+        get_telemetry().event_once("moe_dispatch_plan", layers=layers, **dict(plan))
 
 
 class SharedExpertMoE(nn.Module):

@@ -20,7 +20,9 @@ reference for the ragged path.
 
 from __future__ import annotations
 
-from typing import Callable
+from contextlib import contextmanager
+from functools import partial
+from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -198,6 +200,147 @@ def _share_grouped_product(rows: jax.Array) -> Callable:
     return jax.lax.ragged_dot
 
 
+class _Walk(NamedTuple):
+    """What a walk over sorted slots knows before the step: the rows on the tokens' side, the
+    slots a token has, and the slots a loop step moves."""
+
+    tokens: int
+    top_k: int
+    block_rows: int
+
+
+# a loop step moves about this many bytes of rows (8 MiB: 2048 rows of 2048 bfloat16)
+_WALK_BLOCK_BYTES = 8 * 2**20
+
+
+def _walk_block_rows(capacity: int, hidden: int, itemsize: int) -> int:
+    """The largest divisor of `capacity` whose rows are at most `_WALK_BLOCK_BYTES`."""
+    most = max(1, min(capacity, _WALK_BLOCK_BYTES // (hidden * itemsize)))
+    return next(rows for rows in range(most, 0, -1) if capacity % rows == 0)
+
+
+def _take_rows(
+    walk: _Walk,
+    source: jax.Array,
+    slot: jax.Array,
+    count: jax.Array,
+    gates: jax.Array | None = None,
+    partner: jax.Array | None = None,
+) -> tuple[jax.Array, jax.Array | None]:
+    """``rows[i] = source[slot[i] // top_k]`` (times ``gates[slot[i]]``) for the slots ``i <
+    count``, block by block, stopping after the block that holds the last of them; the rows
+    past `count` are zero and no row of `source` is read for them. With `partner`
+    (``[len(slot), d]``, read up to `count` only) also the gates' gradient, float32 ``[tokens
+    * top_k]``: ``sum_i <partner[i], source[slot[i] // top_k]>`` added at ``slot[i]``."""
+    block = walk.block_rows
+    rows = jnp.zeros((slot.shape[0], source.shape[1]), source.dtype)
+    dgates = None if partner is None else jnp.zeros((walk.tokens * walk.top_k,), jnp.float32)
+
+    def step(index, carry):
+        rows, dgates = carry
+        at = index * block
+        slot_here = jax.lax.dynamic_slice_in_dim(slot, at, block)
+        live = at + jnp.arange(block) < count
+        got = jnp.take(source, slot_here // walk.top_k, axis=0)
+        if partner is not None:
+            beside = jax.lax.dynamic_slice_in_dim(partner, at, block)
+            dots = jnp.sum(beside.astype(jnp.float32) * got.astype(jnp.float32), axis=-1)
+            dgates = dgates.at[slot_here].add(jnp.where(live, dots, 0.0))
+        if gates is not None:
+            got = got * jnp.take(gates, slot_here).astype(got.dtype)[:, None]
+        rows = jax.lax.dynamic_update_slice_in_dim(rows, jnp.where(live[:, None], got, 0), at, 0)
+        return rows, dgates
+
+    return jax.lax.fori_loop(0, -(-count // block), step, (rows, dgates))
+
+
+def _add_rows(
+    walk: _Walk,
+    out: jax.Array,
+    rows: jax.Array,
+    slot: jax.Array,
+    count: jax.Array,
+    gates: jax.Array | None = None,
+) -> jax.Array:
+    """``out[slot[i] // top_k] += rows[i]`` (times ``gates[slot[i]]``) for the slots ``i <
+    count``, block by block as `_take_rows` walks them; no row of `rows` past `count` reaches
+    `out`, whatever it holds. The transpose of `_take_rows`."""
+    block = walk.block_rows
+
+    def step(index, out):
+        at = index * block
+        slot_here = jax.lax.dynamic_slice_in_dim(slot, at, block)
+        live = at + jnp.arange(block) < count
+        given = jax.lax.dynamic_slice_in_dim(rows, at, block)
+        if gates is not None:
+            given = given * jnp.take(gates, slot_here).astype(given.dtype)[:, None]
+        # (a select, after the product: what is not a number stays out)
+        return out.at[slot_here // walk.top_k].add(jnp.where(live[:, None], given, 0))
+
+    return jax.lax.fori_loop(0, -(-count // block), step, out)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _dispatch_rows(walk: _Walk, x: jax.Array, slot: jax.Array, count: jax.Array) -> jax.Array:
+    """The dispatch's gather: the token's row of `x` for each of the first `count` sorted
+    slots of `slot`, zeros after. A loop whose trip count is a value of the step has no
+    reverse rule of its own, so the rule is written here: the transpose is `_add_rows`."""
+    return _take_rows(walk, x, slot, count)[0]
+
+
+def _dispatch_rows_fwd(walk, x, slot, count):
+    return _take_rows(walk, x, slot, count)[0], (slot, count)
+
+
+def _dispatch_rows_bwd(walk, kept, d_rows):
+    slot, count = kept
+    d_x = _add_rows(walk, jnp.zeros((walk.tokens, d_rows.shape[1]), d_rows.dtype), d_rows, slot, count)
+    return d_x, None, None
+
+
+_dispatch_rows.defvjp(_dispatch_rows_fwd, _dispatch_rows_bwd)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _combine_rows(
+    walk: _Walk, out: jax.Array, y: jax.Array, gates: jax.Array, slot: jax.Array, count: jax.Array
+) -> jax.Array:
+    """The combine's weighted scatter-add: `out` plus ``gates[slot[i]] * y[i]`` at the token
+    of each of the first `count` sorted slots. Its rule gathers the cotangent's rows back
+    (times the gates) and reads the gates' gradient off the same rows (`_take_rows`)."""
+    return _add_rows(walk, out, y, slot, count, gates)
+
+
+def _combine_rows_fwd(walk, out, y, gates, slot, count):
+    return _add_rows(walk, out, y, slot, count, gates), (y, gates, slot, count)
+
+
+def _combine_rows_bwd(walk, kept, d_out):
+    y, gates, slot, count = kept
+    d_y, d_gates = _take_rows(walk, d_out, slot, count, gates, partner=y)
+    return d_out, d_y, d_gates.astype(gates.dtype), None, None
+
+
+_combine_rows.defvjp(_combine_rows_fwd, _combine_rows_bwd)
+
+
+# lists that `watch_dispatch_plans` opened, innermost last
+_PLAN_WATCHERS: list[list[dict]] = []
+
+
+@contextmanager
+def watch_dispatch_plans():
+    """What `experts_held_ragged` planned in the trace inside: one entry a call (`capacity`,
+    `block_rows`, `blocks_per_capacity`, `form`). The model reads it for its
+    ``moe_dispatch_plan`` event."""
+    seen: list[dict] = []
+    _PLAN_WATCHERS.append(seen)
+    try:
+        yield seen
+    finally:
+        _PLAN_WATCHERS.pop()
+
+
 def experts_held_ragged(
     x: jax.Array,
     router_weights: jax.Array,
@@ -216,13 +359,18 @@ def experts_held_ragged(
     two grouped products (`_share_grouped_product`); slots of absent experts are dropped before
     them — no dummy bank, nothing stands in for the other chips — and what those experts
     would have added is left out of the result. Shapes are static, the routed rows are not:
-    `capacity` rows are gathered (default: four times the even share ``T k E_held /
-    num_experts``, rounded up to 512) and the grouped products run over the routed ones
-    among them. A step that routes more here than `capacity` walks the sorted slots in
-    chunks of `capacity` rows instead (a scan whose chunks past the last routed row are
-    skipped, each chunk re-computed in the backward pass): slower, one chunk's rows in
-    memory, and no row dropped however uneven the routing. `lax.cond` chooses; the usual
-    step pays for the rows it routes.
+    `capacity` (default: four times the even share ``T k E_held / num_experts``, rounded up
+    to 512) is the size of the buffers, and the work follows `routed`, the slots the step
+    sent here: the dispatch's gather and the combine's weighted scatter-add (`_dispatch_rows`,
+    `_combine_rows`), and their transposes in the backward pass, walk the sorted slots in
+    blocks of rows (`_walk_block_rows`: from the shapes) and stop after the block that holds
+    the last routed one, as the grouped products walk only their groups' tiles. What lies
+    past `routed` in a buffer is never read: the products leave those rows undefined, and
+    no select stands guard — the loops' bounds do. A step that routes more here than
+    `capacity` walks the sorted slots in chunks of `capacity` rows instead (a scan whose
+    chunks past the last routed row are skipped, each chunk re-computed in the backward
+    pass, the last one costing its rows too): slower, one chunk's rows in memory, and no row
+    dropped however uneven the routing. `lax.cond` chooses.
 
     x ``[T, d]``; router_weights / selected_experts ``[T, k]`` (ids over all experts);
     w_fc ``[E_held, d, f]``, w_proj ``[E_held, f, d]`` (no biases, no GLU: `act` is applied
@@ -237,6 +385,16 @@ def experts_held_ragged(
         capacity = -(-4 * slots * held // num_experts // 512) * 512
     capacity = min(max(capacity, 1), slots)
     chunks = -(-slots // capacity)
+    walk = _Walk(tokens, top_k, _walk_block_rows(capacity, hidden, x.dtype.itemsize))
+    for seen in _PLAN_WATCHERS:
+        seen.append(
+            {
+                "capacity": capacity,
+                "block_rows": walk.block_rows,
+                "blocks_per_capacity": capacity // walk.block_rows,
+                "form": "xla_loop",
+            }
+        )
     grouped_product = _share_grouped_product(x)  # asked here, where the model is traced
 
     with jax.named_scope("moe_dispatch"):
@@ -253,11 +411,8 @@ def experts_held_ragged(
         """Add what the sorted slots ``start .. start + capacity`` give to `out`."""
         with jax.named_scope("moe_dispatch"):
             slot = jax.lax.dynamic_slice_in_dim(order, start, capacity)
-            token_index = slot // top_k
-            valid = start + jnp.arange(capacity) < routed
-            # (the select also stops what a grouped product's backward leaves in the rows of
-            # no group from reaching x's gradient through the gather's transpose)
-            xs = jnp.where(valid[:, None], jnp.take(x, token_index, axis=0), 0)
+            count = jnp.clip(routed - start, 0, capacity)
+            xs = _dispatch_rows(walk, x, slot, count)
             # the part of every expert's group that lies in these rows
             sizes = jnp.clip(
                 jnp.minimum(group_ends, start + capacity) - jnp.maximum(group_starts, start), 0
@@ -266,11 +421,7 @@ def experts_held_ragged(
             h = act(grouped_product(xs, w_fc, sizes))
             y = grouped_product(h, w_proj, sizes)
         with jax.named_scope("moe_combine"):
-            # rows past the routed ones belong to no group: whatever the product left
-            # there is not a number of this layer
-            scale = jnp.where(valid, jnp.take(gates, slot), 0.0).astype(y.dtype)
-            y = jnp.where(valid[:, None], y, 0) * scale[:, None]
-            return out.at[token_index].add(y)
+            return _combine_rows(walk, out, y, gates, slot, count)
 
     def at_once(x, w_fc, w_proj, gates):
         return add_rows(jnp.zeros((tokens, hidden), x.dtype), 0, x, w_fc, w_proj, gates)

@@ -325,6 +325,13 @@ KNOWN_EVENTS: tuple[str, ...] = (
     # kernel and on the jnp form (and why: backend, mesh or shape), the chunk, the kernel's
     # launches a layer and pass, and the bytes a layer its backward rule keeps
     "mamba2_scan_plan",
+    # what the row movements of a traced model's layers of experts planned from their shapes
+    # (ops/moe.experts_held_ragged, written by models/shared_expert_moe.say_dispatch_plan):
+    # layers, the buffers' capacity, block_rows (the sorted slots a loop step of the gather,
+    # the weighted scatter-add and their transposes takes), blocks_per_capacity, form; a layer
+    # and step runs ceil(routed_slots / block_rows) of those blocks (routed_slots: the
+    # step_counters event)
+    "moe_dispatch_plan",
     # what the step's forward pass counted, returned by the train step beside the loss
     # (train_utils.make_train_step has_aux) and read where the loss is read: for nemotron_h
     # one entry a layer of experts — routed_slots (token-slots of held experts: the rows

@@ -357,7 +357,10 @@ def test_the_lowered_step_is_what_it_was_before_a_third_family_shared_its_expert
     parameter tree and its lowered train step (bfloat16, `full` remat every block, `skip_nonfinite`,
     counters beside the loss) at this file's size are, letter for letter, what the commit before
     lowered: the hashes were taken there, on this installation (jax 0.9.0). A change of this
-    family's program on purpose takes them anew, and says so."""
+    family's program on purpose takes them anew, and says so: PR 34 did — the expert layer's
+    gather, weighted scatter-add and their transposes became loops over blocks of the routed rows
+    with rules of their own (`ops/moe._dispatch_rows`, `_combine_rows`), so the step's text was
+    taken anew there (8214 lines before); the parameter tree's hash is the one PR 33 took."""
     import hashlib
 
     from dolomite_engine_tpu.distributed import TrainState
@@ -385,5 +388,5 @@ def test_the_lowered_step_is_what_it_was_before_a_third_family_shared_its_expert
     ).as_text()
     tree = str(jax.tree.map(lambda a: (a.shape, str(a.dtype)), state.params))
     assert hashlib.sha256(tree.encode()).hexdigest() == "4f3c47802e75a87cbdc4288b995f51a06c50485f568ee733f27eb4296b8d833b"
-    assert len(text.splitlines()) == 8214
-    assert hashlib.sha256(text.encode()).hexdigest() == "6254ade890d4808203a6d0bf29d6f6750a81a16ef99d5de57e56c566e048a726"
+    assert len(text.splitlines()) == 8862
+    assert hashlib.sha256(text.encode()).hexdigest() == "502abc7e7c2b260705a8fc6bce275d9f57ef816c24e7726355595226bb3ee00c"

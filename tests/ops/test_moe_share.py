@@ -143,3 +143,156 @@ def test_a_share_that_gets_every_slot_drops_none(layer):
     total, counters = shares(same, 8, None)
     np.testing.assert_allclose(total, uncut(same), rtol=1e-4, atol=1e-5)
     assert max(int(c["fullest_expert_rows"]) for c in counters) == T
+
+
+# ------------------------------------------- the row movements follow `routed`, not `capacity`
+
+HELD, FIRST, TOP_K, CAPACITY, BLOCK_ROWS = 4, 8, 2, 16, 4  # 16 tokens x 2 slots: two chunks of 16
+
+
+def swiglu(h):
+    from dolomite_engine_tpu.ops.activations import get_activation_function
+
+    return get_activation_function("swiglu")(h)
+
+
+def routed_layer(routed: int, act):
+    """A layer of 16 tokens whose router sends exactly `routed` of the 32 token-slots, spread
+    over the tokens, to the four held experts."""
+    rng = np.random.default_rng(routed)
+    tokens, slots = 16, 16 * TOP_K
+    chosen = rng.integers(0, FIRST, size=slots)  # absent experts, below the held ones ...
+    chosen[rng.integers(0, 2, size=slots) == 1] += FIRST + HELD  # ... and above them
+    here = rng.permutation(slots)[:routed]
+    chosen[here] = FIRST + rng.integers(0, HELD, size=routed)
+    k = jax.random.split(jax.random.PRNGKey(routed), 4)
+    up = 2 * F if act is swiglu else F
+    return dict(
+        x=jax.random.normal(k[0], (tokens, D)),
+        weights=jax.random.uniform(k[1], (tokens, TOP_K), minval=0.1),
+        w_fc=jax.random.normal(k[2], (HELD, D, up)) * 0.3,
+        w_proj=jax.random.normal(k[3], (HELD, F, D)) * 0.3,
+    ), jnp.asarray(chosen.reshape(tokens, TOP_K), jnp.int32)
+
+
+def masked_take_and_scatter_add(x, weights, w_fc, w_proj, chosen, act):
+    """The layer in plain `jnp`, every slot at once: sort, a masked take, each row through its
+    expert's two matrices, a masked and weighted scatter-add."""
+    local = chosen.reshape(-1) - FIRST
+    key = jnp.where((local >= 0) & (local < HELD), local, HELD)
+    order = jnp.argsort(key, stable=True)
+    valid = jnp.arange(key.shape[0]) < jnp.sum(key < HELD)
+    token, expert = order // TOP_K, jnp.minimum(jnp.take(key, order), HELD - 1)
+    xs = jnp.where(valid[:, None], jnp.take(x, token, axis=0), 0)
+    h = act(jnp.einsum("sd,sdf->sf", xs, jnp.take(w_fc, expert, axis=0)))
+    y = jnp.einsum("sf,sfd->sd", h, jnp.take(w_proj, expert, axis=0))
+    scale = jnp.where(valid, jnp.take(weights.reshape(-1), order), 0.0)
+    return jnp.zeros_like(x).at[token].add(jnp.where(valid[:, None], y, 0) * scale[:, None])
+
+
+def value_and_grads(layer_fn, operands, chosen, act):
+    def loss(x, weights, w_fc, w_proj):
+        return jnp.sum(jnp.sin(layer_fn(x, weights, w_fc, w_proj, chosen, act)))
+
+    return jax.value_and_grad(loss, argnums=(0, 1, 2, 3))(*operands.values())
+
+
+def held_share(x, weights, w_fc, w_proj, chosen, act):
+    return experts_held_ragged(x, weights, chosen, w_fc, w_proj, act, 16, FIRST, capacity=CAPACITY)[0]
+
+
+@pytest.fixture
+def small_blocks(monkeypatch):
+    """Blocks of `BLOCK_ROWS` rows, as the shapes of a toy would never give."""
+    monkeypatch.setattr(moe, "_WALK_BLOCK_BYTES", BLOCK_ROWS * D * 4)
+    assert moe._walk_block_rows(CAPACITY, D, 4) == BLOCK_ROWS
+
+
+@pytest.mark.parametrize("act", [relu2, swiglu], ids=["relu2", "gated"])
+@pytest.mark.parametrize(
+    "routed", [0, 1, BLOCK_ROWS - 1, BLOCK_ROWS, BLOCK_ROWS + 1, CAPACITY, CAPACITY + BLOCK_ROWS + 1]
+)  # the last: two chunks, the second part-filled
+def test_row_movements_that_stop_at_the_last_routed_row_give_the_masked_take_and_scatter_add(
+    small_blocks, routed, act
+):
+    operands, chosen = routed_layer(routed, act)
+    counters = experts_held_ragged(
+        operands["x"], operands["weights"], chosen, operands["w_fc"], operands["w_proj"], act, 16, FIRST, capacity=CAPACITY
+    )[1]
+    assert int(counters["routed_slots"]) == routed
+    mine = value_and_grads(held_share, operands, chosen, act)
+    reference = value_and_grads(masked_take_and_scatter_add, operands, chosen, act)
+    np.testing.assert_allclose(mine[0], reference[0], rtol=1e-5)
+    for name, got, want in zip(operands, mine[1], reference[1]):
+        np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-6, err_msg=name)
+    # and the same under the transformations the models put round the layer
+    under = jax.jit(jax.checkpoint(lambda *a: held_share(*a, chosen, act)))
+    np.testing.assert_allclose(
+        jax.grad(lambda *a: jnp.sum(jnp.sin(under(*a))))(*operands.values()), mine[1][0], rtol=1e-5, atol=1e-7
+    )
+
+
+@jax.custom_vjp
+def poisoned_product(rows, bank, group_sizes):
+    """`lax.ragged_dot` as a kernel that walks its groups' tiles leaves it: the rows of no
+    group are not a number, in the product and in the rows' gradient, and the bank's gradient
+    reads the groups' rows alone."""
+    inside = (jnp.arange(rows.shape[0]) < jnp.sum(group_sizes))[:, None]
+    return jnp.where(inside, jax.lax.ragged_dot(rows, bank, group_sizes), jnp.nan)
+
+
+def _poisoned_fwd(rows, bank, group_sizes):
+    return poisoned_product(rows, bank, group_sizes), (rows, bank, group_sizes)
+
+
+def _poisoned_bwd(kept, d_out):
+    rows, bank, group_sizes = kept
+    inside = (jnp.arange(rows.shape[0]) < jnp.sum(group_sizes))[:, None]
+    _, transpose = jax.vjp(lambda r, b: jax.lax.ragged_dot(r, b, group_sizes), jnp.where(inside, rows, 0), bank)
+    d_rows, d_bank = transpose(jnp.where(inside, d_out, 0))
+    return jnp.where(inside, d_rows, jnp.nan), d_bank, None
+
+
+poisoned_product.defvjp(_poisoned_fwd, _poisoned_bwd)
+
+
+@pytest.mark.parametrize("routed", [BLOCK_ROWS + 1, CAPACITY + BLOCK_ROWS + 1], ids=["at_once", "in_chunks"])
+def test_what_the_products_leave_past_the_routed_rows_reaches_nothing(small_blocks, monkeypatch, routed):
+    operands, chosen = routed_layer(routed, swiglu)
+    reference = value_and_grads(masked_take_and_scatter_add, operands, chosen, swiglu)
+    monkeypatch.setattr(moe, "_share_grouped_product", lambda rows: poisoned_product)
+    # the poison is there: the buffer of a product holds it
+    product = poisoned_product(jnp.ones((CAPACITY, D)), operands["w_fc"], jnp.asarray([1, 0, 2, 0], jnp.int32))
+    assert np.isnan(np.asarray(product)[3:]).all() and np.isfinite(np.asarray(product)[:3]).all()
+    mine = value_and_grads(held_share, operands, chosen, swiglu)
+    np.testing.assert_allclose(mine[0], reference[0], rtol=1e-5)
+    for name, got, want in zip(operands, mine[1], reference[1]):
+        assert np.isfinite(np.asarray(got)).all(), name
+        np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-6, err_msg=name)
+
+
+def test_the_models_say_their_dispatch_plan_once(tmp_path):
+    """`say_dispatch_plan` round two layers of one shape: one ``moe_dispatch_plan`` event, however
+    often the model is traced, with what `experts_held_ragged` planned from the shapes."""
+    import json
+
+    from dolomite_engine_tpu.models.shared_expert_moe import say_dispatch_plan
+    from dolomite_engine_tpu.utils.telemetry import Telemetry, install_telemetry, uninstall_telemetry
+
+    operands, chosen = routed_layer(5, relu2)
+    telemetry = Telemetry(sink_path=str(tmp_path / "sink.jsonl"), rank=0)
+    install_telemetry(telemetry)
+    try:
+        for _ in range(2):  # a model is traced more than once
+            with say_dispatch_plan():
+                for _ in range(2):
+                    jax.eval_shape(lambda: held_share(*operands.values(), chosen, relu2))
+    finally:
+        uninstall_telemetry()
+        telemetry.close()
+    events = [json.loads(line) for line in open(tmp_path / "sink.jsonl")]
+    plans = [e for e in events if e["kind"] == "event" and e["event"] == "moe_dispatch_plan"]
+    assert len(plans) == 1, plans
+    assert {k: plans[0][k] for k in ("layers", "capacity", "block_rows", "blocks_per_capacity", "form")} == {
+        "layers": 2, "capacity": CAPACITY, "block_rows": CAPACITY, "blocks_per_capacity": 1, "form": "xla_loop",
+    }

@@ -340,6 +340,41 @@ def _compiled_cell_step(v5e, cell_name: str):
     return compiled
 
 
+# the compiler's estimate of a step's temporaries at the commit before PR 34 (2d44579, this
+# installation: the expert layers gathered and scattered `capacity` rows at once), GiB
+TEMPORARIES_BEFORE_THE_WALKS = {
+    "train-nemotron-tower-packed8k": 4.576,
+    "train-joyai-flash-mtp-packed8k": 6.964,
+    "train-lfm2-moe-packed8k": 11.071,
+}
+
+
+def _say_and_hold_the_estimate(capsys, cell: str, memory) -> None:
+    """Print the step's state and temporaries beside the temporaries before PR 34, and hold the
+    step to them: walking the routed rows in blocks must not cost a buffer (an estimate on
+    both sides; the chip's reading is `hbm_peak_gib.train`, PERF.md)."""
+    gib = 2.0**30
+    with capsys.disabled():
+        print(
+            f"\n{cell} step for a described v5e: state {memory.argument_size_in_bytes / gib:.2f} GiB, temporaries "
+            f"(estimate) {memory.temp_size_in_bytes / gib:.3f} GiB, before PR 34 {TEMPORARIES_BEFORE_THE_WALKS[cell]:.3f} GiB"
+        )
+    assert memory.temp_size_in_bytes / gib <= TEMPORARIES_BEFORE_THE_WALKS[cell] + 0.01
+
+
+def _whole_buffer_row_movements(text: str, capacity: int, hidden: int) -> list:
+    """The gathers that give, and the scatters that take, `capacity` rows of `hidden` in a
+    compiled step's HLO (a scatter prints its operands by name: the updates' shape is looked up)."""
+    line = r"^\s*(?:ROOT )?(%[\w.\-]+) = (\w+\[[\d,]*\])"
+    shape_of = dict(re.findall(line, text, flags=re.M))
+    found = []
+    for name, shape, op, operands in re.findall(line + r"\S* (gather|scatter)\(([^)]*)\)", text, flags=re.M):
+        rows = shape if op == "gather" else shape_of[operands.split(", ")[-1].strip()]
+        if rows.endswith(f"[{capacity},{hidden}]"):
+            found.append((name, op, rows))
+    return found
+
+
 def test_nemotron_h_tower_step_compiles_for_v5e_at_published_widths(v5e, capsys):
     """The benchmark cell's whole train step — the 9 layers MEMEM*EME of the `nemotron_h`
     tower at published widths, 2 packed rows of 8192 tokens, AdamW, as
@@ -350,11 +385,7 @@ def test_nemotron_h_tower_step_compiles_for_v5e_at_published_widths(v5e, capsys)
     compiled = _compiled_cell_step(v5e, "train-nemotron-tower-packed8k")
     memory, text = compiled.memory_analysis(), compiled.as_text()
     gib = 2.0**30
-    with capsys.disabled():
-        print(
-            f"\nnemotron_h tower step for a described v5e: state {memory.argument_size_in_bytes / gib:.2f} GiB, "
-            f"temporaries (estimate) {memory.temp_size_in_bytes / gib:.2f} GiB"
-        )
+    _say_and_hold_the_estimate(capsys, "train-nemotron-tower-packed8k", memory)
     # on one TPU the experts' grouped products are megablox kernels (`ops/moe._share_grouped_product`): 4 layers
     # x (forward, replay, three in the backward) x 2 products, beside splash and the norms
     assert "ragged-dot" not in text and text.count('custom_call_target="tpu_custom_call"') >= 40
@@ -376,11 +407,7 @@ def test_joyai_flash_step_compiles_for_v5e_at_published_widths(v5e, capsys):
     compiled = _compiled_cell_step(v5e, "train-joyai-flash-mtp-packed8k")
     memory, text = compiled.memory_analysis(), compiled.as_text()
     gib = 2.0**30
-    with capsys.disabled():
-        print(
-            f"\njoyai_llm_flash step for a described v5e: state {memory.argument_size_in_bytes / gib:.2f} GiB, "
-            f"temporaries (estimate) {memory.temp_size_in_bytes / gib:.2f} GiB"
-        )
+    _say_and_hold_the_estimate(capsys, "train-joyai-flash-mtp-packed8k", memory)
     kernels = [line.split(" = ")[0] for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
     count = lambda prefix: sum(name.strip().lstrip("%").startswith(prefix) for name in kernels)  # noqa: E731
     # 6 blocks x (forward, its replay under `full` remat, dkv, dq) of splash: no attention is left to XLA's products
@@ -402,11 +429,13 @@ def test_lfm2_moe_step_compiles_for_v5e_at_published_widths(v5e, capsys):
     compiled = _compiled_cell_step(v5e, "train-lfm2-moe-packed8k")
     memory, text = compiled.memory_analysis(), compiled.as_text()
     gib = 2.0**30
-    with capsys.disabled():
-        print(
-            f"\nlfm2_moe step for a described v5e: state {memory.argument_size_in_bytes / gib:.2f} GiB, "
-            f"temporaries (estimate) {memory.temp_size_in_bytes / gib:.2f} GiB"
-        )
+    _say_and_hold_the_estimate(capsys, "train-lfm2-moe-packed8k", memory)
+    # PR 34: the experts' gather, weighted scatter-add and their transposes walk blocks of rows and stop at the
+    # last routed one: nothing moves the `capacity` of 65,536 rows (four times the even share of 4 x 8192 tokens
+    # x 4 slots x 8 of 64 experts) at once any more, where the step before held 40 such gathers and 16 scatters
+    assert not _whole_buffer_row_movements(text, 65536, 2048)
+    # 4 layers x (forward, its replay, backward) x (a gather, a scatter-add) of the 2048 rows a loop step takes
+    assert len(_whole_buffer_row_movements(text, 2048, 2048)) >= 24
     kernels = [line.split(" = ")[0] for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
     count = lambda prefix: sum(name.strip().lstrip("%").startswith(prefix) for name in kernels)  # noqa: E731
     # 1 attention block x (forward, its replay under `full` remat, dkv, dq) of splash
