@@ -129,7 +129,8 @@ def run_loop(
         # Every boundary of an iteration is one `telemetry.span`: the loop thread's spans
         # tile the iteration (docs/OBSERVABILITY.md "Spans of a training iteration"), so
         # the step record's split sums to its wall time and a profile attributes every
-        # idle gap of the device to a part of the loop.
+        # idle gap of the device to a part of the loop; the spans nested in `loop.sync` and
+        # `loop.log` (the record's `t.inner`) say which part of those.
         telemetry.begin_iterations()
         while global_step < num_training_steps:
             global_step += 1
@@ -154,27 +155,33 @@ def run_loop(
             logging_step = global_step % log_interval == 0
             sync_step = logging_step or monitor.wants_step_metrics
             with telemetry.span("loop.sync"):
+                # Cut where the device's state changes: while `sync.step` waits the step's
+                # program is still running (or has not begun); in `sync.read` it has ended
+                # and the device waits for the host. A step that does not sync opens neither.
                 step_skipped = False
-                if ft_args.skip_nonfinite_steps:
-                    # host sync per step — the price of counting consecutive skips promptly
-                    step_skipped = bool(metrics["skipped"])
+                if sync_step or ft_args.skip_nonfinite_steps:
+                    with telemetry.span("sync.step"):
+                        jax.block_until_ready(metrics)
+                    with telemetry.span("sync.read"):
+                        if ft_args.skip_nonfinite_steps:
+                            # host sync per step — the price of counting consecutive skips promptly
+                            step_skipped = bool(metrics["skipped"])
+                        if sync_step:
+                            # syncing here puts the outstanding device work in the step bucket
+                            # below, so window goodput stays honest without a per-step host sync
+                            loss = float(metrics["loss"])
+                            grad_norm = float(metrics["grad_norm"])
+                            if "counters" in metrics:
+                                # what the step's forward pass counted (a layer of experts each
+                                # entry), read where the loss is read: no program of its own
+                                telemetry.event(
+                                    "step_counters",
+                                    step=global_step,
+                                    **{k: v.tolist() for k, v in jax.device_get(metrics["counters"]).items()},
+                                )
 
                 if not step_skipped:  # a skipped step's loss is non-finite; keep the mean clean
                     unread_losses.append(metrics["loss"])
-
-                if sync_step:
-                    # syncing here puts the outstanding device work in the step bucket
-                    # below, so window goodput stays honest without a per-step host sync
-                    loss = float(metrics["loss"])
-                    grad_norm = float(metrics["grad_norm"])
-                    if "counters" in metrics:
-                        # what the step's forward pass counted (a layer of experts each
-                        # entry), read where the loss is read: no program of its own
-                        telemetry.event(
-                            "step_counters",
-                            step=global_step,
-                            **{k: v.tolist() for k, v in jax.device_get(metrics["counters"]).items()},
-                        )
             step_seconds = time.perf_counter() - step_start
 
             with telemetry.span("loop.account"):
@@ -201,19 +208,23 @@ def run_loop(
 
             with telemetry.span("loop.log"):
                 if logging_step:
-                    loss_running_sum += float(np.sum(jax.device_get(unread_losses)))
-                    loss_running_count += len(unread_losses)
-                    unread_losses.clear()
-                    postfix = log(
-                        step=global_step,
-                        loss=loss,
-                        grad_norm=grad_norm,
-                        loss_running_mean=loss_running_sum / max(loss_running_count, 1),
-                        step_time=data_seconds + step_seconds,
-                    )
-                    progress.set_postfix(**postfix)
-
-                progress.track(global_step)
+                    with telemetry.span("log.read"):  # one read of the steps' loss scalars
+                        loss_running_sum += float(np.sum(jax.device_get(unread_losses)))
+                        loss_running_count += len(unread_losses)
+                        unread_losses.clear()
+                    with telemetry.span("log.track"):  # the entry point's line: eager programs
+                        postfix = log(
+                            step=global_step,
+                            loss=loss,
+                            grad_norm=grad_norm,
+                            loss_running_mean=loss_running_sum / max(loss_running_count, 1),
+                            step_time=data_seconds + step_seconds,
+                        )
+                    with telemetry.span("log.progress"):
+                        progress.set_postfix(**postfix)
+                        progress.track(global_step)
+                else:
+                    progress.track(global_step)
 
             if evaluate is not None and eval_interval and global_step % eval_interval == 0:
                 with telemetry.span("loop.eval", bucket="eval"):

@@ -351,11 +351,14 @@ class HealthMonitor:
         else:
             ratio, flagged = self.straggler.update(step_seconds)
             if flagged:
+                # what the host was doing: the iteration's parts up to here and the span
+                # that holds the excess (nothing outside a train loop's telemetry)
                 anomalies.append(
                     {
                         "signal": "step_time",
                         "value": round(step_seconds, 6),
                         "ratio": round(ratio, 3),
+                        **self.telemetry.iteration_so_far(),
                     }
                 )
 

@@ -16,7 +16,9 @@ Sink schema (one JSON object per line; see docs/OBSERVABILITY.md):
     {"kind": "run_start", "ts", "rank", "devices", "device_kind",
      "peak_tflops_per_device", "model_tflops_per_step", "schema": 1}
     {"kind": "step",   "ts", "rank", "step", "t": {"data", "step" | "compile",
-     "wall", "split": {span name: seconds}}}   # wall/split: inside a train loop only
+     "wall", "split": {span name: seconds}, "inner": {nested span: seconds},
+     "off_loop": {other threads' span: seconds}, "gc": {"seconds", "count"},
+     "host": {"nivcsw", "majflt", "cpu"}}}      # wall .. host: inside a train loop only
     {"kind": "window", "ts", "rank", "step", "window_seconds",
      "goodput": {"compile","data","step","checkpoint","eval","other","goodput_pct"},
      "step_time": {"count","mean","min","max"}, "mfu_pct", "tflops_per_group",
@@ -45,9 +47,19 @@ Spans: :meth:`Telemetry.span` is the one primitive that cuts a boundary of the p
 once, on both clocks. It enters a ``jax.profiler.TraceAnnotation`` (``StepTraceAnnotation``
 for the step's dispatch), reads ``time.perf_counter()`` at both ends, adds the duration to
 the current iteration's split (the ``step`` record's ``t.split``; the loop thread's
-outermost spans tile the iteration, so the parts sum to ``t.wall``) and, where a ``bucket``
-is named, to that goodput bucket. A captured trace and the sink therefore cut at the same
-places. Span names are lower case with no ``(``, ``:``, ``$`` or space.
+outermost spans tile the iteration, so the parts sum to ``t.wall``; a span nested in one of
+them goes to ``t.inner`` under its own name, one that closed on another thread to
+``t.off_loop``) and, where a ``bucket`` is named, to that goodput bucket. A captured trace
+and the sink therefore cut at the same places. Span names are lower case with no ``(``,
+``:``, ``$`` or space.
+
+What else the host did in an iteration rides the same record, always on: ``t.gc`` (Python's
+garbage collections in the process: a ``gc.callbacks`` hook from
+:meth:`Telemetry.begin_iterations` to :meth:`Telemetry.close`, each collection also a span
+``gc.collect`` in a profile) and ``t.host`` (involuntary context switches, major page
+faults and CPU seconds of the process). A slow iteration therefore says what held it, in an
+untraced run too (the ``anomaly`` event of signal ``step_time`` carries the same fields and
+the span that holds the excess; `tools/telemetry_summary.py` prints the slowest iterations).
 
 Compilations: one ``jax.monitoring`` listener a process counts every XLA backend compilation
 (cache loads too) from the start of a train loop (:meth:`Telemetry.begin_iterations`) into
@@ -63,14 +75,18 @@ with the fixed schedule of `train_utils.get_profiler_context`), so it holds whol
 
 from __future__ import annotations
 
+import gc
 import hashlib
 import json
 import logging
 import os
+import resource
 import signal
 import socket
+import statistics
 import threading
 import time
+from collections import deque
 from typing import Any
 
 import jax
@@ -104,7 +120,13 @@ RECORD_SCHEMA: dict[str, tuple[str, ...]] = {
     # and, when written from a train loop (Telemetry.begin_iterations), "wall" (the whole
     # iteration, boundary to boundary) and "split" ({span name: seconds}: the loop thread's
     # outermost spans, which tile the iteration — the record is written once the iteration
-    # has ended, and its own write is charged to the next one as `loop.record`)
+    # has ended, and its own write is charged to the next one as `loop.record`), "inner"
+    # ({span: seconds} of the spans nested in those, absent when none closed: `sync.step`,
+    # `sync.read`, `log.read`, `log.track`, `log.progress`, ...), "off_loop" ({span: seconds}
+    # of spans that closed on other threads during the iteration, absent when none did),
+    # "gc" ({"seconds", "count"} of Python's garbage collections in the process, absent when
+    # none ran) and "host" ({"nivcsw", "majflt", "cpu"}: the iteration's involuntary context
+    # switches, major page faults and CPU seconds of the process)
     "step": ("step", "t"),
     "window": (
         "step",
@@ -448,8 +470,6 @@ def collect_memory_gauges() -> dict[str, int]:
         for key in ("bytes_in_use", "peak_bytes_in_use", "peak_bytes_reserved", "bytes_limit"):
             if key in stats:
                 gauges[f"device{i}/{key}"] = int(stats[key])
-    import resource
-
     # ru_maxrss is KiB on Linux
     gauges["host/peak_rss_bytes"] = (
         int(resource.getrusage(resource.RUSAGE_SELF).ru_maxrss) * 1024
@@ -653,6 +673,13 @@ class OnDemandProfiler:
             self._stop(self._active_since + self.num_steps, None)
 
 
+def _host_readings() -> tuple[int, int, float]:
+    """Involuntary context switches and major page faults of the process so far, and its
+    CPU seconds: was it descheduled, paging, or busy."""
+    usage = resource.getrusage(resource.RUSAGE_SELF)
+    return usage.ru_nivcsw, usage.ru_majflt, time.process_time()
+
+
 def _annotation(name: str, step: int | None):
     if step is None:
         return jax.profiler.TraceAnnotation(name)
@@ -686,12 +713,43 @@ class _Span:
         elapsed = time.perf_counter() - self.start
         telemetry = self.telemetry
         if self.on_loop:
+            # an outermost span is a part of the iteration's tiling; a nested one is a part
+            # of its parent, kept under its own name
             telemetry._span_depth -= 1
-            if telemetry._span_depth == 0:
-                telemetry._split[self.name] = telemetry._split.get(self.name, 0.0) + elapsed
-        if self.bucket is not None:
+            kept = telemetry._split if telemetry._span_depth == 0 else telemetry._inner
+            kept[self.name] = kept.get(self.name, 0.0) + elapsed
+        off_loop = not self.on_loop and telemetry._loop_thread is not None
+        if off_loop or self.bucket is not None:
             with telemetry._lock:
-                telemetry._buckets[self.bucket] = telemetry._buckets.get(self.bucket, 0.0) + elapsed
+                if off_loop:  # another thread's span, while a train loop runs
+                    telemetry._off_loop[self.name] = telemetry._off_loop.get(self.name, 0.0) + elapsed
+                if self.bucket is not None:
+                    telemetry._buckets[self.bucket] = telemetry._buckets.get(self.bucket, 0.0) + elapsed
+
+
+def span_holding_the_excess(split: dict, inner: dict, history) -> str | None:
+    """Which span made an iteration slow: the one whose seconds exceed by most the median
+    of that span over `history` (the flattened ``{span: seconds}`` of earlier iterations; a
+    span is compared with the iterations that ran it). A nested span is named instead of
+    its parent where it holds at least half of that excess. `tools/telemetry_summary.py`
+    applies the same rule to a sink's ``step`` records (it imports nothing of the package;
+    `tests/test_telemetry.py` holds the two to one answer)."""
+
+    def excess(parts: dict) -> dict:
+        return {
+            name: seconds - statistics.median([h[name] for h in history if name in h] or [0.0])
+            for name, seconds in parts.items()
+        }
+
+    if not split:
+        return None
+    outer, nested = excess(split), excess(inner)
+    name = max(outer, key=outer.get)
+    if nested:
+        deepest = max(nested, key=nested.get)
+        if nested[deepest] >= 0.5 * outer[name]:
+            return deepest
+    return name
 
 
 class Telemetry:
@@ -741,6 +799,16 @@ class Telemetry:
         self._iteration_start = 0.0
         self._span_depth = 0
         self._split: dict[str, float] = {}
+        self._inner: dict[str, float] = {}  # the loop thread's nested spans, by name
+        self._off_loop: dict[str, float] = {}  # spans other threads closed (under the lock)
+        # garbage collections of the process since begin_iterations (seconds, count): only
+        # the gc hook writes the totals, only the loop's thread what it has read of them
+        self._gc_total = (0.0, 0)
+        self._gc_read = (0.0, 0)
+        self._gc_open: tuple | None = None  # (annotation, perf_counter) of a running one
+        self._host_read = (0, 0, 0.0)  # ru_nivcsw, ru_majflt, process_time at the boundary
+        # the flattened spans of the last iterations: what a slow one is compared with
+        self._span_history: deque[dict[str, float]] = deque(maxlen=50)
         self._step_in_flight = 0  # the newest dispatched step: where a compile event fell
         self._events_once: set = set()  # what `event_once` has written
 
@@ -881,36 +949,109 @@ class Telemetry:
         """Cut one boundary of the program, once, on both clocks: a ``TraceAnnotation`` in a
         captured profile (``StepTraceAnnotation`` when `step` is given: the dispatch of that
         train step) and ``perf_counter`` for the sink. The duration goes to the current
-        iteration's split when this is an outermost span on the loop's thread, and to the
-        goodput `bucket` when one is named. Outside a train loop (no
-        :meth:`begin_iterations`) it only annotates — nothing is kept or written."""
+        iteration's split when this is an outermost span on the loop's thread (``t.split``),
+        under its own name to ``t.inner`` when it is nested in one, to ``t.off_loop`` when
+        another thread closes it, and to the goodput `bucket` when one is named. Outside a
+        train loop (no :meth:`begin_iterations`) it only annotates — nothing is kept or
+        written."""
         return _Span(self, name, bucket, step)
 
     # ------------------------------------------------------------------ goodput
 
     def begin_iterations(self) -> None:
         """The calling thread's train loop starts here: from now on every
-        :meth:`record_step` closes one iteration, and the spans in between are its split."""
+        :meth:`record_step` closes one iteration, and the spans in between are its split.
+        Python's garbage collections are timed from here to :meth:`close`."""
         self._loop_thread = threading.get_ident()
-        self._iteration_start = time.perf_counter()
         self._span_depth = 0
-        self._split = {}
+        self._split, self._inner = {}, {}
+        with self._lock:
+            self._off_loop = {}
+        self._gc_read = self._gc_total
+        self._host_read = _host_readings()
+        if self._on_gc not in gc.callbacks:
+            gc.callbacks.append(self._on_gc)
+        self._iteration_start = time.perf_counter()
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        """`gc.callbacks` hook: one collection, cut as a span is (an annotation named
+        ``gc.collect`` and ``perf_counter`` at the same two ends), on whichever thread it
+        ran. Kept apart from :class:`_Span`: a collection can start inside the registry's
+        lock, so this takes none, and one between two of the loop's spans is no part of
+        their tiling."""
+        if phase == "start":
+            annotation = _annotation("gc.collect", None)
+            annotation.__enter__()
+            self._gc_open = (annotation, time.perf_counter())
+        elif self._gc_open is not None:
+            annotation, start = self._gc_open
+            self._gc_open = None
+            elapsed = time.perf_counter() - start
+            annotation.__exit__(None, None, None)
+            seconds, count = self._gc_total
+            self._gc_total = (seconds + elapsed, count + 1)
+
+    def _iteration_parts(self, drain: bool) -> dict:
+        """The current iteration beside its wall time: ``split``, ``inner``, ``off_loop``,
+        ``gc`` (the three absent when empty) and ``host``. `drain` starts the next
+        iteration's count (:meth:`record_step`); without it nothing is taken away
+        (:meth:`iteration_so_far`)."""
+        parts: dict[str, Any] = {"split": {k: round(v, 6) for k, v in self._split.items()}}
+        if self._inner:
+            parts["inner"] = {k: round(v, 6) for k, v in self._inner.items()}
+        with self._lock:
+            off_loop = self._off_loop
+            if drain:
+                self._off_loop = {}
+        if off_loop:
+            parts["off_loop"] = {k: round(v, 6) for k, v in off_loop.items()}
+        gc_total, host = self._gc_total, _host_readings()
+        if gc_total[1] > self._gc_read[1]:
+            parts["gc"] = {
+                "seconds": round(gc_total[0] - self._gc_read[0], 6),
+                "count": gc_total[1] - self._gc_read[1],
+            }
+        parts["host"] = {
+            "nivcsw": host[0] - self._host_read[0],
+            "majflt": host[1] - self._host_read[1],
+            "cpu": round(host[2] - self._host_read[2], 6),
+        }
+        if drain:
+            self._split, self._inner = {}, {}
+            self._gc_read, self._host_read = gc_total, host
+        return parts
+
+    def iteration_so_far(self) -> dict:
+        """What the current iteration has spent up to now — ``split``, ``inner``,
+        ``off_loop``, ``gc``, ``host`` as the ``step`` record will carry them — and
+        ``blame``, the span that holds the excess over the last iterations
+        (:func:`span_holding_the_excess`). For the ``anomaly`` event of a slow step; empty
+        outside a train loop."""
+        if self._loop_thread is None:
+            return {}
+        parts = self._iteration_parts(drain=False)
+        blame = span_holding_the_excess(parts["split"], parts.get("inner", {}), self._span_history)
+        if blame is not None:
+            parts["blame"] = blame
+        return parts
 
     def record_step(self, step: int, data_seconds: float, step_seconds: float) -> None:
         """Per-step accounting from the train loops: dataloader wait + jitted-step wall
         time. Writes a step record and feeds the window buckets. In a train loop
         (:meth:`begin_iterations`) this is the iteration's last call: the record also
-        carries the iteration's wall time and its split, and the write itself is the
-        first span (``loop.record``) of the next iteration."""
+        carries the iteration's wall time, its split and what else the host did in it
+        (``inner``, ``off_loop``, ``gc``, ``host``), and the write itself is the first span
+        (``loop.record``) of the next iteration."""
         self._last_step = step
-        timings: dict[str, float] = {"data": round(data_seconds, 6)}
-        if self._loop_thread is not None:
-            boundary = time.perf_counter()
-            timings["wall"] = round(boundary - self._iteration_start, 6)
-            timings["split"] = {k: round(v, 6) for k, v in self._split.items()}
-            self._iteration_start = boundary
-            self._split = {}
-        with self.span("loop.record"):
+        timings: dict[str, Any] = {"data": round(data_seconds, 6)}
+        with self.span("loop.record") as record:
+            if self._loop_thread is not None:
+                # the span's own start is the boundary: reading the iteration's parts is
+                # the head of the next one's `loop.record`, and the tiling has no hole
+                timings["wall"] = round(record.start - self._iteration_start, 6)
+                timings.update(self._iteration_parts(drain=True))
+                self._span_history.append({**timings["split"], **timings.get("inner", {})})
+                self._iteration_start = record.start
             with self._lock:
                 self._buckets["data"] += data_seconds
                 if not self._seen_first_step:
@@ -1014,6 +1155,8 @@ class Telemetry:
         so the sink is flushed and statused on every exit path)."""
         if self.profiler is not None:
             self.profiler.close()
+        if self._on_gc in gc.callbacks:
+            gc.callbacks.remove(self._on_gc)
         self._emit(
             {
                 "kind": "run_end",
@@ -1073,6 +1216,9 @@ class _NullTelemetry:
 
     def begin_iterations(self) -> None:
         pass
+
+    def iteration_so_far(self) -> dict:
+        return {}
 
     def record_step(self, step, data_seconds, step_seconds) -> None:
         pass

@@ -5,11 +5,13 @@ train-loop smoke run guarding the sink against partial-write corruption.
 
 All CPU-only pytrees — no sharded-model paths (those are broken at seed, see memory)."""
 
+import gc
 import importlib.util
 import json
 import os
 import signal
 import threading
+import time
 
 import jax
 import jax.numpy as jnp
@@ -27,13 +29,16 @@ from dolomite_engine_tpu.train_utils import (
     reset_profiler_schedule,
 )
 from dolomite_engine_tpu.utils import StallWatchdog, retry_io
+from dolomite_engine_tpu.utils.diagnostics import HealthMonitor
 from dolomite_engine_tpu.utils.telemetry import (
     OnDemandProfiler,
     Telemetry,
+    _NullTelemetry,
     build_telemetry,
     detect_peak_tflops_per_device,
     get_telemetry,
     install_telemetry,
+    span_holding_the_excess,
     uninstall_telemetry,
 )
 
@@ -468,6 +473,296 @@ def test_step_record_split_sums_to_wall_time(tmp_path):
     assert "loop.record" not in steps[0]["t"]["split"]
     assert list(steps[1]["t"]["split"])[0] == "loop.record"
     assert window["goodput"]["checkpoint"] >= 0.003  # a span's bucket is fed by the same cut
+
+
+def test_nested_spans_go_to_inner_under_their_own_names(tmp_path):
+    """A span nested in one of the loop's is a part of its parent: `t.inner` keeps it under
+    its own name (summed over the iteration), the parts sum to the parent to within call
+    overhead, `t.split` is what it was, and an iteration without one carries no `inner`."""
+    sink = tmp_path / "t.jsonl"
+    telemetry = Telemetry(sink_path=str(sink), rank=0)
+    telemetry.begin_iterations()
+    for step in (1, 2):
+        with telemetry.span("loop.sync"):
+            if step == 1:
+                for _ in range(2):  # the same name twice: summed
+                    with telemetry.span("sync.step"):
+                        time.sleep(0.002)
+                with telemetry.span("sync.read"):
+                    time.sleep(0.001)
+                    with telemetry.span("deeper"):  # two levels down: still its own name
+                        time.sleep(0.001)
+        telemetry.record_step(step, 0.0, 0.005)
+    telemetry.close()
+    first, second = [r["t"] for r in _read_sink(sink) if r["kind"] == "step"]
+    assert list(first["inner"]) == ["sync.step", "deeper", "sync.read"]  # in the order they closed
+    assert first["inner"]["sync.step"] >= 0.004 and first["inner"]["sync.read"] >= 0.002 and first["inner"]["sync.read"] > first["inner"]["deeper"] >= 0.001
+    parts = first["inner"]["sync.step"] + first["inner"]["sync.read"]
+    assert 0 <= first["split"]["loop.sync"] - parts < 2e-3
+    assert list(first["split"]) == ["loop.sync"] and abs(first["wall"] - first["split"]["loop.sync"]) < 2e-3
+    assert "inner" not in second and list(second["split"]) == ["loop.record", "loop.sync"]
+
+
+def test_spans_closed_on_another_thread_reach_the_iterations_off_loop(tmp_path):
+    """The prefetch worker's spans close beside the loop: the iteration in which they closed
+    lists their seconds by name under `t.off_loop`; the next one starts from nothing."""
+    sink = tmp_path / "t.jsonl"
+    telemetry = Telemetry(sink_path=str(sink), rank=0)
+
+    def worker():
+        for _ in range(2):
+            with telemetry.span("prefetch_assemble"):
+                time.sleep(0.002)
+        with telemetry.span("data_fetch", bucket="data"):
+            time.sleep(0.001)
+
+    with telemetry.span("prefetch_assemble"):  # before a train loop: annotates only
+        pass
+    telemetry.begin_iterations()
+    for step in (1, 2):
+        with telemetry.span("train_step", step=step):
+            if step == 1:
+                thread = threading.Thread(target=worker)
+                thread.start()
+                thread.join()
+        telemetry.record_step(step, 0.0, 0.005)
+    so_far = telemetry.iteration_so_far()  # the third iteration has only the write of the second's record
+    assert list(so_far["split"]) == ["loop.record"] and "off_loop" not in so_far and "inner" not in so_far
+    window = telemetry.emit_window(2)
+    telemetry.close()
+    first, second = [r["t"] for r in _read_sink(sink) if r["kind"] == "step"]
+    assert set(first["off_loop"]) == {"prefetch_assemble", "data_fetch"}
+    assert first["off_loop"]["prefetch_assemble"] >= 0.004 and first["off_loop"]["data_fetch"] >= 0.001
+    assert "off_loop" not in second and "inner" not in first
+    assert set(first["split"]) == {"train_step"}  # another thread's span is no part of the loop's tiling
+    assert window["goodput"]["data"] >= 0.001  # and its bucket is fed as before
+
+
+def test_no_span_of_another_thread_is_lost_between_two_records(tmp_path, monkeypatch):
+    """Sixteen workers close spans while the loop drains `t.off_loop` as fast as it can: every
+    span lands in exactly one iteration's record. A clock that reads 0 at a span's start and 1
+    at its end, thread by thread, makes the seconds a count."""
+    import itertools
+    import sys
+
+    from dolomite_engine_tpu.utils import telemetry as telemetry_module
+
+    local = threading.local()
+
+    def clock():
+        if not hasattr(local, "ticks"):
+            local.ticks = itertools.cycle((0.0, 1.0))
+        return next(local.ticks)
+
+    workers, spans_each = 16, 200
+    sink = tmp_path / "t.jsonl"
+    telemetry = Telemetry(sink_path=str(sink), rank=0)
+    monkeypatch.setattr(telemetry_module.time, "perf_counter", clock)
+    telemetry.begin_iterations()
+
+    def work():
+        for _ in range(spans_each):
+            with telemetry.span("prefetch_assemble"):
+                pass
+
+    threads = [threading.Thread(target=work) for _ in range(workers)]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for thread in threads:
+            thread.start()
+        step = 0
+        deadline = time.monotonic() + 60
+        while any(thread.is_alive() for thread in threads) and time.monotonic() < deadline:
+            step += 1
+            telemetry.record_step(step, 0.0, 0.0)
+        for thread in threads:
+            thread.join(timeout=10)
+        assert not any(thread.is_alive() for thread in threads)
+    finally:
+        sys.setswitchinterval(interval)
+    telemetry.record_step(step + 1, 0.0, 0.0)  # what closed after the last drain
+    monkeypatch.undo()
+    telemetry.close()
+    steps = [r["t"] for r in _read_sink(sink) if r["kind"] == "step"]
+    assert sum(t.get("off_loop", {}).get("prefetch_assemble", 0.0) for t in steps) == workers * spans_each
+    assert all(set(t.get("off_loop", {})) <= {"prefetch_assemble"} for t in steps)
+
+
+def test_garbage_collections_are_timed_from_begin_iterations_to_close(tmp_path):
+    """`t.gc`: seconds and count of the collections that ran in the iteration, absent when
+    none ran; the hook is installed by `begin_iterations` and gone after `close`."""
+    sink = tmp_path / "t.jsonl"
+    telemetry = Telemetry(sink_path=str(sink), rank=0)
+    hooks = len(gc.callbacks)
+    was_enabled = gc.isenabled()
+    gc.disable()  # no collection of the interpreter's own inside the test's iterations
+    try:
+        gc.collect()  # before the loop: not counted
+        telemetry.begin_iterations()
+        assert len(gc.callbacks) == hooks + 1
+        telemetry.begin_iterations()  # again: still one hook
+        assert len(gc.callbacks) == hooks + 1
+        for step in (1, 2, 3):
+            with telemetry.span("loop.sync"):
+                if step == 2:
+                    gc.collect()
+                    thread = threading.Thread(target=gc.collect)  # any thread's collection holds them all
+                    thread.start()
+                    thread.join()
+            if step == 2:
+                assert telemetry.iteration_so_far()["gc"]["count"] == 2  # asking takes nothing away
+            telemetry.record_step(step, 0.0, 0.001)
+        telemetry.close()
+        assert len(gc.callbacks) == hooks
+        gc.collect()  # after close: nobody listens, nothing raised
+    finally:
+        if was_enabled:
+            gc.enable()
+    first, second, third = [r["t"] for r in _read_sink(sink) if r["kind"] == "step"]
+    assert "gc" not in first and "gc" not in third
+    assert second["gc"]["count"] == 2 and 0 < second["gc"]["seconds"] <= second["wall"]
+    assert set(second["split"]) == {"loop.record", "loop.sync"}  # a collection is no span of the tiling
+
+
+def test_every_iteration_reads_the_process_once(tmp_path):
+    """`t.host`: the iteration's involuntary context switches, major page faults and CPU
+    seconds; an iteration that sleeps used no CPU, one that spins used its wall time."""
+    sink = tmp_path / "t.jsonl"
+    telemetry = Telemetry(sink_path=str(sink), rank=0)
+    telemetry.begin_iterations()
+    with telemetry.span("loop.sync"):
+        time.sleep(0.02)
+    telemetry.record_step(1, 0.0, 0.02)
+    with telemetry.span("loop.sync"):
+        until = time.perf_counter() + 0.02
+        while time.perf_counter() < until:
+            pass
+    telemetry.record_step(2, 0.0, 0.02)
+    telemetry.close()
+    slept, spun = [r["t"] for r in _read_sink(sink) if r["kind"] == "step"]
+    for t in (slept, spun):
+        assert set(t["host"]) == {"nivcsw", "majflt", "cpu"}
+        assert isinstance(t["host"]["nivcsw"], int) and isinstance(t["host"]["majflt"], int)
+    assert slept["host"]["cpu"] < 0.01 <= spun["host"]["cpu"]
+    assert list(slept) == ["data", "wall", "split", "host", "compile"]  # the optional parts are absent
+
+
+@pytest.mark.parametrize(
+    "stalled, blamed",
+    [
+        ("sync.step", "sync.step"),  # the step's program took long: the nested span, not `loop.sync`
+        ("sync.read", "sync.read"),
+        ("loop.sync", "loop.sync"),  # in the parent itself, outside its parts
+        ("loop.data_wait", "loop.data_wait"),  # a span with nothing nested in it
+    ],
+)
+def test_a_slow_steps_anomaly_says_what_the_host_was_doing(tmp_path, stalled, blamed):
+    """The `anomaly` event of signal `step_time` carries the flagged iteration's parts up to
+    the monitor's call — `split`, `inner`, `host` (and `gc`, `off_loop` when there were
+    any) — and `blame`: the span that holds the excess over its own rolling median."""
+    sink = tmp_path / "t.jsonl"
+    telemetry = Telemetry(sink_path=str(sink), rank=0)
+    monitor = HealthMonitor(telemetry)
+    telemetry.begin_iterations()
+
+    def pause(name):
+        if name == stalled and step == 14:
+            gc.collect()  # what held it: a collection, and 30 ms more
+            time.sleep(0.03)
+        time.sleep(0.001)
+
+    for step in range(1, 15):
+        start = time.perf_counter()
+        with telemetry.span("loop.data_wait"):
+            pause("loop.data_wait")
+        with telemetry.span("loop.sync"):
+            pause("loop.sync")
+            with telemetry.span("sync.step"):
+                pause("sync.step")
+            with telemetry.span("sync.read"):
+                pause("sync.read")
+        with telemetry.span("loop.account"):
+            # the monitor is told a step time (a loaded machine's own pauses flag nothing)
+            flagged = monitor.observe_step(step, step_seconds=0.034 if step == 14 else 0.004)
+        assert bool(flagged) == (step == 14), (step, flagged)
+        telemetry.record_step(step, 0.0, time.perf_counter() - start)
+    telemetry.close()
+    (event,) = [r for r in _read_sink(sink) if r["kind"] == "event" and r["event"] == "anomaly"]
+    assert event["signal"] == "step_time" and event["step"] == 14 and event["ratio"] >= 2.0
+    assert event["blame"] == blamed
+    assert list(event["split"]) == ["loop.record", "loop.data_wait", "loop.sync"]  # up to the monitor's call
+    assert list(event["inner"]) == ["sync.step", "sync.read"]
+    assert {**event["split"], **event["inner"]}[stalled] >= 0.03
+    assert event["gc"]["count"] >= 1 and set(event["host"]) == {"nivcsw", "majflt", "cpu"}
+    # the step's own record carries the same parts, whole
+    record = [r for r in _read_sink(sink) if r["kind"] == "step"][-1]["t"]
+    assert record["inner"] == event["inner"] and record["split"]["loop.sync"] == event["split"]["loop.sync"]
+    assert "loop.account" in record["split"] and record["gc"] == event["gc"]
+
+
+@pytest.mark.parametrize(
+    "split, inner, blamed",
+    [
+        ({}, {}, None),
+        ({"a": 0.010, "b": 0.011}, {}, "b"),  # 1 ms over its median against none
+        ({"a": 0.300, "b": 0.010}, {}, "a"),
+        ({"a": 0.300, "b": 0.010}, {"a.x": 0.290, "a.y": 0.001}, "a.x"),  # the part that holds it
+        ({"a": 0.300, "b": 0.010}, {"a.x": 0.100, "a.y": 0.101}, "a"),  # no part holds half: the parent
+        ({"a": 0.010, "b": 0.010, "new": 0.050}, {}, "new"),  # a span the other iterations never ran
+        ({"a": 0.010, "b": 0.010}, {"rare": 0.004}, "rare"),  # compared with the iterations that ran it
+    ],
+)
+def test_span_holding_the_excess_and_the_summary_tools_copy_agree(split, inner, blamed):
+    history = [{"a": 0.010, "b": 0.010, "a.x": 0.001, "a.y": 0.001}] * 5 + [{"a": 0.012, "b": 0.010, "rare": 0.001}]
+    assert span_holding_the_excess(split, inner, history) == blamed
+    assert _load_summary_tool().span_holding_the_excess(split, inner, history) == blamed
+
+
+def test_summary_tool_prints_the_slowest_iterations(tmp_path, capsys):
+    """An untraced run that held a stall says what it was: step, wall, ratio to the median,
+    the span that holds the excess, and what else the host did in that iteration."""
+    sink = tmp_path / "rank-00000.jsonl"
+    split = {"loop.data_wait": 0.001, "train_step": 0.002, "loop.sync": 0.145, "loop.log": 0.005}
+    inner = {"sync.step": 0.143, "sync.read": 0.0015, "log.read": 0.0005, "log.track": 0.004, "log.progress": 0.0004}
+    host = {"nivcsw": 0, "majflt": 0, "cpu": 0.012}
+    records = []
+    for step in range(2, 42):
+        t = {"data": 1e-5, "step": 0.148, "wall": 0.153, "split": dict(split), "inner": dict(inner), "host": dict(host)}
+        if step == 17:  # a collection of 0.9 s while the loop read the loss
+            t["split"]["loop.sync"] += 0.9
+            t["inner"]["sync.read"] += 0.9
+            t.update(wall=1.053, gc={"seconds": 0.899, "count": 1}, off_loop={"prefetch_assemble": 0.002})
+        if step == 30:  # the process lost the CPU inside the entry point's log line
+            t["split"]["loop.log"] += 0.25
+            t["inner"]["log.track"] += 0.25
+            t.update(wall=0.403, host={"nivcsw": 41, "majflt": 3, "cpu": 0.013})
+        records.append({"kind": "step", "ts": 0.0, "rank": 0, "step": step, "t": t})
+    sink.write_text("".join(json.dumps(r) + "\n" for r in records))
+    assert _load_summary_tool().main([str(sink)]) == 0
+    out = capsys.readouterr().out
+    rows = [line for line in out.splitlines() if line.startswith("| step ") and "p50" not in line]
+    assert len(rows) == 2  # the other 38 lie at the median
+    assert rows[0].startswith("| step 17 | 1053 | 6.88 | sync.read 901.5 ms | 1 in 899 ms | prefetch_assemble 2 ms |")
+    assert rows[1].startswith("| step 30 | 403 | 2.63 | log.track 254 ms | - | - | 41 involuntary switches, 3 major faults, cpu 13 ms |")
+    assert "slowest iterations (median 153 ms)" in out
+
+
+def test_null_registry_answers_everything_the_real_one_does():
+    """`_NullTelemetry` parity: every public method of `Telemetry` exists on the stand-in
+    (a caller never asks which one it got), and the new ones return what an absent loop has."""
+    public = {name for name in vars(Telemetry) if not name.startswith("_") and callable(getattr(Telemetry, name))}
+    assert public <= set(dir(_NullTelemetry)), public - set(dir(_NullTelemetry))
+    null = get_telemetry()
+    assert null.iteration_so_far() == {}
+    null.begin_iterations()  # installs nothing
+    assert not any(getattr(hook, "__self__", None) is null for hook in gc.callbacks)
+    # a monitor over the stand-in flags a slow step without asking for more
+    monitor = HealthMonitor(null)
+    for step in range(1, 14):
+        monitor.observe_step(step, step_seconds=0.01)
+    (anomaly,) = monitor.observe_step(14, step_seconds=0.05)
+    assert anomaly == {"signal": "step_time", "value": 0.05, "ratio": 5.0}
 
 
 def test_span_outside_a_train_loop_costs_no_write(tmp_path):

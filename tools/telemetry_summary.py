@@ -8,7 +8,9 @@ call). Output is paste-ready for PERF.md / bench reports: step-time percentiles
 (steady-state, first-step compile excluded), the goodput breakdown as a % of wall-clock,
 MFU, cumulative counter totals, plus the training-health records — run exit status, the
 `model_report` introspection (param groups/bytes/sharding/HBM), the latest per-group
-`health` stats, anomaly events, and pointers to any crash flight records in the run dir.
+`health` stats, anomaly events, and pointers to any crash flight records in the run dir;
+for a train loop the median split of an iteration and its slowest iterations, each with the
+span that holds the excess and what else the host did in it (gc, other threads, the process).
 
 Schema: docs/OBSERVABILITY.md (`dolomite_engine_tpu/utils/telemetry.py` writes it).
 Malformed lines — the one line a SIGKILL may tear — are counted and skipped, never fatal.
@@ -20,6 +22,7 @@ import argparse
 import glob
 import json
 import os
+import statistics
 import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
@@ -160,6 +163,67 @@ def format_model_report(report: dict) -> list[str]:
     return lines
 
 
+def span_holding_the_excess(split: dict, inner: dict, history: list[dict]) -> str | None:
+    """Which span made an iteration slow: the one whose seconds exceed by most the median of
+    that span over `history` (``{span: seconds}`` of the other iterations; a span is compared
+    with the iterations that ran it), a nested span instead of its parent where it holds at
+    least half of that excess. The rule of `utils/telemetry.span_holding_the_excess` (the
+    ``anomaly`` event's ``blame``), written again because this tool imports nothing of the
+    package; `tests/test_telemetry.py` holds the two to one answer."""
+
+    def excess(parts: dict) -> dict:
+        return {
+            name: seconds - statistics.median([h[name] for h in history if name in h] or [0.0])
+            for name, seconds in parts.items()
+        }
+
+    if not split:
+        return None
+    outer, nested = excess(split), excess(inner)
+    name = max(outer, key=outer.get)
+    if nested:
+        deepest = max(nested, key=nested.get)
+        if nested[deepest] >= 0.5 * outer[name]:
+            return deepest
+    return name
+
+
+def format_slowest_iterations(steps: list[dict], count: int = 5) -> list[str]:
+    """The slowest steady iterations of a train loop, each with the span that holds its
+    excess over that span's median and what else the host did in it (``t.gc``, ``t.off_loop``,
+    ``t.host``): what an untraced run that held a stall says about it. Iterations under 1.2 x
+    the median are left out; nothing is printed when none is slower."""
+    median = statistics.median(r["t"]["wall"] for r in steps)
+    slow = sorted((r for r in steps if r["t"]["wall"] >= 1.2 * median), key=lambda r: -r["t"]["wall"])[:count]
+    if not slow or median <= 0:
+        return []
+    flat = [{**r["t"]["split"], **r["t"].get("inner", {})} for r in steps]
+    lines = [
+        f"| slowest iterations (median {1e3 * median:.4g} ms) | wall ms | x median | span holding the excess "
+        "| gc | off the loop's thread | host |",
+        "|---|---|---|---|---|---|---|",
+    ]
+    for record in slow:
+        t = record["t"]
+        inner = t.get("inner", {})
+        blame = span_holding_the_excess(t["split"], inner, flat)
+        seconds = {**t["split"], **inner}.get(blame, 0.0)
+        gc, host = t.get("gc"), t.get("host")
+        collected = f"{gc['count']} in {1e3 * gc['seconds']:.4g} ms" if gc else "-"
+        beside = ", ".join(f"{k} {1e3 * v:.4g} ms" for k, v in t.get("off_loop", {}).items()) or "-"
+        process = (
+            f"{host['nivcsw']} involuntary switches, {host['majflt']} major faults, cpu {1e3 * host['cpu']:.4g} ms"
+            if host
+            else "-"
+        )
+        lines.append(
+            f"| step {record['step']} | {1e3 * t['wall']:.4g} | {t['wall'] / median:.2f} "
+            f"| {blame} {1e3 * seconds:.4g} ms | {collected} | {beside} | {process} |"
+        )
+    lines.append("")
+    return lines
+
+
 def summarize(records: list[dict]) -> str:
     steps = [r for r in records if r.get("kind") == "step"]
     windows = [r for r in records if r.get("kind") == "window"]
@@ -290,7 +354,8 @@ def summarize(records: list[dict]) -> str:
     # step records written from a train loop carry the loop's spans (t.split, in the order
     # they ran) and the iteration's wall time: the median of each part over the steady
     # steps, and the slowest iteration with its own split — which part stalled
-    split_steps = [r["t"] for r in steps if "split" in r.get("t", {}) and "step" in r["t"]]
+    split_records = [r for r in steps if "split" in r.get("t", {}) and "step" in r["t"]]
+    split_steps = [r["t"] for r in split_records]
     if split_steps:
         names = list(dict.fromkeys(name for t in split_steps for name in t["split"]))
         walls = sorted(t["wall"] for t in split_steps)
@@ -310,6 +375,7 @@ def summarize(records: list[dict]) -> str:
             f"| (whole iteration) | {1e3 * percentile(walls, 50):.4g} | {1e3 * slowest['wall']:.4g} |"
         )
         lines.append("")
+        lines.extend(format_slowest_iterations(split_records))
 
     # ---------------------------------------------------------------- goodput
     if windows:
