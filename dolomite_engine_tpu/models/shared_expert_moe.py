@@ -63,8 +63,11 @@ def say_dispatch_plan():
     the layers of experts traced inside — how many `layers`, and what
     `ops/moe.experts_held_ragged` planned for them from their shapes (the buffers' `capacity`,
     the `block_rows` a loop step of its row movements takes, `blocks_per_capacity`, the
-    `form`). With `routed_slots` of the ``step_counters`` event a layer ran
-    ``ceil(routed_slots / block_rows)`` of `blocks_per_capacity` blocks that step."""
+    `form`; the `activation_block_rows` and `activation_form` of the walk between the grouped
+    products; `group_sizes`: read off the sorted keys). With `routed_slots` of the
+    ``step_counters`` event a layer ran ``ceil(routed_slots / block_rows)`` of
+    `blocks_per_capacity` blocks that step, and its activation ``ceil(routed_slots /
+    activation_block_rows)`` a pass."""
     from ..utils.telemetry import get_telemetry
 
     with watch_dispatch_plans() as plans:
@@ -147,9 +150,9 @@ class SharedExpertMoE(nn.Module):
             name="c_proj",
         )(config.moe_intermediate_size)
 
-        # `moe_dispatch` (the sort, the gather), `moe_experts` (the grouped products) and
-        # `moe_combine` (the weighted scatter-add) are opened inside: one function, so that
-        # the overflow path is the same code at more rows
+        # `moe_dispatch` (the sort, the gather), `moe_experts` (the grouped products and the
+        # activation between them) and `moe_combine` (the weighted scatter-add) are opened
+        # inside: one function, so that the overflow path is the same code at more rows
         routed, counters = experts_held_ragged(
             x.astype(self.dtype),
             weights,

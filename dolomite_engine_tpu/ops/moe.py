@@ -181,19 +181,25 @@ def experts_ragged(
     return out.at[token_index].add(y * gates[:, None])
 
 
-def _share_grouped_product(rows: jax.Array) -> Callable:
-    """``product(rows, bank, group_sizes)``: ``rows[i] @ bank[g]`` for the rows of group ``g``
-    (``group_sizes`` in order; rows past their sum belong to no group and come out
-    undefined). On a TPU, in a trace with no multi-device mesh, jax's megablox grouped matmul
-    (`ops/pallas/moe.held_grouped_product`), else `jax.lax.ragged_dot`. Nothing a user sets:
-    on a v5e the TPU compiler's own `ragged_dot` kernel ran these shapes at 23-33 TFLOP/s and
-    megablox at 50-90 (PERF.md, PR 26); under a mesh the Mosaic kernel would have to go
-    through `parallel.sharding.shard_kernel` with the share's layout across chips (experts
-    over ``ep``), which is not built, and off the TPU it would run interpreted."""
+def _one_tpu(rows: jax.Array) -> bool:
+    """Whether a Mosaic kernel can be launched on `rows` as they are: on a TPU, in a trace
+    with no multi-device mesh (under one the kernel would have to go through
+    `parallel.sharding.shard_kernel` with the share's layout across chips, experts over
+    ``ep``, which is not built; off the TPU it would run interpreted)."""
     from ..parallel.sharding import kernel_sharding
 
     layout = ((rows.shape, (None, None)),)
-    if jax.default_backend() == "tpu" and kernel_sharding(layout, layout) is None:
+    return jax.default_backend() == "tpu" and kernel_sharding(layout, layout) is None
+
+
+def _share_grouped_product(rows: jax.Array) -> Callable:
+    """``product(rows, bank, group_sizes)``: ``rows[i] @ bank[g]`` for the rows of group ``g``
+    (``group_sizes`` in order; rows past their sum belong to no group and come out
+    undefined). On one TPU (`_one_tpu`) jax's megablox grouped matmul
+    (`ops/pallas/moe.held_grouped_product`), else `jax.lax.ragged_dot`. Nothing a user sets:
+    on a v5e the TPU compiler's own `ragged_dot` kernel ran these shapes at 23-33 TFLOP/s and
+    megablox at 50-90 (PERF.md, PR 26)."""
+    if _one_tpu(rows):
         from .pallas.moe import held_grouped_product
 
         return held_grouped_product
@@ -213,9 +219,10 @@ class _Walk(NamedTuple):
 _WALK_BLOCK_BYTES = 8 * 2**20
 
 
-def _walk_block_rows(capacity: int, hidden: int, itemsize: int) -> int:
-    """The largest divisor of `capacity` whose rows are at most `_WALK_BLOCK_BYTES`."""
-    most = max(1, min(capacity, _WALK_BLOCK_BYTES // (hidden * itemsize)))
+def _walk_block_rows(capacity: int, hidden: int, itemsize: int, block_bytes: int | None = None) -> int:
+    """The largest divisor of `capacity` whose rows are at most `block_bytes`
+    (`_WALK_BLOCK_BYTES`)."""
+    most = max(1, min(capacity, (block_bytes or _WALK_BLOCK_BYTES) // (hidden * itemsize)))
     return next(rows for rows in range(most, 0, -1) if capacity % rows == 0)
 
 
@@ -324,6 +331,87 @@ def _combine_rows_bwd(walk, kept, d_out):
 _combine_rows.defvjp(_combine_rows_fwd, _combine_rows_bwd)
 
 
+class _Activation(NamedTuple):
+    """The pass between the two grouped products as planned before the step: the family's
+    callable, the rows a block of its walk takes, and the walk's form (`_share_activation`)."""
+
+    act: Callable
+    block_rows: int
+    form: str
+
+
+# a grid step of the activation's kernel holds about this many bytes of its wider side in VMEM
+# (twice: the next block arrives while this one is computed), beside the other side and the
+# float32 values it computes with
+_KERNEL_BLOCK_BYTES = 4 * 2**20
+
+
+def _share_activation(rows: jax.Array, act: Callable, capacity: int, width: int) -> _Activation:
+    """How `_activate_rows` walks ``[capacity, width]`` rows of `rows`' dtype. ``"pallas"``:
+    one elementwise launch a pass (`ops/pallas/moe.routed_row_blocks`), where
+    a kernel can be launched (`_one_tpu`), both widths fill whole lane rows of 128
+    and a block whole sublane tiles — at another width XLA copies all `capacity` rows into
+    the layout Mosaic wants and back, the very pass this walk is there to avoid (the tower's
+    1856). Else ``"xla_loop"``: `lax.fori_loop` over the same blocks, as the rows' walks.
+    Nothing a user sets."""
+    widths = (width, jax.eval_shape(act, jax.ShapeDtypeStruct((1, width), rows.dtype)).shape[1])
+    size = (capacity, max(widths), rows.dtype.itemsize)
+    block = _walk_block_rows(*size, _KERNEL_BLOCK_BYTES)
+    if _one_tpu(rows) and not any(w % 128 for w in widths) and block % 16 == 0:
+        return _Activation(act, block, "pallas")
+    return _Activation(act, _walk_block_rows(*size), "xla_loop")
+
+
+def _loop_row_blocks(of_block: Callable, count: jax.Array, sides: tuple, width: int, block_rows: int) -> jax.Array:
+    """``of_block(*blocks)`` for the blocks of `block_rows` rows of `sides` that hold one of the
+    first `count` rows, in a ``[rows, width]`` buffer; the loop stops after the block that
+    holds the last of them, and the rows past it are zero."""
+
+    def step(index, out):
+        at = index * block_rows
+        here = of_block(*(jax.lax.dynamic_slice_in_dim(side, at, block_rows) for side in sides))
+        return jax.lax.dynamic_update_slice_in_dim(out, here.astype(out.dtype), at, 0)
+
+    out = jnp.zeros((sides[0].shape[0], width), sides[0].dtype)
+    return jax.lax.fori_loop(0, -(-count // block_rows), step, out)
+
+
+def _row_blocks(form: str) -> Callable:
+    if form == "xla_loop":
+        return _loop_row_blocks
+    from .pallas.moe import routed_row_blocks
+
+    return routed_row_blocks
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _activate_rows(plan: _Activation, h: jax.Array, count: jax.Array) -> jax.Array:
+    """``plan.act`` over the rows of `h` below `count`, block by block, stopping after the
+    block that holds the last of them (`act` works row by row; a gated one halves the
+    width). What lies past that block is undefined, as `h`'s own rows past `count` are. The
+    rule walks the same blocks of `h` and of the cotangent — written here for the reason
+    `_dispatch_rows` gives. The kernel computes in float32 and rounds once; the loop is
+    `act` as XLA compiles it for `h`'s dtype."""
+    width = jax.eval_shape(plan.act, h).shape[1]
+    return _row_blocks(plan.form)(plan.act, count, (h,), width, plan.block_rows)
+
+
+def _activate_rows_fwd(plan, h, count):
+    return _activate_rows(plan, h, count), (h, count)
+
+
+def _activate_rows_bwd(plan, kept, d_out):
+    h, count = kept
+
+    def pull(h_here, d_here):
+        return jax.vjp(plan.act, h_here)[1](d_here)[0]
+
+    return _row_blocks(plan.form)(pull, count, (h, d_out), h.shape[1], plan.block_rows), None
+
+
+_activate_rows.defvjp(_activate_rows_fwd, _activate_rows_bwd)
+
+
 # lists that `watch_dispatch_plans` opened, innermost last
 _PLAN_WATCHERS: list[list[dict]] = []
 
@@ -331,8 +419,8 @@ _PLAN_WATCHERS: list[list[dict]] = []
 @contextmanager
 def watch_dispatch_plans():
     """What `experts_held_ragged` planned in the trace inside: one entry a call (`capacity`,
-    `block_rows`, `blocks_per_capacity`, `form`). The model reads it for its
-    ``moe_dispatch_plan`` event."""
+    `block_rows`, `blocks_per_capacity`, `form`; `activation_block_rows`, `activation_form`,
+    `group_sizes`). The model reads it for its ``moe_dispatch_plan`` event."""
     seen: list[dict] = []
     _PLAN_WATCHERS.append(seen)
     try:
@@ -364,10 +452,12 @@ def experts_held_ragged(
     sent here: the dispatch's gather and the combine's weighted scatter-add (`_dispatch_rows`,
     `_combine_rows`), and their transposes in the backward pass, walk the sorted slots in
     blocks of rows (`_walk_block_rows`: from the shapes) and stop after the block that holds
-    the last routed one, as the grouped products walk only their groups' tiles. What lies
-    past `routed` in a buffer is never read: the products leave those rows undefined, and
-    no select stands guard — the loops' bounds do. A step that routes more here than
-    `capacity` walks the sorted slots in chunks of `capacity` rows instead (a scan whose
+    the last routed one, as the grouped products walk only their groups' tiles, and so does
+    the activation between the two products (`_activate_rows`, in blocks of its own:
+    `_share_activation`). The groups are read off the sorted keys, not counted slot by slot.
+    What lies past `routed` in a buffer is never read: the products and the activation leave
+    those rows undefined, and no select stands guard — the loops' bounds do. A step that
+    routes more here than `capacity` walks the sorted slots in chunks of `capacity` rows instead (a scan whose
     chunks past the last routed row are skipped, each chunk re-computed in the backward
     pass, the last one costing its rows too): slower, one chunk's rows in memory, and no row
     dropped however uneven the routing. `lax.cond` chooses.
@@ -386,6 +476,9 @@ def experts_held_ragged(
     capacity = min(max(capacity, 1), slots)
     chunks = -(-slots // capacity)
     walk = _Walk(tokens, top_k, _walk_block_rows(capacity, hidden, x.dtype.itemsize))
+    # both asked here, where the model is traced
+    grouped_product = _share_grouped_product(x)
+    activation = _share_activation(x, act, capacity, w_fc.shape[-1])
     for seen in _PLAN_WATCHERS:
         seen.append(
             {
@@ -393,16 +486,21 @@ def experts_held_ragged(
                 "block_rows": walk.block_rows,
                 "blocks_per_capacity": capacity // walk.block_rows,
                 "form": "xla_loop",
+                "activation_block_rows": activation.block_rows,
+                "activation_form": activation.form,
+                "group_sizes": "sorted_keys",
             }
         )
-    grouped_product = _share_grouped_product(x)  # asked here, where the model is traced
 
     with jax.named_scope("moe_dispatch"):
         local = selected_experts.reshape(-1) - first_expert
         key = jnp.where((local >= 0) & (local < held), local, held)  # absent experts sort last
-        order = jnp.argsort(key, stable=True)
-        group_sizes = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
-        group_ends = jnp.cumsum(group_sizes)
+        # one stable sort gives the order and the sorted keys, and the keys give the groups
+        sorted_key, order = jax.lax.sort(
+            (key, jnp.arange(slots, dtype=jnp.int32)), num_keys=1, is_stable=True
+        )
+        group_ends = jnp.searchsorted(sorted_key, jnp.arange(1, held + 1, dtype=key.dtype)).astype(jnp.int32)
+        group_sizes = jnp.diff(group_ends, prepend=0)
         group_starts = group_ends - group_sizes
         routed = group_ends[-1]
         order = jnp.pad(order, (0, chunks * capacity - slots))  # whole chunks to slice
@@ -418,7 +516,7 @@ def experts_held_ragged(
                 jnp.minimum(group_ends, start + capacity) - jnp.maximum(group_starts, start), 0
             )
         with jax.named_scope("moe_experts"):
-            h = act(grouped_product(xs, w_fc, sizes))
+            h = _activate_rows(activation, grouped_product(xs, w_fc, sizes), count)
             y = grouped_product(h, w_proj, sizes)
         with jax.named_scope("moe_combine"):
             return _combine_rows(walk, out, y, gates, slot, count)

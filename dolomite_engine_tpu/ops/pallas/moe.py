@@ -290,3 +290,50 @@ def held_grouped_product(
             tiling=tiling,
             interpret=_interpret_default(interpret),
         )
+
+
+# ------------------------------------------------- the held experts' activation, routed rows only
+
+# what a launch of `routed_row_blocks` may hold in VMEM: two copies of every side's block and
+# the float32 values between them (`ops/moe._KERNEL_BLOCK_BYTES` sizes the blocks); a v5e
+# has 128 MiB, and a kernel that does not ask gets 16
+_ROW_BLOCKS_VMEM_BYTES = 64 * 2**20
+
+
+def routed_row_blocks(
+    of_block, count: jax.Array, sides: tuple, width: int, block_rows: int, *, interpret: bool | None = None
+) -> jax.Array:
+    """``of_block(*blocks)`` for the blocks of `block_rows` rows of `sides` (``[rows, w]``
+    each) that hold one of the first `count` rows, in a ``[rows, width]`` array of their
+    dtype: one elementwise launch over all the blocks, `count` handed to it before the grid
+    runs. A grid step past the last routed row computes nothing and moves nothing — its
+    block indices are the last routed block's, which VMEM already holds — so the rows past
+    that block are what the buffer held: undefined. `of_block` gets and gives float32
+    (Mosaic has no bfloat16 comparison or broadcast on a v5e), rounded once on the way out.
+    The drop-in for `ops/moe._loop_row_blocks`, on one device."""
+    rows = sides[0].shape[0]
+
+    def at(index, count_ref):
+        return jnp.minimum(index, jnp.maximum(pl.cdiv(count_ref[0], block_rows) - 1, 0)), 0
+
+    def kernel(count_ref, *refs):
+        @pl.when(pl.program_id(0) * block_rows < count_ref[0])
+        def _():
+            here = of_block(*(ref[...].astype(jnp.float32) for ref in refs[:-1]))
+            refs[-1][...] = here.astype(refs[-1].dtype)
+
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(pl.cdiv(rows, block_rows),),
+            in_specs=[pl.BlockSpec((block_rows, side.shape[1]), at) for side in sides],
+            out_specs=pl.BlockSpec((block_rows, width), at),
+        ),
+        out_shape=jax.ShapeDtypeStruct((rows, width), sides[0].dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",), vmem_limit_bytes=_ROW_BLOCKS_VMEM_BYTES
+        ),
+        interpret=_interpret_default(interpret),
+        name="moe_routed_row_blocks",  # the launch's name in a trace and in the compiled step
+    )(jnp.reshape(count, (1,)).astype(jnp.int32), *sides)

@@ -352,7 +352,9 @@ KNOWN_EVENTS: tuple[str, ...] = (
     # layers, the buffers' capacity, block_rows (the sorted slots a loop step of the gather,
     # the weighted scatter-add and their transposes takes), blocks_per_capacity, form; a layer
     # and step runs ceil(routed_slots / block_rows) of those blocks (routed_slots: the
-    # step_counters event)
+    # step_counters event); activation_block_rows and activation_form (pallas or xla_loop: the
+    # walk of the activation between the grouped products, ceil(routed_slots /
+    # activation_block_rows) blocks a pass), group_sizes (sorted_keys: read off the sort)
     "moe_dispatch_plan",
     # what the step's forward pass counted, returned by the train step beside the loss
     # (train_utils.make_train_step has_aux) and read where the loss is read: for nemotron_h

@@ -360,7 +360,11 @@ def test_the_lowered_step_is_what_it_was_before_a_third_family_shared_its_expert
     family's program on purpose takes them anew, and says so: PR 34 did — the expert layer's
     gather, weighted scatter-add and their transposes became loops over blocks of the routed rows
     with rules of their own (`ops/moe._dispatch_rows`, `_combine_rows`), so the step's text was
-    taken anew there (8214 lines before); the parameter tree's hash is the one PR 33 took."""
+    taken anew there (8214 lines before); PR 37 did again — the activation between the grouped
+    products walks blocks of rows up to the last routed one (`ops/moe._activate_rows`) and the
+    group sizes are read off the sorted keys, so the only operations that differ stand under
+    `moe_dispatch` and `moe_experts` or in the unnamed helpers called from there (8862 lines
+    before); the parameter tree's hash is the one PR 33 took."""
     import hashlib
 
     from dolomite_engine_tpu.distributed import TrainState
@@ -388,5 +392,5 @@ def test_the_lowered_step_is_what_it_was_before_a_third_family_shared_its_expert
     ).as_text()
     tree = str(jax.tree.map(lambda a: (a.shape, str(a.dtype)), state.params))
     assert hashlib.sha256(tree.encode()).hexdigest() == "4f3c47802e75a87cbdc4288b995f51a06c50485f568ee733f27eb4296b8d833b"
-    assert len(text.splitlines()) == 8862
-    assert hashlib.sha256(text.encode()).hexdigest() == "502abc7e7c2b260705a8fc6bce275d9f57ef816c24e7726355595226bb3ee00c"
+    assert len(text.splitlines()) == 9264
+    assert hashlib.sha256(text.encode()).hexdigest() == "a3baaca4eee4139a33ab4fd95f710cf8305b8e576a3b158ac26d6266d47b5bae"

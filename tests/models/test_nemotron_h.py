@@ -135,7 +135,11 @@ def test_the_tower_is_what_it_was_before_its_expert_layer_moved():
     0.9.0). A change of the tower's program on purpose takes them anew, and says so: PR 34 did —
     the expert layer's gather, weighted scatter-add and their transposes became loops over blocks
     of the routed rows with rules of their own (`ops/moe._dispatch_rows`, `_combine_rows`), so the
-    step's text was taken anew there (6527 lines before); the parameter tree's hash is the first."""
+    step's text was taken anew there (6527 lines before); PR 37 did again — the activation between
+    the grouped products walks blocks of rows up to the last routed one (`ops/moe._activate_rows`)
+    and the group sizes are read off the sorted keys, so the only operations that differ stand under
+    `moe_dispatch` and `moe_experts` or in the unnamed helpers called from there (6970 lines
+    before); the parameter tree's hash is the first."""
     import hashlib
 
     from dolomite_engine_tpu.distributed import TrainState
@@ -163,8 +167,8 @@ def test_the_tower_is_what_it_was_before_its_expert_layer_moved():
     ).as_text()
     tree = str(jax.tree.map(lambda a: (a.shape, str(a.dtype)), state.params))
     assert hashlib.sha256(tree.encode()).hexdigest() == "36990d468b39e5c040180d1e45a25dac74eeeb1d513dc6bc37c03b12fe9fca5b"
-    assert len(text.splitlines()) == 6970
-    assert hashlib.sha256(text.encode()).hexdigest() == "6c6647ef2c8794b59b3ace6758808d675322ed2e831a098c94d2a7d945f6dda5"
+    assert len(text.splitlines()) == 7270
+    assert hashlib.sha256(text.encode()).hexdigest() == "553c0684785df58d7e7807f9f4fc3495ade20eabc9fda04d0db0b0fa26ae19a0"
     from dolomite_engine_tpu.models import nemotron_h, shared_expert_moe
 
     assert nemotron_h.SharedExpertMoE is shared_expert_moe.SharedExpertMoE is SharedExpertMoE

@@ -232,6 +232,119 @@ def test_row_movements_that_stop_at_the_last_routed_row_give_the_masked_take_and
     )
 
 
+# ------------------------------------- the activation and the group sizes follow `routed` too
+
+FORMS = ["xla_loop", "pallas"]  # the kernel runs interpreted here
+
+
+@pytest.fixture
+def activation_in(monkeypatch):
+    """The activation's walk in a form and a block that the shapes of a toy would never give."""
+
+    def force(form):
+        monkeypatch.setattr(
+            moe, "_share_activation", lambda rows, act, capacity, width: moe._Activation(act, BLOCK_ROWS, form)
+        )
+
+    return force
+
+
+@pytest.mark.parametrize("form", FORMS)
+@pytest.mark.parametrize("act", [relu2, swiglu], ids=["relu2", "gated"])
+@pytest.mark.parametrize("count", [0, 1, BLOCK_ROWS - 1, BLOCK_ROWS, BLOCK_ROWS + 1, CAPACITY])
+def test_the_walked_activation_is_the_activation_on_the_rows_below_count(count, act, form):
+    """Values and gradients, whatever lies past `count` in the product and in the cotangent."""
+    k = jax.random.split(jax.random.PRNGKey(count), 2)
+    below = (jnp.arange(CAPACITY) < count)[:, None]
+    h = jax.random.normal(k[0], (CAPACITY, 2 * F))
+    d_out = jax.random.normal(k[1], jax.eval_shape(act, h).shape)
+    plan = moe._Activation(act, BLOCK_ROWS, form)
+    out, pull = jax.vjp(lambda h: moe._activate_rows(plan, h, jnp.int32(count)), jnp.where(below, h, jnp.nan))
+    (d_h,) = pull(jnp.where(below, d_out, jnp.nan))
+    want, want_pull = jax.vjp(act, h)
+    assert out.shape == want.shape and d_h.shape == h.shape
+    np.testing.assert_allclose(out[:count], want[:count], rtol=1e-6, atol=1e-7)
+    np.testing.assert_allclose(d_h[:count], want_pull(d_out)[0][:count], rtol=1e-6, atol=1e-7)
+    # and no block after the one that holds the last routed row was touched
+    blocks = -(-count // BLOCK_ROWS)
+    if form == "xla_loop":
+        assert not np.asarray(out[blocks * BLOCK_ROWS :]).any() and not np.asarray(d_h[blocks * BLOCK_ROWS :]).any()
+
+
+def test_the_activation_walks_the_blocks_that_hold_routed_rows_and_no_more():
+    """The loop's trip count is ``ceil(count / block_rows)``: a full `capacity` walks every
+    block once, which is all the walk can cost over the plain pass (its loop steps)."""
+    calls = []
+
+    def counting(h):
+        jax.debug.callback(lambda: calls.append(1))
+        return relu2(h)
+
+    plan = moe._Activation(counting, BLOCK_ROWS, "xla_loop")
+    for count in (0, BLOCK_ROWS + 1, CAPACITY):
+        calls.clear()
+        jax.block_until_ready(moe._activate_rows(plan, jnp.ones((CAPACITY, F)), jnp.int32(count)))
+        jax.effects_barrier()
+        assert len(calls) == -(-count // BLOCK_ROWS)
+
+
+def test_the_activation_kernel_is_for_one_tpu_and_widths_that_fill_lane_rows(monkeypatch):
+    """What the choice observes: where a kernel can be launched (`_one_tpu`), both widths and
+    the block's rows. The cells' shapes: lfm2 and joyai take the kernel, the tower's 1856 the loop."""
+    x = jnp.zeros((8, D), jnp.bfloat16)
+    plan = moe._share_activation(x, swiglu, 65536, 3072)
+    assert (plan.form, plan.block_rows) == ("xla_loop", 1024)  # no TPU here: 8 MiB of 3072 bfloat16
+    monkeypatch.setattr(moe, "_one_tpu", lambda rows: True)
+    assert moe._share_activation(x, swiglu, 65536, 3072)[1:] == (512, "pallas")  # lfm2
+    assert moe._share_activation(x, swiglu, 32768, 1536)[1:] == (1024, "pallas")  # joyai
+    assert moe._share_activation(x, relu2, 24576, 1856)[1:] == (2048, "xla_loop")  # the tower
+    assert moe._share_activation(x, swiglu, 24, 256)[1:] == (24, "xla_loop")  # no whole sublane tiles
+
+
+@pytest.mark.parametrize("act", [relu2, swiglu], ids=["relu2", "gated"])
+@pytest.mark.parametrize("routed", [BLOCK_ROWS + 1, CAPACITY + BLOCK_ROWS + 1], ids=["at_once", "in_chunks"])
+def test_the_layer_with_the_activation_kernel_gives_the_masked_take_and_scatter_add(
+    small_blocks, activation_in, routed, act
+):
+    activation_in("pallas")
+    operands, chosen = routed_layer(routed, act)
+    assert "pallas_call" in str(jax.make_jaxpr(lambda *a: held_share(*a, chosen, act))(*operands.values()))
+    mine = value_and_grads(held_share, operands, chosen, act)
+    reference = value_and_grads(masked_take_and_scatter_add, operands, chosen, act)
+    np.testing.assert_allclose(mine[0], reference[0], rtol=1e-5)
+    for name, got, want in zip(operands, mine[1], reference[1]):
+        np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-6, err_msg=name)
+
+
+def chosen_slots(case: str) -> np.ndarray:
+    """32 token-slots over 16 experts, of which `FIRST .. FIRST + HELD - 1` are held."""
+    rng = np.random.default_rng(7)
+    slots = 16 * TOP_K
+    absent = np.where(rng.integers(0, 2, size=slots) == 1, rng.integers(0, FIRST, size=slots), rng.integers(FIRST + HELD, 16, size=slots))
+    return {
+        "no_slot_held": rng.integers(0, FIRST, size=slots),  # all below the held experts
+        "absent_slots_only": absent,  # below and above them
+        "every_slot_held": FIRST + rng.integers(0, HELD, size=slots),
+        "one_expert_only": np.full(slots, FIRST + 2),
+        "a_random_draw": rng.integers(0, 16, size=slots),
+    }[case]
+
+
+@pytest.mark.parametrize("case", ["no_slot_held", "absent_slots_only", "every_slot_held", "one_expert_only", "a_random_draw"])
+def test_the_group_sizes_read_off_the_sorted_keys_are_the_bincounts(case):
+    operands, _ = routed_layer(5, relu2)
+    chosen = chosen_slots(case)
+    counters = experts_held_ragged(
+        operands["x"], operands["weights"], jnp.asarray(chosen.reshape(16, TOP_K), jnp.int32),
+        operands["w_fc"], operands["w_proj"], relu2, 16, FIRST, capacity=CAPACITY,
+    )[1]
+    want = np.bincount(chosen, minlength=16)[FIRST : FIRST + HELD]
+    np.testing.assert_array_equal(counters["held_expert_rows"], want)
+    assert counters["held_expert_rows"].dtype == jnp.int32
+    assert int(counters["routed_slots"]) == want.sum() and int(counters["absent_slots"]) == 32 - want.sum()
+    assert int(counters["fullest_expert_rows"]) == want.max()
+
+
 @jax.custom_vjp
 def poisoned_product(rows, bank, group_sizes):
     """`lax.ragged_dot` as a kernel that walks its groups' tiles leaves it: the rows of no
@@ -256,8 +369,12 @@ def _poisoned_bwd(kept, d_out):
 poisoned_product.defvjp(_poisoned_fwd, _poisoned_bwd)
 
 
+@pytest.mark.parametrize("form", FORMS)
 @pytest.mark.parametrize("routed", [BLOCK_ROWS + 1, CAPACITY + BLOCK_ROWS + 1], ids=["at_once", "in_chunks"])
-def test_what_the_products_leave_past_the_routed_rows_reaches_nothing(small_blocks, monkeypatch, routed):
+def test_what_the_products_leave_past_the_routed_rows_reaches_nothing(small_blocks, activation_in, monkeypatch, routed, form):
+    """The first product's rows past `routed` are not a number, so the activation's are not
+    either, in either form: neither reaches the layer's output nor any gradient."""
+    activation_in(form)
     operands, chosen = routed_layer(routed, swiglu)
     reference = value_and_grads(masked_take_and_scatter_add, operands, chosen, swiglu)
     monkeypatch.setattr(moe, "_share_grouped_product", lambda rows: poisoned_product)
@@ -293,6 +410,8 @@ def test_the_models_say_their_dispatch_plan_once(tmp_path):
     events = [json.loads(line) for line in open(tmp_path / "sink.jsonl")]
     plans = [e for e in events if e["kind"] == "event" and e["event"] == "moe_dispatch_plan"]
     assert len(plans) == 1, plans
-    assert {k: plans[0][k] for k in ("layers", "capacity", "block_rows", "blocks_per_capacity", "form")} == {
+    want = {
         "layers": 2, "capacity": CAPACITY, "block_rows": CAPACITY, "blocks_per_capacity": 1, "form": "xla_loop",
+        "activation_block_rows": CAPACITY, "activation_form": "xla_loop", "group_sizes": "sorted_keys",
     }
+    assert {k: plans[0][k] for k in want} == want

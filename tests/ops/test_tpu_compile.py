@@ -177,6 +177,27 @@ def test_mamba2_scan_kernel_compiles_for_v5e_at_the_cell_s_shapes(v5e, case):
     assert not re.search(r"\[2,64,(8,8|64),128,128\]", text)
 
 
+@pytest.mark.parametrize("capacity, width", [pytest.param(65536, 3072, id="lfm2"), pytest.param(32768, 1536, id="joyai")])
+def test_routed_row_blocks_kernel_compiles_for_v5e_at_the_cells_shapes(v5e, monkeypatch, capacity, width):
+    """The activation between the held experts' grouped products as one launch a pass
+    (`ops/pallas/moe.routed_row_blocks`, chosen by `ops/moe._share_activation`), forward and
+    backward, at the widths and the blocks the two gated cells run: Mosaic takes the float32
+    arithmetic, the split of a gated row and the blocks within the VMEM the launch asks for."""
+    from dolomite_engine_tpu.ops import moe
+    from dolomite_engine_tpu.ops.activations import get_activation_function
+    from dolomite_engine_tpu.utils import packages
+
+    monkeypatch.setattr(moe, "_one_tpu", lambda rows: True)
+    monkeypatch.setattr(packages, "pallas_interpret_mode", lambda: False)
+    one_chip = SingleDeviceSharding(v5e[0])
+    h = jax.ShapeDtypeStruct((capacity, width), jnp.bfloat16, sharding=one_chip)
+    plan = moe._share_activation(h, get_activation_function("swiglu"), capacity, width)
+    assert plan.form == "pallas"
+    both = jax.value_and_grad(lambda h, count: _sum_sq(moe._activate_rows(plan, h, count)))
+    text = jax.jit(both).lower(h, jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+
+
 def _sharded_block_gradient_text(v5e, width, wrap=lambda block: block) -> str:
     """The compiled value and gradient of norm, rope+QKV and splash under fsdp 2 x tp 2 with
     sequence parallelism; `wrap` puts the block under a `jax.checkpoint`."""
@@ -393,6 +414,9 @@ def test_nemotron_h_tower_step_compiles_for_v5e_at_published_widths(v5e, capsys)
     kernels = [line.split(" = ")[0] for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
     assert sum("mamba2_scan_fwd" in name for name in kernels) == 8, kernels
     assert sum("mamba2_scan_bwd" in name for name in kernels) == 4, kernels
+    # the activation between the products walks its blocks in an XLA loop here (`ops/moe._share_activation`): 1856
+    # does not fill whole lane rows, and Mosaic's layout would cost a copy of all 24,576 rows a side
+    assert not any("moe_routed_row_blocks" in name for name in kernels), kernels
     assert 6.0 * gib < memory.argument_size_in_bytes < 6.5 * gib  # 667M parameters x 10 B of state
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 14.5 * gib
 
@@ -414,6 +438,9 @@ def test_joyai_flash_step_compiles_for_v5e_at_published_widths(v5e, capsys):
     assert (count("splash_mha_fwd"), count("splash_mha_dkv"), count("splash_mha_dq")) == (12, 6, 6), kernels
     # 5 layers of experts x (forward and its replay: 2 products each; backward: 2 for the rows, 2 for the banks)
     assert "ragged-dot" not in text and (count("gmm"), count("tgmm")) == (60, 20), kernels
+    # and between the two products the activation's launch (PR 37; 1536 and 768 fill whole lane rows): 5 layers x
+    # (forward, replay, backward) x the two paths `lax.cond` chooses from
+    assert count("moe_routed_row_blocks") == 30, kernels
     assert 6.2 * gib < memory.argument_size_in_bytes < 6.5 * gib  # 680.4M parameters x 10 B of state
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.0 * gib
 
@@ -442,6 +469,10 @@ def test_lfm2_moe_step_compiles_for_v5e_at_published_widths(v5e, capsys):
     assert (count("splash_mha_fwd"), count("splash_mha_dkv"), count("splash_mha_dq")) == (2, 1, 1), kernels
     # 4 layers of experts x (forward and its replay: 2 products each; backward: 2 for the rows, 2 for the banks)
     assert "ragged-dot" not in text and (count("gmm"), count("tgmm")) == (48, 16), kernels
+    # PR 37: the SwiGLU between the two products is one elementwise launch a pass that is handed the routed rows
+    # (`ops/pallas/moe.routed_row_blocks`: 3072 and 1536 fill whole lane rows): 4 layers x (forward, replay,
+    # backward) in each of the two paths `lax.cond` chooses from (all rows at once; in chunks of `capacity`)
+    assert count("moe_routed_row_blocks") == 24, kernels
     assert not any("rope_qkv" in name for name in kernels), kernels  # the norms sit before the rotation: XLA's form
     assert 4.3 * gib < memory.argument_size_in_bytes < 4.5 * gib  # 469.3M parameters x 10 B of state
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.5 * gib
