@@ -16,6 +16,7 @@ from .config import (
     Lfm2MoeConfig,
     MoEConfig,
     NemotronHConfig,
+    OuroConfig,
     RNNDolomiteConfig,
 )
 from .gpt_dolomite import CausalLMOutput, GPTDolomiteForCausalLM, GPTDolomiteModel
@@ -30,6 +31,7 @@ from .joyai_flash import JoyAIFlashForCausalLM, JoyAIFlashModel
 from .lfm2_moe import Lfm2MoeForCausalLM, Lfm2MoeModel
 from .moe_dolomite import MoEDolomiteForCausalLM, MoEDolomiteModel
 from .nemotron_h import NemotronHForCausalLM, NemotronHModel
+from .ouro import OuroForCausalLM, OuroModel
 from .rnn_dolomite import RNNDolomiteForCausalLM, RNNDolomiteModel
 
 _CONFIG_CLASSES: dict[str, type] = {
@@ -42,6 +44,7 @@ _CONFIG_CLASSES: dict[str, type] = {
     "nemotron_h": NemotronHConfig,
     "joyai_llm_flash": JoyAIFlashConfig,
     "lfm2_moe": Lfm2MoeConfig,
+    "ouro": OuroConfig,
 }
 
 _MODEL_CLASSES: dict[str, type] = {
@@ -54,6 +57,7 @@ _MODEL_CLASSES: dict[str, type] = {
     "nemotron_h": NemotronHForCausalLM,
     "joyai_llm_flash": JoyAIFlashForCausalLM,
     "lfm2_moe": Lfm2MoeForCausalLM,
+    "ouro": OuroForCausalLM,
 }
 
 # families trained/driven through the seq2seq (AutoModelForSeq2SeqLM) surface

@@ -653,3 +653,57 @@ class Lfm2MoeConfig(CommonConfig):
             vocabulary_rows_held=self.vocab_size,
             **(self.deployment or {}),
         )
+
+
+@dataclass
+class OuroConfig(CommonConfig):
+    """`ouro` (Ouro-2.6B's family, a looped language model): ONE stack of `n_layer` blocks
+    applied `total_ut_steps` times over the same weights, the final norm closing every pass,
+    and an exit gate (a linear layer to one number, with a bias) read after every pass
+    (`models/ouro.py` has the equations). A block norms each sub-layer's input AND its output
+    (four RMSNorms). The head is untied.
+
+    The repo's names carry the widths they always carried; `total_ut_steps` and
+    `early_exit_threshold` are the public `config.json`'s keys. `exit_entropy_coef` is the
+    loss's weight on the entropy of the gate's distribution over passes (the family's report:
+    0.05). `early_exit_threshold` is generation's (the cumulated exit probability at which a
+    token stops; at 1 every token runs every pass) and the training path does not read it."""
+
+    model_type: str = "ouro"
+    attention_head_type: str = "mha"
+    position_embedding_type: str = "rope"
+    normalization_function: str = "rmsnorm"
+    activation_function: str = "swiglu"
+    layer_norm_epsilon: float = 1e-6
+    rope_theta: float = 1000000.0
+    add_bias: bool = False
+    tie_word_embeddings: bool = False
+    total_ut_steps: int = 4
+    early_exit_threshold: float = 1.0
+    exit_entropy_coef: float = 0.05
+
+    def __post_init__(self) -> None:
+        super().__post_init__()
+        if self.total_ut_steps < 1:
+            raise ValueError(f"total_ut_steps {self.total_ut_steps}: the stack runs at least once")
+        if self.tie_word_embeddings:
+            raise ValueError("tie_word_embeddings true: the family's head is a table of its own (the published models')")
+
+    @classmethod
+    def fused_loss_reads_untied_head(cls) -> bool:
+        return True
+
+    @classmethod
+    def supported_position_embeddings(cls) -> frozenset[PositionEmbeddingType]:
+        return frozenset({PositionEmbeddingType.rope})
+
+    # what `train_utils.get_model_tflops`, `estimate_remat_activation_bytes` and the
+    # ``remat_plan`` / ``loop_plan`` events count a step's work by: a family without these two
+    # applies each block and reads the head once
+    @property
+    def block_applications(self) -> int:
+        return self.total_ut_steps * self.n_layer
+
+    @property
+    def head_readings(self) -> int:
+        return self.total_ut_steps

@@ -181,17 +181,27 @@ def remat_plan(
     checkpoint_every: int,
     rematerialized: list[bool],
     kernel_residual_bytes: list[int],
+    applications_per_block: int = 1,
 ) -> dict:
     """What the telemetry event ``remat_plan`` says, once a traced model: how the remat
     policy engaged. A block an entry: whether it sits under `jax.checkpoint`, and the bytes a
     batch row of the residuals its attention kernel tagged in this trace
     (`ops.attention.watch_kernel_residuals`; 0 where attention lowered through XLA or the
     block holds none). A kernel whose residuals the policy does not keep runs its forward
-    again in the backward pass."""
+    again in the backward pass. A family that applies its blocks more than once a step (a
+    looped model's passes: `applications_per_block`) also says how many applications that
+    makes, and how many of them replay."""
     names = names_kept_on_device(resolve_remat_policy(checkpoint_policy))
     through_kernel = [b for r, b in zip(rematerialized, kernel_residual_bytes) if r and b]
     kept = ATTENTION_KERNEL_RESIDUALS_CHECKPOINT_NAME in names
+    looped = {}
+    if applications_per_block != 1:
+        looped = {
+            "block_applications": applications_per_block * len(rematerialized),
+            "applications_rematerialized": applications_per_block * sum(rematerialized),
+        }
     return {
+        **looped,
         "policy": checkpoint_policy or "full",
         "checkpoint_every": checkpoint_every,
         "saved_names": names,
@@ -203,7 +213,7 @@ def remat_plan(
     }
 
 
-def say_remat_plan(model: nn.Module, kernel_residual_bytes: list[int]) -> None:
+def say_remat_plan(model: nn.Module, kernel_residual_bytes: list[int], applications_per_block: int = 1) -> None:
     """Write the ``remat_plan`` event of a model that rematerializes (its
     `checkpoint_policy`, `checkpoint_every`, `rematerialized`), once a distinct plan."""
     if model.checkpoint_every:
@@ -216,6 +226,7 @@ def say_remat_plan(model: nn.Module, kernel_residual_bytes: list[int]) -> None:
                 model.checkpoint_every,
                 model.rematerialized,
                 kernel_residual_bytes,
+                applications_per_block,
             ),
         )
 

@@ -244,6 +244,11 @@ def _chunk_ce_terms(
 
     The chunk's ``[B, chunk, V]`` logits exist only inside this function.
     """
+    return _ce_terms(_chunk_logits(h, table, logit_scale, compute_dtype), y, upcast, want_z, with_lse=True)
+
+
+def _chunk_logits(h: jax.Array, table: jax.Array, logit_scale: float | None, compute_dtype) -> jax.Array:
+    """One chunk's ``[B, chunk, V]`` logits, XLA reference lowering."""
     from ..parallel.sharding import logical_constraint
 
     # The table arrives pinned to its ACTIVATION layout (`fused_linear_cross_entropy`);
@@ -260,7 +265,22 @@ def _chunk_ce_terms(
     logits = logical_constraint(logits, ("act_batch", None, "act_vocab"))
     if logit_scale is not None:
         logits = logits * logit_scale
-    return _ce_terms(logits, y, upcast, want_z, with_lse=True)
+    return logits
+
+
+@jax.named_scope("ce_chunk")
+def _chunk_token_terms(
+    h: jax.Array, table: jax.Array, y: jax.Array, logit_scale: float | None, upcast: bool, compute_dtype
+) -> tuple[jax.Array, jax.Array]:
+    """One chunk's PER-TOKEN ``(loss, lse)``, both ``[B, chunk]`` float32: the token's
+    cross-entropy (0 on an IGNORE_INDEX row) and its log-sum-exp."""
+    logits = _chunk_logits(h, table, logit_scale, compute_dtype)
+    if upcast:
+        logits = logits.astype(jnp.float32)
+    mask = y != IGNORE_INDEX
+    lse = jax.scipy.special.logsumexp(logits, axis=-1)
+    picked = jnp.take_along_axis(logits, jnp.where(mask, y, 0)[..., None], axis=-1)[..., 0]
+    return jnp.where(mask, lse - picked, 0.0).astype(jnp.float32), lse.astype(jnp.float32)
 
 
 def _chunked_ce_forward(hidden_c, labels_c, table, logit_scale, upcast, compute_dtype, want_z):
@@ -388,6 +408,25 @@ def _tile_grads(
 
 
 def _chunked_ce_terms_bwd(logit_scale, upcast, compute_dtype, want_z, tiling, residuals, cts):
+    def coefficients(valid, lse_c):
+        # d(loss_sum)/d(logits) = softmax - onehot and d(z_sum)/d(logits) = 2 lse softmax on
+        # valid rows (z_sum = sum lse^2); num_tokens has no gradient. Without `upcast` the
+        # forward's terms are compute-dtype: the coefficients are cast where they are used
+        ct_loss, ct_z = (ct.astype(jnp.float32) for ct in cts[:2])
+        label_coef = jnp.where(valid, ct_loss, 0.0)
+        softmax_coef = label_coef
+        if want_z:
+            softmax_coef = jnp.where(valid, ct_loss + 2.0 * ct_z * lse_c, 0.0)
+        return softmax_coef, label_coef
+
+    return _chunked_ce_grads(residuals, coefficients, logit_scale, upcast, tiling)
+
+
+def _chunked_ce_grads(residuals, coefficients, logit_scale, upcast, tiling):
+    """The backward rule's walk, for the summed terms and the per-token ones alike:
+    ``(d hidden_c, None, d table)`` from the residuals and ``coefficients(valid, lse_c) ->
+    (softmax_coef, label_coef)``, each ``[n_chunks, B, chunk]`` float32 and 0 on IGNORE_INDEX
+    rows: ``d logits = softmax_coef x softmax - label_coef x onehot`` a token."""
     hidden_c, labels_c, table, lse_c = residuals
     n_chunks, _, _, hidden_size = hidden_c.shape
     blocks, tiles = tiling.token_blocks, tiling.vocab_tiles
@@ -415,15 +454,7 @@ def _chunked_ce_terms_bwd(logit_scale, upcast, compute_dtype, want_z, tiling, re
     row = jnp.arange(rows, dtype=jnp.int32)
     shard_start = vocab_local * jnp.arange(shards, dtype=jnp.int32)[:, None]
 
-    # d(loss_sum)/d(logits) = softmax - onehot and d(z_sum)/d(logits) = 2 lse softmax on
-    # valid rows (z_sum = sum lse^2); num_tokens has no gradient. Without `upcast` the
-    # forward's terms are compute-dtype: the coefficients are cast where they are used
-    ct_loss, ct_z = (ct.astype(jnp.float32) for ct in cts[:2])
-    valid = labels_c != IGNORE_INDEX
-    label_coef = jnp.where(valid, ct_loss, 0.0)
-    softmax_coef = label_coef
-    if want_z:
-        softmax_coef = jnp.where(valid, ct_loss + 2.0 * ct_z * lse_c, 0.0)
+    softmax_coef, label_coef = coefficients(labels_c != IGNORE_INDEX, lse_c)
 
     def block_grads(h, y, lse, s_coef, l_coef):
         """One token block: scan the vocabulary tiles, carrying the block's d hidden."""
@@ -474,6 +505,65 @@ def _chunked_ce_terms_bwd(logit_scale, upcast, compute_dtype, want_z, tiling, re
 _chunked_ce_terms.defvjp(_chunked_ce_terms_fwd, _chunked_ce_terms_bwd)
 
 
+def _chunked_ce_token_forward(hidden_c, labels_c, table, logit_scale, upcast, compute_dtype):
+    """The forward scan over chunks that keeps every token's terms apart: ``(loss_c, lse_c)``,
+    both ``[n_chunks, B, chunk]`` float32."""
+    from ..ops.pallas import use_pallas
+
+    if use_pallas("fused_ce"):
+        # the kernel's own per-row (log-sum-exp, label's logit): nothing of it to step aside
+        from ..ops.pallas.fused_ce import fused_ce_rowwise
+
+        def chunk_terms(h, y):
+            lse, picked = fused_ce_rowwise(
+                h.reshape(-1, h.shape[-1]), table, y.reshape(-1), logit_scale=logit_scale, compute_dtype=compute_dtype
+            )
+            return jnp.where(y != IGNORE_INDEX, (lse - picked).reshape(y.shape), 0.0), lse.reshape(y.shape)
+    else:
+
+        def chunk_terms(h, y):
+            return _chunk_token_terms(h, table, y, logit_scale, upcast, compute_dtype)
+
+    with jax.named_scope("loss_chunks"):
+        return jax.lax.map(lambda xs: chunk_terms(*xs), (hidden_c, labels_c))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _chunked_ce_token_terms(
+    hidden_c: jax.Array,  # [n_chunks, B, chunk, H]
+    labels_c: jax.Array,  # [n_chunks, B, chunk]
+    table: jax.Array,  # [V, H] in compute dtype
+    logit_scale: float | None,
+    upcast: bool,
+    compute_dtype,
+    tiling: LossTiling,
+) -> tuple[jax.Array, jax.Array]:
+    """`_chunked_ce_terms` with nothing summed over tokens: every token's cross-entropy and
+    log-sum-exp, so that a caller may weigh tokens (and learn the weights: the weight's
+    gradient is the token's own term, which autodiff of the caller's product gives). The same
+    residuals, the same backward walk (`_chunked_ce_grads`); a token's cotangents take the
+    place the summed rule's scalars had."""
+    return _chunked_ce_token_terms_fwd(hidden_c, labels_c, table, logit_scale, upcast, compute_dtype, tiling)[0]
+
+
+def _chunked_ce_token_terms_fwd(hidden_c, labels_c, table, logit_scale, upcast, compute_dtype, tiling):
+    loss_c, lse_c = _chunked_ce_token_forward(hidden_c, labels_c, table, logit_scale, upcast, compute_dtype)
+    # what is handed out is 0 on a row without a label, as its gradient is; the rule keeps the row's own
+    return (loss_c, jnp.where(labels_c != IGNORE_INDEX, lse_c, 0.0)), (hidden_c, labels_c, table, lse_c)
+
+
+def _chunked_ce_token_terms_bwd(logit_scale, upcast, compute_dtype, tiling, residuals, cts):
+    def coefficients(valid, lse_c):
+        # d(loss_i)/d(logits_i) = softmax - onehot, d(lse_i)/d(logits_i) = softmax
+        ct_loss, ct_lse = (ct.astype(jnp.float32) for ct in cts)
+        return jnp.where(valid, ct_loss + ct_lse, 0.0), jnp.where(valid, ct_loss, 0.0)
+
+    return _chunked_ce_grads(residuals, coefficients, logit_scale, upcast, tiling)
+
+
+_chunked_ce_token_terms.defvjp(_chunked_ce_token_terms_fwd, _chunked_ce_token_terms_bwd)
+
+
 def fused_linear_cross_entropy(
     hidden: jax.Array,
     embedding: jax.Array,
@@ -484,6 +574,7 @@ def fused_linear_cross_entropy(
     logit_scale: float | None = None,
     compute_dtype=jnp.bfloat16,
     z_loss_coef: float = 0.0,
+    weights: jax.Array | None = None,
 ) -> jax.Array:
     """LM-head matmul + CE without ever materializing the [B, S, V] logits.
 
@@ -514,7 +605,61 @@ def fused_linear_cross_entropy(
     hidden: [B, S, H]; embedding: [V, H] (tied-embedding layout); labels: [B, S] with
     IGNORE_INDEX. Chunking is along sequence, so dp/fsdp/ep batch sharding is untouched.
     ``z_loss_coef`` adds ``coef * mean(logsumexp^2)`` exactly like `causal_lm_loss`.
+
+    ``weights`` ([B, S], differentiable) weighs every token's terms: the loss is
+    ``sum_i w_i (l_i + coef lse_i^2) / count of labels``, the gradients of the hidden states
+    and of the table are scaled token by token by ``w_i``, and ``d loss / d w_i`` is the
+    token's own term over the count (`fused_linear_token_cross_entropy`: the same chunks,
+    the same backward walk, the tokens' terms kept apart). Without weights nothing of that
+    is traced: the program is what it was (tests/ops/test_loss_token_weights.py holds its
+    jaxpr's hash).
     """
+    if weights is not None:
+        token_loss, lse = fused_linear_token_cross_entropy(
+            hidden, embedding, labels, chunk_size=chunk_size, upcast=upcast, logit_scale=logit_scale, compute_dtype=compute_dtype
+        )
+        if z_loss_coef != 0.0:
+            token_loss = token_loss + z_loss_coef * jnp.square(lse)
+        valid = labels != IGNORE_INDEX
+        weighed = jnp.sum(jnp.where(valid, weights.astype(jnp.float32) * token_loss, 0.0))
+        return weighed / jnp.maximum(jnp.sum(valid.astype(jnp.float32)), 1.0)
+
+    hidden_c, labels_c, emb, tiling = _chunked_operands(hidden, embedding, labels, chunk_size, compute_dtype)
+    loss_sum, z_sum, num_tokens = _chunked_ce_terms(
+        hidden_c, labels_c, emb, logit_scale, upcast, compute_dtype, z_loss_coef != 0.0, tiling
+    )
+    denom = jnp.maximum(num_tokens, 1.0)
+    loss = loss_sum / denom
+    if z_loss_coef != 0.0:
+        loss = loss + z_loss_coef * (z_sum / denom)
+    return loss
+
+
+def fused_linear_token_cross_entropy(
+    hidden: jax.Array,
+    embedding: jax.Array,
+    labels: jax.Array,
+    *,
+    chunk_size: int = 256,
+    upcast: bool = True,
+    logit_scale: float | None = None,
+    compute_dtype=jnp.bfloat16,
+) -> tuple[jax.Array, jax.Array]:
+    """`fused_linear_cross_entropy` before its sum over tokens: ``(loss, lse)``, both
+    ``[B, S]`` float32 — every token's cross-entropy and its logits' log-sum-exp, both 0 (and
+    without a gradient) where the token's label is IGNORE_INDEX — with the logits as short-lived
+    as there, forward and backward. For a loss that weighs tokens by something that learns
+    (a looped model's exit gate: `models/ouro.py`)."""
+    S = hidden.shape[1]
+    hidden_c, labels_c, emb, tiling = _chunked_operands(hidden, embedding, labels, chunk_size, compute_dtype)
+    loss_c, lse_c = _chunked_ce_token_terms(hidden_c, labels_c, emb, logit_scale, upcast, compute_dtype, tiling)
+    rows = lambda x: x.swapaxes(0, 1).reshape(x.shape[1], -1)[:, :S]  # noqa: E731
+    return rows(loss_c), rows(lse_c)
+
+
+def _chunked_operands(hidden, embedding, labels, chunk_size: int, compute_dtype):
+    """``(hidden_c [n_chunks, B, chunk, H], labels_c [n_chunks, B, chunk], the table in its
+    activation layout, the backward rule's tiling)`` of one call of the chunked loss."""
     from ..parallel.sharding import logical_constraint
     from ..utils.telemetry import get_telemetry
 
@@ -540,14 +685,7 @@ def fused_linear_cross_entropy(
     emb = logical_constraint(embedding.astype(compute_dtype), ("act_vocab", None))
     tiling, record = plan_loss_backward(B, n_chunks, chunk_size, emb.shape[0], H)
     get_telemetry().event_once("loss_tiling", **record)
-    loss_sum, z_sum, num_tokens = _chunked_ce_terms(
-        hidden_c, labels_c, emb, logit_scale, upcast, compute_dtype, z_loss_coef != 0.0, tiling
-    )
-    denom = jnp.maximum(num_tokens, 1.0)
-    loss = loss_sum / denom
-    if z_loss_coef != 0.0:
-        loss = loss + z_loss_coef * (z_sum / denom)
-    return loss
+    return hidden_c, labels_c, emb, tiling
 
 
 def load_balancing_loss(

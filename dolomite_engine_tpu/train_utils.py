@@ -391,6 +391,9 @@ def get_model_tflops(
 ) -> float:
     """Analytic model TFLOPs per step per device-group (reference `train_utils.py:197-236`):
     attn = 4bsh(h(1+k/n) + s), mlp = 4bshf (+2bshf GLU), lm_head = 6bshv, bwd = 2x fwd.
+    A family that applies its blocks or reads its head more than once a step says how often
+    (`block_applications`, `head_readings` of its config: `OuroConfig`, a looped model's
+    passes x blocks and passes); without them a block and the head count once, as they did.
 
     The recompute term is derived from the SELECTED remat policy, not just
     `checkpoint_every`: ``full`` adds one forward per checkpointed block,
@@ -407,7 +410,7 @@ def get_model_tflops(
     n = config.n_head
     k = config.num_key_value_heads
     v = config.vocab_size
-    l = config.n_layer
+    l = getattr(config, "block_applications", config.n_layer)
 
     attention_flops = 4 * b * s * h * (h * (1 + k / n) + s)
     mlp_flops = 4 * b * s * h * f
@@ -460,7 +463,7 @@ def get_model_tflops(
             block_recompute = block
         recompute = l * block_recompute / max(every, 1)
 
-    lm_head = 6 * b * s * h * v
+    lm_head = 6 * b * s * h * v * getattr(config, "head_readings", 1)
 
     return (forward + backward + recompute + lm_head) / 1e12
 
@@ -497,7 +500,8 @@ def estimate_remat_activation_bytes(
     f = config.n_inner
     n = config.n_head
     kvh = config.num_key_value_heads
-    l = config.n_layer
+    # (a looped model keeps the input of every APPLICATION of a block: `OuroConfig`)
+    l = getattr(config, "block_applications", config.n_layer)
 
     token_bytes = b * s * dtype_bytes
     boundary = l // max(every, 1) * token_bytes * h if every else l * token_bytes * h

@@ -326,6 +326,11 @@ KNOWN_EVENTS: tuple[str, ...] = (
     # many of them keep the kernel's output and log-sum-exp (the others run the forward
     # kernel again in the backward pass), and those bytes a block and batch row
     "remat_plan",
+    # a looped model's loop where the model was traced (models/ouro.loop_plan): passes, blocks,
+    # the block applications of a step and how many replay, the head's readings, and the bytes
+    # of the block inputs and pass outputs kept between forward and backward (rows,
+    # tokens_per_row: of what batch)
+    "loop_plan",
     # what the splash kernel's launches run where attention was traced
     # (ops/attention._splash_attention_local): block_q, block_kv, the rows of a call, a
     # launch's grid (heads, query blocks, key slots), launches a call, and whether the block

@@ -476,3 +476,25 @@ def test_lfm2_moe_step_compiles_for_v5e_at_published_widths(v5e, capsys):
     assert not any("rope_qkv" in name for name in kernels), kernels  # the norms sit before the rotation: XLA's form
     assert 4.3 * gib < memory.argument_size_in_bytes < 4.5 * gib  # 469.3M parameters x 10 B of state
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.5 * gib
+
+
+def test_ouro_step_compiles_for_v5e_at_published_widths_with_one_copy_of_the_stack(v5e, capsys):
+    """The cell `train-ouro-loop4-packed8k`'s whole train step — eight sandwich-normed blocks of
+    `ouro` at published widths run four times over shared weights, 2 packed rows of 8192 tokens,
+    the gate, the head read over the four passes' rows stacked, AdamW — for one described v5e. The
+    passes are one scan: the program holds ONE copy of each block's kernels (8 blocks x forward and
+    its replay, dkv, dq of splash; four copies would read 64 / 32 / 32), and it fits the chip (an
+    estimate: the chip's reading is in PERF.md)."""
+    compiled = _compiled_cell_step(v5e, "train-ouro-loop4-packed8k")
+    memory, text = compiled.memory_analysis(), compiled.as_text()
+    gib = 2.0**30
+    with capsys.disabled():
+        print(
+            f"\ntrain-ouro-loop4-packed8k step for a described v5e: state {memory.argument_size_in_bytes / gib:.2f} GiB, "
+            f"temporaries (estimate) {memory.temp_size_in_bytes / gib:.3f} GiB"
+        )
+    kernels = [line.split(" = ")[0] for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    count = lambda prefix: sum(name.strip().lstrip("%").startswith(prefix) for name in kernels)  # noqa: E731
+    assert (count("splash_mha_fwd"), count("splash_mha_dkv"), count("splash_mha_dq")) == (16, 8, 8), kernels
+    assert 5.6 * gib < memory.argument_size_in_bytes < 5.9 * gib  # 612.5M parameters x 10 B of state
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.5 * gib

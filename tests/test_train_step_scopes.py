@@ -236,3 +236,47 @@ def test_lfm2_moe_names_its_operators_and_its_experts_without_a_shared_one():
     for name in dots:
         if "/blocks/" in name:
             assert re.search(r"/(short_conv|attention|dense_mlp|moe)/", name), name
+
+
+def test_ouro_names_the_pass_its_norms_the_gate_and_the_weighting():
+    """`ouro`'s lowered train step carries the scopes docs/OBSERVABILITY.md lists — `blocks/pass`
+    (ONE name for the loop's body: the passes are the scan's iterations), `block_norms` inside it,
+    `exit_gate`, `head_loss` with `pass_weighting` inside it — forward and backward; every matmul of
+    the blocks sits inside the scan over passes; and the head is read by one pair of scans."""
+    from dolomite_engine_tpu.models import get_model_class
+    from tests.models.test_ouro import CFG
+
+    model = get_model_class("ouro")(config=config_from_dict(CFG), checkpoint_every=1, dtype=jnp.bfloat16)
+    ids = jnp.zeros((1, 32), jnp.int32)
+    params = nn.unbox(model.init(jax.random.PRNGKey(0), ids)["params"])
+    optimizer = optax.adamw(1e-3)
+    state = TrainState(step=jnp.zeros((), jnp.int32), params=params, opt_state=optimizer.init(params))
+
+    def loss_fn(params, micro, rng):
+        out = model.apply({"params": params}, micro["text"], compute_loss=True)
+        return out.loss, out.counters
+
+    lowered = jax.jit(make_train_step(loss_fn, optimizer, skip_nonfinite=True, has_aux=True)).lower(
+        state, {"text": jnp.zeros((1, 1, 32), jnp.int32)}, jax.random.PRNGKey(0)
+    )
+    names = [name for _, name in _operation_names(lowered)]
+    dots = [name for op, name in _operation_names(lowered) if op == "dot_general"]
+    for scope in ("exit_gate", "pass_weighting"):
+        assert any(f"/{scope}/" in name and "transpose(" not in name for name in names), scope
+        assert any(f"/{scope}/" in name and "transpose(" in name for name in names), scope
+    # the loop's body is a function of its own in the lowering (its names start again at the
+    # scan's module: the compiled program's carry the whole path, `.../blocks/while/body/closed_call/stack/pass/...`);
+    # the backward pass holds the blocks' replays under `checkpoint/rematted_computation`
+    for scope in ("pass", "block_norms"):
+        assert any(f"/{scope}/" in name and "rematted_computation" not in name for name in names), scope
+        assert any(f"/{scope}/" in name and "rematted_computation" in name for name in names), scope
+    assert all("/pass/" in name for name in names if "/block_norms/" in name)
+    assert all("/head_loss/" in name for name in names if "/pass_weighting/" in name)
+    assert not any("/pass_weighting/" in name for name in dots)  # the weighting holds no matmul
+    # every matmul of a block inside the loop's body, under the one name
+    block_dots = [name for name in dots if "/attn/" in name or "/mlp/" in name]
+    assert block_dots and all("/pass/" in name for name in block_dots), block_dots
+    assert any(op == "while" and "/blocks/" in name for op, name in _operation_names(lowered))
+    # the head: one forward scan over chunks, and the backward rule's
+    loss_scans = {name for op, name in _operation_names(lowered) if op == "while" and "head_loss" in name}
+    assert sum("transpose(" not in name for name in loss_scans) == 1, loss_scans
