@@ -26,11 +26,19 @@ from jax.sharding import PartitionSpec
 IGNORE_INDEX = -100
 
 
-def _ce_terms(
-    logits: jax.Array, labels: jax.Array, upcast: bool, want_z: bool, with_lse: bool = False
-) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array | None]:
-    """(loss_sum, z_sum, num_tokens, lse): `lse` is the per-token log-sum-exp in float32 —
-    all the chunked loss's backward rule keeps of the logits — or None unless `with_lse`."""
+def cross_entropy_terms(
+    logits: jax.Array,
+    labels: jax.Array,
+    upcast: bool = True,
+    want_z: bool = False,
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Token-level CE reduced to (loss_sum, z_sum, num_tokens).
+
+    ``z_sum`` is the PaLM-style z-loss numerator — sum over valid tokens of
+    ``logsumexp(logits)^2`` — computed only when `want_z` (an extra reduction over the
+    vocab axis otherwise). The single formula shared by the unchunked loss and the chunk
+    scan, so their parity is summation-order-only (1-2 float32 ulp).
+    """
     if upcast:
         logits = logits.astype(jnp.float32)
 
@@ -43,28 +51,10 @@ def _ce_terms(
     loss_sum = -jnp.sum(jnp.where(mask, token_logprobs, 0.0))
     num_tokens = jnp.sum(mask.astype(jnp.float32))
     z_sum = jnp.zeros((), jnp.float32)
-    lse = None
-    if want_z or with_lse:
-        lse = jax.scipy.special.logsumexp(logits, axis=-1).astype(jnp.float32)
     if want_z:
+        lse = jax.scipy.special.logsumexp(logits, axis=-1).astype(jnp.float32)
         z_sum = jnp.sum(jnp.where(mask, jnp.square(lse), 0.0))
-    return loss_sum, z_sum, num_tokens, lse if with_lse else None
-
-
-def cross_entropy_terms(
-    logits: jax.Array,
-    labels: jax.Array,
-    upcast: bool = True,
-    want_z: bool = False,
-) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Token-level CE reduced to (loss_sum, z_sum, num_tokens).
-
-    ``z_sum`` is the PaLM-style z-loss numerator — sum over valid tokens of
-    ``logsumexp(logits)^2`` — computed only when `want_z` (an extra reduction over the
-    vocab axis otherwise). The single formula shared by the unchunked and chunked loss
-    paths, so their parity is summation-order-only (1-2 float32 ulp).
-    """
-    return _ce_terms(logits, labels, upcast, want_z)[:3]
+    return loss_sum, z_sum, num_tokens
 
 
 def cross_entropy_loss(
@@ -135,10 +125,11 @@ def causal_lm_loss(
 
 
 class LossTiling(NamedTuple):
-    """How the chunked loss's backward rule cuts the ``[tokens, vocab]`` logits it recomputes
-    (:func:`plan_loss_backward`). Static and hashable: it is resolved where the forward is
-    traced — a `custom_vjp`'s backward rule is traced later, outside the model's
-    logical-axis rules — and handed to the rule as a non-differentiable argument."""
+    """How the PER-TOKEN rule's backward (`_chunked_ce_token_terms`: the one rule that still
+    recomputes) cuts the ``[tokens, vocab]`` logits it forms again (:func:`plan_loss_backward`).
+    Static and hashable: it is resolved where the forward is traced — a `custom_vjp`'s
+    backward rule is traced later, outside the model's logical-axis rules — and handed to
+    the rule as a non-differentiable argument. The summed rule's plan is :class:`LossBlocks`."""
 
     token_blocks: int  # outer loop: blocks of whole forward chunks (every batch row of each)
     vocab_tiles: int  # inner scan: tiles of the vocabulary
@@ -154,14 +145,59 @@ class LossTiling(NamedTuple):
         return (n_chunks // self.token_blocks, batch, chunk, self.vocab_shards, self.tile_rows)
 
 
+class LossBlocks(NamedTuple):
+    """How the SUMMED rule's differentiated forward (`_chunked_ce_terms_fwd`) walks the
+    tokens (:func:`plan_loss_blocks`): `token_blocks` blocks of whole forward chunks, each
+    against the whole local vocabulary, its logits kept for the length of the block's body.
+    Static and hashable, resolved where the forward is traced, as :class:`LossTiling` is."""
+
+    token_blocks: int
+    batch_axes: tuple | str | None  # as `LossTiling`'s
+    vocab_axes: tuple | str | None
+    constrain: bool
+
+    def logits_block(self, batch: int, n_chunks: int, chunk: int, vocab: int) -> tuple[int, ...]:
+        """Shape of the logits a block keeps: ``[chunks a block, batch, chunk, V]``."""
+        return (n_chunks // self.token_blocks, batch, chunk, vocab)
+
+
+# What one token block's kept logits may take of a device's memory, in bytes: one packed row
+# of 4096 tokens against a 49152-row table in bfloat16 — the flagship's own head, the dense
+# benchmark cells' whole step — 4096 x 49152 x 2 = 384 MiB. Keeping a block costs 6 bytes an
+# element of HBM traffic (a write, two reads: 7 ps at a v5e's 819 GB/s); forming it again
+# costs 2 x H flops an element (26 ps at H 2560, 42 ps at H 4096, at peak), and the dense
+# cells read 11.8 of 15.75 GiB (PR 38's ledger lines). More tokens than the budget holds take
+# more blocks, and only then is anything carried (the table's float32 gradient, once a block).
+_KEPT_LOGITS_BYTES = 4096 * 49152 * 2
+
+
 def _divisors(n: int) -> list[int]:
     return [d for d in range(1, n + 1) if n % d == 0]
+
+
+def _loss_shards(batch: int, vocab: int) -> tuple[tuple | str | None, tuple | str | None, int, int, bool]:
+    """``(batch_axes, vocab_axes, batch_shards, vocab_shards, under a mesh)`` of the chunked
+    loss's logits under the ambient mesh and rules: tokens are sharded as "act_batch", the
+    vocabulary as "act_vocab"."""
+    from ..parallel.sharding import logical_spec
+
+    resolved = logical_spec((batch, vocab), ("act_batch", "act_vocab"))
+    if resolved is None:
+        return None, None, 1, 1, False
+    mesh, (batch_axes, vocab_axes) = resolved
+
+    def size(axes) -> int:
+        names = () if axes is None else (axes,) if isinstance(axes, str) else axes
+        return math.prod(mesh.shape[a] for a in names)
+
+    return batch_axes, vocab_axes, size(batch_axes), size(vocab_axes), True
 
 
 def plan_loss_backward(
     batch: int, n_chunks: int, chunk: int, vocab: int, hidden_size: int
 ) -> tuple[LossTiling, dict]:
-    """Choose the backward rule's tiling from the shapes the loss sees, and price it.
+    """Choose the PER-TOKEN rule's backward tiling from the shapes the loss sees, and price
+    it (the summed rule recomputes nothing: :func:`plan_loss_blocks`).
 
     The rule has two gradients to accumulate: the table's ``[V, H]``, summed over tokens,
     and the hidden states' ``[T, H]``, summed over the vocabulary. A loop over token blocks
@@ -181,21 +217,10 @@ def plan_loss_backward(
     the table every tile). A vocabulary the tile count does not divide is padded with rows
     that the rule masks out of the softmax.
 
-    Returns the tiling and the record of it the telemetry event ``loss_tiling`` carries.
+    Returns the tiling and the record of it the telemetry event ``loss_tiling`` carries
+    (``logits_products`` 2: the forward's and the rule's own).
     """
-    from ..parallel.sharding import logical_spec
-
-    batch_axes = vocab_axes = None
-    batch_shards = vocab_shards = 1
-    resolved = logical_spec((batch, vocab), ("act_batch", "act_vocab"))
-    if resolved is not None:
-        mesh, (batch_axes, vocab_axes) = resolved
-
-        def size(axes) -> int:
-            names = () if axes is None else (axes,) if isinstance(axes, str) else axes
-            return math.prod(mesh.shape[a] for a in names)
-
-        batch_shards, vocab_shards = size(batch_axes), size(vocab_axes)
+    batch_axes, vocab_axes, batch_shards, vocab_shards, constrain = _loss_shards(batch, vocab)
     tokens_local = batch * n_chunks * chunk // batch_shards
     vocab_local = vocab // vocab_shards
 
@@ -212,10 +237,10 @@ def plan_loss_backward(
         tile_rows = -(-tile_rows // 128) * 128  # padded anyway: keep the tile lane-aligned
         vocab_tiles = -(-vocab_local // tile_rows)
     tiling = LossTiling(
-        token_blocks, vocab_tiles, vocab_shards, tile_rows, batch_axes, vocab_axes,
-        resolved is not None,
+        token_blocks, vocab_tiles, vocab_shards, tile_rows, batch_axes, vocab_axes, constrain
     )
     record = dict(
+        logits_products=2,
         token_blocks=token_blocks,
         vocab_tiles=vocab_tiles,
         tile_rows=tile_rows,
@@ -229,6 +254,49 @@ def plan_loss_backward(
     return tiling, record
 
 
+def plan_loss_blocks(
+    batch: int, n_chunks: int, chunk: int, vocab: int, hidden_size: int, logits_itemsize: int
+) -> tuple[LossBlocks, dict]:
+    """Choose the SUMMED rule's token blocks from the shapes the loss sees, and price them.
+
+    The differentiated forward keeps one block's logits — ``[tokens a block, V_local]`` in
+    the compute dtype, `logits_itemsize` bytes an element, a device — from the product that
+    forms them to the two gradient products that consume them, under `_KEPT_LOGITS_BYTES`.
+    A single block carries nothing: both gradients leave their products once. More tokens
+    than one block holds take the fewest blocks (a divisor of ``n_chunks``: a block is whole
+    forward chunks, every batch row of each) that fit, and an outer loop carries the table's
+    float32 gradient, read and written once a block: ``token_blocks x 2 x V_local x H x 4``
+    bytes, so the fewest blocks also move the fewest accumulator bytes. The vocabulary is
+    not tiled (one product contracts a device's whole share of it) and no hidden-state
+    accumulator exists. Where even one chunk's logits pass the budget a block is one chunk:
+    what the undifferentiated scan holds anyway. Local sizes follow the ambient mesh and
+    rules as in :func:`plan_loss_backward`.
+
+    Returns the plan and the record of it the telemetry event ``loss_tiling`` carries
+    (``logits_products`` 1: nothing is formed again).
+    """
+    batch_axes, vocab_axes, batch_shards, vocab_shards, constrain = _loss_shards(batch, vocab)
+    tokens_local = batch * n_chunks * chunk // batch_shards
+    vocab_local = vocab // vocab_shards
+
+    def kept_bytes(token_blocks: int) -> int:
+        return tokens_local // token_blocks * vocab_local * logits_itemsize
+
+    token_blocks = next((d for d in _divisors(n_chunks) if kept_bytes(d) <= _KEPT_LOGITS_BYTES), n_chunks)
+    table_trips = 1 if token_blocks == 1 else 2 * token_blocks
+    record = dict(
+        logits_products=1,
+        token_blocks=token_blocks,
+        vocab_shards=vocab_shards,
+        tokens_per_device=tokens_local,
+        kept_logits_bytes=kept_bytes(token_blocks),
+        # float32 bytes of the one accumulator the outer loop carries, a device
+        table_carry_bytes=4 * hidden_size * vocab_local if token_blocks > 1 else 0,
+        accumulator_bytes_moved=4 * hidden_size * (table_trips * vocab_local + tokens_local),
+    )
+    return LossBlocks(token_blocks, batch_axes, vocab_axes, constrain), record
+
+
 @jax.named_scope("ce_chunk")  # a scan body starts with no name of its own in a profile
 def _chunk_ce_terms(
     h: jax.Array,
@@ -238,13 +306,11 @@ def _chunk_ce_terms(
     upcast: bool,
     compute_dtype,
     want_z: bool,
-) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+) -> tuple[jax.Array, jax.Array, jax.Array]:
     """One chunk's LM-head matmul + CE reduction, XLA reference lowering: ``(loss_sum,
-    z_sum, num_tokens, lse)`` with ``lse`` the chunk's per-token log-sum-exp in float32.
-
-    The chunk's ``[B, chunk, V]`` logits exist only inside this function.
-    """
-    return _ce_terms(_chunk_logits(h, table, logit_scale, compute_dtype), y, upcast, want_z, with_lse=True)
+    z_sum, num_tokens)``. The chunk's ``[B, chunk, V]`` logits exist only inside this
+    function."""
+    return cross_entropy_terms(_chunk_logits(h, table, logit_scale, compute_dtype), y, upcast, want_z)
 
 
 def _chunk_logits(h: jax.Array, table: jax.Array, logit_scale: float | None, compute_dtype) -> jax.Array:
@@ -284,9 +350,7 @@ def _chunk_token_terms(
 
 
 def _chunked_ce_forward(hidden_c, labels_c, table, logit_scale, upcast, compute_dtype, want_z):
-    """The forward scan over chunks: ``((loss_sum, z_sum, num_tokens), lse_c)`` with
-    ``lse_c [n_chunks, B, chunk]`` the float32 log-sum-exp of every token — all the
-    backward rule keeps of the logits."""
+    """The scan over chunks of the undifferentiated call: ``(loss_sum, z_sum, num_tokens)``."""
     from ..ops.pallas import use_pallas
 
     if use_pallas("fused_ce"):
@@ -294,8 +358,7 @@ def _chunked_ce_forward(hidden_c, labels_c, table, logit_scale, upcast, compute_
 
         def chunk_terms(h, y):
             return fused_ce_chunk(
-                h, table, y, logit_scale=logit_scale, upcast=upcast,
-                compute_dtype=compute_dtype, return_lse=True,
+                h, table, y, logit_scale=logit_scale, upcast=upcast, compute_dtype=compute_dtype
             )
     else:
 
@@ -303,12 +366,11 @@ def _chunked_ce_forward(hidden_c, labels_c, table, logit_scale, upcast, compute_
             return _chunk_ce_terms(h, table, y, logit_scale, upcast, compute_dtype, want_z)
 
     def body(carry, xs):
-        loss_sum, z_sum, num, lse = chunk_terms(*xs)
-        return (carry[0] + loss_sum, carry[1] + z_sum, carry[2] + num), lse
+        return tuple(a + t for a, t in zip(carry, chunk_terms(*xs))), None
 
     zero = jnp.zeros((), jnp.float32)
     with jax.named_scope("loss_chunks"):
-        return jax.lax.scan(body, (zero, zero, zero), (hidden_c, labels_c))
+        return jax.lax.scan(body, (zero, zero, zero), (hidden_c, labels_c))[0]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
@@ -319,44 +381,163 @@ def _chunked_ce_terms(
     logit_scale: float | None,
     upcast: bool,
     compute_dtype,
-    want_z: bool,
-    tiling: LossTiling,
-) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """(loss_sum, z_sum, num_tokens) over all chunks; at most one chunk's logits live.
+    z_loss_coef: float,
+    blocks: LossBlocks,
+) -> tuple[jax.Array, jax.Array]:
+    """``(loss_sum + z_loss_coef x z_sum, num_tokens)`` over all tokens: the SUMMED rule. The
+    coefficient is inside so that one gradient serves both terms; `num_tokens` has none.
 
-    Forward: a scan over sequence chunks (`_chunked_ce_forward`). The ``fused_ce`` kernel
+    Undifferentiated (evaluation, `jax.eval_shape`, this primal): a scan over sequence
+    chunks (`_chunked_ce_forward`), at most one chunk's logits live. The ``fused_ce`` kernel
     family dispatches there: on Pallas the per-chunk reduction runs
     `ops/pallas/fused_ce.fused_ce_chunk` (vocab-tiled online logsumexp — the chunk logits
-    never leave VMEM); the XLA reference scans `_chunk_ce_terms`.
+    never leave VMEM); the XLA reference scans `_chunk_ce_terms`. It forms no gradient and
+    needs no block of logits.
 
-    Backward: ONE rule for both backends (`_chunked_ce_terms_bwd`), so gradients depend on
-    the forward's backend only through the log-sum-exp it saved (1-2 float32 ulp apart).
-    Residuals are ``(hidden, labels, table, lse)``: the inputs plus one float per token,
-    nothing logits-sized. The rule recomputes the logits a tile at a time, forms
-    ``softmax - onehot`` from the saved log-sum-exp (no second reduction over the
-    vocabulary) and runs the two gradient matmuls. Which accumulator its loops carry is
-    `tiling`'s choice (:func:`plan_loss_backward`): the inner scan runs over vocabulary
-    tiles and carries the hidden states' gradient of one token block — each tile's table
-    gradient leaves one matmul that contracts over the block's every token, accumulated in
-    float32 inside the MXU as the unchunked reference's is, and is written once (the
-    scan's `ys`); only where one block may not hold all tokens does an outer loop over
-    token blocks carry the table's float32 gradient, once a block. (Before PR 25 the rule
-    scanned the forward's chunks and carried the whole float32 ``[V, H]`` gradient through
-    HBM once per `chunk` tokens: 1 GB a chunk at V 49152, H 2560.)
+    Differentiated (`_chunked_ce_terms_fwd`): the head's logits are computed ONCE. The
+    forward walks token blocks (`blocks`, :func:`plan_loss_blocks`); a block's
+    ``[tokens, V]`` logits leave one product in the compute dtype and are kept for the
+    length of the block's body — under `_KEPT_LOGITS_BYTES` a device — which reduces them
+    to the block's terms (a token's log-sum-exp minus its label's logit, as the per-token
+    forward has it: 1 ulp a token from the scan's log-softmax), forms ``d logits = softmax_coef x
+    softmax - label_coef x onehot`` for a UNIT cotangent (`_softmax_minus_onehot`, the
+    per-token rule's own expression) and runs the two gradient products with float32
+    accumulation. Residuals are the gradients themselves: ``d hidden`` (the size and dtype
+    of `hidden_c`, which is no residual any more) and ``d table`` (the table's dtype);
+    nothing logits-sized, no log-sum-exp. One block carries nothing; several carry the
+    table's float32 gradient once a block. The backward rule (`_chunked_ce_terms_bwd`) only
+    scales both by the cotangent. (PR 25 to PR 38 kept the log-sum-exp alone and formed
+    every logit again in the backward rule — a fourth product beside the three a head
+    needs — because the budget for live logits was one chunk's; the per-token rule,
+    `_chunked_ce_token_terms`, still does: its cotangents are a token's own and do not
+    exist when the forward runs.)
     """
-    return _chunked_ce_forward(
-        hidden_c, labels_c, table, logit_scale, upcast, compute_dtype, want_z
-    )[0]
+    loss_sum, z_sum, num_tokens = _chunked_ce_forward(
+        hidden_c, labels_c, table, logit_scale, upcast, compute_dtype, z_loss_coef != 0.0
+    )
+    return _objective(loss_sum, z_sum, z_loss_coef), num_tokens
+
+
+def _objective(loss_sum: jax.Array, z_sum: jax.Array, z_loss_coef: float) -> jax.Array:
+    return loss_sum + z_loss_coef * z_sum if z_loss_coef != 0.0 else loss_sum
+
+
+def _softmax_minus_onehot(
+    logits: jax.Array,  # [*tokens, *vocab]: scaled, in the dtype the product left them
+    y: jax.Array,  # [*tokens]
+    lse: jax.Array,  # [*tokens] float32
+    softmax_coef: jax.Array,  # [*tokens]: cotangent of a token's softmax row ...
+    label_coef: jax.Array,  # ... and of its label's logit; both 0 on IGNORE_INDEX rows
+    vocab_ids: jax.Array,  # [*vocab] int32: the vocabulary row of a logit
+    upcast: bool,
+) -> jax.Array:
+    """``softmax_coef x exp(logits - lse) - label_coef x onehot`` in the precision `upcast`
+    states: the gradient of a token's terms with respect to its logits, for both rules."""
+    if upcast:
+        logits = logits.astype(jnp.float32)
+    col = (..., *(None,) * vocab_ids.ndim)
+    lse, softmax_coef, label_coef = (
+        x.astype(logits.dtype)[col] for x in (lse, softmax_coef, label_coef)
+    )
+    return jnp.exp(logits - lse) * softmax_coef - jnp.where(vocab_ids == y[col], label_coef, 0)
 
 
 def _chunked_ce_terms_fwd(
-    hidden_c, labels_c, table, logit_scale, upcast, compute_dtype, want_z, tiling
+    hidden_c, labels_c, table, logit_scale, upcast, compute_dtype, z_loss_coef, blocks
 ):
-    terms, lse_c = _chunked_ce_forward(
-        hidden_c, labels_c, table, logit_scale, upcast, compute_dtype, want_z
-    )
-    # O(B*S*H + V*H + B*S): nothing logits-sized is saved
-    return terms, (hidden_c, labels_c, table, lse_c)
+    n_chunks = hidden_c.shape[0]
+    want_z = z_loss_coef != 0.0
+    specs = {
+        "hidden": PartitionSpec(None, blocks.batch_axes, None, None),
+        "logits": PartitionSpec(None, blocks.batch_axes, None, blocks.vocab_axes),
+        "table": PartitionSpec(blocks.vocab_axes, None),
+    }
+
+    def constrain(x, what):
+        return jax.lax.with_sharding_constraint(x, specs[what]) if blocks.constrain else x
+
+    # the table as `fused_linear_cross_entropy` pinned it, in its ACTIVATION layout: the
+    # gather (ZeRO-3) is done once, outside the walk
+    w = constrain(table, "table")
+    vocab_ids = jnp.arange(table.shape[0], dtype=jnp.int32)
+
+    @jax.named_scope("ce_block")  # a scan body starts with no name of its own in a profile
+    def block_terms_and_grads(h, y):
+        """One token block ``[c, B, chunk]``: its terms, ``d hidden`` and the float32
+        ``d table`` for a unit cotangent, from ONE product's logits."""
+        h = h.astype(compute_dtype)
+        with jax.named_scope("logits"):
+            logits = jax.lax.dot_general(h, w, (((3,), (1,)), ((), ())))  # [c, B, chunk, V]
+        # vocab-parallel ("act_vocab" -> tp) as the scan's chunks are: the reductions over
+        # the vocabulary end in a psum, the table is not gathered
+        logits = constrain(logits, "logits")
+        if logit_scale is not None:
+            logits = logits * logit_scale
+        # the block's terms, a token's as `_chunk_token_terms` forms them (log-sum-exp minus
+        # the label's logit) — with the label's logit gathered from the KEPT logits, before the
+        # upcast: `cross_entropy_terms` gathers from the float32 log-softmax, which XLA then writes out
+        # whole (768 MiB for this block's 384: the compiled head, PR 39); 1 ulp a token apart
+        valid = y != IGNORE_INDEX
+        picked = jnp.take_along_axis(logits, jnp.where(valid, y, 0)[..., None], axis=-1)[..., 0]
+        reduced = logits.astype(jnp.float32) if upcast else logits
+        lse = jax.scipy.special.logsumexp(reduced, axis=-1)
+        loss_sum = jnp.sum(jnp.where(valid, lse - picked.astype(lse.dtype), 0.0).astype(jnp.float32))
+        lse = lse.astype(jnp.float32)
+        z_sum = jnp.sum(jnp.where(valid, jnp.square(lse), 0.0)) if want_z else jnp.zeros((), jnp.float32)
+        num = jnp.sum(valid.astype(jnp.float32))
+        # d(loss_sum)/d(logits) = softmax - onehot and d(z_sum)/d(logits) = 2 lse softmax on
+        # valid rows (z_sum = sum lse^2): the coefficients of a unit cotangent
+        label_coef = jnp.where(valid, 1.0, 0.0)
+        softmax_coef = jnp.where(valid, 1.0 + 2.0 * z_loss_coef * lse, 0.0) if want_z else label_coef
+        dlogits = _softmax_minus_onehot(logits, y, lse, softmax_coef, label_coef, vocab_ids, upcast)
+        dlogits = dlogits.astype(h.dtype)
+        if logit_scale is not None:
+            dlogits = dlogits * logit_scale
+        dlogits = constrain(dlogits, "logits")
+        with jax.named_scope("grad_hidden"):
+            # contracts a device's whole share of the vocabulary: across "act_vocab" shards
+            # the partial sums are reduced once a block
+            dh = jax.lax.dot_general(
+                dlogits, w, (((3,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            )
+        with jax.named_scope("grad_table"):
+            dw = jax.lax.dot_general(
+                dlogits, h, (((0, 1, 2), (0, 1, 2)), ((), ())), preferred_element_type=jnp.float32
+            )
+        return (loss_sum, z_sum, num), constrain(dh, "hidden").astype(hidden_c.dtype), constrain(dw, "table")
+
+    with jax.named_scope("loss_chunks"):
+        if blocks.token_blocks == 1:
+            (loss_sum, z_sum, num_tokens), dh, dw = block_terms_and_grads(hidden_c, labels_c)
+        else:
+            # more tokens than one block may keep the logits of: the table's float32
+            # gradient is carried, once a block
+            def outer(carry, xs):
+                terms, dh, dw = block_terms_and_grads(*xs)
+                return (*(a + t for a, t in zip(carry[:3], terms)), carry[3] + dw), dh
+
+            zero = jnp.zeros((), jnp.float32)
+            per_block = [
+                x.reshape(blocks.token_blocks, n_chunks // blocks.token_blocks, *x.shape[1:])
+                for x in (hidden_c, labels_c)
+            ]
+            with jax.named_scope("token_blocks"):
+                (loss_sum, z_sum, num_tokens, dw), dh = jax.lax.scan(
+                    outer, (zero, zero, zero, constrain(jnp.zeros(table.shape, jnp.float32), "table")), per_block
+                )
+        dtable = constrain(dw.astype(table.dtype), "table")
+    # O(B*S*H + V*H): the two gradients for a unit cotangent, nothing logits-sized
+    return (_objective(loss_sum, z_sum, z_loss_coef), num_tokens), (dh.reshape(hidden_c.shape), dtable)
+
+
+def _chunked_ce_terms_bwd(logit_scale, upcast, compute_dtype, z_loss_coef, blocks, residuals, cts):
+    # two elementwise passes XLA fuses into their consumers; num_tokens has no gradient
+    ct = cts[0].astype(jnp.float32)
+    dh, dtable = ((g.astype(jnp.float32) * ct).astype(g.dtype) for g in residuals)
+    return dh, None, dtable
+
+
+_chunked_ce_terms.defvjp(_chunked_ce_terms_fwd, _chunked_ce_terms_bwd)
 
 
 @jax.named_scope("ce_tile")  # the backward scan's body: one vocabulary tile of one token block
@@ -382,13 +563,7 @@ def _tile_grads(
     logits = constrain(logits, "logits")
     if logit_scale is not None:
         logits = logits * logit_scale
-    if upcast:
-        logits = logits.astype(jnp.float32)
-    col = (..., None, None)
-    lse, softmax_coef, label_coef = (
-        x.astype(logits.dtype)[col] for x in (lse, softmax_coef, label_coef)
-    )
-    dlogits = jnp.exp(logits - lse) * softmax_coef - jnp.where(vocab_ids == y[col], label_coef, 0)
+    dlogits = _softmax_minus_onehot(logits, y, lse, softmax_coef, label_coef, vocab_ids, upcast)
     dlogits = jnp.where(vocab_ids >= 0, dlogits, 0).astype(h.dtype)
     if logit_scale is not None:
         dlogits = dlogits * logit_scale
@@ -407,24 +582,13 @@ def _tile_grads(
     return constrain(dh, "hidden"), constrain(dw, "tile")
 
 
-def _chunked_ce_terms_bwd(logit_scale, upcast, compute_dtype, want_z, tiling, residuals, cts):
-    def coefficients(valid, lse_c):
-        # d(loss_sum)/d(logits) = softmax - onehot and d(z_sum)/d(logits) = 2 lse softmax on
-        # valid rows (z_sum = sum lse^2); num_tokens has no gradient. Without `upcast` the
-        # forward's terms are compute-dtype: the coefficients are cast where they are used
-        ct_loss, ct_z = (ct.astype(jnp.float32) for ct in cts[:2])
-        label_coef = jnp.where(valid, ct_loss, 0.0)
-        softmax_coef = label_coef
-        if want_z:
-            softmax_coef = jnp.where(valid, ct_loss + 2.0 * ct_z * lse_c, 0.0)
-        return softmax_coef, label_coef
-
-    return _chunked_ce_grads(residuals, coefficients, logit_scale, upcast, tiling)
-
-
 def _chunked_ce_grads(residuals, coefficients, logit_scale, upcast, tiling):
-    """The backward rule's walk, for the summed terms and the per-token ones alike:
-    ``(d hidden_c, None, d table)`` from the residuals and ``coefficients(valid, lse_c) ->
+    """The per-token rule's backward walk, which forms the logits again a vocabulary tile at
+    a time (`tiling`, :func:`plan_loss_backward`: the inner scan over tiles carries a token
+    block's ``d hidden``, each tile's table gradient leaves one matmul and is written once,
+    an outer scan over token blocks carries the table's float32 gradient only where one block
+    may not hold all tokens): ``(d hidden_c, None, d table)`` from the residuals
+    ``(hidden, labels, table, lse)`` and ``coefficients(valid, lse_c) ->
     (softmax_coef, label_coef)``, each ``[n_chunks, B, chunk]`` float32 and 0 on IGNORE_INDEX
     rows: ``d logits = softmax_coef x softmax - label_coef x onehot`` a token."""
     hidden_c, labels_c, table, lse_c = residuals
@@ -502,9 +666,6 @@ def _chunked_ce_grads(residuals, coefficients, logit_scale, upcast, tiling):
         return dh.reshape(hidden_c.shape), None, constrain(dtable, "table")
 
 
-_chunked_ce_terms.defvjp(_chunked_ce_terms_fwd, _chunked_ce_terms_bwd)
-
-
 def _chunked_ce_token_forward(hidden_c, labels_c, table, logit_scale, upcast, compute_dtype):
     """The forward scan over chunks that keeps every token's terms apart: ``(loss_c, lse_c)``,
     both ``[n_chunks, B, chunk]`` float32."""
@@ -538,11 +699,13 @@ def _chunked_ce_token_terms(
     compute_dtype,
     tiling: LossTiling,
 ) -> tuple[jax.Array, jax.Array]:
-    """`_chunked_ce_terms` with nothing summed over tokens: every token's cross-entropy and
-    log-sum-exp, so that a caller may weigh tokens (and learn the weights: the weight's
-    gradient is the token's own term, which autodiff of the caller's product gives). The same
-    residuals, the same backward walk (`_chunked_ce_grads`); a token's cotangents take the
-    place the summed rule's scalars had."""
+    """The PER-TOKEN rule: `_chunked_ce_terms` with nothing summed over tokens — every token's
+    cross-entropy and log-sum-exp, so that a caller may weigh tokens (and learn the weights:
+    the weight's gradient is the token's own term, which autodiff of the caller's product
+    gives). Its cotangents are a token's own and arrive after the forward, so nothing can be
+    formed there: the residuals are ``(hidden, labels, table)`` and the tokens' float32
+    log-sum-exp — one float a token, nothing logits-sized — and the backward walk
+    (`_chunked_ce_grads`) recomputes the logits."""
     return _chunked_ce_token_terms_fwd(hidden_c, labels_c, table, logit_scale, upcast, compute_dtype, tiling)[0]
 
 
@@ -576,31 +739,44 @@ def fused_linear_cross_entropy(
     z_loss_coef: float = 0.0,
     weights: jax.Array | None = None,
 ) -> jax.Array:
-    """LM-head matmul + CE without ever materializing the [B, S, V] logits.
+    """LM-head matmul + CE without the float32 [B, S, V] logits of the plain path.
 
-    Forward: the sequence axis is cut into chunks of `chunk_size`; a `lax.scan` computes
-    each chunk's logits ([B, chunk, V]), reduces them to (loss_sum, z_sum, count) and the
-    tokens' log-sum-exp, and discards them. The whole reduction sits behind a `custom_vjp`
-    whose residuals are (hidden, labels, table) and that one float per token.
+    Undifferentiated (evaluation): the sequence axis is cut into chunks of `chunk_size`; a
+    `lax.scan` computes each chunk's logits ([B, chunk, V]), reduces them to (loss_sum,
+    z_sum, count) and discards them: peak logits memory is O(chunk).
 
-    Backward: the rule recomputes logits under the same budget — `chunk_size` x V elements
-    a batch row — but cuts them the other way where that is cheaper: all tokens (or a
-    block of them) against a TILE of the vocabulary, so that the loop carries the hidden
-    states' float32 gradient ([tokens, H]) instead of the table's ([V, H], read and
-    written once an iteration). The tiling follows the shapes — tokens and vocabulary rows
-    a device holds, `chunk_size` — through :func:`plan_loss_backward`; there is no knob.
-    When a telemetry is installed the choice is written once as a ``loss_tiling`` event.
+    Differentiated, without `weights` (the SUMMED rule, `_chunked_ce_terms`: what every model
+    but a looped one trains through): the logits are computed once. The forward walks token
+    blocks of whole chunks; a block's logits ([chunks a block, B, chunk, V], compute dtype)
+    are kept from the product that forms them to the two gradient products — d hidden and
+    d table, float32 accumulation — that the same forward runs for a unit cotangent, then
+    dropped. The `custom_vjp`'s residuals are those two gradients (the size of `hidden` and
+    of the table) and its backward rule only scales them. What a block may keep is a
+    constant a device, `_KEPT_LOGITS_BYTES`: one 4096-token row against a 49152-row table
+    in bf16, 384 MiB — memory traded for the product that formed every logit a second
+    time. The blocks follow the shapes — tokens and vocabulary rows a device holds,
+    `chunk_size` — through :func:`plan_loss_blocks`; there is no knob. One block carries
+    nothing; several carry the table's float32 gradient once a block.
 
-    Peak logits memory is O(chunk) in both directions and drops S/chunk_size-fold (at seq
-    2048 / vocab 50k the full tensor is the single largest allocation in a train step).
+    Differentiated with `weights`, or through `fused_linear_token_cross_entropy` (the
+    PER-TOKEN rule, `_chunked_ce_token_terms`): the cotangents are a token's own and arrive
+    after the forward, so the residuals are (hidden, labels, table) and one float a token,
+    and the backward rule RECOMPUTES the logits under the scan's budget — `chunk_size` x V
+    elements a batch row — all tokens (or a block of them) against a TILE of the vocabulary
+    (:func:`plan_loss_backward`).
+
+    When a telemetry is installed either choice is written once as a ``loss_tiling`` event
+    (``logits_products`` 1 or 2, ``token_blocks``, the bytes kept and carried).
+
     The reference has no counterpart (it materializes logits and calls F.cross_entropy,
     `model_wrapper/pretraining.py:89-127`); this is the TPU/HBM-side answer to that cost
-    — the same move as Liger-kernel's chunked fused CE on GPU.
+    — the same move as Liger-kernel's chunked fused CE on GPU, which also forms the
+    gradients where it forms the logits.
 
-    With the ``fused_ce`` kernel family on Pallas the per-chunk forward reduction
-    additionally runs as a vocab-tiled online-logsumexp kernel (`ops/pallas/fused_ce.py`)
-    whose logits tiles never leave VMEM; the backward rule is the same for both (see
-    `_chunked_ce_terms`).
+    With the ``fused_ce`` kernel family on Pallas the per-chunk forward reduction of the
+    undifferentiated call and of the per-token rule runs as a vocab-tiled online-logsumexp
+    kernel (`ops/pallas/fused_ce.py`) whose logits tiles never leave VMEM; the summed rule's
+    differentiated forward keeps its logits and is XLA's on every backend.
 
     hidden: [B, S, H]; embedding: [V, H] (tied-embedding layout); labels: [B, S] with
     IGNORE_INDEX. Chunking is along sequence, so dp/fsdp/ep batch sharding is untouched.
@@ -609,10 +785,8 @@ def fused_linear_cross_entropy(
     ``weights`` ([B, S], differentiable) weighs every token's terms: the loss is
     ``sum_i w_i (l_i + coef lse_i^2) / count of labels``, the gradients of the hidden states
     and of the table are scaled token by token by ``w_i``, and ``d loss / d w_i`` is the
-    token's own term over the count (`fused_linear_token_cross_entropy`: the same chunks,
-    the same backward walk, the tokens' terms kept apart). Without weights nothing of that
-    is traced: the program is what it was (tests/ops/test_loss_token_weights.py holds its
-    jaxpr's hash).
+    token's own term over the count. Each rule's program is held by a hash of its jaxpr
+    (tests/ops/test_loss_token_weights.py): a change to one must not move the other.
     """
     if weights is not None:
         token_loss, lse = fused_linear_token_cross_entropy(
@@ -624,15 +798,12 @@ def fused_linear_cross_entropy(
         weighed = jnp.sum(jnp.where(valid, weights.astype(jnp.float32) * token_loss, 0.0))
         return weighed / jnp.maximum(jnp.sum(valid.astype(jnp.float32)), 1.0)
 
-    hidden_c, labels_c, emb, tiling = _chunked_operands(hidden, embedding, labels, chunk_size, compute_dtype)
-    loss_sum, z_sum, num_tokens = _chunked_ce_terms(
-        hidden_c, labels_c, emb, logit_scale, upcast, compute_dtype, z_loss_coef != 0.0, tiling
+    hidden_c, labels_c, emb = _chunked_operands(hidden, embedding, labels, chunk_size, compute_dtype)
+    blocks = _planned(plan_loss_blocks(*_loss_shapes(hidden_c, emb), jnp.dtype(compute_dtype).itemsize))
+    objective, num_tokens = _chunked_ce_terms(
+        hidden_c, labels_c, emb, logit_scale, upcast, compute_dtype, z_loss_coef, blocks
     )
-    denom = jnp.maximum(num_tokens, 1.0)
-    loss = loss_sum / denom
-    if z_loss_coef != 0.0:
-        loss = loss + z_loss_coef * (z_sum / denom)
-    return loss
+    return objective / jnp.maximum(num_tokens, 1.0)
 
 
 def fused_linear_token_cross_entropy(
@@ -651,7 +822,8 @@ def fused_linear_token_cross_entropy(
     as there, forward and backward. For a loss that weighs tokens by something that learns
     (a looped model's exit gate: `models/ouro.py`)."""
     S = hidden.shape[1]
-    hidden_c, labels_c, emb, tiling = _chunked_operands(hidden, embedding, labels, chunk_size, compute_dtype)
+    hidden_c, labels_c, emb = _chunked_operands(hidden, embedding, labels, chunk_size, compute_dtype)
+    tiling = _planned(plan_loss_backward(*_loss_shapes(hidden_c, emb)))
     loss_c, lse_c = _chunked_ce_token_terms(hidden_c, labels_c, emb, logit_scale, upcast, compute_dtype, tiling)
     rows = lambda x: x.swapaxes(0, 1).reshape(x.shape[1], -1)[:, :S]  # noqa: E731
     return rows(loss_c), rows(lse_c)
@@ -659,9 +831,8 @@ def fused_linear_token_cross_entropy(
 
 def _chunked_operands(hidden, embedding, labels, chunk_size: int, compute_dtype):
     """``(hidden_c [n_chunks, B, chunk, H], labels_c [n_chunks, B, chunk], the table in its
-    activation layout, the backward rule's tiling)`` of one call of the chunked loss."""
+    activation layout)`` of one call of the chunked loss."""
     from ..parallel.sharding import logical_constraint
-    from ..utils.telemetry import get_telemetry
 
     B, S, H = hidden.shape
     chunk_size = min(chunk_size, S)
@@ -678,14 +849,27 @@ def _chunked_operands(hidden, embedding, labels, chunk_size: int, compute_dtype)
     labels_c = labels.reshape(B, n_chunks, chunk_size).swapaxes(0, 1)
 
     # Pin the table to its ACTIVATION layout (vocab over tp only; replicated otherwise)
-    # once, here: under ZeRO-3 the tied table arrives fsdp-sharded, and both scans and the
-    # backward rule (whose residual this is: it gathers nothing again) compute with the
-    # gathered one — ZeRO-3's gather/compute/scatter contract, the scatter being the
-    # transpose of this constraint.
+    # once, here: under ZeRO-3 the tied table arrives fsdp-sharded, and the scan, the
+    # summed rule's block walk and the per-token backward rule (whose residual this is: it
+    # gathers nothing again) compute with the gathered one — ZeRO-3's gather/compute/scatter
+    # contract, the scatter being the transpose of this constraint.
     emb = logical_constraint(embedding.astype(compute_dtype), ("act_vocab", None))
-    tiling, record = plan_loss_backward(B, n_chunks, chunk_size, emb.shape[0], H)
+    return hidden_c, labels_c, emb
+
+
+def _loss_shapes(hidden_c: jax.Array, table: jax.Array) -> tuple[int, int, int, int, int]:
+    """``(batch, n_chunks, chunk, vocab, hidden_size)``: what either plan is made from."""
+    n_chunks, batch, chunk, hidden_size = hidden_c.shape
+    return batch, n_chunks, chunk, table.shape[0], hidden_size
+
+
+def _planned(plan_and_record):
+    """A rule's plan, said once a trace as the ``loss_tiling`` event."""
+    from ..utils.telemetry import get_telemetry
+
+    plan, record = plan_and_record
     get_telemetry().event_once("loss_tiling", **record)
-    return hidden_c, labels_c, emb, tiling
+    return plan
 
 
 def load_balancing_loss(

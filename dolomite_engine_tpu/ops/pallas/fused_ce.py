@@ -13,10 +13,12 @@ Numerics: the tile matmul accumulates fp32 (``preferred_element_type``); a non-f
 ``compute_dtype`` is round-tripped through that dtype after the dot so the tile sees the
 same quantized logits as the XLA reference's ``compute_dtype`` matmul. The online
 max/sum recurrence reassociates the reduction, so parity vs the reference is 1-2 float32
-ulp (asserted in tier-1), not bitwise. Gradients never run this kernel: the chunked
-loss's `custom_vjp` has one backward rule for both forward backends, which takes from the
-forward only the per-token log-sum-exp this kernel also returns
-(`ops/loss._chunked_ce_terms`).
+ulp (asserted in tier-1), not bitwise. Gradients never run this kernel. The per-token
+rule's backward (`ops/loss._chunked_ce_token_terms`) is one for both forward backends and
+takes from the forward only the per-token log-sum-exp this kernel also returns; the summed
+rule (`ops/loss._chunked_ce_terms`) runs the kernel in its undifferentiated call alone —
+differentiated, its forward keeps a token block's logits to form the gradients over them
+(PR 39), which is the opposite of this kernel's point, and is XLA's on every backend.
 """
 
 from __future__ import annotations
@@ -177,11 +179,8 @@ def fused_ce_chunk(
     upcast: bool,
     compute_dtype,
     interpret: bool | None = None,
-    return_lse: bool = False,
-) -> tuple:
-    """One chunk's (loss_sum, z_sum, num_tokens) via the vocab-tiled kernel and,
-    `return_lse`, the tokens' float32 log-sum-exp ``[B, chunk]`` as a fourth (the
-    residual the chunked loss's backward rule forms its softmax from).
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """One chunk's (loss_sum, z_sum, num_tokens) via the vocab-tiled kernel.
 
     Drop-in for `ops/loss._chunk_ce_terms` on the forward pass: h [B, chunk, H],
     table [V, H], y [B, chunk]. The kernel always reduces in fp32, which matches the
@@ -204,4 +203,4 @@ def fused_ce_chunk(
     loss_sum = jnp.sum(jnp.where(mask, lse - lab, 0.0))
     z_sum = jnp.sum(jnp.where(mask, jnp.square(lse), 0.0))
     num = jnp.sum(mask.astype(jnp.float32))
-    return (loss_sum, z_sum, num) + ((lse.reshape(y.shape),) if return_lse else ())
+    return loss_sum, z_sum, num

@@ -316,9 +316,13 @@ KNOWN_EVENTS: tuple[str, ...] = (
     "anomaly",
     # one XLA backend compilation: seconds, program (the jitted function's name), step
     "compile",
-    # the tiling the chunked loss's backward rule chose where the step was traced
-    # (ops/loss.plan_loss_backward): token_blocks x vocab_tiles, tile_rows, vocab_shards,
-    # tokens_per_device, and the float32 bytes of the accumulators its loops carry
+    # how a call of the chunked loss engaged where the step was traced. The summed rule
+    # (ops/loss.plan_loss_blocks): logits_products 1 — the gradients are formed in the
+    # differentiated forward —, token_blocks, kept_logits_bytes a device (one block's logits),
+    # table_carry_bytes (the float32 gradient an outer loop carries; 0 at one block). The
+    # per-token rule (ops/loss.plan_loss_backward): logits_products 2 — its backward forms
+    # the logits again —, token_blocks x vocab_tiles, tile_rows, hidden_carry_bytes,
+    # table_carry_bytes. Both: vocab_shards, tokens_per_device, accumulator_bytes_moved
     "loss_tiling",
     # how the remat policy engaged where the model was traced (models/gpt_dolomite.remat_plan):
     # policy, checkpoint_every, the checkpoint_name tags it keeps, blocks and how many sit

@@ -364,7 +364,11 @@ def test_the_lowered_step_is_what_it_was_before_a_third_family_shared_its_expert
     products walks blocks of rows up to the last routed one (`ops/moe._activate_rows`) and the
     group sizes are read off the sorted keys, so the only operations that differ stand under
     `moe_dispatch` and `moe_experts` or in the unnamed helpers called from there (8862 lines
-    before); the parameter tree's hash is the one PR 33 took."""
+    before); PR 39 did a third time — the head's logits are computed once, in both passes through
+    the head: the chunked loss's summed rule forms both gradients in its differentiated forward
+    over a token block's kept logits and its backward rule only scales them
+    (`ops/loss._chunked_ce_terms`), so what differs stands under `head_loss` (9264 lines before);
+    the parameter tree's hash is the one PR 33 took."""
     import hashlib
 
     from dolomite_engine_tpu.distributed import TrainState
@@ -392,5 +396,5 @@ def test_the_lowered_step_is_what_it_was_before_a_third_family_shared_its_expert
     ).as_text()
     tree = str(jax.tree.map(lambda a: (a.shape, str(a.dtype)), state.params))
     assert hashlib.sha256(tree.encode()).hexdigest() == "4f3c47802e75a87cbdc4288b995f51a06c50485f568ee733f27eb4296b8d833b"
-    assert len(text.splitlines()) == 9264
-    assert hashlib.sha256(text.encode()).hexdigest() == "a3baaca4eee4139a33ab4fd95f710cf8305b8e576a3b158ac26d6266d47b5bae"
+    assert len(text.splitlines()) == 8979
+    assert hashlib.sha256(text.encode()).hexdigest() == "c86fd38c4d2ebde4f9169ec711ea30c7a3bb05972644b0b649ba51eef1995b9a"

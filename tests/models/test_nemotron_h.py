@@ -139,7 +139,10 @@ def test_the_tower_is_what_it_was_before_its_expert_layer_moved():
     the grouped products walks blocks of rows up to the last routed one (`ops/moe._activate_rows`)
     and the group sizes are read off the sorted keys, so the only operations that differ stand under
     `moe_dispatch` and `moe_experts` or in the unnamed helpers called from there (6970 lines
-    before); the parameter tree's hash is the first."""
+    before); PR 39 did a third time — the head's logits are computed once: the chunked loss's
+    summed rule forms both gradients in its differentiated forward over a token block's kept
+    logits and its backward rule only scales them (`ops/loss._chunked_ce_terms`), so what differs
+    stands under `head_loss` (7270 lines before); the parameter tree's hash is the first."""
     import hashlib
 
     from dolomite_engine_tpu.distributed import TrainState
@@ -167,8 +170,8 @@ def test_the_tower_is_what_it_was_before_its_expert_layer_moved():
     ).as_text()
     tree = str(jax.tree.map(lambda a: (a.shape, str(a.dtype)), state.params))
     assert hashlib.sha256(tree.encode()).hexdigest() == "36990d468b39e5c040180d1e45a25dac74eeeb1d513dc6bc37c03b12fe9fca5b"
-    assert len(text.splitlines()) == 7270
-    assert hashlib.sha256(text.encode()).hexdigest() == "553c0684785df58d7e7807f9f4fc3495ade20eabc9fda04d0db0b0fa26ae19a0"
+    assert len(text.splitlines()) == 7104
+    assert hashlib.sha256(text.encode()).hexdigest() == "dfec910f7fc86d2ca344ad7ad9e65ee52dde2439295c3b1aa2d148931e9bc880"
     from dolomite_engine_tpu.models import nemotron_h, shared_expert_moe
 
     assert nemotron_h.SharedExpertMoE is shared_expert_moe.SharedExpertMoE is SharedExpertMoE

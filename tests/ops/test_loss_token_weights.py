@@ -1,9 +1,10 @@
 """The chunked head loss with per-token weights (`ops/loss.fused_linear_cross_entropy(weights=)`,
 `fused_linear_token_cross_entropy`): values and every gradient against a plain ``jnp`` loss;
 the gradient with respect to a weight is that token's own loss; unit weights are the weightless
-loss; the Pallas `fused_ce` family takes the weights too; and — the dense cells run it — the
-weightless call traces to the program it traced to before the weights existed (the sha256 of
-its jaxpr, forward and backward, taken at the parent commit d345296).
+loss; the Pallas `fused_ce` family takes the weights too; and the two rules' programs are held
+apart by the sha256 of their jaxprs, forward and backward: the per-token rule's (what Ouro's gate
+learns through) taken at PR 39's parent dd1a9eb and equal on both sides of it, the weightless
+call's taken anew by PR 39 on purpose (its differentiated forward now forms the gradients).
 
 Tolerances: float32 on both sides, another order of summation: 1e-6 relative on a loss, 1e-6
 absolute on gradients of size ~0.1."""
@@ -103,14 +104,18 @@ def weightless_jaxpr(z, upcast) -> str:
 @pytest.mark.parametrize(
     "z, upcast, sha256",
     [
-        (0.0, True, "09d77a4a953840aefa68f344cf045c2b357751192662ccbe8299f7ad81419f82"),
-        (1e-4, False, "04b3cf904ba1ebedef0862c702db81ffde47ce0ce4b9e02bf9e97f5824442673"),
+        (0.0, True, "50c8a0acfc98768b3922d80d2a7e2ffbac722ec8dcfff7b97fdf6f3e9fe01bff"),
+        (1e-4, False, "5be3a811acd1349eba5d45b71f43a0e65e58aaddfc94f6997c3503a7fa5edd95"),
     ],
     ids=["upcast", "z_loss_compute_dtype"],
 )
 def test_the_weightless_call_traces_to_the_program_it_traced_to_before(z, upcast, sha256):
-    """Loss and both gradients of the weightless call, as a jaxpr's text, hashed at the parent
-    commit (d345296, this installation's jax): the dense cells' program did not move."""
+    """Loss and both gradients of the weightless call, as a jaxpr's text, hashed on this
+    installation's jax. **PR 39 replaced both hashes on purpose** (d345296's were 09d77a4a...
+    and 04b3cf90...): the summed rule's differentiated forward keeps a token block's logits and
+    forms the gradients there, its backward rule only scales them — the dense and expert
+    cells' head is another program, one product shorter. A later PR that moves these moves
+    five of the six cells' step."""
     text = weightless_jaxpr(z, upcast)
     assert hashlib.sha256(text.encode()).hexdigest() == sha256
     # ... and a weighted call is another program (the hash is not blind)
@@ -119,3 +124,32 @@ def test_the_weightless_call_traces_to_the_program_it_traced_to_before(z, upcast
         jnp.zeros((2, 24, 16), jnp.bfloat16), jnp.zeros((40, 16), jnp.float32), jnp.ones((2, 24))
     ))
     assert weighted != text
+
+
+def per_token_jaxpr(upcast, logit_scale, through_weights) -> str:
+    hidden, table, labels = jnp.zeros((2, 24, 16), jnp.bfloat16), jnp.zeros((40, 16), jnp.float32), jnp.zeros((2, 24), jnp.int32)
+
+    def weighed(h, t, w):
+        if through_weights:
+            return fused_linear_cross_entropy(h, t, labels, chunk_size=8, compute_dtype=jnp.bfloat16, z_loss_coef=1e-4, weights=w)
+        loss, lse = fused_linear_token_cross_entropy(h, t, labels, chunk_size=8, upcast=upcast, logit_scale=logit_scale, compute_dtype=jnp.bfloat16)
+        return jnp.sum(w * (loss + 1e-4 * lse**2))
+
+    return str(jax.make_jaxpr(jax.value_and_grad(weighed, argnums=(0, 1, 2)))(hidden, table, jnp.ones((2, 24), jnp.float32)))
+
+
+@pytest.mark.parametrize(
+    "upcast, logit_scale, through_weights, sha256",
+    [
+        (True, None, False, "8b2bf59289b137982835460edcacfd9a6b06e3c1002867c915e5d5b9fdf70100"),
+        (False, 0.5, False, "59a2351871b50a4bccc3221d0e4ef314c576d99c6a8731397db6c07dab4151a0"),
+        (True, None, True, "856394d40a095f123684472233c26ad73aa4ee011af4e9463c5749a511d53cd3"),
+    ],
+    ids=["token_terms_upcast", "token_terms_compute_dtype_logit_scale", "weights"],
+)
+def test_the_per_token_rule_traces_to_the_program_it_traced_to_before(upcast, logit_scale, through_weights, sha256):
+    """The per-token rule — every token's terms handed out, a token's own cotangents taken,
+    the logits recomputed a vocabulary tile at a time — with its three gradients, as a jaxpr's
+    text hashed at PR 39's parent (dd1a9eb, this installation's jax): the looped cell's head is
+    the program it was while the summed rule beside it changed."""
+    assert hashlib.sha256(per_token_jaxpr(upcast, logit_scale, through_weights).encode()).hexdigest() == sha256

@@ -244,6 +244,184 @@ def test_plan_loss_backward_follows_the_shapes(batch, n_chunks, chunk, vocab, ex
     assert record["table_carry_bytes"] == (0 if tiling.token_blocks == 1 else 4 * hidden_size * tiling.vocab_tiles * tiling.tile_rows)
 
 
+# ---- the summed rule (PR 39): the head's logits are computed once, the differentiated forward
+# forms both gradients over a token block's kept logits, the backward rule only scales them
+
+_SUMMED_NUMERICS = {
+    "fp32": dict(),
+    "fp32_z_loss": dict(z_loss_coef=1e-3),
+    "fp32_logit_scale_z_loss": dict(logit_scale=0.125, z_loss_coef=1e-3),
+    "bf16": dict(compute_dtype=jnp.bfloat16),
+    "bf16_z_loss_logit_scale": dict(compute_dtype=jnp.bfloat16, z_loss_coef=1e-3, logit_scale=0.5),
+    "bf16_no_upcast": dict(compute_dtype=jnp.bfloat16, upcast=False),
+    "bf16_no_upcast_z_loss": dict(compute_dtype=jnp.bfloat16, upcast=False, z_loss_coef=1e-3),
+}
+
+
+def _summed_operands(B=3, S=22, H=16, V=53, seed=0):
+    """A sequence no chunk of 8 divides, a vocabulary no tile divides (a prime), rows without
+    a label at both ends of a row and across a chunk's edge."""
+    rng = np.random.RandomState(seed)
+    hidden = jnp.asarray(rng.randn(B, S, H), jnp.float32)
+    table = jnp.asarray(rng.randn(V, H) * 0.3, jnp.float32)
+    labels = rng.randint(0, V, size=(B, S))
+    labels[0, -1] = labels[-1, 0] = labels[1, 6:10] = -100
+    return hidden, table, jnp.asarray(labels, jnp.int32)
+
+
+def _unchunked_loss(hidden, table, labels, compute_dtype=jnp.float32, logit_scale=None, upcast=True, z_loss_coef=0.0):
+    logits = jnp.dot(hidden.astype(compute_dtype), table.astype(compute_dtype).T)
+    if logit_scale is not None:
+        logits = logits * logit_scale
+    return causal_lm_loss(logits, jnp.zeros(labels.shape, jnp.int32), labels=labels, z_loss_coef=z_loss_coef, upcast=upcast)
+
+
+def _assert_follows(got, want, compute_dtype, upcast=True):
+    """A loss and both gradients against the unchunked reference's: float32 to an ulp or two
+    (another order of summation); bf16 within 2 bf16 ulp of the gradient's largest entry (the
+    reference rounds its own gradients to bf16), the band the tiled rule had."""
+    (loss, grads), (ref_loss, ref_grads) = got, want
+    fp32 = jnp.dtype(compute_dtype) == jnp.float32
+    np.testing.assert_allclose(float(loss), float(ref_loss), rtol=0, atol=2e-6 if upcast else 0.1)
+    for g, r in zip(grads, ref_grads):
+        atol = 2e-7 if fp32 else 2 * 2.0**-7 * float(jnp.max(jnp.abs(r)))
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r), rtol=0, atol=atol)
+
+
+@pytest.mark.parametrize("numerics", list(_SUMMED_NUMERICS))
+def test_summed_rule_follows_the_unchunked_loss(numerics):
+    """Value and both gradients of `fused_linear_cross_entropy` without weights — the rule whose
+    differentiated forward keeps a token block's logits and forms the gradients there — against
+    `causal_lm_loss` on whole logits, in float32 and bf16, with and without the z-loss,
+    `logit_scale` and `upcast`; and the undifferentiated call (the chunk scan) gives the
+    differentiated forward's value."""
+    from dolomite_engine_tpu.ops.loss import fused_linear_cross_entropy
+
+    options = _SUMMED_NUMERICS[numerics]
+    hidden, table, labels = _summed_operands()
+    fused = lambda h, t: fused_linear_cross_entropy(h, t, labels, chunk_size=8, **{"compute_dtype": jnp.float32, **options})  # noqa: E731
+    got = jax.value_and_grad(fused, argnums=(0, 1))(hidden, table)
+    want = jax.value_and_grad(lambda h, t: _unchunked_loss(h, t, labels, **options), argnums=(0, 1))(hidden, table)
+    _assert_follows(got, want, options.get("compute_dtype", jnp.float32), options.get("upcast", True))
+    # the scan sums a chunk at a time, the block all at once: an order of summation apart
+    np.testing.assert_allclose(float(fused(hidden, table)), float(got[0]), rtol=0, atol=2e-6 if options.get("upcast", True) else 0.1)
+
+
+def test_summed_rule_scales_its_kept_gradients_by_the_cotangent():
+    """The backward rule only scales: a loss times 3 (gradient accumulation, an auxiliary
+    head's weight) has 3 times the gradients, to float32's rounding of one product."""
+    from dolomite_engine_tpu.ops.loss import fused_linear_cross_entropy
+
+    hidden, table, labels = _summed_operands()
+    fused = lambda h, t: fused_linear_cross_entropy(h, t, labels, chunk_size=8, compute_dtype=jnp.float32, z_loss_coef=1e-3)  # noqa: E731
+    once = jax.grad(fused, argnums=(0, 1))(hidden, table)
+    thrice = jax.grad(lambda h, t: 3.0 * fused(h, t), argnums=(0, 1))(hidden, table)
+    for a, b in zip(once, thrice):
+        np.testing.assert_allclose(3.0 * np.asarray(a), np.asarray(b), rtol=2e-7, atol=0)
+
+
+@pytest.mark.parametrize(
+    "batch, n_chunks, chunk, vocab, itemsize, expected",
+    [
+        (1, 16, 256, 49152, 2, (1, 384 * 2**20)),  # the dense cells: one packed row of 4096, the budget itself
+        (4, 16, 256, 49152, 2, (4, 384 * 2**20)),  # the shipped 3B job's micro batch: a row's worth a block
+        (2, 32, 256, 16384, 2, (2, 256 * 2**20)),  # the 8k cells' heads at an eighth of the vocabulary
+        (1, 16, 256, 49152, 4, (2, 384 * 2**20)),  # float32 logits: half the tokens a block
+        (1, 128, 256, 49152, 2, (8, 384 * 2**20)),  # one row of 32768 tokens: long context
+        (64, 16, 256, 49152, 2, (16, 1536 * 2**20)),  # one chunk passes the budget: a block is the scan's chunk
+        (1, 16, 256, 50257, 2, (2, 2048 * 50257 * 2)),  # a prime vocabulary: nothing is padded, nothing tiled
+        (2, 3, 7, 211, 4, (1, 2 * 3 * 7 * 211 * 4)),
+    ],
+)
+def test_plan_loss_blocks_follows_the_shapes(batch, n_chunks, chunk, vocab, itemsize, expected):
+    """The summed rule's blocks are chosen from the shapes alone: the fewest that keep a
+    block's logits under the budget in bytes, a divisor of the chunks; one block carries
+    nothing, several carry the table's float32 gradient once a block."""
+    from dolomite_engine_tpu.ops.loss import _KEPT_LOGITS_BYTES, plan_loss_blocks
+
+    hidden_size = 64
+    blocks, record = plan_loss_blocks(batch, n_chunks, chunk, vocab, hidden_size, itemsize)
+    assert (blocks.token_blocks, record["kept_logits_bytes"]) == expected
+    assert not blocks.constrain and record["vocab_shards"] == 1 and n_chunks % blocks.token_blocks == 0  # no mesh here
+    assert record["kept_logits_bytes"] <= _KEPT_LOGITS_BYTES or blocks.token_blocks == n_chunks
+    if blocks.token_blocks > 1:  # one block fewer would not have fit
+        fewer = max(d for d in range(1, blocks.token_blocks) if n_chunks % d == 0)
+        assert batch * n_chunks * chunk // fewer * vocab * itemsize > _KEPT_LOGITS_BYTES
+    assert record["logits_products"] == 1 and "hidden_carry_bytes" not in record and "vocab_tiles" not in record
+    assert record["table_carry_bytes"] == (0 if blocks.token_blocks == 1 else 4 * hidden_size * vocab)
+    trips = 1 if blocks.token_blocks == 1 else 2 * blocks.token_blocks
+    assert record["accumulator_bytes_moved"] == 4 * hidden_size * (trips * vocab + batch * n_chunks * chunk)
+    assert blocks.logits_block(batch, n_chunks, chunk, vocab) == (n_chunks // blocks.token_blocks, batch, chunk, vocab)
+
+
+@pytest.mark.parametrize("z_loss_coef", [0.0, 1e-3], ids=["no_z_loss", "z_loss"])
+@pytest.mark.parametrize(
+    "planned_from, token_blocks",
+    [((2, 32, 256, 16384, 2), 2), ((4, 16, 256, 49152, 2), 4)],
+    ids=["two_blocks", "four_blocks"],
+)
+def test_summed_rule_walks_the_blocks_its_plan_gives(planned_from, token_blocks, z_loss_coef):
+    """More tokens than one block may keep the logits of: the plan of shapes over the budget
+    (the 8k cells' head, the shipped job's micro batch — no knob is turned) drives the rule
+    at a toy's size; the walk carries the table's float32 gradient and gives the unchunked
+    loss and gradients."""
+    from dolomite_engine_tpu.ops.loss import _chunked_ce_terms, _chunked_operands, plan_loss_blocks
+
+    blocks = plan_loss_blocks(*planned_from[:4], 64, planned_from[4])[0]
+    assert blocks.token_blocks == token_blocks
+    hidden, table, labels = _summed_operands(S=30)  # 4 chunks of 8, the last padded
+
+    def walked(h, t):
+        hidden_c, labels_c, emb = _chunked_operands(h, t, labels, 8, jnp.float32)
+        assert hidden_c.shape[0] == 4
+        objective, num_tokens = _chunked_ce_terms(hidden_c, labels_c, emb, None, True, jnp.float32, z_loss_coef, blocks)
+        return objective / num_tokens
+
+    got = jax.value_and_grad(walked, argnums=(0, 1))(hidden, table)
+    want = jax.value_and_grad(lambda h, t: _unchunked_loss(h, t, labels, z_loss_coef=z_loss_coef), argnums=(0, 1))(hidden, table)
+    _assert_follows(got, want, jnp.float32)
+    loops = [eqn for eqn in jax.make_jaxpr(jax.grad(walked, argnums=(0, 1)))(hidden, table).jaxpr.eqns if eqn.primitive.name == "scan"]
+    assert [eqn.params["length"] for eqn in loops] == [token_blocks]
+
+
+def _dot_generals(jaxpr) -> list:
+    """The output shapes of every `dot_general` in `jaxpr`, nested jaxprs included."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            found.append(tuple(eqn.outvars[0].aval.shape))
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    found.extend(_dot_generals(inner))
+    return found
+
+
+@pytest.mark.parametrize("rule", ["summed", "per_token"])
+def test_differentiated_head_computes_its_logits_once(rule):
+    """The structure of loss and gradients as one jaxpr: the summed rule holds three products
+    — ONE whose output is a token block's ``[chunks, B, chunk, V]`` logits, and the two
+    gradients' — and no loop at one block; the per-token rule, whose cotangents arrive after
+    its forward, still holds four (the parent's count for both): the scan's ``[B, chunk, V]``
+    and the backward's ``[chunks a block, B, chunk, shards, tile rows]`` are logits twice."""
+    from dolomite_engine_tpu.ops.loss import fused_linear_cross_entropy
+
+    B, S, H, V, chunk = 2, 32, 16, 211, 8
+    hidden, table, labels = jnp.zeros((B, S, H)), jnp.zeros((V, H)), jnp.zeros((B, S), jnp.int32)
+    weights = jnp.ones((B, S)) if rule == "per_token" else None
+    loss = lambda h, t: fused_linear_cross_entropy(h, t, labels, chunk_size=chunk, compute_dtype=jnp.float32, weights=weights)  # noqa: E731
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(loss, argnums=(0, 1)))(hidden, table).jaxpr
+    products = _dot_generals(jaxpr)
+    logits = [shape for shape in products if H not in shape]
+    if rule == "summed":
+        assert logits == [(S // chunk, B, chunk, V)] and len(products) == 3
+        assert sorted(p for p in products if p not in logits) == sorted([(S // chunk, B, chunk, H), (V, H)])
+        assert not [eqn for eqn in jaxpr.eqns if eqn.primitive.name in ("scan", "while")]
+    else:
+        assert len(logits) == 2 and len(products) == 4 and (B, chunk, V) in logits
+
+
 def test_fused_lm_head_loss_model_parity():
     """GPTDolomite with fused_lm_head_loss=True gives the same loss as the logits path."""
     import numpy as np

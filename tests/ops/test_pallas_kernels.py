@@ -930,11 +930,14 @@ _CE_NUMERICS = {
 def test_fused_ce_chunked_matches_unchunked_to_ulp(tiling, z_coef, numerics):
     """Acceptance: chunked-vs-unchunked loss AND grads within 1-2 float32 ulp, with the
     per-chunk reduction on the XLA reference and on the fused_ce kernel — over the shapes
-    that make the backward rule choose each loop order (one token block or several, one
-    vocabulary tile or several, a vocabulary no tile count divides), with IGNORE_INDEX
-    rows, z-loss on and off, `logit_scale`, bf16 operands and `upcast` both ways (the bf16
-    rows within 2 bf16 ulp of the gradient's largest entry: the unchunked reference
-    rounds its own gradients to bf16)."""
+    that make the per-token rule's backward choose each loop order (one token block or
+    several, one vocabulary tile or several, a vocabulary no tile count divides), with
+    IGNORE_INDEX rows, z-loss on and off, `logit_scale`, bf16 operands and `upcast` both
+    ways (the bf16 rows within 2 bf16 ulp of the gradient's largest entry: the unchunked
+    reference rounds its own gradients to bf16). Since PR 39 the weightless call's
+    gradients leave its differentiated forward (one block of kept logits at these sizes,
+    XLA's on either backend); the backend's scan serves its undifferentiated value, and
+    the tiled backward the same loss with unit weights."""
     from dolomite_engine_tpu.ops.loss import (
         causal_lm_loss, fused_linear_cross_entropy, plan_loss_backward,
     )
@@ -963,14 +966,22 @@ def test_fused_ce_chunked_matches_unchunked_to_ulp(tiling, z_coef, numerics):
 
     ref_loss, ref_grads = jax.value_and_grad(unchunked, argnums=(0, 1))(hidden, table)
     fp32 = dtype == jnp.float32
+    # loss: summation-order only -> 1-2 fp32 ulp around ~5.3 (bf16 logits without
+    # upcast: the unchunked loss itself is a bf16 sum)
+    loss_atol = 2e-6 if upcast else 0.1
     for backend in ("xla", "pallas"):
         with kernel_overrides(fused_ce=backend):
             loss, grads = jax.value_and_grad(chunked, argnums=(0, 1))(hidden, table)
-        # loss: summation-order only -> 1-2 fp32 ulp around ~5.3 (bf16 logits without
-        # upcast: the unchunked loss itself is a bf16 sum)
-        loss_atol = 2e-6 if upcast else 0.1
-        np.testing.assert_allclose(float(loss), float(ref_loss), rtol=0, atol=loss_atol)
-        for g, r in zip(grads, ref_grads):
+            undifferentiated = chunked(hidden, table)
+            unit_loss, unit_grads = jax.value_and_grad(
+                lambda h, t: fused_linear_cross_entropy(
+                    h, t, labels, chunk_size=chunk, z_loss_coef=z_coef, weights=jnp.ones((B, S)), **options
+                ),
+                argnums=(0, 1),
+            )(hidden, table)
+        for value in (loss, undifferentiated, unit_loss):
+            np.testing.assert_allclose(float(value), float(ref_loss), rtol=0, atol=loss_atol)
+        for g, r in zip(grads + unit_grads, ref_grads + ref_grads):
             # same atol style as the remat-policy matrix: ~1 fp32 ulp at magnitude 1
             atol = 1.2e-7 if fp32 else 2 * 2.0**-7 * float(jnp.max(jnp.abs(r)))
             np.testing.assert_allclose(np.asarray(g), np.asarray(r), rtol=0, atol=atol)
@@ -1095,16 +1106,20 @@ def _loop_carries(jaxpr) -> list:
 
 @pytest.mark.parametrize("V, blocks", [(1999, 1), (199, 2)], ids=["one_block", "two_blocks"])
 def test_fused_ce_peak_logits_memory_is_o_chunk(V, blocks):
-    """Acceptance: the chunked lowering never materializes a [B*S, V]-sized logits
-    buffer — asserted through the shared perf-signature HLO-feature API
-    (utils/program_signature.py, the same checks `tools/perf_ledger.py` gates on):
-    the unchunked grad program must contain the full [B, S, V] tile, the chunked one
-    must not (at most the forward's [B, chunk, V] scan tile and the backward rule's
-    [chunks a block, B, chunk, shards, tile rows] tile, no larger) — and no loop of the
-    backward program carries a table-shaped float32 buffer where one token block holds
-    all tokens; where there are several, one loop does, once a block, not once a chunk."""
+    """Acceptance: the rules that stay chunked — the undifferentiated call and the
+    per-token rule, forward and backward — never materialize a [B*S, V]-sized logits buffer,
+    asserted through the shared perf-signature HLO-feature API (utils/program_signature.py,
+    the same checks `tools/perf_ledger.py` gates on): the unchunked grad program must
+    contain the full [B, S, V] tile, the chunked ones must not (at most the forward's
+    [B, chunk, V] scan tile and the backward rule's [chunks a block, B, chunk, shards, tile
+    rows] tile, no larger) — and no loop of the backward program carries a table-shaped
+    float32 buffer where one token block holds all tokens; where there are several, one
+    loop does, once a block, not once a chunk. The summed rule's differentiated program
+    (PR 39) keeps ONE token block's logits, [chunks a block, B, chunk, V] under a budget in
+    bytes that a toy never reaches, and neither a chunk tile nor a vocabulary tile beside it;
+    with one block no loop is left in it at all."""
     from dolomite_engine_tpu.ops.loss import (
-        causal_lm_loss, fused_linear_cross_entropy, plan_loss_backward,
+        causal_lm_loss, fused_linear_cross_entropy, plan_loss_backward, plan_loss_blocks,
     )
     from dolomite_engine_tpu.utils.program_signature import capture_program_signature
 
@@ -1117,19 +1132,25 @@ def test_fused_ce_peak_logits_memory_is_o_chunk(V, blocks):
     assert plan.token_blocks == blocks
     tile = plan.logits_tile(B, S // chunk, chunk)
     assert np.prod(tile) <= 1.1 * B * chunk * V  # the forward's budget (a padded tile's worth over)
+    kept = plan_loss_blocks(B, S // chunk, chunk, V, H, 4)[0]
+    assert kept.token_blocks == 1  # 0.5 MB of the 384 MiB a block may keep
 
     def unchunked(h, t):
         return causal_lm_loss(jnp.dot(h, t.T), jnp.zeros((B, S), jnp.int32), labels=labels)
 
-    def chunked(h, t):
+    def chunked(h, t, weights=None):
         return fused_linear_cross_entropy(
-            h, t, labels, chunk_size=chunk, compute_dtype=jnp.float32
+            h, t, labels, chunk_size=chunk, compute_dtype=jnp.float32, weights=weights
         )
+
+    def per_token(h, t):
+        return chunked(h, t, jnp.ones((B, S), jnp.float32))
 
     checks = {
         "full_logits": ((B, S, V), "f32"),
         "chunk_logits": ((B, chunk, V), "f32"),
         "tile_logits": (tile, "f32"),
+        "block_logits": (kept.logits_block(B, S // chunk, chunk, V), "f32"),
     }
     # forward AND backward: grad of the loss is where remat pressure lives.
     # compile=False: the assertion is about the lowering, not the buffer assignment
@@ -1137,17 +1158,32 @@ def test_fused_ce_peak_logits_memory_is_o_chunk(V, blocks):
         jax.grad(unchunked, argnums=(0, 1)), hidden, table,
         name="ce_unchunked_grad", compile=False, shape_checks=checks,
     )
-    sig_chunked = capture_program_signature(
+    sig_per_token = capture_program_signature(
+        jax.grad(per_token, argnums=(0, 1)), hidden, table,
+        name="ce_per_token_grad", compile=False, shape_checks=checks,
+    )
+    sig_undifferentiated = capture_program_signature(
+        chunked, hidden, table, name="ce_chunked", compile=False, shape_checks=checks,
+    )
+    sig_summed = capture_program_signature(
         jax.grad(chunked, argnums=(0, 1)), hidden, table,
-        name="ce_chunked_grad", compile=False, shape_checks=checks,
+        name="ce_summed_grad", compile=False, shape_checks=checks,
     )
     assert sig_unchunked.hlo["checks"]["full_logits"]  # the reference builds full logits
     assert not sig_unchunked.hlo["checks"]["tile_logits"]
-    assert not sig_chunked.hlo["checks"]["full_logits"]
-    assert sig_chunked.hlo["checks"]["chunk_logits"]  # ...while the forward's chunk tile
-    assert sig_chunked.hlo["checks"]["tile_logits"]  # and the backward's vocabulary tile exist
+    assert not sig_per_token.hlo["checks"]["full_logits"]
+    assert sig_per_token.hlo["checks"]["chunk_logits"]  # ...while the forward's chunk tile
+    assert sig_per_token.hlo["checks"]["tile_logits"]  # and the backward's vocabulary tile exist
+    assert not sig_per_token.hlo["checks"]["block_logits"]
+    assert sig_undifferentiated.hlo["checks"] == {
+        "full_logits": False, "chunk_logits": True, "tile_logits": False, "block_logits": False,
+    }
+    # ("chunk_logits" is blind here: the check is textual, and a chunk's shape is the tail of
+    # the block's [chunks, B, chunk, V]; the product count in tests/ops/test_ops.py is not)
+    summed = sig_summed.hlo["checks"]
+    assert summed["block_logits"] and not summed["full_logits"] and not summed["tile_logits"]
 
-    loops = _loop_carries(jax.make_jaxpr(jax.grad(chunked, argnums=(0, 1)))(hidden, table).jaxpr)
+    loops = _loop_carries(jax.make_jaxpr(jax.grad(per_token, argnums=(0, 1)))(hidden, table).jaxpr)
     assert len(loops) >= 2  # the forward's scan and the backward's
     table_sized = [
         (kind, trips) for kind, trips, carry in loops
@@ -1156,6 +1192,7 @@ def test_fused_ce_peak_logits_memory_is_o_chunk(V, blocks):
     # one block: every tile's table gradient leaves its matmul once (the scan's ys);
     # two blocks: the outer loop alone carries it, for 2 trips and not S // chunk = 8
     assert table_sized == ([] if blocks == 1 else [("scan", blocks)])
+    assert not _loop_carries(jax.make_jaxpr(jax.grad(chunked, argnums=(0, 1)))(hidden, table).jaxpr)
 
 
 # ------------------------------------------------------------------- fused_rope_qkv

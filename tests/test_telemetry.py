@@ -825,37 +825,47 @@ def test_compiles_counter_names_the_step_of_a_recompile(tmp_path):
 
 
 def test_loss_tiling_event_is_written_once_a_trace(tmp_path):
-    """The chunked loss's backward rule says how it engaged: one `loss_tiling` event where
-    the step is traced (token blocks x vocabulary tiles, the bytes its loops carry), not
-    one a trace of the same shapes, and a new one when the shapes choose another tiling."""
+    """The chunked loss says how it engaged: one `loss_tiling` event where the step is traced,
+    not one a trace of the same shapes, and a new one when the shapes choose another plan —
+    the summed rule's (`logits_products` 1: the gradients leave the differentiated forward,
+    over a token block's kept logits) and the per-token rule's (2: its backward forms the
+    logits again, token blocks x vocabulary tiles, the bytes its loops carry)."""
     from dolomite_engine_tpu.ops.loss import fused_linear_cross_entropy
 
     sink = tmp_path / "t.jsonl"
     telemetry = Telemetry(sink_path=str(sink), rank=0)
     install_telemetry(telemetry)
 
-    def grads(batch, seq, vocab):
+    def grads(batch, seq, vocab, weights=None):
         hidden = jnp.ones((batch, seq, 16), jnp.float32)
         table = jnp.ones((vocab, 16), jnp.float32)
         labels = jnp.zeros((batch, seq), jnp.int32)
         loss = lambda h, t: fused_linear_cross_entropy(  # noqa: E731
-            h, t, labels, chunk_size=8, compute_dtype=jnp.float32
+            h, t, labels, chunk_size=8, compute_dtype=jnp.float32, weights=weights
         )
         return jax.jit(jax.grad(loss, argnums=(0, 1))).lower(hidden, table)
 
     grads(2, 64, 1999)
     grads(2, 64, 1999)  # traced again (another jit of the same step): nothing new to say
     grads(2, 64, 199)
+    grads(2, 64, 1999, jnp.ones((2, 64)))
+    grads(2, 64, 199, jnp.ones((2, 64)))
     uninstall_telemetry()
     get_telemetry().event_once("loss_tiling", token_blocks=1)  # the no-op registry has the method too
     telemetry.close()
 
     events = [r for r in _read_sink(sink) if r["kind"] == "event" and r["event"] == "loss_tiling"]
-    assert [(e["token_blocks"], e["vocab_tiles"], e["tile_rows"]) for e in events] == [(1, 8, 256), (2, 4, 50)]
-    one_block, two_blocks = events
+    assert [e["logits_products"] for e in events] == [1, 1, 2, 2]
+    for kept, vocab in zip(events[:2], (1999, 199)):
+        assert (kept["token_blocks"], kept["kept_logits_bytes"], kept["table_carry_bytes"]) == (1, 4 * 128 * vocab, 0)
+        assert kept["accumulator_bytes_moved"] == 4 * 16 * (vocab + 128) and kept["tokens_per_device"] == 128
+        assert not {"hidden_carry_bytes", "vocab_tiles", "tile_rows"} & set(kept)  # they went with the vocabulary scan
+    one_block, two_blocks = events[2:]
+    assert [(e["token_blocks"], e["vocab_tiles"], e["tile_rows"]) for e in events[2:]] == [(1, 8, 256), (2, 4, 50)]
     assert one_block["table_carry_bytes"] == 0 and one_block["hidden_carry_bytes"] == 4 * 16 * 128
     assert two_blocks["table_carry_bytes"] == 4 * 16 * 200 and two_blocks["tokens_per_device"] == 128
-    assert "step" not in one_block and one_block["vocab_shards"] == 1
+    assert "kept_logits_bytes" not in one_block
+    assert all("step" not in e and e["vocab_shards"] == 1 for e in events)
 
 
 def test_null_registry_is_safe_without_install():

@@ -3,10 +3,11 @@
 Lowers `make_train_step` for a tiny scanned `gpt_dolomite` with the chunked loss and reads
 the framework names of the lowered operations (`jit(train_step)/.../op`, what a profile
 calls `tf_op`): every phase scope is there, every scan sits in a scope of its own, the
-loss's backward matmuls are under `head_loss` (`ce_tile`: recomputed logits, hidden and
-table gradients), and no matmul is left with JAX's bare
-`transpose(jvp())` — a backward rule or scan body with no name of its own. Scopes are
-metadata: the compile-cache key strips them, so the compiled program is what it was.
+head's three matmuls are under `head_loss` (`ce_block`: the logits, computed once, and the
+hidden and table gradients the differentiated forward forms over them — PR 39; the per-token
+rule's `ce_chunk` and `ce_tile` are held by the looped model's test), and no matmul is left
+with JAX's bare `transpose(jvp())` — a backward rule or scan body with no name of its own.
+Scopes are metadata: the compile-cache key strips them, so the compiled program is what it was.
 """
 
 import re
@@ -94,31 +95,33 @@ def test_phase_scope_is_in_the_lowered_step(names, scope):
 
 def test_every_scan_and_the_cond_sit_in_a_scope_of_their_own(names):
     whiles = sorted({name for op, name in names if op == "while"})
-    assert len(whiles) == 4  # the layer scan and the loss's chunk scan, forward and backward
+    # the layer scan, forward and backward; the head's one block of kept logits is no loop
+    # (more tokens than a block keeps would walk `head_loss/loss_chunks/token_blocks/while`)
+    assert len(whiles) == 2
     for name in whiles:
-        assert ("/blocks/while" in name) != ("/head_loss/loss_chunks/while" in name), name
-    assert sum("transpose(" in name for name in whiles) == 2
+        assert "/blocks/while" in name and "/head_loss/" not in name, name
+    assert sum("transpose(" in name for name in whiles) == 1
     conds = {name for op, name in names if op == "case"}
     assert conds and all(name.endswith("optimizer/cond") for name in conds), conds
 
 
 def test_the_loss_backward_matmuls_are_under_head_loss(names):
-    # the backward rule of the chunked loss (a custom_vjp) is traced under the scopes of
-    # its call and opens its forward's own: its scan is head_loss/loss_chunks ...
-    assert any(
-        op == "while" and "transpose(" in name and name.endswith("head_loss/loss_chunks/while")
-        for op, name in names
-    )
-    # ... and inside the scans' bodies the matmuls carry names that tell them apart: the
-    # forward's chunk (`ce_chunk`), and in the backward rule's vocabulary tile (`ce_tile`)
-    # the recomputed logits and the two gradient matmuls
-    dots = {name for op, name in names if op == "dot_general"}
-    assert {
-        "ce_chunk/dot_general", "ce_tile/logits/dot_general",
-        "ce_tile/grad_hidden/dot_general", "ce_tile/grad_table/dot_general",
-    } <= dots
-    # the rule differentiates nothing: no replayed forward, no transposed chunk
-    assert not [name for name in dots if "jvp(ce_chunk)" in name]
+    # the summed rule's differentiated forward forms the gradients where it forms the logits:
+    # THREE matmuls under head_loss/loss_chunks, in the block's body (`ce_block`), told apart
+    # by name — the logits (once: the parent's `ce_chunk` and `ce_tile/logits` were two) and
+    # the two gradients' — and none in the backward pass, whose rule only scales
+    dots = sorted(name for op, name in names if op == "dot_general" and "/head_loss/" in name)
+    assert [name.split("/head_loss/")[1] for name in dots] == [
+        "loss_chunks/ce_block/grad_hidden/dot_general",
+        "loss_chunks/ce_block/grad_table/dot_general",
+        "loss_chunks/ce_block/logits/dot_general",
+    ]
+    assert not [name for name in dots if "transpose(" in name]
+    # the rule differentiates nothing and replays nothing
+    assert not [name for _, name in names if "ce_chunk" in name or "ce_tile" in name]
+    # ... and what the backward pass does under head_loss is elementwise: the scaling
+    backward = {op for op, name in names if "/head_loss/" in name and "transpose(" in name}
+    assert "multiply" in backward and not backward & {"dot_general", "while", "exponential", "reduce"}, backward
 
 
 def test_no_matmul_is_left_without_an_owner(names):
@@ -127,7 +130,7 @@ def test_no_matmul_is_left_without_an_owner(names):
     bare = [n for n in dots if n in ("dot_general", "jvp()/dot_general", "transpose(jvp())/dot_general")]
     assert not bare, bare
     for name in dots:
-        assert "ce_chunk" in name or "ce_tile" in name or "h_scan" in name, name
+        assert "ce_block" in name or "h_scan" in name, name
 
 
 def test_accumulation_and_health_have_scopes_too():
@@ -143,7 +146,7 @@ def test_the_unrolled_model_carries_the_same_phases():
         assert any(scope in name.split("/") for _, name in names), scope
     dots = [
         name for op, name in names
-        if op == "dot_general" and "ce_chunk" not in name and "ce_tile" not in name
+        if op == "dot_general" and "ce_block" not in name
     ]
     assert dots and all("/blocks/" in name for name in dots), dots[:3]
 
@@ -187,12 +190,11 @@ def test_joyai_flash_names_its_layers_and_both_passes_through_the_head():
     in_blocks = [name for name in names if "/blocks/" in name]
     assert any("/blocks/mtp/" in name and "/latent_attention/" in name for name in in_blocks)
     assert any("/blocks/mtp/" in name and "/moe/" in name for name in in_blocks)
-    # (a scan's body is named relative to its call: the loss's two passes are told apart by their scans)
-    plain = lambda name: re.sub(r"(transpose|jvp)\(|\)", "", name)  # noqa: E731
-    loss_scans = sorted(plain(name) for op, name in _operation_names(lowered) if op == "while" and "loss_chunks" in name)
-    assert len(loss_scans) == 4, loss_scans  # each pass's forward scan and its backward rule's
-    assert sum(name.endswith("head_loss/mtp/mtp_head_loss/loss_chunks/while") for name in loss_scans) == 2, loss_scans
-    assert sum(name.endswith("head_loss/loss_chunks/while") and "mtp" not in name for name in loss_scans) == 2, loss_scans
+    # the head's two passes, three matmuls each (PR 39: the logits once), told apart by scope
+    in_head = sorted(name.split("/head_loss/")[1] for name in dots if "/head_loss/" in name)
+    products = [f"loss_chunks/ce_block/{product}/dot_general" for product in ("grad_hidden", "grad_table", "logits")]
+    assert in_head == products + [f"mtp/mtp_head_loss/{name}" for name in products], in_head
+    assert not [name for op, name in _operation_names(lowered) if op == "while" and "head_loss" in name]
     # no matmul of the blocks outside a layer's scope
     for name in dots:
         if "/blocks/" in name:

@@ -135,20 +135,22 @@ def _train_step_suite(model_type: str):
 
 
 def _logits_checks(batch: int, seq: int, hidden: int, vocab: int, chunk: int) -> dict:
-    """Fused CE: the [batch, seq, vocab] fp32 logits must not exist; the forward's
-    [batch, chunk, vocab] scan tile must, and so must the tile the backward rule recomputes
-    — all tokens of a block against a slice of the vocabulary, as `ops/loss.
-    plan_loss_backward` cuts it under the ambient mesh (the same budget the other way)."""
-    from dolomite_engine_tpu.ops.loss import plan_loss_backward
+    """Fused CE: the [batch, seq, vocab] fp32 logits of the plain path must not exist. An
+    undifferentiated program holds the scan's [batch, chunk, vocab] tile; a differentiated
+    one (the summed rule: `ops/loss._chunked_ce_terms_fwd`) keeps one token block's logits
+    — [chunks a block, batch, chunk, vocab], as `ops/loss.plan_loss_blocks` cuts them under
+    the ambient mesh and its budget in bytes — and nothing twice (the checks are textual:
+    a chunk's shape is the tail of a block's, so `chunk_logits` reads true there too)."""
+    from dolomite_engine_tpu.ops.loss import plan_loss_blocks
 
     n_chunks = seq // chunk
-    tile = plan_loss_backward(batch, n_chunks, chunk, vocab, hidden)[0].logits_tile(
-        batch, n_chunks, chunk
+    block = plan_loss_blocks(batch, n_chunks, chunk, vocab, hidden, 4)[0].logits_block(
+        batch, n_chunks, chunk, vocab
     )
     return {
         "full_logits": ((batch, seq, vocab), "f32"),
         "chunk_logits": ((batch, chunk, vocab), "f32"),
-        "tile_logits": (tile, "f32"),
+        "block_logits": (block, "f32"),
     }
 
 
