@@ -8,6 +8,7 @@ GSPMD every registered model is tensor-parallel capable (sharding is declarative
 """
 
 from .config import (
+    AfmoeConfig,
     CommonConfig,
     DenseMoEConfig,
     EncDecDolomiteConfig,
@@ -20,6 +21,7 @@ from .config import (
     RNNDolomiteConfig,
 )
 from .gpt_dolomite import CausalLMOutput, GPTDolomiteForCausalLM, GPTDolomiteModel
+from .afmoe import AfmoeForCausalLM, AfmoeModel
 from .dense_moe import DenseMoEForCausalLM, DenseMoEModel
 from .enc_dec_dolomite import EncDecDolomiteForSeq2SeqLM
 from .gpt_crosslayer import (
@@ -45,6 +47,7 @@ _CONFIG_CLASSES: dict[str, type] = {
     "joyai_llm_flash": JoyAIFlashConfig,
     "lfm2_moe": Lfm2MoeConfig,
     "ouro": OuroConfig,
+    "afmoe": AfmoeConfig,
 }
 
 _MODEL_CLASSES: dict[str, type] = {
@@ -58,6 +61,7 @@ _MODEL_CLASSES: dict[str, type] = {
     "joyai_llm_flash": JoyAIFlashForCausalLM,
     "lfm2_moe": Lfm2MoeForCausalLM,
     "ouro": OuroForCausalLM,
+    "afmoe": AfmoeForCausalLM,
 }
 
 # families trained/driven through the seq2seq (AutoModelForSeq2SeqLM) surface

@@ -69,6 +69,9 @@ class CommonConfig:
     # eps `layer_norm_epsilon`) between the QKV split and the rotation
     # (`ops/rope.split_qkv_apply_rope`)
     qk_norm: bool = False
+    # ``sigmoid(W_g h)``, as wide as the heads' output, multiplied into it before the
+    # out-projection (`modeling_utils.Attention`, scope ``attention_gate``)
+    attention_output_gate: bool = False
 
     def __post_init__(self) -> None:
         if self.n_inner is None:
@@ -645,6 +648,152 @@ class Lfm2MoeConfig(CommonConfig):
             layer_types=",".join(self.layer_types),
             blocks_conv=self.layer_types.count("conv"),
             blocks_attention=self.layer_types.count("full_attention"),
+            blocks_dense=self.num_dense_layers,
+            blocks_experts=self.expert_layers,
+            experts_held=count,
+            first_expert_held=first,
+            experts_published=self.num_experts,
+            vocabulary_rows_held=self.vocab_size,
+            **(self.deployment or {}),
+        )
+
+
+@dataclass
+class AfmoeConfig(CommonConfig):
+    """`afmoe` (Trinity-Mini's family): every block is attention of one of two kinds by
+    `layer_types[i]` — ``sliding_attention`` (rope by halves; a query sees its own key and the
+    `sliding_window` - 1 before it, inside its document) or ``full_attention`` (NO positions;
+    every earlier key of its document) — and then one of two feed-forwards by depth: a dense
+    SwiGLU MLP of `n_inner` in the first `num_dense_layers` blocks, sigmoid-routed SwiGLU
+    experts with a shared expert (`shared_expert_moe.SharedExpertMoE`) in the others. Every
+    query and key head is RMS-normed before the rotation (`qk_norm`), the heads' output is
+    multiplied by ``sigmoid(W_g h)`` before the out-projection (`attention_output_gate`), and
+    each sub-layer stands between a norm of its input and a norm of its output
+    (`modeling_utils.sandwich_normed_block`: Ouro's four norms). `mup_enabled` multiplies the
+    embedding's output by ``sqrt(n_embd)`` (the repo's `m_emb`) and does nothing else. The head
+    is untied.
+
+    The repo's names carry the widths they always carried (`n_embd`, `n_head`,
+    `num_key_value_heads`, `attention_head_dim`, `n_inner`); the rest are the public
+    `config.json`'s keys (`route_norm_epsilon` is the public modeling code's literal).
+    `experts_held` and `deployment` are `NemotronHConfig`'s."""
+
+    model_type: str = "afmoe"
+    attention_head_type: str = "gqa"
+    position_embedding_type: str = "rope"
+    normalization_function: str = "rmsnorm"
+    activation_function: str = "swiglu"
+    layer_norm_epsilon: float = 1e-5
+    rope_theta: float = 10000
+    add_bias: bool = False
+    tie_word_embeddings: bool = False
+    qk_norm: bool = True
+    attention_output_gate: bool = True
+    # the kind of every block's attention
+    layer_types: list[str] | None = None
+    sliding_window: int = 2048
+    # feed-forward sublayers
+    num_dense_layers: int = 2
+    num_experts: int = 128
+    num_experts_per_tok: int = 8
+    moe_intermediate_size: int = 1024
+    num_shared_experts: int = 1
+    score_func: str = "sigmoid"
+    route_norm: bool = True
+    route_scale: float = 2.826
+    route_norm_epsilon: float = 1e-20
+    mup_enabled: bool = True
+    experts_held: list[int] | None = None
+    deployment: dict | None = None
+
+    buffer_names = ("e_score_correction_bias",)
+
+    def __post_init__(self) -> None:
+        if self.layer_types is None:
+            self.layer_types = ["full_attention"] * self.n_layer
+        if self.mup_enabled and self.m_emb is None:
+            self.m_emb = float(self.n_embd) ** 0.5
+        super().__post_init__()
+        if len(self.layer_types) != self.n_layer:
+            raise ValueError(f"layer_types names {len(self.layer_types)} layers, n_layer is {self.n_layer}")
+        unknown = set(self.layer_types) - {"sliding_attention", "full_attention"}
+        if unknown:
+            raise ValueError(f"layer_types knows sliding_attention and full_attention, not {sorted(unknown)}")
+        if self.sliding_window < 1:
+            raise ValueError(f"sliding_window {self.sliding_window}: a query sees at least its own key")
+        if self.score_func != "sigmoid":
+            raise ValueError(f"score_func {self.score_func!r}: the router is built with sigmoid scores (the published models')")
+        if not self.qk_norm or not self.attention_output_gate:
+            raise ValueError("qk_norm / attention_output_gate false: the family's attention has both (the published models')")
+        if self.tie_word_embeddings:
+            raise ValueError("tie_word_embeddings true: the family's head is a table of its own (the published models')")
+        if not 0 <= self.num_dense_layers <= self.n_layer:
+            raise ValueError(f"num_dense_layers {self.num_dense_layers} lies outside 0..{self.n_layer}")
+        self.experts_held = _checked_experts_held(self.experts_held, self.num_experts)
+
+    @classmethod
+    def fused_loss_reads_untied_head(cls) -> bool:
+        return True
+
+    @classmethod
+    def supported_position_embeddings(cls) -> frozenset[PositionEmbeddingType]:
+        return frozenset({PositionEmbeddingType.rope})
+
+    # what `SharedExpertMoE` reads, under the names the other expert families gave them
+    @property
+    def routed_scaling_factor(self) -> float:
+        return self.route_scale
+
+    @property
+    def norm_topk_prob(self) -> bool:
+        return self.route_norm
+
+    @property
+    def norm_topk_prob_epsilon(self) -> float:
+        return self.route_norm_epsilon
+
+    @property
+    def moe_shared_expert_intermediate_size(self) -> int:
+        return self.num_shared_experts * self.moe_intermediate_size
+
+    @property
+    def expert_layers(self) -> int:
+        """Layers of experts whose counters a step returns: the blocks after the dense ones."""
+        return self.n_layer - self.num_dense_layers
+
+    def layer_window(self, index: int) -> int | None:
+        """The window of block `index`'s attention (None: a full layer)."""
+        return self.sliding_window if self.layer_types[index] == "sliding_attention" else None
+
+    def held_experts(self) -> tuple[int, int]:
+        return tuple(self.experts_held) if self.experts_held else (0, self.num_experts)
+
+    def forward_block_flops(self, b: int, s: int) -> float:
+        """`NemotronHConfig.forward_block_flops` for this family: attention's projections with
+        the gate's, the score and value products over ``s`` keys a token in a full layer and
+        ``min(s, sliding_window)`` in a window layer (the function's convention counts the full
+        square; a window layer's square is a band)."""
+        h, heads, kv, d = self.n_embd, self.n_head, self.num_key_value_heads, self.head_dim
+        held = self.held_experts()[1]
+        projections = 2 * b * s * (h * (heads + 2 * kv) * d + 2 * heads * d * h)  # q, k, v; the gate and the out-projection
+        keys = {"full_attention": s, "sliding_attention": min(s, self.sliding_window)}
+        attention = sum(projections + 4 * b * s * keys[kind] * heads * d for kind in self.layer_types)
+        dense = 2 * b * s * 3 * h * self.n_inner
+        experts = 2 * b * s * (
+            h * self.num_experts
+            + 3 * h * self.moe_shared_expert_intermediate_size
+            + self.num_experts_per_tok * held / self.num_experts * 3 * h * self.moe_intermediate_size
+        )
+        return float(attention + self.num_dense_layers * dense + self.expert_layers * experts)
+
+    def layout_record(self) -> dict:
+        """What the run's one `model_layout` telemetry event says."""
+        first, count = self.held_experts()
+        return dict(
+            layer_types=",".join(self.layer_types),
+            blocks_window=self.layer_types.count("sliding_attention"),
+            blocks_full=self.layer_types.count("full_attention"),
+            sliding_window=self.sliding_window,
             blocks_dense=self.num_dense_layers,
             blocks_experts=self.expert_layers,
             experts_held=count,

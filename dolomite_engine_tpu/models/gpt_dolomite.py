@@ -679,16 +679,18 @@ class GPTDolomiteForCausalLM(nn.Module):
             z_loss_coef=self.config.z_loss_coef,
         )
 
-    # names of what the family's blocks count (`step_counters`)
+    # names of what the family's blocks count (`step_counters`) and of what
+    # `splash_step_counters` counts (a family whose layers differ by mask counts by kind)
     family_counter_names = ()
+    splash_counter_names = SPLASH_COUNTERS
 
     @property
     def step_counter_names(self) -> tuple:
         """Names of what a forward pass counts for the train step to return beside the loss:
         the family's own and, where the blocks' attention is expected through the splash
-        kernel, `ops.attention.SPLASH_COUNTERS`. Empty: the train step returns what it
-        always returned."""
-        return self.family_counter_names + (SPLASH_COUNTERS if splash_expected(self.attention_implementation) else ())
+        kernel, `splash_counter_names` (`ops.attention.SPLASH_COUNTERS`). Empty: the train
+        step returns what it always returned."""
+        return self.family_counter_names + (self.splash_counter_names if splash_expected(self.attention_implementation) else ())
 
     def step_counters(self, extras: list) -> dict | None:
         """Hook: the family's counters of this forward pass from the per-block extras (None:
@@ -704,7 +706,12 @@ class GPTDolomiteForCausalLM(nn.Module):
             return {}
         if segment_ids is None and attention_mask is not None and attention_mask.ndim == 2:
             segment_ids = attention_mask
-        return splash_block_counters(hidden_states.shape[0], hidden_states.shape[1], segment_ids)
+        return self.count_splash_blocks(hidden_states.shape[0], hidden_states.shape[1], segment_ids)
+
+    def count_splash_blocks(self, batch: int, seq: int, segment_ids: jax.Array | None) -> dict:
+        """Hook: `splash_counter_names` of `batch` rows of `seq` tokens under these ids (a family
+        whose layers differ by mask counts by kind)."""
+        return splash_block_counters(batch, seq, segment_ids)
 
     def compute_aux_loss(
         self,

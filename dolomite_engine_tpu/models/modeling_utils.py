@@ -255,6 +255,34 @@ def get_norm(config: CommonConfig, dtype: Dtype, name: str | None = None) -> Nor
     )
 
 
+def sandwich_normed_block(
+    config: CommonConfig,
+    dtype: Dtype,
+    hidden_states: jax.Array,
+    attention: Callable[[jax.Array], jax.Array],
+    feed_forward: Callable[[jax.Array], Any],
+) -> tuple[jax.Array, Any]:
+    """The four-norm block of the families that norm each sub-layer's input AND its output
+    (`ouro`, `afmoe`): ``a = h + N2(attention(N1(h)))``, ``h' = a + N4(feed_forward(N3(a)))``,
+    the norms `ln_1`, `ln_1_out`, `ln_2`, `ln_2_out` of the calling block (call it inside the
+    block's compact ``__call__``), each under the scope ``block_norms``. The residual add
+    cannot be fused with the norm that follows it, as `Block` fuses `ln_2`'s. `feed_forward`
+    may return ``(output, extras)`` (a layer of experts with its counters); the extras, or
+    None, come back beside the block's output."""
+
+    def norm(name: str, x: jax.Array) -> jax.Array:
+        with jax.named_scope("block_norms"):
+            return get_norm(config, dtype, name)(x)
+
+    out = checkpoint_name(attention(norm("ln_1", hidden_states)), ATTENTION_OUT_CHECKPOINT_NAME)
+    hidden_states = hidden_states + norm("ln_1_out", out).astype(hidden_states.dtype)
+    out, extras = feed_forward(norm("ln_2", hidden_states)), None
+    if isinstance(out, tuple):
+        out, extras = out
+    hidden_states = hidden_states + norm("ln_2_out", out).astype(hidden_states.dtype)
+    return logical_constraint(hidden_states, ("act_batch", "act_seq", "act_embed")), extras
+
+
 def depth_scaled_init_std(config: CommonConfig) -> float:
     """Residual-projection init std (GPT-2 depth scaling, reference gpt_dolomite init):
     initializer_range / sqrt(total residual-branch count). Decoder-only families add 2
@@ -586,12 +614,20 @@ def _paged_prefill_pallas_attention(
 
 
 class Attention(nn.Module):
-    """Self-attention with fused QKV, RoPE/alibi, KV cache, all head types."""
+    """Self-attention with fused QKV, RoPE/alibi, KV cache, all head types.
+
+    `window`: the layer sees a query's own key and the ``window - 1`` before it (a family whose
+    layers differ by mask says so a layer: `models/afmoe.py`); None is every key before it. A
+    layer that takes no positions is called with ``rope_cos_sin=None``. A config with
+    `attention_output_gate` multiplies ``sigmoid(W_g h)`` (``W_g``: `g_proj`, as wide as the
+    heads' output, no bias, its input the layer's input) into the heads' output before `c_proj`
+    (scope ``attention_gate``)."""
 
     config: CommonConfig
     attention_implementation: AttentionImplementation = AttentionImplementation.sdpa
     causal: bool = True
     dtype: Dtype = jnp.float32
+    window: int | None = None
 
     @nn.compact
     def __call__(
@@ -665,8 +701,31 @@ class Attention(nn.Module):
         softmax_scale = get_softmax_scale(config, head_dim)
         attn_pdrop = 0.0 if deterministic else config.attn_pdrop
 
+        def project_out(out: jax.Array) -> jax.Array:
+            """The heads' output ``[B, S, Hq, D]`` through the gate (a config that has one),
+            `c_proj` and the residual dropout."""
+            out = out.reshape(batch, seq, num_heads * head_dim)
+            if config.attention_output_gate:
+                with jax.named_scope("attention_gate"):
+                    gate = ParameterizedLinear(
+                        features=num_heads * head_dim,
+                        use_bias=False,
+                        std=config.initializer_range,
+                        kernel_axes=("embed", "heads"),
+                        dtype=self.dtype,
+                        name="g_proj",
+                    )(hidden_states)
+                    out = out * jax.nn.sigmoid(gate)
+            out = c_proj(out)
+            return nn.Dropout(rate=config.resid_pdrop)(out, deterministic=deterministic)
+
         query_offset = 0
         if kv_cache is not None:
+            if self.window is not None:
+                raise NotImplementedError(
+                    f"a KV cache under a window of {self.window}: the cache's decode and prefill walks know "
+                    "no window (ROADMAP M6)"
+                )
             assert cache_index is not None
             # the kernel accumulates scores and softmax in fp32 (the eager-reference
             # numerics), so a config that opts out of fp32 softmax stays on XLA
@@ -677,10 +736,7 @@ class Attention(nn.Module):
                 out, kv_cache = _paged_pallas_attention(
                     query, key, value, kv_cache, cache_index, softmax_scale
                 )
-                out = out.reshape(batch, seq, num_heads * head_dim)
-                out = c_proj(out)
-                out = nn.Dropout(rate=config.resid_pdrop)(out, deterministic=deterministic)
-                return out, kv_cache
+                return project_out(out), kv_cache
             if config.attention_softmax_in_fp32 and _paged_prefill_eligible(
                 kv_cache, cache_index, attention_mask, segment_ids, alibi_bias,
                 self.causal, attn_pdrop, seq,
@@ -689,10 +745,7 @@ class Attention(nn.Module):
                     query, key, value, kv_cache, cache_index, attention_mask,
                     softmax_scale,
                 )
-                out = out.reshape(batch, seq, num_heads * head_dim)
-                out = c_proj(out)
-                out = nn.Dropout(rate=config.resid_pdrop)(out, deterministic=deterministic)
-                return out, kv_cache
+                return project_out(out), kv_cache
             # prefill fast path ONLY when the write position is STATICALLY zero and the
             # chunk is multi-token (generation_utils passes cache_index=0 as a python int):
             # attending over the just-written LOCAL k/v is then exactly cache[0:seq], and
@@ -736,12 +789,9 @@ class Attention(nn.Module):
             dropout=attn_pdrop,
             dropout_rng=dropout_rng,
             query_offset=query_offset,
+            window=self.window,
         )
-
-        out = out.reshape(batch, seq, num_heads * head_dim)
-        out = c_proj(out)
-        out = nn.Dropout(rate=config.resid_pdrop)(out, deterministic=deterministic)
-        return out, kv_cache
+        return project_out(out), kv_cache
 
 
 class LatentAttention(nn.Module):

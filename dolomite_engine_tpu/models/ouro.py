@@ -38,7 +38,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
-from jax.ad_checkpoint import checkpoint_name
 
 from ..enums import AttentionImplementation
 from ..ops.attention import watch_kernel_residuals
@@ -47,7 +46,7 @@ from ..ops.rope import RoPEParams, get_cos_sin
 from ..parallel.sharding import logical_constraint
 from .config import OuroConfig
 from .gpt_dolomite import CausalLMOutput, HeadTableForCausalLM, resolve_remat_policy, say_remat_plan
-from .modeling_utils import ATTENTION_OUT_CHECKPOINT_NAME, MLP, Attention, ParameterizedEmbedding, get_norm
+from .modeling_utils import MLP, Attention, ParameterizedEmbedding, get_norm, sandwich_normed_block
 
 NOT_BUILT = {
     "kv_cache": "a KV cache (generation and the serving engine need one per pass and layer)",
@@ -88,19 +87,15 @@ class OuroBlock(nn.Module):
     @nn.compact
     def __call__(self, hidden_states: jax.Array, attention_mask=None, segment_ids=None, rope_cos_sin=None, deterministic: bool = True) -> jax.Array:
         config = self.config
-
-        def norm(name: str, x: jax.Array) -> jax.Array:
-            with jax.named_scope("block_norms"):
-                return get_norm(config, self.dtype, name)(x)
-
-        out, _ = Attention(config=config, attention_implementation=self.attention_implementation, dtype=self.dtype, name="attn")(
-            norm("ln_1", hidden_states), attention_mask=attention_mask, segment_ids=segment_ids, rope_cos_sin=rope_cos_sin, deterministic=deterministic
+        attn = Attention(config=config, attention_implementation=self.attention_implementation, dtype=self.dtype, name="attn")
+        hidden_states, _ = sandwich_normed_block(
+            config,
+            self.dtype,
+            hidden_states,
+            lambda h: attn(h, attention_mask=attention_mask, segment_ids=segment_ids, rope_cos_sin=rope_cos_sin, deterministic=deterministic)[0],
+            lambda h: MLP(config=config, dtype=self.dtype, name="mlp")(h, deterministic=deterministic),
         )
-        out = checkpoint_name(out, ATTENTION_OUT_CHECKPOINT_NAME)
-        hidden_states = hidden_states + norm("ln_1_out", out).astype(hidden_states.dtype)
-        out = MLP(config=config, dtype=self.dtype, name="mlp")(norm("ln_2", hidden_states), deterministic=deterministic)
-        hidden_states = hidden_states + norm("ln_2_out", out).astype(hidden_states.dtype)
-        return logical_constraint(hidden_states, ("act_batch", "act_seq", "act_embed"))
+        return hidden_states
 
 
 class OuroStack(nn.Module):

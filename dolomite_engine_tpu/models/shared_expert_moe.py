@@ -1,5 +1,5 @@
 """The expert layer of the families routed by sigmoid scores with a correction bias
-(`nemotron_h`, `joyai_llm_flash`, `lfm2_moe`): a router that scores ALL experts, the chip's
+(`nemotron_h`, `joyai_llm_flash`, `lfm2_moe`, `afmoe`): a router that scores ALL experts, the chip's
 share of the routed experts (`ops/moe.experts_held_ragged`) and — where the family has one — a
 shared expert every token passes. Also what those families refuse, said once
 (`refuse_what_is_not_built`, `refuse_generation_cache`)."""
@@ -82,7 +82,7 @@ class SharedExpertMoE(nn.Module):
     of each held expert).
 
     Every width comes from the family's config (`NemotronHConfig`, `JoyAIFlashConfig`,
-    `Lfm2MoeConfig`): `num_experts`, `num_experts_per_tok`, `moe_intermediate_size`,
+    `Lfm2MoeConfig`, `AfmoeConfig`): `num_experts`, `num_experts_per_tok`, `moe_intermediate_size`,
     `moe_shared_expert_intermediate_size`, `routed_scaling_factor`, `norm_topk_prob`,
     `held_experts()` and `activation_function` — with a gated one (``swiglu``) the up
     banks and the shared expert's up projection are twice as wide, ``[up | gate]`` as

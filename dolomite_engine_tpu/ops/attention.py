@@ -20,6 +20,13 @@ tables in scalar memory, and those are built each step from the rows' segment id
 (`document_block_pairs`, `_document_block_tables`): a (query block, key block) pair under the
 diagonal that no document spans is neither fetched nor computed, in the forward pass, in dkv and
 in dq. A call without segment ids runs jax's static causal tables, as before.
+
+A window (`window`: query i sees key j iff ``0 <= i - j < window``, itself and the ``window - 1``
+before it, inside its document) is one more term everywhere the causal term stands: in the
+dense mask of `make_attention_mask` (eager, sdpa), in `document_block_pairs` (a key block
+further back than the window reaches is not needed) and in the splash kernel's in-block mask
+function (jax's `LocalMask` in place of its `CausalMask`). None is no window, and the programs
+of before. Ring / ulysses and the legacy flash kernel refuse a window rather than ignore it.
 """
 
 from __future__ import annotations
@@ -29,6 +36,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..enums import AttentionImplementation
 
@@ -44,8 +52,11 @@ def make_attention_mask(
     segment_ids_q: jax.Array | None = None,
     segment_ids_kv: jax.Array | None = None,
     query_offset: jax.Array | int = 0,
+    window: int | None = None,
 ) -> jax.Array | None:
     """Boolean [B, 1, Sq, Skv] mask (True = attend), or None when fully visible.
+
+    `window` (with `causal`): a query sees itself and the ``window - 1`` keys before it.
 
     `query_offset` may be a per-row [B] vector (continuous batching: every slot
     continues at its own cache position), producing a per-row causal frontier. This
@@ -59,11 +70,13 @@ def make_attention_mask(
         if getattr(query_offset, "ndim", 0) == 1:
             q_pos = jnp.arange(query_length)[None, :, None] + query_offset[:, None, None]
             k_pos = jnp.arange(key_length)[None, None, :]
-            mask = (k_pos <= q_pos)[:, None]  # [B, 1, Sq, Skv]
+            mask = _causal_pairs(q_pos, k_pos, window)[:, None]  # [B, 1, Sq, Skv]
         else:
             q_pos = jnp.arange(query_length)[:, None] + query_offset
             k_pos = jnp.arange(key_length)[None, :]
-            mask = (k_pos <= q_pos)[None, None]
+            mask = _causal_pairs(q_pos, k_pos, window)[None, None]
+    elif window is not None:
+        raise NotImplementedError("a window without the causal mask is not built")
 
     if attention_mask is not None:
         pad = attention_mask.astype(bool)[:, None, None, :]  # [B, 1, 1, Skv]
@@ -80,6 +93,12 @@ def make_attention_mask(
         mask = seg if mask is None else jnp.logical_and(mask, seg)
 
     return mask
+
+
+def _causal_pairs(q_pos: jax.Array, k_pos: jax.Array, window: int | None) -> jax.Array:
+    """``k <= q``, and with a window ``q - k < window``."""
+    pairs = k_pos <= q_pos
+    return pairs if window is None else pairs & (q_pos - k_pos < window)
 
 
 def paged_scatter_kv(
@@ -357,6 +376,7 @@ def _tpu_splash_attention(
     segment_ids: jax.Array | None,
     softmax_scale: float,
     interpret: bool = False,
+    window: int | None = None,
 ) -> jax.Array:
     """GQA-native Pallas splash attention: K/V keep their kv-head count (no `_repeat_kv`
     HBM blowup); the kernel maps q head h to kv head h // (Hq // Hkv). Causal-only (alibi
@@ -379,7 +399,7 @@ def _tpu_splash_attention(
     )
 
     def local(q, k, v, *seg):
-        return (_splash_attention_local(q, k, v, seg[0] if seg else None, softmax_scale, interpret),)
+        return (_splash_attention_local(q, k, v, seg[0] if seg else None, softmax_scale, interpret, window),)
 
     (out,) = shard_kernel(local, sharding)(q, k, v, *segments)
     return out
@@ -405,22 +425,43 @@ def watch_kernel_residuals():
 
 # what a step's forward pass counts of the kernel's block tables (`splash_block_counters`)
 SPLASH_COUNTERS = ("splash_blocks_visited", "splash_blocks_causal")
+# ... of a model whose layers differ by mask (`models/afmoe.py`): the tables of the window
+# layers and of the full layers, each summed over the layers of its kind, and the blocks
+# under the diagonal summed over all of them
+SPLASH_COUNTERS_BY_KIND = ("splash_blocks_visited_window", "splash_blocks_visited_full", "splash_blocks_causal")
 
 
-def document_block_pairs(segment_ids: jax.Array, block: int) -> jax.Array:
+def window_block_reach(window: int, block: int) -> int:
+    """How many key blocks back of its own a query block can reach under `window`: the nearest
+    pair of query block i and key block j < i lies ``(i - j - 1) * block + 1`` apart, and a
+    window lets through distances up to ``window - 1``."""
+    return (window - 2) // block + 1
+
+
+def _block_band(n: int, window: int, block: int) -> np.ndarray:
+    """bool ``[n, n]``: key block j on or under the diagonal of query block i and within the
+    window's reach."""
+    return np.triu(np.tril(np.ones((n, n), bool)), -window_block_reach(window, block))
+
+
+def document_block_pairs(segment_ids: jax.Array, block: int, window: int | None = None) -> jax.Array:
     """bool ``[B, n, n]`` (``n = S // block``): whether (query block i, key block j) of a row
     holds a pair the causal, per-document mask lets through — j on or under the diagonal and
     the two blocks' ids meet. The test is each block's ``[min, max]`` of the ids: ranges that
     do not meet share no id, whatever the order of the ids, so a needed pair is never dropped;
     on ids that do not decrease along the row it is exact. Padding (id 0, at a row's tail)
-    is given the largest id first, so that it too is in order."""
+    is given the largest id first, so that it too is in order. With a `window`, one more
+    term: a key block further back than the window reaches (`window_block_reach`) is not
+    needed, whatever its ids."""
     batch, seq = segment_ids.shape
     n = seq // block
     ids = segment_ids.astype(jnp.int32)
     ids = jnp.where(ids == 0, jnp.iinfo(jnp.int32).max, ids).reshape(batch, n, block)
     low, high = ids.min(-1), ids.max(-1)  # [B, n]
     meet = (low[:, :, None] <= high[:, None, :]) & (low[:, None, :] <= high[:, :, None])
-    return meet & jnp.tril(jnp.ones((n, n), bool))
+    if window is None:
+        return meet & jnp.tril(jnp.ones((n, n), bool))
+    return meet & _block_band(n, window, block)
 
 
 def _document_block_tables(needed: jax.Array):
@@ -454,18 +495,40 @@ def _document_block_tables(needed: jax.Array):
     return forward, (key_major(needed.astype(jnp.int32)), key_major(following))
 
 
-def splash_block_counters(batch: int, seq: int, segment_ids: jax.Array | None = None) -> dict:
+def splash_block_counters(batch: int, seq: int, segment_ids: jax.Array | None = None, window: int | None = None) -> dict:
     """`SPLASH_COUNTERS` of one attention layer over these rows: the (query block, key block)
     pairs the kernel's tables make it run, and those under the diagonal. Their ratio is what
-    the documents left of the work; 1.0 says the tables skipped nothing (no segment ids).
-    Zeros where the kernel does not take the length."""
+    the documents (and the layer's `window`, where it has one) left of the work; 1.0 says the
+    tables skipped nothing (no segment ids, no window). Layers of one mask share the ids'
+    tables; a model whose layers differ by mask asks once a kind
+    (`splash_block_counters_by_kind`). Zeros where the kernel does not take the length."""
     if seq % 128 != 0:
         return dict.fromkeys(SPLASH_COUNTERS, jnp.zeros((), jnp.int32))
     block = _pick_block(seq)
     n = seq // block
     causal = jnp.asarray(batch * n * (n + 1) // 2, jnp.int32)
-    visited = causal if segment_ids is None else document_block_pairs(segment_ids, block).sum(dtype=jnp.int32)
+    if segment_ids is not None:
+        visited = document_block_pairs(segment_ids, block, window).sum(dtype=jnp.int32)
+    elif window is not None:
+        visited = jnp.asarray(batch * _block_band(n, window, block).sum(), jnp.int32)
+    else:
+        visited = causal
     return {"splash_blocks_visited": visited, "splash_blocks_causal": causal}
+
+
+def splash_block_counters_by_kind(
+    batch: int, seq: int, segment_ids: jax.Array | None, window: int, window_layers: int, full_layers: int
+) -> dict:
+    """`SPLASH_COUNTERS_BY_KIND` of a step whose `window_layers` attention layers run under
+    `window` and whose `full_layers` run without: each kind's tables counted once and
+    multiplied by its layers, the blocks under the diagonal by all of them."""
+    windowed = splash_block_counters(batch, seq, segment_ids, window)
+    full = splash_block_counters(batch, seq, segment_ids)
+    return {
+        "splash_blocks_visited_window": window_layers * windowed["splash_blocks_visited"],
+        "splash_blocks_visited_full": full_layers * full["splash_blocks_visited"],
+        "splash_blocks_causal": (window_layers + full_layers) * full["splash_blocks_causal"],
+    }
 
 
 def _rows_end_to_end(x: jax.Array) -> jax.Array:
@@ -473,16 +536,18 @@ def _rows_end_to_end(x: jax.Array) -> jax.Array:
     return jnp.transpose(x, (2, 0, 1, 3)).reshape(x.shape[2], x.shape[0] * x.shape[1], x.shape[3])
 
 
-def _splash_attention_local(q, k, v, segment_ids, softmax_scale: float, interpret: bool):
-    """jax's splash kernel over one device's rows, causal, blocks of `_pick_block`.
+def _splash_attention_local(q, k, v, segment_ids, softmax_scale: float, interpret: bool, window: int | None = None):
+    """jax's splash kernel over one device's rows, causal (under a `window`, where the layer
+    has one), blocks of `_pick_block`.
 
     Without segment ids: the static causal kernel (`make_splash_mha_single_device`), whose
     tables know the diagonal, under `jax.vmap` over the rows. With segment ids: the same
     kernel functions on tables built from the ids (`_document_block_tables`), so the blocks
     no document spans are not run; the rows go in end to end as one sequence (Pallas would
     batch a per-row scalar-prefetch operand with a loop of slices and copies over the rows).
-    The mask inside a block — causal on positions, equality on segment ids — is the same
-    on both paths, and a skipped block is one whose every entry it masked."""
+    The mask inside a block — causal on positions (jax's `CausalMask` function; with a
+    window its `LocalMask` function: ``q - (window - 1) <= k <= q``), equality on segment ids —
+    is the same on both paths, and a skipped block is one whose every entry it masked."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as _sk,
         splash_attention_mask as _sm,
@@ -518,6 +583,8 @@ def _splash_attention_local(q, k, v, segment_ids, softmax_scale: float, interpre
         launches_per_call=1 if segment_ids is not None else batch,
         tables="segment_ids" if segment_ids is not None else "static",
         why_static=None if segment_ids is not None else "the call has no segment ids",
+        # a layer under a window, and the key blocks (its own among them) a query block can reach
+        **({} if window is None else {"window": window, "window_key_blocks": window_block_reach(window, bkv) + 1}),
     )
 
     # jax's static causal kernel of one row: its tables know the diagonal. The name makes it
@@ -525,7 +592,11 @@ def _splash_attention_local(q, k, v, segment_ids, softmax_scale: float, interpre
     # (save_dots does) and the backward pass need not run the forward kernel again; outside
     # a remat and under a policy without the name the tag is an identity that does not
     # reach the HLO
-    mask = _sm.MultiHeadMask([_sm.CausalMask((sq, skv)) for _ in range(num_q_heads)])
+    if window is None:
+        head_mask = _sm.CausalMask((sq, skv))
+    else:
+        head_mask = _sm.LocalMask((sq, skv), window_size=(window - 1, 0), offset=0)
+    mask = _sm.MultiHeadMask([head_mask for _ in range(num_q_heads)])
     kernel = _sk.make_splash_mha_single_device(
         mask,
         block_sizes=block_sizes,
@@ -544,7 +615,7 @@ def _splash_attention_local(q, k, v, segment_ids, softmax_scale: float, interpre
     # tables; the positions are those of the rows laid end to end, where a row's causal
     # order is what it was
     (block_mask, data_next), (block_mask_dkv, data_next_dkv) = _document_block_tables(
-        document_block_pairs(segment_ids, bq)
+        document_block_pairs(segment_ids, bq, window)
     )
     tables = kernel.fwd_mask_info._replace(
         data_next=data_next, block_mask=block_mask, q_sequence=jnp.arange(batch * sq, dtype=jnp.int32)
@@ -650,12 +721,21 @@ def attention(
     dropout: float = 0.0,
     dropout_rng: jax.Array | None = None,
     query_offset: jax.Array | int = 0,
+    window: int | None = None,
 ) -> jax.Array:
-    """Dispatch to the configured implementation; returns [B, Sq, Hq, D]."""
+    """Dispatch to the configured implementation; returns [B, Sq, Hq, D]. `window`: a query
+    sees itself and the ``window - 1`` keys before it (causal attention only)."""
     if softmax_scale is None:
         softmax_scale = q.shape[-1] ** -0.5
+    if window is not None and (not causal or window < 1):
+        raise ValueError(f"a window of {window} keys on {'causal' if causal else 'non-causal'} attention")
 
     if implementation in (AttentionImplementation.ring, AttentionImplementation.ulysses):
+        if window is not None:
+            raise NotImplementedError(
+                f"{implementation.value} attention under a window of {window}: the exchange of key/value "
+                "shards knows no window (a shard further back than the window would still travel); not built"
+            )
         from ..parallel.mesh import MeshManager
         from .ring_attention import ring_attention_sharded
         from .ulysses_attention import ulysses_attention_sharded
@@ -754,7 +834,12 @@ def attention(
             warn_rank_0("flash_attention_2 lowers as sdpa here: " + "; ".join(dropped))
     if use_flash:
         if causal and alibi_bias is None and _use_splash_kernel():
-            return _tpu_splash_attention(q, k, v, segment_ids, softmax_scale)
+            return _tpu_splash_attention(q, k, v, segment_ids, softmax_scale, window=window)
+        if window is not None:
+            raise NotImplementedError(
+                f"the legacy flash kernel under a window of {window}: its causal mask knows no window; "
+                "run the splash kernel (kernel_args splash_attention) or sdpa"
+            )
         return _tpu_flash_attention(q, k, v, alibi_bias, segment_ids, causal, softmax_scale)
 
     if segment_ids is not None and q.shape[1] != k.shape[1]:
@@ -768,6 +853,7 @@ def attention(
         attention_mask=attention_mask,
         segment_ids_q=segment_ids,
         query_offset=query_offset,
+        window=window,
     )
 
     if implementation == AttentionImplementation.eager or dropout > 0.0:

@@ -400,6 +400,8 @@ def get_model_tflops(
     ``save_dots``/``offload_dots`` add ~0 (only elementwise ops replay),
     ``save_attention_out`` discounts the saved out-projection dot — so reported MFU
     tracks the actual recompute a policy buys instead of flattering partial-remat runs.
+    A family whose layers attend under a window counts that layer's score and value products
+    over ``min(s, window)`` keys a token (`AfmoeConfig.forward_block_flops`).
     """
     from .ops.activations import is_glu
 
@@ -505,6 +507,13 @@ def estimate_remat_activation_bytes(
 
     token_bytes = b * s * dtype_bytes
     boundary = l // max(every, 1) * token_bytes * h if every else l * token_bytes * h
+    # the keys a query's kept scores span, a layer's mean: a layer under a window keeps a band of
+    # `min(s, window)` (a family whose layers differ by mask says so a layer: `AfmoeConfig.layer_window`)
+    keys = s
+    if hasattr(config, "layer_window"):
+        keys = sum(min(s, config.layer_window(i) or s) for i in range(config.n_layer)) / config.n_layer
+    # the gate on attention's output is one more projection as wide as the heads' output
+    gate_width = n * config.head_dim if getattr(config, "attention_output_gate", False) else 0
 
     per_block_extra = kernel_residuals = 0.0
     if every:
@@ -514,7 +523,8 @@ def estimate_remat_activation_bytes(
             glu = 2 if "glu" in str(config.activation_function) else 1
             per_block_extra = token_bytes * (
                 h * (1 + 2 * kvh / n)  # qkv projection output
-                + (0 if attention_kernel else n * s + h)  # scores [b, n, s, s] + context
+                + (0 if attention_kernel else n * keys + h)  # scores [b, n, s, keys] + context
+                + gate_width  # the gate's projection output (a config with `attention_output_gate`)
                 + 2 * h  # attention out proj + mlp c_proj
                 + glu * f  # c_fc output
             )

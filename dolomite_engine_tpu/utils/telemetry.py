@@ -339,13 +339,15 @@ KNOWN_EVENTS: tuple[str, ...] = (
     # (ops/attention._splash_attention_local): block_q, block_kv, the rows of a call, a
     # launch's grid (heads, query blocks, key slots), launches a call, and whether the block
     # tables come from the rows' segment ids ("segment_ids": blocks no document spans are
-    # skipped) or are jax's static causal ones ("static", with why_static)
+    # skipped) or are jax's static causal ones ("static", with why_static); a layer under a
+    # window says window and window_key_blocks (the key blocks a query block can reach)
     "splash_block_plan",
     # what a model cut to one chip's share holds of what was published (models/config.py
     # NemotronHConfig.layout_record: pattern, experts held of published, vocabulary rows
     # held, the deployment's numbers; JoyAIFlashConfig.layout_record: blocks by kind in the
     # pattern's place; Lfm2MoeConfig.layout_record: layer_types and the blocks by operator
-    # and by feed-forward), once a run
+    # and by feed-forward; AfmoeConfig.layout_record: layer_types, the blocks by attention's
+    # kind and by feed-forward, sliding_window), once a run
     "model_layout",
     # the one rope+QKV seam where a call norms q and k per head (ops/rope.split_qkv_apply_rope,
     # a config with qk_norm) and the fused rope+QKV kernel, promoted there, steps aside: form
@@ -371,7 +373,9 @@ KNOWN_EVENTS: tuple[str, ...] = (
     # the grouped products multiply), absent_slots, fullest_expert_rows, held_expert_rows;
     # for joyai_llm_flash the same (its multi-token-prediction module's layer last) and the
     # loss's two parts main_loss and mtp_loss with mtp_targets, the positions the second had;
-    # for lfm2_moe the four counters of its layers of experts;
+    # for lfm2_moe the four counters of its layers of experts; for afmoe the same, and its splash
+    # counters by the layer's kind (splash_blocks_visited_window, splash_blocks_visited_full,
+    # splash_blocks_causal: ops/attention.splash_block_counters_by_kind);
     # for every family whose attention runs the splash kernel splash_blocks_visited and
     # splash_blocks_causal (ops/attention.splash_block_counters: the block pairs one
     # attention layer's tables ran over the step's rows, and those under the diagonal)

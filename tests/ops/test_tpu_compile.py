@@ -481,6 +481,35 @@ def test_lfm2_moe_step_compiles_for_v5e_at_published_widths(v5e, capsys):
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.5 * gib
 
 
+def test_afmoe_step_compiles_for_v5e_at_published_widths_with_both_kinds_of_attention_through_splash(v5e, capsys):
+    """The cell `train-trinity-mini-swa-packed16k`'s whole train step — four window layers and a
+    full one of `afmoe` at published widths (32 query heads over 4 key/value heads of 128, a window
+    of 2048, QK norms, the gate, four norms a block), a dense MLP and four layers of 128-way experts
+    with a shared one, 2 packed rows of 16384 tokens, AdamW, built as `pretrain.main` builds it —
+    for one described v5e: every attention layer lowers through splash on the block tables (the
+    window layers under jax's local mask function), the fused rope+QKV kernel steps aside for the
+    norms, the megablox products run gated banks of 1024, and the program fits the chip (an
+    estimate: the chip's reading is in PERF.md)."""
+    compiled = _compiled_cell_step(v5e, "train-trinity-mini-swa-packed16k")
+    memory, text = compiled.memory_analysis(), compiled.as_text()
+    gib = 2.0**30
+    with capsys.disabled():
+        print(
+            f"\ntrain-trinity-mini-swa-packed16k step for a described v5e: state {memory.argument_size_in_bytes / gib:.2f} GiB, "
+            f"temporaries (estimate) {memory.temp_size_in_bytes / gib:.3f} GiB"
+        )
+    kernels = [line.split(" = ")[0] for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    count = lambda prefix: sum(name.strip().lstrip("%").startswith(prefix) for name in kernels)  # noqa: E731
+    # 5 attention blocks x (forward, its replay under `full` remat, dkv, dq) of splash: no layer of either kind is left to XLA's products
+    assert (count("splash_mha_fwd"), count("splash_mha_dkv"), count("splash_mha_dq")) == (10, 5, 5), kernels
+    # 4 layers of experts: the grouped products and the activation's launch between them
+    assert "ragged-dot" not in text and count("gmm") >= 48 and count("tgmm") == 16 and count("moe_routed_row_blocks") >= 24, kernels
+    assert not any("rope_qkv" in name for name in kernels), kernels  # the norms sit before the rotation: XLA's form
+    assert not _whole_buffer_row_movements(text, 8 * 32768, 2048)  # the experts' rows are walked in blocks, never moved whole
+    assert 4.6 * gib < memory.argument_size_in_bytes < 4.8 * gib  # 504.1M parameters x 10 B of state
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.0 * gib  # the issue's bound for 2 rows a step
+
+
 def test_ouro_step_compiles_for_v5e_at_published_widths_with_one_copy_of_the_stack(v5e, capsys):
     """The cell `train-ouro-loop4-packed8k`'s whole train step — eight sandwich-normed blocks of
     `ouro` at published widths run four times over shared weights, 2 packed rows of 8192 tokens,

@@ -282,3 +282,46 @@ def test_ouro_names_the_pass_its_norms_the_gate_and_the_weighting():
     # the head: one forward scan over chunks, and the backward rule's
     loss_scans = {name for op, name in _operation_names(lowered) if op == "while" and "head_loss" in name}
     assert sum("transpose(" not in name for name in loss_scans) == 1, loss_scans
+
+
+def test_afmoe_names_its_two_kinds_of_attention_the_gate_and_its_norms():
+    """`afmoe`'s lowered train step carries the scopes docs/OBSERVABILITY.md lists — `attention`
+    and inside it `attention_window` or `attention_full` by the layer's kind, `qk_norm` and
+    `attention_gate` inside those, `dense_mlp`, the experts' five, `block_norms` — forward and
+    backward, and leaves no matmul of the blocks outside a layer's scope."""
+    from dolomite_engine_tpu.models import get_model_class
+    from tests.models.test_afmoe import CFG
+
+    model = get_model_class("afmoe")(config=config_from_dict(CFG), checkpoint_every=1, dtype=jnp.bfloat16)
+    ids = jnp.zeros((1, 32), jnp.int32)
+    params = nn.unbox(model.init(jax.random.PRNGKey(0), ids)["params"])
+    optimizer = optax.adamw(1e-3)
+    state = TrainState(step=jnp.zeros((), jnp.int32), params=params, opt_state=optimizer.init(params))
+
+    def loss_fn(params, micro, rng):
+        out = model.apply({"params": params}, micro["text"], compute_loss=True)
+        return out.loss, out.counters
+
+    lowered = jax.jit(make_train_step(loss_fn, optimizer, skip_nonfinite=True, has_aux=True)).lower(
+        state, {"text": jnp.zeros((1, 1, 32), jnp.int32)}, jax.random.PRNGKey(0)
+    )
+    names = [name for _, name in _operation_names(lowered)]
+    dots = [name for op, name in _operation_names(lowered) if op == "dot_general"]
+    for scope in (
+        "attention_window", "attention_full", "qk_norm", "attention_gate", "dense_mlp", "block_norms",
+        "moe_router", "moe_dispatch", "moe_experts", "moe_shared_expert", "moe_combine",
+    ):
+        assert any(f"/{scope}/" in name and "transpose(" not in name for name in names), scope
+        assert any(f"/{scope}/" in name and "transpose(" in name for name in names), scope
+    for inner in ("attention_window", "attention_full"):
+        assert all("/attention/" in name for name in names if f"/{inner}/" in name)
+    for inner in ("qk_norm", "attention_gate"):
+        assert all(re.search(r"/attention/(attention_window|attention_full)/", name) for name in names if f"/{inner}/" in name)
+    # four of the five blocks are window layers (h_0, h_1, h_3, h_4), one is full (h_2): a kind's scope holds its blocks only
+    assert {re.search(r"/(h_\d)/", name).group(1) for name in names if "/attention_full/" in name and re.search(r"/(h_\d)/", name)} == {"h_2"}
+    assert {re.search(r"/(h_\d)/", name).group(1) for name in names if "/attention_window/" in name and re.search(r"/(h_\d)/", name)} == {"h_0", "h_1", "h_3", "h_4"}
+    # the gate is a projection as wide as the heads' output and an elementwise pass; the norms hold no matmul
+    assert any("/attention_gate/" in name for name in dots) and not any("/block_norms/" in name or "/qk_norm/" in name for name in dots)
+    for name in dots:
+        if "/blocks/" in name:
+            assert re.search(r"/(attention|dense_mlp|moe)/", name), name
