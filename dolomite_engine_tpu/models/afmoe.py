@@ -23,8 +23,9 @@ chip's share of the experts). After the last block a norm and an untied head.
 Packed rows (``segment_ids``): attention, the window and positions reset at document
 boundaries. On a TPU both kinds run jax's splash kernel on this repo's block tables
 (`ops/attention.document_block_pairs`): a window layer's tables drop the key blocks its window
-does not reach, so the two kinds of one step visit different block counts, and the step
-returns them apart (`ops/attention.SPLASH_COUNTERS_BY_KIND`).
+does not reach and are only as wide as that reach, so its launches walk ``reach + 1`` key slots
+a query block where a full layer's walk a row's; the two kinds of one step visit different
+block counts, and the step returns them apart (`ops/attention.SPLASH_COUNTERS_BY_KIND`).
 
 Training path only, and what that refuses is said where the expert families share it
 (`shared_expert_moe.refuse_what_is_not_built`, `refuse_generation_cache`): a generation cache

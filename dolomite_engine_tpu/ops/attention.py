@@ -495,6 +495,40 @@ def _document_block_tables(needed: jax.Array):
     return forward, (key_major(needed.astype(jnp.int32)), key_major(following))
 
 
+def _banded_block_tables(needed: jax.Array, width: int):
+    """`_document_block_tables` for a layer under a window that reaches ``width - 1`` key blocks
+    back of a query block's own, fewer than a row has: the same steps run in the same order,
+    and a launch's grid walks `width` slots a block, not a row's ``n`` (the kernels read the
+    grid's width off the tables, take a key's position from ``data_next`` and not from the slot,
+    and open and close a block's accumulation at the first and last slot whether or not those
+    run).
+
+    Forward and dq get ``[1, B * n, width]``: slot s of query block (b, i) is key block
+    (b, i - (width - 1) + s), the last slot the diagonal; a slot before the row's first block
+    does not run. dkv gets ``[1, width, B * n]``: slot s of key block (b, j) is query block
+    (b, j + s), slot 0 the diagonal; a slot past the row's last block does not run. A step that
+    does not run names the next block of its own query (key) block that does, else the diagonal
+    — always a block of the same row: the index maps fetch what ``data_next`` names, and an
+    index outside ``[0, B * n)`` is a DMA out of bounds on the chip, not an exception. The
+    diagonal always runs, so in the forward's grid order that is the next step that runs."""
+    batch, n, _ = needed.shape
+    place = jnp.arange(batch * n, dtype=jnp.int32).reshape(batch, n, 1)  # a block's, among the rows end to end
+    block, slot = jnp.arange(n, dtype=jnp.int32), jnp.arange(width, dtype=jnp.int32)
+
+    def band(pairs, partner, diagonal):
+        # pairs[b, i, partner[i, s]], nothing where the partner lies outside the row. Read out
+        # by comparing against the constant partner table: a gather of so few elements compiles
+        # to several times the program code on the chip, in every layer that builds tables
+        runs = (pairs[:, :, None, :] & (block == partner[:, :, None])).any(-1)  # [B, n, width]
+        following = jax.lax.cummin(jnp.where(runs, slot, width), axis=2, reverse=True)
+        return runs.astype(jnp.int32), place + jnp.where(following == width, diagonal, following) - diagonal
+
+    forward = band(needed, block[:, None] - (width - 1) + slot, width - 1)
+    dkv = band(jnp.swapaxes(needed, 1, 2), block[:, None] + slot, 0)
+    key_major = lambda t: jnp.swapaxes(t.reshape(batch * n, width), 0, 1)[None]
+    return tuple(t.reshape(1, batch * n, width) for t in forward), tuple(key_major(t) for t in dkv)
+
+
 def splash_block_counters(batch: int, seq: int, segment_ids: jax.Array | None = None, window: int | None = None) -> dict:
     """`SPLASH_COUNTERS` of one attention layer over these rows: the (query block, key block)
     pairs the kernel's tables make it run, and those under the diagonal. Their ratio is what
@@ -545,6 +579,10 @@ def _splash_attention_local(q, k, v, segment_ids, softmax_scale: float, interpre
     kernel functions on tables built from the ids (`_document_block_tables`), so the blocks
     no document spans are not run; the rows go in end to end as one sequence (Pallas would
     batch a per-row scalar-prefetch operand with a loop of slices and copies over the rows).
+    Those tables are a row wide, and a launch's grid walks a row's key slots for every query
+    block; under a `window` that reaches fewer they are as wide as the reach
+    (`_banded_block_tables`: 5 of 32 slots at a window of 2048 on rows of 16384 in blocks of
+    512), and the same blocks run in the same order.
     The mask inside a block — causal on positions (jax's `CausalMask` function; with a
     window its `LocalMask` function: ``q - (window - 1) <= k <= q``), equality on segment ids —
     is the same on both paths, and a skipped block is one whose every entry it masked."""
@@ -573,8 +611,7 @@ def _splash_attention_local(q, k, v, segment_ids, softmax_scale: float, interpre
     for seen in _RESIDUAL_WATCHERS:
         # the output is as wide as the values (latent attention scores over a wider head)
         seen.append(num_q_heads * sq * (v.shape[3] * q.dtype.itemsize + 4))
-    get_telemetry().event_once(
-        "splash_block_plan",
+    plan = dict(
         block_q=bq,
         block_kv=bkv,
         rows=batch,
@@ -586,6 +623,13 @@ def _splash_attention_local(q, k, v, segment_ids, softmax_scale: float, interpre
         # a layer under a window, and the key blocks (its own among them) a query block can reach
         **({} if window is None else {"window": window, "window_key_blocks": window_block_reach(window, bkv) + 1}),
     )
+    # the ids' tables of a layer whose window reaches fewer key blocks than a row has are that
+    # narrow, and so is the launches' grid; every other call's plan and tables are untouched
+    key_slots = None
+    if segment_ids is not None and window is not None and plan["window_key_blocks"] < skv // bkv:
+        key_slots = plan["window_key_blocks"]
+        plan.update(grid=(num_q_heads, batch * (sq // bq), key_slots), key_slots=key_slots)
+    get_telemetry().event_once("splash_block_plan", **plan)
 
     # jax's static causal kernel of one row: its tables know the diagonal. The name makes it
     # tag its output and log-sum-exp with `checkpoint_name`, so a remat policy can keep them
@@ -614,8 +658,9 @@ def _splash_attention_local(q, k, v, segment_ids, softmax_scale: float, interpre
     # the same kernel (its functions, mask value, in-block causal function) on the documents'
     # tables; the positions are those of the rows laid end to end, where a row's causal
     # order is what it was
-    (block_mask, data_next), (block_mask_dkv, data_next_dkv) = _document_block_tables(
-        document_block_pairs(segment_ids, bq, window)
+    needed = document_block_pairs(segment_ids, bq, window)
+    (block_mask, data_next), (block_mask_dkv, data_next_dkv) = (
+        _document_block_tables(needed) if key_slots is None else _banded_block_tables(needed, key_slots)
     )
     tables = kernel.fwd_mask_info._replace(
         data_next=data_next, block_mask=block_mask, q_sequence=jnp.arange(batch * sq, dtype=jnp.int32)

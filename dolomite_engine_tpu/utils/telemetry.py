@@ -340,7 +340,9 @@ KNOWN_EVENTS: tuple[str, ...] = (
     # launch's grid (heads, query blocks, key slots), launches a call, and whether the block
     # tables come from the rows' segment ids ("segment_ids": blocks no document spans are
     # skipped) or are jax's static causal ones ("static", with why_static); a layer under a
-    # window says window and window_key_blocks (the key blocks a query block can reach)
+    # window says window and window_key_blocks (the key blocks a query block can reach) and,
+    # where that is fewer than a row's and the tables are the ids', key_slots: the tables are
+    # that narrow, and it is the grid's last (a record without it: a row's key blocks)
     "splash_block_plan",
     # what a model cut to one chip's share holds of what was published (models/config.py
     # NemotronHConfig.layout_record: pattern, experts held of published, vocabulary rows

@@ -20,6 +20,7 @@ from dolomite_engine_tpu.enums import AttentionImplementation
 from dolomite_engine_tpu.ops import attention as attention_ops
 from dolomite_engine_tpu.ops.attention import (
     SPLASH_COUNTERS_BY_KIND,
+    _document_block_tables,
     _pick_block,
     _repeat_kv,
     _tpu_splash_attention,
@@ -34,7 +35,7 @@ from dolomite_engine_tpu.ops.attention import (
 )
 from dolomite_engine_tpu.utils.telemetry import Telemetry, install_telemetry, uninstall_telemetry
 
-from tests.ops.test_splash_block_tables import _ids, _random_documents  # rows of documents, as the tables' own tests make them
+from tests.ops.test_splash_block_tables import _ids, _launch_grids, _random_documents  # rows of documents, as the tables' own tests make them
 
 SEQ, BLOCK = 640, 128  # `_pick_block(640)` is 128: five blocks a row
 
@@ -145,6 +146,22 @@ def test_no_window_leaves_the_tables_jaxpr_alone():
     assert digest(lambda s: document_block_pairs(s, BLOCK_T, 200)) != PARENT_JAXPRS["document_block_pairs"]
 
 
+# the gradient of the segmented call on 2 rows of 1024 (two blocks of 512 a row) as the commit
+# before this one (aac3fe9) traced it: no window, and windows that reach both of a row's blocks
+PARENT_GRADIENTS = {None: "e7cb68c746d8e6ab", 200: "ac172c352f59d8aa", 2000: "0fd794f6e3397908"}
+
+
+@pytest.mark.parametrize("window", PARENT_GRADIENTS)
+def test_a_call_whose_window_reaches_the_whole_row_or_that_has_none_is_the_parent_s_program(window):
+    """Banded tables are built only where they are narrower than a row: everywhere else the
+    three launches, their tables and everything around them are what they were."""
+    q = jax.ShapeDtypeStruct((2, SEQ_T, 4, 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((2, SEQ_T, 2, 128), jnp.bfloat16)
+    ids = jax.ShapeDtypeStruct((2, SEQ_T), jnp.int32)
+    grad = jax.grad(lambda q, k, v, s: _tpu_splash_attention(q, k, v, s, 128**-0.5, window=window).astype(jnp.float32).sum(), argnums=(0, 1, 2))
+    assert hashlib.sha256(str(jax.make_jaxpr(grad)(q, kv, kv, ids)).encode()).hexdigest()[:16] == PARENT_GRADIENTS[window]
+
+
 def test_counters_by_kind_count_each_kind_s_tables():
     rows = jnp.asarray(np.stack([ID_ROWS["packed_seed0"][0], ID_ROWS["one_document"][0]]))
     block = _pick_block(SEQ_T)  # 512: two blocks a row
@@ -213,6 +230,75 @@ def test_splash_on_the_windowed_tables_is_sdpa_under_the_same_window(rows, windo
         np.testing.assert_allclose(np.asarray(ours), np.asarray(plain), atol=2e-4, rtol=2e-4)
 
 
+# ---------------------------------------------------------------- tables as wide as the window reaches
+
+BANDED_ROWS = {
+    "packed_documents": [_ids([128, 384, 128], SEQ), _ids([40, 300, 200, 100], SEQ)],
+    "tail_padding": [_ids([150, 170, 130], SEQ), _ids([500], SEQ)],
+    "one_document": [_ids([SEQ], SEQ)],
+    "ids_shuffled": [np.random.RandomState(3).permutation(9)[_ids([90, 200, 30, 170, 150], SEQ)].astype(np.int32) + 1],
+}
+# (window, the key slots its reach gives at five blocks of 128 a row): the diagonal alone, one
+# block back, all but a row's first block
+BANDED_WINDOWS = {1: 1, 100: 2, 300: 4}
+
+
+@pytest.mark.parametrize("window", BANDED_WINDOWS)
+@pytest.mark.parametrize("rows", BANDED_ROWS)
+def test_banded_tables_give_the_row_wide_tables_result_bit_for_bit_and_sdpa_s(rows, window, monkeypatch):
+    """Output, dq, dk and dv under tables ``reach + 1`` slots wide: bit for bit those of the
+    row-wide tables the commit before this one (aac3fe9) built for every layer (the same blocks
+    run in the same order), and `sdpa`'s under the same window wherever a token is no padding."""
+    hq, hkv, d = 2, 1, 128
+    segment_ids = jnp.asarray(np.stack(BANDED_ROWS[rows]))
+    batch, n, slots = segment_ids.shape[0], SEQ // BLOCK, BANDED_WINDOWS[window]
+    assert slots == window_block_reach(window, BLOCK) + 1 < n
+    rng = np.random.RandomState(len(rows) + window)
+    q = jnp.asarray(rng.randn(batch, SEQ, hq, d), jnp.float32)
+    k = jnp.asarray(rng.randn(batch, SEQ, hkv, d), jnp.float32)
+    v = jnp.asarray(rng.randn(batch, SEQ, hkv, d), jnp.float32)
+    real = (segment_ids != 0)[:, :, None, None]
+    weight = jnp.asarray(rng.randn(batch, SEQ, hq, d), jnp.float32) * real
+
+    def kernel(q, k, v):
+        return _tpu_splash_attention(q, k, v, segment_ids, d**-0.5, interpret=True, window=window)
+
+    def reference(q, k, v):
+        mask = make_attention_mask(batch, SEQ, SEQ, causal=True, segment_ids_q=segment_ids, window=window)
+        return sdpa_attention(q, _repeat_kv(k, hq), _repeat_kv(v, hq), mask, None, d**-0.5)
+
+    def value_and_gradients(fn):
+        def loss(q, k, v):
+            out = fn(q, k, v)
+            return (out * weight).sum(), out * real
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+
+    banded = value_and_gradients(kernel)
+    asked = []  # (`append` returns None: the call goes on to the row-wide tables)
+    monkeypatch.setattr(attention_ops, "_banded_block_tables", lambda needed, width: asked.append(width) or _document_block_tables(needed))
+    jax.tree.map(np.testing.assert_array_equal, banded, value_and_gradients(kernel))
+    assert asked == [slots]
+    jax.tree.map(lambda ours, plain: np.testing.assert_allclose(np.asarray(ours), np.asarray(plain), atol=2e-4, rtol=2e-4), banded, value_and_gradients(reference))
+
+
+# (window, the key slots a launch walks at five blocks of 128 a row): under a row's where the
+# window reaches fewer; a row's for a window that reaches it all, one longer than the row, and none
+GRID_WINDOWS = {1: 1, 100: 2, 200: 3, 300: 4, 400: 5, 2000: 5, None: 5}
+
+
+@pytest.mark.parametrize("window", GRID_WINDOWS)
+def test_a_layer_s_three_launches_walk_the_key_slots_its_window_reaches(window):
+    """The grids of forward, dq and dkv read off the gradient's jaxpr: heads x query blocks of
+    all rows x key slots (dkv: key blocks x heads x query slots), the slots the window's reach
+    plus the diagonal, a row's in a full layer and never more."""
+    batch, hq, d, n, slots = 3, 4, 128, SEQ // BLOCK, GRID_WINDOWS[window]
+    q = jax.ShapeDtypeStruct((batch, SEQ, hq, d), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((batch, SEQ, 1, d), jnp.bfloat16)
+    ids = jax.ShapeDtypeStruct((batch, SEQ), jnp.int32)
+    grad = jax.grad(lambda q, k, v, s: _tpu_splash_attention(q, k, v, s, d**-0.5, window=window).astype(jnp.float32).sum(), argnums=(0, 1, 2))
+    assert _launch_grids(jax.make_jaxpr(grad)(q, kv, kv, ids)) == {"fwd": (hq, batch * n, slots), "dq": (hq, batch * n, slots), "dkv": (batch * n, hq, slots)}
+
+
 def test_splash_without_segment_ids_runs_jax_s_own_local_mask():
     """No ids, a window: the static program on jax's `LocalMask`, against the dense mask."""
     rng = np.random.RandomState(5)
@@ -222,23 +308,41 @@ def test_splash_without_segment_ids_runs_jax_s_own_local_mask():
     np.testing.assert_allclose(out, sdpa_attention(q, _repeat_kv(k, 2), _repeat_kv(v, 2), mask, None, 128**-0.5), atol=1e-4, rtol=1e-4)
 
 
-def test_the_block_plan_says_the_window_and_its_reach(tmp_path):
+def test_the_block_plan_says_the_window_its_reach_and_the_slots_walked(tmp_path, monkeypatch):
+    """Which tables a call builds and what its `splash_block_plan` says: only the ids' tables of
+    a window that reaches fewer key blocks than a row has are banded, and only that record says
+    `key_slots`; every other call builds what it built and says what it said."""
+    built = []
+    for name in ("_document_block_tables", "_banded_block_tables"):
+        monkeypatch.setattr(attention_ops, name, lambda *args, name=name, real=getattr(attention_ops, name): built.append(name) or real(*args))
     sink = tmp_path / "t.jsonl"
     telemetry = Telemetry(sink_path=str(sink), rank=0)
     install_telemetry(telemetry)
     try:
         q = jnp.zeros((2, 4096, 4, 128), jnp.float32)
         ids = jnp.ones((2, 4096), jnp.int32)
-        for window in (2048, 2048, None):  # a second window layer has nothing new to say; a full layer has
-            jax.make_jaxpr(lambda q: _tpu_splash_attention(q, q[:, :, :1], q[:, :, :1], ids, 1.0, interpret=True, window=window))(q)
+        grids = []
+        # a second window layer has nothing new to say; a full layer has, and a window that reaches the whole row, and a call without ids
+        for window, given in ((2048, ids), (2048, ids), (None, ids), (8192, ids), (2048, None)):
+            jaxpr = jax.make_jaxpr(lambda q: _tpu_splash_attention(q, q[:, :, :1], q[:, :, :1], given, 1.0, interpret=True, window=window))(q)
+            grids.append(_launch_grids(jaxpr))
     finally:
         uninstall_telemetry()
         telemetry.close()
+    assert built == ["_banded_block_tables"] * 2 + ["_document_block_tables"] * 2
     plans = [json.loads(line) for line in sink.read_text().splitlines()]
     plans = [p for p in plans if p["kind"] == "event" and p["event"] == "splash_block_plan"]
-    assert [(p.get("window"), p.get("window_key_blocks"), p["block_kv"], p["tables"]) for p in plans] == [
-        (2048, 5, 512, "segment_ids"), (None, None, 512, "segment_ids"),
+    # the cell's blocks at a quarter of its row: 8 key slots a row, 5 under the window
+    assert [(p.get("window"), p.get("window_key_blocks"), p.get("key_slots"), p["grid"], p["block_kv"], p["tables"]) for p in plans] == [
+        (2048, 5, 5, [4, 16, 5], 512, "segment_ids"), (None, None, None, [4, 16, 8], 512, "segment_ids"),
+        (8192, 17, None, [4, 16, 8], 512, "segment_ids"), (2048, 5, None, [4, 16, 8], 512, "static"),
     ]
+    # what the commit before this one (aac3fe9) wrote, key for key, wherever the tables are not banded
+    full = {"kind", "event", "ts", "rank", "block_q", "block_kv", "rows", "grid", "launches_per_call", "tables", "why_static"}
+    assert [set(p) - full for p in plans] == [{"window", "window_key_blocks", "key_slots"}, set(), {"window", "window_key_blocks"}, {"window", "window_key_blocks"}]
+    assert all(set(p) >= full for p in plans)
+    # the grid a record of the ids' tables says is the forward launch's
+    assert grids[:4] == [{"fwd": (4, 16, 5)}] * 2 + [{"fwd": (4, 16, 8)}] * 2
 
 
 # ---------------------------------------------------------------- the paths that know no window

@@ -26,6 +26,7 @@ from dolomite_engine_tpu.models import config_from_dict, gpt_dolomite
 from dolomite_engine_tpu.models.gpt_dolomite import GPTDolomiteForCausalLM
 from dolomite_engine_tpu.ops.attention import (
     SPLASH_COUNTERS,
+    _banded_block_tables,
     _document_block_tables,
     _pick_block,
     _repeat_kv,
@@ -35,6 +36,7 @@ from dolomite_engine_tpu.ops.attention import (
     sdpa_attention,
     splash_block_counters,
     splash_expected,
+    window_block_reach,
 )
 from dolomite_engine_tpu.utils.telemetry import Telemetry, install_telemetry, uninstall_telemetry
 
@@ -228,6 +230,64 @@ def test_tables_name_the_running_step_s_block_or_the_next_one_that_runs(case):
             assert data_next_dkv[0, slot, key] == first_of_row + (key % n if step is None else step), (key, slot)
 
 
+@pytest.mark.parametrize("window", [1, 64, 200, 850])  # at blocks of 64: 1, 2, 5 and 16 - 1 key slots
+@pytest.mark.parametrize("case", ["packed_seed0", "packed_seed1", "tail_padding", "one_document", "ids_shuffled"])
+def test_banded_tables_run_the_row_wide_tables_steps_in_a_band_as_wide_as_the_window_reaches(case, window):
+    """The same two rows under a window that reaches fewer key blocks than a row has: forward /
+    dq tables ``[1, B n, W]`` whose slot s of query block i is key block ``i - (W - 1) + s``,
+    dkv's ``[1, W, B n]`` whose slot s of key block j is query block ``j + s``. Laid back over a
+    row's slots they are the row-wide tables' steps (every needed pair has exactly one running
+    step); a step that runs names its own block, a skipped one the next that runs in its own
+    band (else the diagonal), and every entry of both `data_next` tables lies in its own row —
+    the index maps fetch what it names, and the chip does not check."""
+    rows = np.stack([ID_ROWS[case][0], ID_ROWS["packed_seed2"][0]])
+    needed = document_block_pairs(jnp.asarray(rows), BLOCK_T, window)
+    batch, n, _ = needed.shape
+    width = window_block_reach(window, BLOCK_T) + 1
+    assert width < n
+    (wide_mask, wide_next), (wide_mask_dkv, wide_next_dkv) = jax.tree.map(np.asarray, _document_block_tables(needed))
+    (block_mask, data_next), (block_mask_dkv, data_next_dkv) = jax.tree.map(np.asarray, _banded_block_tables(needed, width))
+    assert block_mask.shape == data_next.shape == (1, batch * n, width)
+    assert block_mask_dkv.shape == data_next_dkv.shape == (1, width, batch * n)
+    row_of = np.arange(batch * n) // n
+
+    def over_the_row(band, partner):
+        """[B n, W] of a band's slots -> [B n, n] of a row's: slot s of block (row, i) is the row's slot partner(i, s)."""
+        laid = np.zeros((batch * n, n), band.dtype)
+        for block in range(batch * n):
+            for slot in range(width):
+                if 0 <= partner(block % n, slot) < n:
+                    laid[block, partner(block % n, slot)] = band[block, slot]
+                else:
+                    assert band[block, slot] == 0, (block, slot)  # a slot outside the row never runs
+        return laid
+
+    # forward and dq: the band's running steps are the row-wide table's, and name the blocks it names
+    key_of = lambda i, s: i - (width - 1) + s  # noqa: E731
+    np.testing.assert_array_equal(over_the_row(block_mask[0], key_of), wide_mask[0])
+    running = block_mask[0] != 0
+    assert running.sum() == int(needed.sum()) and running[:, -1].all()  # the diagonal, the last slot, always runs
+    np.testing.assert_array_equal(over_the_row(np.where(running, data_next[0], 0), key_of), np.where(wide_mask[0] != 0, wide_next[0], 0))
+    expected = _walk(running, ((i, s) for i in range(batch * n) for s in range(width)))
+    for (i, s), step in expected.items():
+        assert data_next[0, i, s] == step[0] - (width - 1) + step[1], (i, s)
+        assert step[0] == i  # the next step that runs is one of the same query block
+    np.testing.assert_array_equal(data_next[0] // n, np.broadcast_to(row_of[:, None], data_next[0].shape))
+
+    # dkv: the band of each key block; past a key block's last running step the next head
+    # starts over at its diagonal, slot 0
+    query_of = lambda j, s: j + s  # noqa: E731
+    np.testing.assert_array_equal(over_the_row(block_mask_dkv[0].T, query_of), wide_mask_dkv[0].T)
+    running = block_mask_dkv[0].T != 0  # [key block, query slot]
+    assert running.sum() == int(needed.sum()) and running[:, 0].all()
+    np.testing.assert_array_equal(over_the_row(np.where(running, data_next_dkv[0].T, 0), query_of), np.where(wide_mask_dkv[0].T != 0, wide_next_dkv[0].T, 0))
+    for key in range(batch * n):
+        expected = _walk(running[key], range(width))
+        for slot, step in expected.items():
+            assert data_next_dkv[0, slot, key] == key + (0 if step is None else step), (key, slot)
+    np.testing.assert_array_equal(data_next_dkv[0] // n, np.broadcast_to(row_of[None, :], data_next_dkv[0].shape))
+
+
 @pytest.mark.parametrize("case", ["packed_seed0", "tail_padding", "one_document", "every_token_its_own"])
 def test_counters_count_the_tables(case):
     ids = ID_ROWS[case][0]
@@ -290,6 +350,11 @@ def _pallas_calls(jaxpr, under=(), found=None) -> list:
     return found
 
 
+def _launch_grids(jaxpr) -> dict:
+    """{"fwd" | "dq" | "dkv": grid} of the splash launches of `jaxpr` (their names: ``splash_mha_<which>_...``)."""
+    return {eqn.params["name"].split("_")[2]: tuple(eqn.params["grid_mapping"].grid) for eqn, _ in _pallas_calls(jaxpr.jaxpr)}
+
+
 def test_segmented_gradient_is_three_launches_over_all_rows_and_no_loop_over_rows():
     """Forward, dkv and dq, each one launch for the call's rows laid end to end (grid: heads x
     query blocks of all rows x key slots of one row), their tables traced values — not a loop
@@ -302,8 +367,7 @@ def test_segmented_gradient_is_three_launches_over_all_rows_and_no_loop_over_row
     jaxpr = jax.make_jaxpr(grad)(q, kv, kv, ids)
     calls = _pallas_calls(jaxpr.jaxpr)
     n = seq // _pick_block(seq)
-    grids = {eqn.params["name"].split("_")[2]: tuple(eqn.params["grid_mapping"].grid) for eqn, _ in calls}
-    assert grids == {"fwd": (hq, batch * n, n), "dq": (hq, batch * n, n), "dkv": (batch * n, hq, n)}
+    assert _launch_grids(jaxpr) == {"fwd": (hq, batch * n, n), "dq": (hq, batch * n, n), "dkv": (batch * n, hq, n)}
     for eqn, under in calls:
         assert not {"while", "scan"} & set(under), under
         # block_mask and data_next are operands computed from the ids, no constants
@@ -330,6 +394,7 @@ def test_splash_block_plan_is_written_once_a_distinct_plan(tmp_path):
         ("static", "the call has no segment ids", 2),
     ]
     assert all((p["block_q"], p["block_kv"], p["rows"], p["grid"]) == (BLOCK, BLOCK, 2, [4, 2 * SEQ // BLOCK, SEQ // BLOCK]) for p in plans)
+    assert not any("key_slots" in p or "window" in p for p in plans)  # no window: a launch walks a row's key slots, and the record says no other
 
 
 # ---------------------------------------------------------------- the counters a step returns
