@@ -411,14 +411,25 @@ class NemotronHConfig(CommonConfig):
         held = self.held_experts()[1]
         per_kind = {
             "M": 2 * b * s * (h * (self.mamba_inner + self.mamba_conv_dim + self.mamba_num_heads) + self.mamba_inner * h),
-            "*": 2 * b * s * (h * (heads + 2 * kv) * d + heads * d * h) + 4 * b * s * s * heads * d,
+            "*": 2 * b * s * (h * (heads + 2 * kv) * d + heads * d * h),
             "E": 2 * b * s * (
                 h * self.num_experts
                 + 2 * h * self.moe_shared_expert_intermediate_size
                 + self.num_experts_per_tok * held / self.num_experts * 2 * h * self.moe_intermediate_size
             ),
         }
-        return float(sum(per_kind[kind] for kind in self.hybrid_override_pattern))
+        return float(sum(per_kind[kind] for kind in self.hybrid_override_pattern) + self.attention_product_flops(b, s))
+
+    @property
+    def attention_blocks(self) -> int:
+        """The blocks that attend (`estimate_remat_activation_bytes` counts the attention
+        kernel's kept residuals by it; a family without it attends in every block)."""
+        return self.hybrid_override_pattern.count("*")
+
+    def attention_product_flops(self, b: int, s: int) -> float:
+        """The score and value products of `forward_block_flops`, all attending blocks at once:
+        what a replay does not run again where the attention kernel's residuals are kept."""
+        return float(self.attention_blocks * 4 * b * s * s * self.n_head * self.head_dim)
 
     def layout_record(self) -> dict:
         """What the run's one `model_layout` telemetry event says."""
@@ -524,7 +535,7 @@ class JoyAIFlashConfig(CommonConfig):
             + h * (self.kv_lora_rank + self.qk_rope_head_dim)
             + self.kv_lora_rank * heads * (self.qk_nope_head_dim + v)
             + heads * v * h
-        ) + 2 * b * s * s * heads * (qk + v)
+        )
         dense = 2 * b * s * 3 * h * self.n_inner
         experts = 2 * b * s * (
             h * self.num_experts
@@ -534,7 +545,18 @@ class JoyAIFlashConfig(CommonConfig):
         dense_blocks = self.first_k_dense_replace
         expert_blocks = self.expert_layers
         mtp = self.num_nextn_predict_layers * 2 * b * s * 2 * h * h
-        return float((dense_blocks + expert_blocks) * attention + dense_blocks * dense + expert_blocks * experts + mtp)
+        blocks = (dense_blocks + expert_blocks) * attention + dense_blocks * dense + expert_blocks * experts + mtp
+        return float(blocks + self.attention_product_flops(b, s))
+
+    @property
+    def attention_blocks(self) -> int:
+        """`NemotronHConfig.attention_blocks`: every block and the multi-token-prediction module's."""
+        return self.n_layer + self.num_nextn_predict_layers
+
+    def attention_product_flops(self, b: int, s: int) -> float:
+        """`NemotronHConfig.attention_product_flops` (scores over the wider head, values of `v_head_dim`)."""
+        qk = self.qk_nope_head_dim + self.qk_rope_head_dim
+        return float(self.attention_blocks * 2 * b * s * s * self.n_head * (qk + self.v_head_dim))
 
     def layout_record(self) -> dict:
         """What the run's one `model_layout` telemetry event says."""
@@ -631,15 +653,24 @@ class Lfm2MoeConfig(CommonConfig):
         held = self.held_experts()[1]
         per_operator = {
             "conv": 2 * b * s * (3 * h * h + h * h),
-            "full_attention": 2 * b * s * (h * (heads + 2 * kv) * d + heads * d * h) + 4 * b * s * s * heads * d,
+            "full_attention": 2 * b * s * (h * (heads + 2 * kv) * d + heads * d * h),
         }
         dense = 2 * b * s * 3 * h * self.n_inner
         experts = 2 * b * s * (
             h * self.num_experts
             + self.num_experts_per_tok * held / self.num_experts * 3 * h * self.moe_intermediate_size
         )
-        operators = sum(per_operator[kind] for kind in self.layer_types)
+        operators = sum(per_operator[kind] for kind in self.layer_types) + self.attention_product_flops(b, s)
         return float(operators + self.num_dense_layers * dense + self.expert_layers * experts)
+
+    @property
+    def attention_blocks(self) -> int:
+        """`NemotronHConfig.attention_blocks`."""
+        return self.layer_types.count("full_attention")
+
+    def attention_product_flops(self, b: int, s: int) -> float:
+        """`NemotronHConfig.attention_product_flops`."""
+        return float(self.attention_blocks * 4 * b * s * s * self.n_head * self.head_dim)
 
     def layout_record(self) -> dict:
         """What the run's one `model_layout` telemetry event says."""
@@ -776,8 +807,7 @@ class AfmoeConfig(CommonConfig):
         h, heads, kv, d = self.n_embd, self.n_head, self.num_key_value_heads, self.head_dim
         held = self.held_experts()[1]
         projections = 2 * b * s * (h * (heads + 2 * kv) * d + 2 * heads * d * h)  # q, k, v; the gate and the out-projection
-        keys = {"full_attention": s, "sliding_attention": min(s, self.sliding_window)}
-        attention = sum(projections + 4 * b * s * keys[kind] * heads * d for kind in self.layer_types)
+        attention = len(self.layer_types) * projections + self.attention_product_flops(b, s)
         dense = 2 * b * s * 3 * h * self.n_inner
         experts = 2 * b * s * (
             h * self.num_experts
@@ -785,6 +815,11 @@ class AfmoeConfig(CommonConfig):
             + self.num_experts_per_tok * held / self.num_experts * 3 * h * self.moe_intermediate_size
         )
         return float(attention + self.num_dense_layers * dense + self.expert_layers * experts)
+
+    def attention_product_flops(self, b: int, s: int) -> float:
+        """`NemotronHConfig.attention_product_flops`: a full layer's square, a window layer's band."""
+        keys = {"full_attention": s, "sliding_attention": min(s, self.sliding_window)}
+        return float(sum(4 * b * s * keys[kind] * self.n_head * self.head_dim for kind in self.layer_types))
 
     def layout_record(self) -> dict:
         """What the run's one `model_layout` telemetry event says."""

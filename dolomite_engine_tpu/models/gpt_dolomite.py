@@ -88,11 +88,19 @@ def _and_these_names(policy, *names: str):
     return both
 
 
-def resolve_named_remat_policy(policy: str):
+def resolve_named_remat_policy(policy: str, applications_per_block: int = 1):
     """Map a `gradient_checkpointing_args.policy` name to a jax policy fn.
 
-    - ``full``: save nothing inside the block (jax's default) — the all-or-nothing remat
-      the `checkpoint_every` knob always had; maximum recompute, minimum memory.
+    - ``full``: replay everything a block computes through XLA; where attention lowered
+      through the Pallas kernel, keep the kernel's output and log-sum-exp (by their
+      `checkpoint_name`), so the backward pass does not launch the forward kernel a second
+      time: the slowest thing a replay runs, and what is kept is bit for bit what it would
+      compute. The cost is ``heads x S x (v_head x itemsize + 4)`` bytes a block and batch
+      row (the ``remat_plan`` event's ``attention_kernel_residual_bytes_per_block_row``); on
+      the XLA `sdpa` path nothing carries the name and nothing is kept. A stack that applies
+      its blocks more than once a step (``applications_per_block`` > 1: a looped model's
+      passes) would keep them that many times over, and keeps nothing. The literal "keep
+      nothing" is the raw ``checkpoint_policy: nothing_saveable``.
     - ``save_dots``: save every matmul output (`dots_saveable`) and, where attention
       lowered through the Pallas kernel, the kernel's output and log-sum-exp (by their
       `checkpoint_name`: a `pallas_call` is no dot, and `dots_saveable` alone runs the whole
@@ -109,7 +117,9 @@ def resolve_named_remat_policy(policy: str):
       kernel's output and log-sum-exp stay on the device.
     """
     if policy == "full":
-        return None
+        if applications_per_block > 1:
+            return None
+        return jax.checkpoint_policies.save_only_these_names(ATTENTION_KERNEL_RESIDUALS_CHECKPOINT_NAME)
     if policy == "save_dots":
         return _and_these_names(
             jax.checkpoint_policies.dots_saveable, ATTENTION_KERNEL_RESIDUALS_CHECKPOINT_NAME
@@ -138,18 +148,20 @@ def scan_group_size(n_layer: int, checkpoint_every: int) -> int:
     return 1
 
 
-def resolve_remat_policy(name: str | None):
+def resolve_remat_policy(name: str | None, applications_per_block: int = 1):
     """Map a checkpoint-policy name to a jax policy fn.
 
     Accepts BOTH vocabularies: the named policies (`REMAT_POLICY_NAMES` — the
     `gradient_checkpointing_args.policy` spelling, see `resolve_named_remat_policy`)
     and the raw `jax.checkpoint_policies` attribute names the legacy
-    ``checkpoint_policy`` key always took (e.g. ``dots_saveable``). None keeps jax's
-    default (save nothing — the ``full`` policy)."""
+    ``checkpoint_policy`` key always took (e.g. ``dots_saveable``; ``nothing_saveable`` is
+    the literal "keep nothing"). None is the ``full`` policy. ``applications_per_block``:
+    how often a step applies each block of the stack that asks (what ``full`` keeps depends
+    on it)."""
     if name is None:
-        return None
+        name = "full"
     if name in REMAT_POLICY_NAMES:
-        return resolve_named_remat_policy(name)
+        return resolve_named_remat_policy(name, applications_per_block)
     if name not in _REMAT_POLICIES:
         raise ValueError(
             f"unknown checkpoint_policy '{name}' (expected a named policy "
@@ -191,7 +203,7 @@ def remat_plan(
     again in the backward pass. A family that applies its blocks more than once a step (a
     looped model's passes: `applications_per_block`) also says how many applications that
     makes, and how many of them replay."""
-    names = names_kept_on_device(resolve_remat_policy(checkpoint_policy))
+    names = names_kept_on_device(resolve_remat_policy(checkpoint_policy, applications_per_block))
     through_kernel = [b for r, b in zip(rematerialized, kernel_residual_bytes) if r and b]
     kept = ATTENTION_KERNEL_RESIDUALS_CHECKPOINT_NAME in names
     looped = {}

@@ -110,7 +110,9 @@ class OuroStack(nn.Module):
 
     def setup(self) -> None:
         config = self.config
-        remat_policy = resolve_remat_policy(self.checkpoint_policy)
+        # the scan over passes applies every block `total_ut_steps` times: whatever a block kept
+        # would be kept that often (2.03 GiB at the cell's size, of 0.45 free), so `full` keeps nothing
+        remat_policy = resolve_remat_policy(self.checkpoint_policy, applications_per_block=config.total_ut_steps)
         self.rematerialized = rematerialized_blocks(self.checkpoint_every, config.n_layer)
         blocks = []
         for rematerialized in self.rematerialized:

@@ -181,6 +181,7 @@ def train(
         sequence_length=sequence_length,
         gradient_checkpointing_method=args.distributed_args.gradient_checkpointing_method,
         gradient_checkpointing_args=args.distributed_args.gradient_checkpointing_args,
+        attention_kernel=splash_expected(model.attention_implementation),
     )
 
     def loss_fn(params, micro, rng, fp8_state=None):

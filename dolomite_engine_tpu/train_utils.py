@@ -23,6 +23,8 @@ import jax.numpy as jnp
 import optax
 from flax import struct
 
+from .models.gpt_dolomite import names_kept_on_device, resolve_remat_policy
+from .models.modeling_utils import ATTENTION_KERNEL_RESIDUALS_CHECKPOINT_NAME
 from .utils import ExperimentsTracker, get_telemetry, log_rank_0, profiler_call_at_step_boundary
 from .utils.diagnostics import per_group_health
 
@@ -382,12 +384,23 @@ def resolve_checkpointing_args(
     return every, policy
 
 
+def _keeps_kernel_residuals(policy: str, config) -> bool:
+    """Whether `policy` keeps the attention kernel's output and log-sum-exp in this family's
+    stack, asked of the policy the stack itself resolves (a stack that applies its blocks more
+    than once a step keeps nothing under ``full``)."""
+    applications_per_block = getattr(config, "block_applications", config.n_layer) // config.n_layer
+    return ATTENTION_KERNEL_RESIDUALS_CHECKPOINT_NAME in names_kept_on_device(
+        resolve_remat_policy(policy, applications_per_block)
+    )
+
+
 def get_model_tflops(
     config,
     batch_size: int,
     sequence_length: int,
     gradient_checkpointing_method=None,
     gradient_checkpointing_args: dict | None = None,
+    attention_kernel: bool = False,
 ) -> float:
     """Analytic model TFLOPs per step per device-group (reference `train_utils.py:197-236`):
     attn = 4bsh(h(1+k/n) + s), mlp = 4bshf (+2bshf GLU), lm_head = 6bshv, bwd = 2x fwd.
@@ -396,7 +409,10 @@ def get_model_tflops(
     passes x blocks and passes); without them a block and the head count once, as they did.
 
     The recompute term is derived from the SELECTED remat policy, not just
-    `checkpoint_every`: ``full`` adds one forward per checkpointed block,
+    `checkpoint_every`: ``full`` adds one forward per checkpointed block — less, where
+    attention lowers through the Pallas kernel (``attention_kernel``:
+    `ops.attention.splash_expected`) and the stack keeps the kernel's residuals, the score
+    and value products, which the backward pass then does not run again —,
     ``save_dots``/``offload_dots`` add ~0 (only elementwise ops replay),
     ``save_attention_out`` discounts the saved out-projection dot — so reported MFU
     tracks the actual recompute a policy buys instead of flattering partial-remat runs.
@@ -415,6 +431,7 @@ def get_model_tflops(
     l = getattr(config, "block_applications", config.n_layer)
 
     attention_flops = 4 * b * s * h * (h * (1 + k / n) + s)
+    product_flops = 4 * b * s * h * s  # the score and value products, of `attention_flops`
     mlp_flops = 4 * b * s * h * f
     if is_glu(config.activation_function):
         mlp_flops += 2 * b * s * h * f
@@ -433,6 +450,7 @@ def get_model_tflops(
         # a family whose block is not attention + MLP counts its own blocks, all of them at
         # once, under this function's conventions (`NemotronHConfig.forward_block_flops`)
         attention_flops, mlp_flops, l = config.forward_block_flops(b, s), 0.0, 1
+        product_flops = config.attention_product_flops(b, s)
 
     forward = l * (attention_flops + mlp_flops)
     backward = 2 * forward
@@ -463,6 +481,8 @@ def get_model_tflops(
             block_recompute = block - 4 * b * s * h * h
         else:  # "full", nothing_saveable, and conservative fallback for raw names
             block_recompute = block
+            if attention_kernel and policy == "full" and _keeps_kernel_residuals(policy, config):
+                block_recompute -= product_flops
         recompute = l * block_recompute / max(every, 1)
 
     lm_head = 6 * b * s * h * v * getattr(config, "head_readings", 1)
@@ -484,9 +504,10 @@ def estimate_remat_activation_bytes(
 
     ``attention_kernel``: attention lowers through the Pallas kernel
     (`ops.attention.splash_expected`). Its score and context products are then no dots and
-    no policy sees them; ``save_dots`` and ``offload_dots`` keep, by name, the kernel's
-    output and the rows' float32 log-sum-exp on the device (what the ``remat_plan``
-    telemetry event counts a block and row), the raw ``*saveable`` names keep neither.
+    no policy sees them; ``full``, ``save_dots`` and ``offload_dots`` keep, by name, the
+    kernel's output and the rows' float32 log-sum-exp on the device (what the ``remat_plan``
+    telemetry event counts a block and row; ``full`` only in a stack that applies its blocks
+    once a step), the raw ``dots_saveable`` and ``nothing_saveable`` keep neither.
 
     Counts only what the policy SAVES (block-boundary carries plus the policy's
     selected residuals per checkpointed block); XLA scratch, attention workspace, and
@@ -515,9 +536,17 @@ def estimate_remat_activation_bytes(
     # the gate on attention's output is one more projection as wide as the heads' output
     gate_width = n * config.head_dim if getattr(config, "attention_output_gate", False) else 0
 
-    per_block_extra = kernel_residuals = 0.0
+    kept_on_device = kept_under_full = per_block_extra = 0.0
+    if every and attention_kernel:
+        # the kernel's output [b, n, s, head] and log-sum-exp [b, n, s] float32, a checkpointed block
+        # that attends (a family whose blocks do not all attend says how many do: `attention_blocks`)
+        kernel_residuals = getattr(config, "attention_blocks", l) // every * (
+            b * s * n * (getattr(config, "v_head_dim", config.head_dim) * dtype_bytes + 4)
+        )
+        kept_on_device = kernel_residuals if _keeps_kernel_residuals(policy, config) else 0.0
+        kept_under_full = kernel_residuals if _keeps_kernel_residuals("full", config) else 0.0
     if every:
-        if policy in ("save_dots", "offload_dots") or "saveable" in policy:
+        if policy in ("save_dots", "offload_dots") or ("saveable" in policy and policy != "nothing_saveable"):
             # every dot output: fused qkv + attention scores + context + out proj +
             # c_fc (2f for GLU) + c_proj
             glu = 2 if "glu" in str(config.activation_function) else 1
@@ -528,20 +557,16 @@ def estimate_remat_activation_bytes(
                 + 2 * h  # attention out proj + mlp c_proj
                 + glu * f  # c_fc output
             )
-            if attention_kernel and policy in ("save_dots", "offload_dots"):
-                # the kernel's output [b, n, s, head] and log-sum-exp [b, n, s] float32
-                kernel_residuals = token_bytes * h + b * s * n * 4
         elif policy == "save_attention_out":
             per_block_extra = token_bytes * h
     checkpointed_blocks = (l // max(every, 1)) if every else 0
     extra = checkpointed_blocks * per_block_extra
-    kept_on_device = checkpointed_blocks * kernel_residuals
 
     # offload parks the saved dots in pinned host memory: device HBM sees only the
     # boundaries and the kernel's residuals, the host pays `extra`
     device_bytes = boundary + kept_on_device + (0.0 if policy == "offload_dots" else extra)
     host_bytes = extra if policy == "offload_dots" else 0.0
-    full_bytes = float(boundary)  # the full policy saves boundaries only
+    full_bytes = float(boundary + kept_under_full)  # the boundaries, and the kernel's residuals
 
     return {
         "checkpoint_every": every,
