@@ -48,25 +48,11 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from ..enums import AttentionImplementation
-from ..ops.attention import SPLASH_COUNTERS_BY_KIND, splash_block_counters_by_kind, watch_kernel_residuals
-from ..ops.rope import RoPEParams, get_cos_sin
-from ..parallel.sharding import logical_constraint
+from ..ops.attention import SPLASH_COUNTERS_BY_KIND, splash_block_counters_by_kind
 from .config import AfmoeConfig
-from .gpt_dolomite import HeadTableForCausalLM, resolve_remat_policy, say_remat_plan
-from .modeling_utils import MLP, Attention, ParameterizedEmbedding, get_norm, sandwich_normed_block
-from .shared_expert_moe import (
-    STEP_COUNTERS,
-    SharedExpertMoE,
-    refuse_generation_cache,
-    refuse_what_is_not_built,
-    say_dispatch_plan,
-    stack_step_counters,
-)
-
-NO_CACHE = (
-    "window layers would free pages full layers keep: per-layer page budgets and a window in the "
-    "paged decode and chunked prefill walks are not built: ROADMAP M6"
-)
+from .modeling_utils import MLP, Attention, sandwich_normed_block
+from .shared_expert_moe import SharedExpertMoE
+from .unrolled_stack import UnrolledStack, UnrolledStackForCausalLM
 
 
 class AfmoeBlock(nn.Module):
@@ -108,98 +94,27 @@ class AfmoeBlock(nn.Module):
         return sandwich_normed_block(config, self.dtype, hidden_states, attention, feed_forward)
 
 
-class AfmoeModel(nn.Module):
-    config: AfmoeConfig
-    attention_implementation: AttentionImplementation = AttentionImplementation.sdpa
-    dtype: Any = jnp.float32
-    checkpoint_every: int = 0
-    checkpoint_policy: str | None = None
-    scan_layers: bool = False
+class AfmoeModel(UnrolledStack):
+    family = "afmoe"
+    why_no_scan = "the blocks differ by attention's kind and by feed-forward and a scan over whole periods is not built"
+    replicated_under = {"tp": "the attention heads and their gate", "ep": "the experts held"}
+    no_cache = (
+        "window layers would free pages full layers keep: per-layer page budgets and a window in the "
+        "paged decode and chunked prefill walks are not built"
+    )
+    roadmap_item = "ROADMAP M6"
+    block_cls = AfmoeBlock
 
-    def setup(self) -> None:
-        config = self.config
-        refuse_what_is_not_built(
-            "afmoe",
-            self.scan_layers,
-            "the blocks differ by attention's kind and by feed-forward and a scan over whole periods is not built",
-            {"tp": "the attention heads and their gate", "ep": "the experts held"},
-        )
-        self.wte = ParameterizedEmbedding(
-            num_embeddings=config.vocab_size, features=config.n_embd, std=config.initializer_range, dtype=self.dtype
-        )
-        self.rope_params = RoPEParams.from_config(config.head_dim, config.rope_theta, config.rope_scaling, config.n_positions)
-        remat_policy = resolve_remat_policy(self.checkpoint_policy)
-        self.rematerialized = tuple(
-            self.checkpoint_every > 0 and i % self.checkpoint_every == 0 for i in range(config.n_layer)
-        )
-        blocks = []
-        for i in range(config.n_layer):
-            cls = AfmoeBlock
-            if self.rematerialized[i]:
-                # flax counts the module instance as argument 0; deterministic is arg 5.
-                # prevent_cse stays on, as for the other unrolled families
-                cls = nn.remat(cls, static_argnums=(5,), policy=remat_policy)
-            blocks.append(
-                cls(
-                    config=config,
-                    window=config.layer_window(i),
-                    dense=i < config.num_dense_layers,
-                    attention_implementation=self.attention_implementation,
-                    dtype=self.dtype,
-                )
-            )
-        self.h = blocks
-        self.ln_f = get_norm(config, self.dtype)
-
-    def __call__(
-        self,
-        input_ids: jax.Array,
-        position_ids: jax.Array | None = None,
-        attention_mask: jax.Array | None = None,
-        segment_ids: jax.Array | None = None,
-        kv_caches: list | None = None,
-        cache_index: jax.Array | None = None,
-        deterministic: bool = True,
-        inputs_embeds: jax.Array | None = None,
-    ) -> tuple[jax.Array, None, list]:
-        if kv_caches is not None:
-            refuse_generation_cache("afmoe", NO_CACHE)
-        config = self.config
-        batch, seq = input_ids.shape
-        with jax.named_scope("embed"):
-            hidden_states = self.wte(input_ids) if inputs_embeds is None else inputs_embeds
-            if config.m_emb is not None:
-                hidden_states = hidden_states * config.m_emb
-            hidden_states = logical_constraint(hidden_states, ("act_batch", "act_seq", "act_embed"))
-            if position_ids is None:
-                position_ids = jnp.broadcast_to(jnp.arange(seq, dtype=jnp.int32)[None], (batch, seq))
-            rope_cos_sin = get_cos_sin(self.rope_params, position_ids, dtype=self.dtype)
-        if segment_ids is None and attention_mask is not None:
-            segment_ids = attention_mask.astype(jnp.int32)  # the pad tokens are a document of their own
-        extras, kernel_residual_bytes = [], []
-        with jax.named_scope("blocks"), watch_kernel_residuals() as seen, say_dispatch_plan():
-            for block in self.h:
-                calls_before = len(seen)
-                hidden_states, counters = block(hidden_states, attention_mask, segment_ids, rope_cos_sin, deterministic)
-                kernel_residual_bytes.append(sum(seen[calls_before:]))
-                if counters is not None:
-                    extras.append(counters)
-        say_remat_plan(self, kernel_residual_bytes)
-        with jax.named_scope("final_norm"):
-            hidden_states = self.ln_f(hidden_states)
-        return hidden_states, None, extras
+    @nn.nowrap
+    def block_arguments(self, i: int) -> dict:
+        return dict(window=self.config.layer_window(i), dense=i < self.config.num_dense_layers)
 
 
-class AfmoeForCausalLM(HeadTableForCausalLM):
+class AfmoeForCausalLM(UnrolledStackForCausalLM):
     """The blocks under the repo's untied head table and chunked loss."""
 
     base_model_cls: type = AfmoeModel
-    family_counter_names = STEP_COUNTERS
     splash_counter_names = SPLASH_COUNTERS_BY_KIND
-
-    def step_counters(self, extras: list) -> dict | None:
-        """``{name: int32[layers of experts, ...]}`` from the blocks' counters."""
-        return stack_step_counters(extras)
 
     def count_splash_blocks(self, batch: int, seq: int, segment_ids: jax.Array | None) -> dict:
         """`SPLASH_COUNTERS_BY_KIND` of these rows: the layers do NOT share the ids' tables — a
@@ -210,6 +125,3 @@ class AfmoeForCausalLM(HeadTableForCausalLM):
         return splash_block_counters_by_kind(
             batch, seq, segment_ids, config.sliding_window, window_layers, config.n_layer - window_layers
         )
-
-    def init_kv_caches(self, batch_size: int, max_length: int, dtype=None) -> list:
-        refuse_generation_cache("afmoe", "ROADMAP M6")

@@ -301,24 +301,11 @@ class RNNDolomiteConfig(CommonConfig):
         assert set(self.attention_pattern) <= {"a", "d"}
 
 
-def _checked_experts_held(experts_held, num_experts: int) -> list[int] | None:
-    """`experts_held` = (first, count) as two ints, or None (all held)."""
-    if experts_held is None:
-        return None
-    first, count = experts_held
-    if first < 0 or count < 1 or first + count > num_experts:
-        raise ValueError(f"experts_held {experts_held} lies outside 0..{num_experts}")
-    return [int(first), int(count)]
-
-
-@dataclass
-class NemotronHConfig(CommonConfig):
-    """The `nemotron_h` tower (Nemotron-H / Nemotron-Labs-TwoTower's first tower): every
-    layer is ONE mixer behind a pre-norm and a residual, chosen by `hybrid_override_pattern`
-    over ``M`` (Mamba-2), ``E`` (routed experts + a shared expert) and ``*`` (attention
-    without positions). The repo's names carry the widths they always carried (`n_embd`,
-    `n_head`, `num_key_value_heads`, `attention_head_dim`); the rest are the public
-    `config.json`'s keys.
+class ExpertShareConfig(CommonConfig):
+    """What the configs of the families routed by sigmoid scores over a chip's share of the experts
+    (`models/unrolled_stack.py`, `models/shared_expert_moe.py`) say alike. It has no field of its
+    own — `experts_held`, `deployment` and `num_experts` stand in each family's class, in the order
+    its `to_dict()` always had — and reads theirs:
 
     `experts_held` = (first, count): the chip's share of a layer's experts under expert
     parallelism. The router keeps `num_experts` outputs and `num_experts_per_tok` choices;
@@ -326,6 +313,49 @@ class NemotronHConfig(CommonConfig):
     `experts_held_ragged`). None holds all. `deployment` is free text and numbers about the
     cut (published depth and vocabulary, chips sharing a layer) for the run's `model_layout`
     event; the program reads nothing from it."""
+
+    # parameter leaves the optimizer must leave as they are (buffers of the public models)
+    buffer_names = ("e_score_correction_bias",)
+
+    def __post_init__(self) -> None:
+        super().__post_init__()
+        if self.experts_held is not None:
+            first, count = self.experts_held
+            if first < 0 or count < 1 or first + count > self.num_experts:
+                raise ValueError(f"experts_held {self.experts_held} lies outside 0..{self.num_experts}")
+            self.experts_held = [int(first), int(count)]
+
+    @classmethod
+    def fused_loss_reads_untied_head(cls) -> bool:
+        return True  # (`HeadTableForCausalLM`: the head is a table, tied or not)
+
+    @classmethod
+    def supported_position_embeddings(cls) -> frozenset[PositionEmbeddingType]:
+        return frozenset({PositionEmbeddingType.rope})
+
+    def held_experts(self) -> tuple[int, int]:
+        return tuple(self.experts_held) if self.experts_held else (0, self.num_experts)
+
+    def share_record(self) -> dict:
+        """The end of every family's `layout_record`: the share held, of what was published."""
+        first, count = self.held_experts()
+        return dict(
+            experts_held=count,
+            first_expert_held=first,
+            experts_published=self.num_experts,
+            vocabulary_rows_held=self.vocab_size,
+            **(self.deployment or {}),
+        )
+
+
+@dataclass
+class NemotronHConfig(ExpertShareConfig):
+    """The `nemotron_h` tower (Nemotron-H / Nemotron-Labs-TwoTower's first tower): every
+    layer is ONE mixer behind a pre-norm and a residual, chosen by `hybrid_override_pattern`
+    over ``M`` (Mamba-2), ``E`` (routed experts + a shared expert) and ``*`` (attention
+    without positions). The repo's names carry the widths they always carried (`n_embd`,
+    `n_head`, `num_key_value_heads`, `attention_head_dim`); the rest are the public
+    `config.json`'s keys; `experts_held` and `deployment` are `ExpertShareConfig`'s."""
 
     model_type: str = "nemotron_h"
     attention_head_type: str = "gqa"
@@ -356,8 +386,6 @@ class NemotronHConfig(CommonConfig):
     experts_held: list[int] | None = None
     deployment: dict | None = None
 
-    # parameter leaves the optimizer must leave as they are (buffers of the public model)
-    buffer_names = ("e_score_correction_bias",)
     # the literal of the family's public code in the renormalisation's denominator (a third family's differs)
     norm_topk_prob_epsilon = 1e-20
 
@@ -378,11 +406,6 @@ class NemotronHConfig(CommonConfig):
             )
         if self.mamba_num_heads % self.mamba_n_groups:
             raise ValueError("mamba_num_heads must be a multiple of mamba_n_groups")
-        self.experts_held = _checked_experts_held(self.experts_held, self.num_experts)
-
-    @classmethod
-    def fused_loss_reads_untied_head(cls) -> bool:
-        return True
 
     @classmethod
     def supported_position_embeddings(cls) -> frozenset[PositionEmbeddingType]:
@@ -395,9 +418,6 @@ class NemotronHConfig(CommonConfig):
     @property
     def mamba_conv_dim(self) -> int:
         return self.mamba_inner + 2 * self.mamba_n_groups * self.ssm_state_size
-
-    def held_experts(self) -> tuple[int, int]:
-        return tuple(self.experts_held) if self.experts_held else (0, self.num_experts)
 
     def forward_block_flops(self, b: int, s: int) -> float:
         """Forward matmul FLOPs of all the blocks for `b` rows of `s` tokens, under
@@ -433,19 +453,14 @@ class NemotronHConfig(CommonConfig):
 
     def layout_record(self) -> dict:
         """What the run's one `model_layout` telemetry event says."""
-        first, count = self.held_experts()
         return dict(
             pattern=self.hybrid_override_pattern,
-            experts_held=count,
-            first_expert_held=first,
-            experts_published=self.num_experts,
-            vocabulary_rows_held=self.vocab_size,
-            **(self.deployment or {}),
+            **self.share_record(),
         )
 
 
 @dataclass
-class JoyAIFlashConfig(CommonConfig):
+class JoyAIFlashConfig(ExpertShareConfig):
     """`joyai_llm_flash` (JoyAI-LLM-Flash; its keys are the DeepSeek-V3 family's): every block
     is latent attention (`modeling_utils.LatentAttention`) and then a feed-forward sublayer —
     a dense SwiGLU MLP of `n_inner` in the first `first_k_dense_replace` blocks, routed
@@ -456,7 +471,7 @@ class JoyAIFlashConfig(CommonConfig):
 
     The repo's names carry the widths they always carried (`n_embd`, `n_head`, `n_inner`);
     the rest are the public `config.json`'s keys. `experts_held` and `deployment` are
-    `NemotronHConfig`'s."""
+    `ExpertShareConfig`'s."""
 
     model_type: str = "joyai_llm_flash"
     attention_head_type: str = "mha"
@@ -487,7 +502,6 @@ class JoyAIFlashConfig(CommonConfig):
     mtp_loss_coef: float = 0.3
     deployment: dict | None = None
 
-    buffer_names = ("e_score_correction_bias",)
     norm_topk_prob_epsilon = 1e-20  # (as `NemotronHConfig`'s)
 
     def __post_init__(self) -> None:
@@ -500,15 +514,6 @@ class JoyAIFlashConfig(CommonConfig):
             raise ValueError("qk_rope_head_dim must be even (rotary pairs)")
         if not 0 <= self.first_k_dense_replace <= self.n_layer:
             raise ValueError(f"first_k_dense_replace {self.first_k_dense_replace} lies outside 0..{self.n_layer}")
-        self.experts_held = _checked_experts_held(self.experts_held, self.num_experts)
-
-    @classmethod
-    def fused_loss_reads_untied_head(cls) -> bool:
-        return True
-
-    @classmethod
-    def supported_position_embeddings(cls) -> frozenset[PositionEmbeddingType]:
-        return frozenset({PositionEmbeddingType.rope})
 
     @property
     def moe_shared_expert_intermediate_size(self) -> int:
@@ -519,9 +524,6 @@ class JoyAIFlashConfig(CommonConfig):
         """Layers of experts whose counters a step returns: the blocks after the dense ones,
         and the multi-token-prediction module's."""
         return self.n_layer - self.first_k_dense_replace + self.num_nextn_predict_layers
-
-    def held_experts(self) -> tuple[int, int]:
-        return tuple(self.experts_held) if self.experts_held else (0, self.num_experts)
 
     def forward_block_flops(self, b: int, s: int) -> float:
         """`NemotronHConfig.forward_block_flops` for this family: the blocks and the
@@ -560,21 +562,16 @@ class JoyAIFlashConfig(CommonConfig):
 
     def layout_record(self) -> dict:
         """What the run's one `model_layout` telemetry event says."""
-        first, count = self.held_experts()
         return dict(
             blocks_dense=self.first_k_dense_replace,
             blocks_experts=self.n_layer - self.first_k_dense_replace,
             blocks_mtp=self.num_nextn_predict_layers,
-            experts_held=count,
-            first_expert_held=first,
-            experts_published=self.num_experts,
-            vocabulary_rows_held=self.vocab_size,
-            **(self.deployment or {}),
+            **self.share_record(),
         )
 
 
 @dataclass
-class Lfm2MoeConfig(CommonConfig):
+class Lfm2MoeConfig(ExpertShareConfig):
     """`lfm2_moe` (LFM2-24B-A2B's family): every block is ONE operator behind a pre-norm and a
     residual — by `layer_types[i]` a gated short convolution (``conv``: `models/lfm2_moe.ShortConv`)
     or grouped-query attention with per-head QK norms and rope (``full_attention``) — and then
@@ -585,7 +582,7 @@ class Lfm2MoeConfig(CommonConfig):
     The repo's names carry the widths they always carried (`n_embd`, `n_head`,
     `num_key_value_heads`, `n_inner`); the rest are the public `config.json`'s keys
     (`norm_topk_prob_epsilon` is the public modeling code's literal). `experts_held` and
-    `deployment` are `NemotronHConfig`'s."""
+    `deployment` are `ExpertShareConfig`'s."""
 
     model_type: str = "lfm2_moe"
     attention_head_type: str = "gqa"
@@ -613,7 +610,6 @@ class Lfm2MoeConfig(CommonConfig):
     experts_held: list[int] | None = None
     deployment: dict | None = None
 
-    buffer_names = ("e_score_correction_bias",)
     # the family has no shared expert (`SharedExpertMoE` then builds none)
     moe_shared_expert_intermediate_size = 0
 
@@ -632,19 +628,11 @@ class Lfm2MoeConfig(CommonConfig):
             raise ValueError("use_expert_bias false: the router is built with its bias (the published models')")
         if not 0 <= self.num_dense_layers <= self.n_layer:
             raise ValueError(f"num_dense_layers {self.num_dense_layers} lies outside 0..{self.n_layer}")
-        self.experts_held = _checked_experts_held(self.experts_held, self.num_experts)
-
-    @classmethod
-    def supported_position_embeddings(cls) -> frozenset[PositionEmbeddingType]:
-        return frozenset({PositionEmbeddingType.rope})
 
     @property
     def expert_layers(self) -> int:
         """Layers of experts whose counters a step returns: the blocks after the dense ones."""
         return self.n_layer - self.num_dense_layers
-
-    def held_experts(self) -> tuple[int, int]:
-        return tuple(self.experts_held) if self.experts_held else (0, self.num_experts)
 
     def forward_block_flops(self, b: int, s: int) -> float:
         """`NemotronHConfig.forward_block_flops` for this family (the convolution's taps and
@@ -674,23 +662,18 @@ class Lfm2MoeConfig(CommonConfig):
 
     def layout_record(self) -> dict:
         """What the run's one `model_layout` telemetry event says."""
-        first, count = self.held_experts()
         return dict(
             layer_types=",".join(self.layer_types),
             blocks_conv=self.layer_types.count("conv"),
             blocks_attention=self.layer_types.count("full_attention"),
             blocks_dense=self.num_dense_layers,
             blocks_experts=self.expert_layers,
-            experts_held=count,
-            first_expert_held=first,
-            experts_published=self.num_experts,
-            vocabulary_rows_held=self.vocab_size,
-            **(self.deployment or {}),
+            **self.share_record(),
         )
 
 
 @dataclass
-class AfmoeConfig(CommonConfig):
+class AfmoeConfig(ExpertShareConfig):
     """`afmoe` (Trinity-Mini's family): every block is attention of one of two kinds by
     `layer_types[i]` — ``sliding_attention`` (rope by halves; a query sees its own key and the
     `sliding_window` - 1 before it, inside its document) or ``full_attention`` (NO positions;
@@ -707,7 +690,7 @@ class AfmoeConfig(CommonConfig):
     The repo's names carry the widths they always carried (`n_embd`, `n_head`,
     `num_key_value_heads`, `attention_head_dim`, `n_inner`); the rest are the public
     `config.json`'s keys (`route_norm_epsilon` is the public modeling code's literal).
-    `experts_held` and `deployment` are `NemotronHConfig`'s."""
+    `experts_held` and `deployment` are `ExpertShareConfig`'s."""
 
     model_type: str = "afmoe"
     attention_head_type: str = "gqa"
@@ -737,8 +720,6 @@ class AfmoeConfig(CommonConfig):
     experts_held: list[int] | None = None
     deployment: dict | None = None
 
-    buffer_names = ("e_score_correction_bias",)
-
     def __post_init__(self) -> None:
         if self.layer_types is None:
             self.layer_types = ["full_attention"] * self.n_layer
@@ -760,15 +741,6 @@ class AfmoeConfig(CommonConfig):
             raise ValueError("tie_word_embeddings true: the family's head is a table of its own (the published models')")
         if not 0 <= self.num_dense_layers <= self.n_layer:
             raise ValueError(f"num_dense_layers {self.num_dense_layers} lies outside 0..{self.n_layer}")
-        self.experts_held = _checked_experts_held(self.experts_held, self.num_experts)
-
-    @classmethod
-    def fused_loss_reads_untied_head(cls) -> bool:
-        return True
-
-    @classmethod
-    def supported_position_embeddings(cls) -> frozenset[PositionEmbeddingType]:
-        return frozenset({PositionEmbeddingType.rope})
 
     # what `SharedExpertMoE` reads, under the names the other expert families gave them
     @property
@@ -796,9 +768,6 @@ class AfmoeConfig(CommonConfig):
         """The window of block `index`'s attention (None: a full layer)."""
         return self.sliding_window if self.layer_types[index] == "sliding_attention" else None
 
-    def held_experts(self) -> tuple[int, int]:
-        return tuple(self.experts_held) if self.experts_held else (0, self.num_experts)
-
     def forward_block_flops(self, b: int, s: int) -> float:
         """`NemotronHConfig.forward_block_flops` for this family: attention's projections with
         the gate's, the score and value products over ``s`` keys a token in a full layer and
@@ -823,7 +792,6 @@ class AfmoeConfig(CommonConfig):
 
     def layout_record(self) -> dict:
         """What the run's one `model_layout` telemetry event says."""
-        first, count = self.held_experts()
         return dict(
             layer_types=",".join(self.layer_types),
             blocks_window=self.layer_types.count("sliding_attention"),
@@ -831,11 +799,7 @@ class AfmoeConfig(CommonConfig):
             sliding_window=self.sliding_window,
             blocks_dense=self.num_dense_layers,
             blocks_experts=self.expert_layers,
-            experts_held=count,
-            first_expert_held=first,
-            experts_published=self.num_experts,
-            vocabulary_rows_held=self.vocab_size,
-            **(self.deployment or {}),
+            **self.share_record(),
         )
 
 

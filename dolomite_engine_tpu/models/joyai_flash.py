@@ -38,28 +38,13 @@ from flax import linen as nn
 from jax.ad_checkpoint import checkpoint_name
 
 from ..enums import AttentionImplementation
-from ..ops.attention import watch_kernel_residuals
 from ..ops.loss import IGNORE_INDEX, causal_lm_loss, derive_causal_labels
-from ..ops.rope import RoPEParams, get_cos_sin
 from ..parallel.sharding import logical_constraint
 from .config import JoyAIFlashConfig
-from .gpt_dolomite import CausalLMOutput, HeadTableForCausalLM, resolve_remat_policy, say_remat_plan
-from .modeling_utils import (
-    ATTENTION_OUT_CHECKPOINT_NAME,
-    MLP,
-    LatentAttention,
-    ParameterizedEmbedding,
-    ParameterizedLinear,
-    get_norm,
-)
-from .shared_expert_moe import (
-    STEP_COUNTERS,
-    SharedExpertMoE,
-    refuse_generation_cache,
-    refuse_what_is_not_built,
-    say_dispatch_plan,
-    stack_step_counters,
-)
+from .gpt_dolomite import CausalLMOutput, say_remat_plan
+from .modeling_utils import ATTENTION_OUT_CHECKPOINT_NAME, MLP, LatentAttention, ParameterizedLinear, get_norm
+from .shared_expert_moe import STEP_COUNTERS, SharedExpertMoE, stack_step_counters
+from .unrolled_stack import UnrolledStack, UnrolledStackForCausalLM
 
 # beside the experts' counters: both parts of the loss and the positions the second had
 LOSS_PARTS = ("main_loss", "mtp_loss", "mtp_targets")
@@ -132,55 +117,32 @@ class MultiTokenPrediction(nn.Module):
             return get_norm(config, self.dtype, "norm")(hidden_states), counters
 
 
-class JoyAIFlashModel(nn.Module):
-    config: JoyAIFlashConfig
-    attention_implementation: AttentionImplementation = AttentionImplementation.sdpa
-    dtype: Any = jnp.float32
-    checkpoint_every: int = 0
-    checkpoint_policy: str | None = None
-    scan_layers: bool = False
+class JoyAIFlashModel(UnrolledStack):
+    family = "joyai_llm_flash"
+    why_no_scan = "the dense first block differs from the expert blocks and a scan over the like ones is not built"
+    replicated_under = {"tp": "the latent attention's heads", "ep": "the experts held"}
+    no_cache = "a latent page and the absorbed decode form are not built"
+    roadmap_item = "ROADMAP M5"
+    block_cls = JoyAIFlashBlock
+
+    @nn.nowrap
+    def block_arguments(self, i: int) -> dict:
+        return dict(dense=i < self.config.first_k_dense_replace)
+
+    @nn.nowrap
+    def rope_width(self) -> int:
+        return self.config.qk_rope_head_dim
+
+    @nn.nowrap
+    def block_count(self) -> int:
+        return self.config.n_layer + self.config.num_nextn_predict_layers
 
     def setup(self) -> None:
-        config = self.config
-        refuse_what_is_not_built(
-            "joyai_llm_flash",
-            self.scan_layers,
-            "the dense first block differs from the expert blocks and a scan over the like ones is not built",
-            {"tp": "the latent attention's heads", "ep": "the experts held"},
-        )
-        self.wte = ParameterizedEmbedding(
-            num_embeddings=config.vocab_size, features=config.n_embd, std=config.initializer_range, dtype=self.dtype
-        )
-        self.rope_params = RoPEParams.from_config(
-            config.qk_rope_head_dim, config.rope_theta, config.rope_scaling, config.n_positions
-        )
-        remat_policy = resolve_remat_policy(self.checkpoint_policy)
-        blocks = config.n_layer + config.num_nextn_predict_layers
-        self.rematerialized = tuple(
-            self.checkpoint_every > 0 and i % self.checkpoint_every == 0 for i in range(blocks)
-        )
-
-        def block_cls(i: int) -> type:
-            # flax counts the module instance as argument 0; deterministic is arg 5.
-            # prevent_cse stays on, as for the other unrolled families
-            if self.rematerialized[i]:
-                return nn.remat(JoyAIFlashBlock, static_argnums=(5,), policy=remat_policy)
-            return JoyAIFlashBlock
-
-        self.h = [
-            block_cls(i)(
-                config=config,
-                dense=i < config.first_k_dense_replace,
-                attention_implementation=self.attention_implementation,
-                dtype=self.dtype,
-            )
-            for i in range(config.n_layer)
-        ]
-        self.ln_f = get_norm(config, self.dtype)
-        if config.num_nextn_predict_layers:
+        super().setup()
+        if self.config.num_nextn_predict_layers:
             self.mtp = MultiTokenPrediction(
-                config=config,
-                block_cls=block_cls(config.n_layer),
+                config=self.config,
+                block_cls=self.block_class(self.config.n_layer),
                 attention_implementation=self.attention_implementation,
                 dtype=self.dtype,
             )
@@ -197,46 +159,25 @@ class JoyAIFlashModel(nn.Module):
         inputs_embeds: jax.Array | None = None,
         predict_second: bool = False,
     ) -> tuple[jax.Array, None, list, jax.Array | None]:
-        """(normed hidden states, None, the expert layers' counters, and — `predict_second`,
-        with a multi-token-prediction module — its normed hidden states, else None)."""
-        if kv_caches is not None:
-            refuse_generation_cache(
-                "joyai_llm_flash", "a latent page and the absorbed decode form are not built: ROADMAP M5"
-            )
-        batch, seq = input_ids.shape
-        with jax.named_scope("embed"):
-            hidden_states = self.wte(input_ids) if inputs_embeds is None else inputs_embeds
-            hidden_states = logical_constraint(hidden_states, ("act_batch", "act_seq", "act_embed"))
-            if position_ids is None:
-                position_ids = jnp.broadcast_to(jnp.arange(seq, dtype=jnp.int32)[None], (batch, seq))
-            rope_cos_sin = get_cos_sin(self.rope_params, position_ids, dtype=self.dtype)
-        if segment_ids is None and attention_mask is not None:
-            segment_ids = attention_mask.astype(jnp.int32)  # the pad tokens are a document of their own
-        extras, kernel_residual_bytes, second = [], [], None
-        with watch_kernel_residuals() as seen, say_dispatch_plan():
-            with jax.named_scope("blocks"):
-                for block in self.h:
-                    calls_before = len(seen)
-                    hidden_states, counters = block(hidden_states, attention_mask, segment_ids, rope_cos_sin, deterministic)
-                    kernel_residual_bytes.append(sum(seen[calls_before:]))
-                    if counters is not None:
-                        extras.append(counters)
+        """The stack's pass and, after the blocks, the multi-token-prediction module's:
+        (normed hidden states, None, the expert layers' counters, and — `predict_second`,
+        with such a module — its normed hidden states, else None)."""
+        hidden_states, block_inputs = self.embed(input_ids, position_ids, attention_mask, segment_ids, kv_caches, inputs_embeds)
+        second = None
+        with self.watch_blocks() as run:
+            for block in self.h:
+                hidden_states = run(block, hidden_states, *block_inputs, deterministic)
             # (at initialization too, whatever the call asks for: the module's parameters exist)
             if self.config.num_nextn_predict_layers and (predict_second or self.is_initializing()):
-                with jax.named_scope("blocks"), jax.named_scope("mtp"):
-                    calls_before = len(seen)
+                with jax.named_scope("mtp"):
                     # the last position's next token is not in `input_ids`; it has no target either
                     next_embeds = self.wte(jnp.roll(input_ids, -1, axis=1))
-                    second, counters = self.mtp(
-                        hidden_states, next_embeds, attention_mask, segment_ids, rope_cos_sin, deterministic
-                    )
-                    kernel_residual_bytes.append(sum(seen[calls_before:]))
-                    extras.append(counters)
-        if len(kernel_residual_bytes) == len(self.rematerialized):
-            say_remat_plan(self, kernel_residual_bytes)
+                    second = run(self.mtp, hidden_states, next_embeds, *block_inputs, deterministic)
+        if len(run.kernel_residual_bytes) == len(self.rematerialized):
+            say_remat_plan(self, run.kernel_residual_bytes)
         with jax.named_scope("final_norm"):
             hidden_states = self.ln_f(hidden_states)
-        return hidden_states, None, extras, second
+        return hidden_states, None, run.extras, second
 
 
 def second_token_labels(labels: jax.Array, segment_ids: jax.Array | None) -> jax.Array:
@@ -251,7 +192,7 @@ def second_token_labels(labels: jax.Array, segment_ids: jax.Array | None) -> jax
     return jnp.where(next_segment == segment_ids, shifted, IGNORE_INDEX)
 
 
-class JoyAIFlashForCausalLM(HeadTableForCausalLM):
+class JoyAIFlashForCausalLM(UnrolledStackForCausalLM):
     """The blocks under the repo's untied head table and chunked loss, the loss read twice
     where the model predicts a second token."""
 
@@ -317,6 +258,3 @@ class JoyAIFlashForCausalLM(HeadTableForCausalLM):
         counters.update(main_loss=main_loss, mtp_loss=mtp_loss, mtp_targets=mtp_targets)
         counters.update(self.splash_step_counters(hidden_states, segment_ids, attention_mask))
         return CausalLMOutput(loss=loss, counters=counters)
-
-    def init_kv_caches(self, batch_size: int, max_length: int, dtype=None) -> list:
-        refuse_generation_cache("joyai_llm_flash", "ROADMAP M5")

@@ -41,31 +41,19 @@ from flax import linen as nn
 from jax.ad_checkpoint import checkpoint_name
 
 from ..enums import AttentionImplementation
-from ..ops.attention import watch_kernel_residuals
 from ..ops.causal_conv import causal_conv1d
-from ..ops.rope import RoPEParams, get_cos_sin
 from ..parallel.sharding import logical_constraint
 from .config import Lfm2MoeConfig
-from .gpt_dolomite import HeadTableForCausalLM, resolve_remat_policy, say_remat_plan
 from .modeling_utils import (
     ATTENTION_OUT_CHECKPOINT_NAME,
     MLP,
     Attention,
-    ParameterizedEmbedding,
     ParameterizedLinear,
     depth_scaled_init_std,
     get_norm,
 )
-from .shared_expert_moe import (
-    STEP_COUNTERS,
-    SharedExpertMoE,
-    refuse_generation_cache,
-    refuse_what_is_not_built,
-    say_dispatch_plan,
-    stack_step_counters,
-)
-
-NO_CACHE = "the short convolution's taps are not a state of the serving engine's cache: ROADMAP M2"
+from .shared_expert_moe import SharedExpertMoE
+from .unrolled_stack import UnrolledStack, UnrolledStackForCausalLM
 
 
 class ShortConv(nn.Module):
@@ -150,94 +138,20 @@ class Lfm2MoeBlock(nn.Module):
         return hidden_states, counters
 
 
-class Lfm2MoeModel(nn.Module):
-    config: Lfm2MoeConfig
-    attention_implementation: AttentionImplementation = AttentionImplementation.sdpa
-    dtype: Any = jnp.float32
-    checkpoint_every: int = 0
-    checkpoint_policy: str | None = None
-    scan_layers: bool = False
+class Lfm2MoeModel(UnrolledStack):
+    family = "lfm2_moe"
+    why_no_scan = "the blocks differ by operator and by feed-forward and a scan over whole periods is not built"
+    replicated_under = {"tp": "the convolution's channels and the attention heads", "ep": "the experts held"}
+    no_cache = "the short convolution's taps are not a state of the serving engine's cache"
+    roadmap_item = "ROADMAP M2"
+    block_cls = Lfm2MoeBlock
 
-    def setup(self) -> None:
-        config = self.config
-        refuse_what_is_not_built(
-            "lfm2_moe",
-            self.scan_layers,
-            "the blocks differ by operator and by feed-forward and a scan over whole periods is not built",
-            {"tp": "the convolution's channels and the attention heads", "ep": "the experts held"},
-        )
-        self.wte = ParameterizedEmbedding(
-            num_embeddings=config.vocab_size, features=config.n_embd, std=config.initializer_range, dtype=self.dtype
-        )
-        self.rope_params = RoPEParams.from_config(config.head_dim, config.rope_theta, config.rope_scaling, config.n_positions)
-        remat_policy = resolve_remat_policy(self.checkpoint_policy)
-        self.rematerialized = tuple(
-            self.checkpoint_every > 0 and i % self.checkpoint_every == 0 for i in range(config.n_layer)
-        )
-        blocks = []
-        for i, operator in enumerate(config.layer_types):
-            cls = Lfm2MoeBlock
-            if self.rematerialized[i]:
-                # flax counts the module instance as argument 0; deterministic is arg 5.
-                # prevent_cse stays on, as for the other unrolled families
-                cls = nn.remat(cls, static_argnums=(5,), policy=remat_policy)
-            blocks.append(
-                cls(
-                    config=config,
-                    operator=operator,
-                    dense=i < config.num_dense_layers,
-                    attention_implementation=self.attention_implementation,
-                    dtype=self.dtype,
-                )
-            )
-        self.h = blocks
-        self.ln_f = get_norm(config, self.dtype)
-
-    def __call__(
-        self,
-        input_ids: jax.Array,
-        position_ids: jax.Array | None = None,
-        attention_mask: jax.Array | None = None,
-        segment_ids: jax.Array | None = None,
-        kv_caches: list | None = None,
-        cache_index: jax.Array | None = None,
-        deterministic: bool = True,
-        inputs_embeds: jax.Array | None = None,
-    ) -> tuple[jax.Array, None, list]:
-        if kv_caches is not None:
-            refuse_generation_cache("lfm2_moe", NO_CACHE)
-        batch, seq = input_ids.shape
-        with jax.named_scope("embed"):
-            hidden_states = self.wte(input_ids) if inputs_embeds is None else inputs_embeds
-            hidden_states = logical_constraint(hidden_states, ("act_batch", "act_seq", "act_embed"))
-            if position_ids is None:
-                position_ids = jnp.broadcast_to(jnp.arange(seq, dtype=jnp.int32)[None], (batch, seq))
-            rope_cos_sin = get_cos_sin(self.rope_params, position_ids, dtype=self.dtype)
-        if segment_ids is None and attention_mask is not None:
-            segment_ids = attention_mask.astype(jnp.int32)  # the pad tokens are a document of their own
-        extras, kernel_residual_bytes = [], []
-        with jax.named_scope("blocks"), watch_kernel_residuals() as seen, say_dispatch_plan():
-            for block in self.h:
-                calls_before = len(seen)
-                hidden_states, counters = block(hidden_states, attention_mask, segment_ids, rope_cos_sin, deterministic)
-                kernel_residual_bytes.append(sum(seen[calls_before:]))
-                if counters is not None:
-                    extras.append(counters)
-        say_remat_plan(self, kernel_residual_bytes)
-        with jax.named_scope("final_norm"):
-            hidden_states = self.ln_f(hidden_states)
-        return hidden_states, None, extras
+    @nn.nowrap
+    def block_arguments(self, i: int) -> dict:
+        return dict(operator=self.config.layer_types[i], dense=i < self.config.num_dense_layers)
 
 
-class Lfm2MoeForCausalLM(HeadTableForCausalLM):
+class Lfm2MoeForCausalLM(UnrolledStackForCausalLM):
     """The blocks under the embedding's table as the head (tied) and the repo's chunked loss."""
 
     base_model_cls: type = Lfm2MoeModel
-    family_counter_names = STEP_COUNTERS
-
-    def step_counters(self, extras: list) -> dict | None:
-        """``{name: int32[layers of experts, ...]}`` from the blocks' counters."""
-        return stack_step_counters(extras)
-
-    def init_kv_caches(self, batch_size: int, max_length: int, dtype=None) -> list:
-        refuse_generation_cache("lfm2_moe", "ROADMAP M2")

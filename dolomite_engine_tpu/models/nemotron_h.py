@@ -32,6 +32,7 @@ scopes of their calls.
 from __future__ import annotations
 
 import math
+from contextlib import contextmanager
 from typing import Any
 
 import jax
@@ -39,27 +40,13 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from ..enums import AttentionImplementation
-from ..ops.attention import watch_kernel_residuals
 from ..ops.causal_conv import causal_conv1d
 from ..ops.mamba2 import gated_group_rmsnorm, mamba2_scan, watch_scan_lowerings
 from ..parallel.sharding import logical_constraint
 from .config import NemotronHConfig
-from .gpt_dolomite import HeadTableForCausalLM, resolve_remat_policy, say_remat_plan
-from .modeling_utils import (
-    Attention,
-    ParameterizedEmbedding,
-    ParameterizedLinear,
-    depth_scaled_init_std,
-    get_norm,
-)
-from .shared_expert_moe import (
-    STEP_COUNTERS,
-    SharedExpertMoE,
-    refuse_generation_cache,
-    refuse_what_is_not_built,
-    say_dispatch_plan,
-    stack_step_counters,
-)
+from .modeling_utils import Attention, ParameterizedLinear, depth_scaled_init_std, get_norm
+from .shared_expert_moe import SharedExpertMoE
+from .unrolled_stack import UnrolledStack, UnrolledStackForCausalLM
 
 
 def _inverse_softplus(x: jax.Array) -> jax.Array:
@@ -177,7 +164,8 @@ class Mamba2Mixer(nn.Module):
 
 
 class NemotronHBlock(nn.Module):
-    """``x + mixer(norm(x))``; `mixer` is one letter of the pattern."""
+    """``x + mixer(norm(x))``; `mixer` is one letter of the pattern. (Called as every block of
+    `unrolled_stack.UnrolledStack` is: `rope_cos_sin` is None here, the tower takes no positions.)"""
 
     config: NemotronHConfig
     mixer: str
@@ -186,7 +174,7 @@ class NemotronHBlock(nn.Module):
 
     @nn.compact
     def __call__(
-        self, hidden_states: jax.Array, attention_mask=None, segment_ids=None, deterministic: bool = True
+        self, hidden_states: jax.Array, attention_mask=None, segment_ids=None, rope_cos_sin=None, deterministic: bool = True
     ) -> tuple[jax.Array, dict | None]:
         config = self.config
         h = get_norm(config, self.dtype, "ln_1")(hidden_states)
@@ -210,106 +198,35 @@ class NemotronHBlock(nn.Module):
         return hidden_states, counters
 
 
-class NemotronHModel(nn.Module):
-    config: NemotronHConfig
-    attention_implementation: AttentionImplementation = AttentionImplementation.sdpa
-    dtype: Any = jnp.float32
-    checkpoint_every: int = 0
-    checkpoint_policy: str | None = None
-    scan_layers: bool = False
+class NemotronHModel(UnrolledStack):
+    family = "nemotron_h"
+    why_no_scan = "the layers of a pattern differ and a scan over whole periods is not built"
+    replicated_under = {"tp": "the Mamba-2 heads", "ep": "the experts held"}
+    no_cache = "Mamba state and convolution taps are not in the serving engine's cache"
+    roadmap_item = "ROADMAP M2"
+    block_cls = NemotronHBlock
 
-    def setup(self) -> None:
-        config = self.config
-        refuse_what_is_not_built(
-            "nemotron_h",
-            self.scan_layers,
-            "the layers of a pattern differ and a scan over whole periods is not built",
-            {"tp": "the Mamba-2 heads", "ep": "the experts held"},
-        )
-        self.wte = ParameterizedEmbedding(
-            num_embeddings=config.vocab_size,
-            features=config.n_embd,
-            std=config.initializer_range,
-            dtype=self.dtype,
-        )
-        remat_policy = resolve_remat_policy(self.checkpoint_policy)
-        self.rematerialized = tuple(
-            self.checkpoint_every > 0 and i % self.checkpoint_every == 0
-            for i in range(len(config.hybrid_override_pattern))
-        )
-        blocks = []
-        for i, mixer in enumerate(config.hybrid_override_pattern):
-            cls = NemotronHBlock
-            if self.rematerialized[i]:
-                # flax counts the module instance as argument 0; deterministic is arg 4.
-                # prevent_cse stays on: the layers are unrolled, and XLA would merge a
-                # layer's replay with its forward pass and keep every layer's activations
-                cls = nn.remat(cls, static_argnums=(4,), policy=remat_policy)
-            blocks.append(
-                cls(
-                    config=config,
-                    mixer=mixer,
-                    attention_implementation=self.attention_implementation,
-                    dtype=self.dtype,
-                )
-            )
-        self.h = blocks
-        self.ln_f = get_norm(config, self.dtype)
+    @nn.nowrap
+    def block_arguments(self, i: int) -> dict:
+        return dict(mixer=self.config.hybrid_override_pattern[i])
 
-    def __call__(
-        self,
-        input_ids: jax.Array,
-        position_ids: jax.Array | None = None,
-        attention_mask: jax.Array | None = None,
-        segment_ids: jax.Array | None = None,
-        kv_caches: list | None = None,
-        cache_index: jax.Array | None = None,
-        deterministic: bool = True,
-        inputs_embeds: jax.Array | None = None,
-    ) -> tuple[jax.Array, None, list]:
-        if kv_caches is not None:
-            refuse_generation_cache(
-                "nemotron_h", "Mamba state and convolution taps are not in the serving engine's cache: ROADMAP M2"
-            )
-        with jax.named_scope("embed"):
-            hidden_states = self.wte(input_ids) if inputs_embeds is None else inputs_embeds
-            hidden_states = logical_constraint(hidden_states, ("act_batch", "act_seq", "act_embed"))
-        if segment_ids is None and attention_mask is not None:
-            # padded rows: the pad tokens are a document of their own
-            segment_ids = attention_mask.astype(jnp.int32)
-        extras = []
-        kernel_residual_bytes = []
-        with (
-            jax.named_scope("blocks"),
-            watch_kernel_residuals() as seen,
-            watch_scan_lowerings() as scans,
-            say_dispatch_plan(),
-        ):
-            for block in self.h:
-                calls_before = len(seen)
-                hidden_states, counters = block(hidden_states, attention_mask, segment_ids, deterministic)
-                kernel_residual_bytes.append(sum(seen[calls_before:]))
-                if counters is not None:
-                    extras.append(counters)
-        say_remat_plan(self, kernel_residual_bytes)
+    @nn.nowrap
+    def rope_width(self) -> None:
+        return None  # no positions: the Mamba layers before an attention layer carry the order
+
+    @nn.nowrap
+    @contextmanager
+    def watch_blocks(self):
+        """... and the ``mamba2_scan_plan`` event of the scans the `M` layers lowered."""
+        with watch_scan_lowerings() as scans, super().watch_blocks() as run:
+            yield run
         if scans:
             from ..utils.telemetry import get_telemetry
 
             get_telemetry().event_once("mamba2_scan_plan", **scan_plan(scans))
-        with jax.named_scope("final_norm"):
-            hidden_states = self.ln_f(hidden_states)
-        return hidden_states, None, extras
 
 
-class NemotronHForCausalLM(HeadTableForCausalLM):
+class NemotronHForCausalLM(UnrolledStackForCausalLM):
     """The tower under the repo's untied head table and chunked loss."""
 
     base_model_cls: type = NemotronHModel
-    family_counter_names = STEP_COUNTERS
-
-    def step_counters(self, extras: list) -> dict | None:
-        """``{name: int32[layers of experts, ...]}`` from the blocks' counters."""
-        return stack_step_counters(extras)
-
-    def init_kv_caches(self, batch_size: int, max_length: int, dtype=None) -> list:
-        refuse_generation_cache("nemotron_h", "ROADMAP M2")

@@ -324,7 +324,9 @@ KNOWN_EVENTS: tuple[str, ...] = (
     # the logits again —, token_blocks x vocab_tiles, tile_rows, hidden_carry_bytes,
     # table_carry_bytes. Both: vocab_shards, tokens_per_device, accumulator_bytes_moved
     "loss_tiling",
-    # how the remat policy engaged where the model was traced (models/gpt_dolomite.remat_plan):
+    # how the remat policy engaged where the model was traced (models/gpt_dolomite.remat_plan,
+    # said by the dense stack, by the looped one and — for nemotron_h, joyai_llm_flash, lfm2_moe
+    # and afmoe alike — by the one stack of models/unrolled_stack.py):
     # policy, checkpoint_every, the checkpoint_name tags it keeps, blocks and how many sit
     # under jax.checkpoint, how many of those ran attention through the Pallas kernel, how
     # many of them keep the kernel's output and log-sum-exp (the others run the forward
@@ -344,7 +346,8 @@ KNOWN_EVENTS: tuple[str, ...] = (
     # where that is fewer than a row's and the tables are the ids', key_slots: the tables are
     # that narrow, and it is the grid's last (a record without it: a row's key blocks)
     "splash_block_plan",
-    # what a model cut to one chip's share holds of what was published (models/config.py
+    # what a model cut to one chip's share holds of what was published (models/config.py:
+    # every record ends in ExpertShareConfig.share_record; before it
     # NemotronHConfig.layout_record: pattern, experts held of published, vocabulary rows
     # held, the deployment's numbers; JoyAIFlashConfig.layout_record: blocks by kind in the
     # pattern's place; Lfm2MoeConfig.layout_record: layer_types and the blocks by operator
@@ -356,12 +359,14 @@ KNOWN_EVENTS: tuple[str, ...] = (
     # ("xla"), why_xla, heads (query heads, key/value heads, head width), once a traced model
     "rope_qkv_plan",
     # which lowering of the Mamba-2 chunked scan each M layer of a traced model took
-    # (models/nemotron_h.scan_plan, from ops/mamba2.scan_lowering): the layers on the Pallas
+    # (models/nemotron_h.scan_plan, from ops/mamba2.scan_lowering, written where the tower
+    # extends the shared stack's watch_blocks): the layers on the Pallas
     # kernel and on the jnp form (and why: backend, mesh or shape), the chunk, the kernel's
     # launches a layer and pass, and the bytes a layer its backward rule keeps
     "mamba2_scan_plan",
     # what the row movements of a traced model's layers of experts planned from their shapes
-    # (ops/moe.experts_held_ragged, written by models/shared_expert_moe.say_dispatch_plan):
+    # (ops/moe.experts_held_ragged, written by models/shared_expert_moe.say_dispatch_plan
+    # round the blocks of models/unrolled_stack.UnrolledStack):
     # layers, the buffers' capacity, block_rows (the sorted slots a loop step of the gather,
     # the weighted scatter-add and their transposes takes), blocks_per_capacity, form; a layer
     # and step runs ceil(routed_slots / block_rows) of those blocks (routed_slots: the
@@ -370,7 +375,9 @@ KNOWN_EVENTS: tuple[str, ...] = (
     # activation_block_rows) blocks a pass), group_sizes (sorted_keys: read off the sort)
     "moe_dispatch_plan",
     # what the step's forward pass counted, returned by the train step beside the loss
-    # (train_utils.make_train_step has_aux) and read where the loss is read: for nemotron_h
+    # (train_utils.make_train_step has_aux) and read where the loss is read; the four unrolled
+    # families' come from one place (models/unrolled_stack.UnrolledStackForCausalLM.step_counters
+    # over the blocks' counters the stack collected): for nemotron_h
     # one entry a layer of experts — routed_slots (token-slots of held experts: the rows
     # the grouped products multiply), absent_slots, fullest_expert_rows, held_expert_rows;
     # for joyai_llm_flash the same (its multi-token-prediction module's layer last) and the

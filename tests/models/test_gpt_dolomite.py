@@ -192,3 +192,11 @@ def test_checkpoint_policy_remat_is_numerics_identical():
         GPTDolomiteForCausalLM(
             config=config, checkpoint_every=1, checkpoint_policy="nope"
         ).init(jax.random.PRNGKey(0), ids)
+
+
+def test_the_lowered_step_is_what_it_was():
+    """The dense family shares `Attention`, `ops/attention` and the head with the families of
+    `family_contract.py`, and is pinned beside them (`PINNED_STEPS`)."""
+    from .family_contract import check_the_lowered_step
+
+    check_the_lowered_step("gpt_dolomite")

@@ -1,16 +1,13 @@
 """`ouro` (models/ouro.py: a looped language model) at a small size on the CPU, against the plain
-reference (`benchmark/reference/ouro.py`) on seeded weights: every pass's logits of packed rows;
-the loss with its parts (every pass's cross-entropy, the gate's distribution, its entropy) and
-every leaf's gradient; three AdamW steps through the trainer's own step; a shared weight's
-gradient as the sum of the gradients of four untied copies; the gate's distribution summing to
-one and, driven to "never stop early", a loss that is the fourth pass's cross-entropy; the loop
-as ONE scan whatever the number of passes; what the counts of a step's work read at
-`total_ut_steps` 1 and 4; the telemetry's ``loop_plan``; what the family refuses, from one place.
-
-Tolerances: everything here is float32 under ``highest`` matmul precision on both sides, so
-values agree to rounding in another order of summation: 2e-4 on logits of size ~1, 2e-5 relative
-on a loss and its parts, 2e-3 on a leaf's gradient elements against the leaf's largest and on the
-norms of the first gradient and of the parameters' change."""
+reference (`benchmark/reference/ouro.py`) on seeded weights. The family's contract — registered,
+the loss with its parts (every pass's cross-entropy, the gate's distribution, its entropy) and every
+leaf's gradient, three AdamW steps through the trainer's own step, the lowered step — is
+`family_contract.py`'s; here is what is the loop's own: what its tree holds; every pass's logits of
+packed rows; a shared weight's gradient as the sum of the gradients of four untied copies; the
+gate's distribution summing to one and, driven to "never stop early", a loss that is the fourth
+pass's cross-entropy; the reference's controls; the loop as ONE scan whatever the number of passes;
+what the counts of a step's work read at `total_ut_steps` 1 and 4; the telemetry's ``loop_plan``;
+what the family refuses, from one place; an fsdp mesh."""
 
 import json
 
@@ -18,77 +15,37 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from flax import linen as nn
 
-from benchmark import compare, weights_ouro as W
+from benchmark import compare
 from benchmark.reference import ouro as reference
-from dolomite_engine_tpu.enums import LRDecaySchedule, Mode
-from dolomite_engine_tpu.model_wrapper.pretraining import ModelWrapperForPretraining
-from dolomite_engine_tpu.models import config_from_dict, get_config_class, get_model_class
+from dolomite_engine_tpu.enums import LRDecaySchedule
+from dolomite_engine_tpu.models import config_from_dict
 from dolomite_engine_tpu.models.gpt_dolomite import remat_plan
 from dolomite_engine_tpu.models.ouro import OuroStack, exit_distribution, loop_plan, pass_step_counter_names
 from dolomite_engine_tpu.ops.rope import RoPEParams, get_cos_sin
 from dolomite_engine_tpu.optimization import get_optimizer, get_scheduler
 from dolomite_engine_tpu.train_utils import estimate_remat_activation_bytes, get_model_tflops, make_train_step
 
-CFG = dict(
-    model_type="ouro", vocab_size=256, n_positions=64, n_embd=32, n_layer=2, n_head=4, n_inner=48, total_ut_steps=4, rope_theta=1e6,
-    resid_pdrop=0.0, embd_pdrop=0.0, attn_pdrop=0.0, bos_token_id=0, eos_token_id=0, pad_token_id=0,
-    fused_lm_head_loss=True, loss_chunk_size=16, z_loss_coef=1e-4, initializer_range=0.1,
-)
-OPTIMIZER = dict(lr=1e-3, weight_decay=0.1, betas=[0.9, 0.95], eps=1e-10, gradient_clipping=1.0)
-NORMS = ("ln_1", "ln_1_out", "ln_2", "ln_2_out")
+from .family_contract import FAMILIES, OPTIMIZER, batches, built, contract_tests, model_of, packed_row, program_loss_and_grads, program_tree, text_of, wrapper_for
+
+CFG = FAMILIES["ouro"].cfg
+NORMS = FAMILIES["ouro"].norms
+globals().update(contract_tests("ouro"))
 
 
-def model_and_weights(cfg=CFG, seed=3, **kwargs):
-    model = get_model_class("ouro")(config=config_from_dict(cfg), **kwargs)
-    weights = W.make_all(cfg, seed)
-    # norm weights away from one, so that a norm in the wrong place or with the wrong weight shows;
-    # a gate away from one half, so that the passes weigh unevenly
-    for i, layer in enumerate(weights["layers"]):
-        for name in NORMS:
-            layer[name] = 1.0 + 0.3 * jnp.cos(jnp.arange(layer[name].shape[0], dtype=jnp.float32) + i + len(name))
-    weights["outer"]["ln_f"] = 1.0 + 0.2 * jnp.sin(jnp.arange(cfg["n_embd"], dtype=jnp.float32))
-    weights["outer"]["gate_w"] = 5.0 * weights["outer"]["gate_w"]
-    weights["outer"]["gate_b"] = jnp.asarray([0.3], jnp.float32)
-    return model, weights, W.unrolled_program_tree(weights, cfg)
 
-
-def packed_row(docs, seed=1, length=CFG["n_positions"]):
-    """[length + 1] tokens: documents of the given lengths, each ending in eos (0), the rest one more."""
-    rng = np.random.default_rng(seed)
-    text = rng.integers(1, CFG["vocab_size"], size=length + 1).astype(np.int32)
-    text[np.cumsum(docs) - 1] = 0
-    return text
-
-
-def wrapper_for(cfg=CFG, zero_stage=0, **kwargs):
-    return ModelWrapperForPretraining(
-        mode=Mode.training, pretrained_config=cfg, dtype="fp32", sequence_length=cfg["n_positions"],
-        reset_attention_mask=True, reset_position_ids=True, zero_stage=zero_stage, **kwargs,
-    )
-
-
-def test_registered_under_its_model_type_and_the_seeded_weights_fit_the_program_tree():
-    assert get_config_class("ouro").__name__ == "OuroConfig"
-    model, _, params = model_and_weights()
-    assert type(model).__name__ == "OuroForCausalLM"
-    assert model.family_counter_names == pass_step_counter_names(4) and model.step_counter_names[:11] == model.family_counter_names
-    assert model.family_counter_names == (
+def test_the_tree_holds_one_set_of_blocks_under_the_loop_s_stack_and_a_gate():
+    model, own, (_, _, params, _) = model_of("ouro"), program_tree("ouro"), built("ouro")
+    assert model.family_counter_names == pass_step_counter_names(4) == (
         "pass_loss_1", "pass_loss_2", "pass_loss_3", "pass_loss_4", "exit_mass_1", "exit_mass_2", "exit_mass_3", "exit_mass_4",
         "exit_entropy", "weighted_loss", "last_pass_loss",
     )
-    own = nn.unbox(jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 16), jnp.int32)))["params"])
-    assert jax.tree.structure(own) == jax.tree.structure(params)
-    assert jax.tree.leaves(jax.tree.map(lambda a: a.shape, own)) == jax.tree.leaves(jax.tree.map(lambda a: a.shape, params))
     # ONE set of blocks under the loop's stack, four norms a block, an untied head, a gate with a bias
     assert set(own) == {"transformer", "lm_head", "exit_gate"} and set(own["transformer"]) == {"wte", "stack"}
     assert set(own["transformer"]["stack"]) == {"h_0", "h_1", "ln_f"} and set(own["transformer"]["stack"]["h_0"]) == {"attn", "mlp", *NORMS}
     assert own["exit_gate"]["kernel"].shape == (32, 1) and own["exit_gate"]["bias"].shape == (1,)
     assert set(own["transformer"]["stack"]["h_0"]["attn"]["c_attn"]) == {"kernel"}  # no bias in the linear layers
-    names = W.leaves_by_name(params)
-    assert len(names) == len(jax.tree.leaves(params)) and {"wte", "lm_head", "gate_w", "gate_b", "ln_f", "layer1.ln_2_out"} <= set(names)
-    assert sum(int(np.prod(a.shape)) for a in jax.tree.leaves(params)) == W.count_parameters(CFG)["total"]
+    assert {"wte", "lm_head", "gate_w", "gate_b", "ln_f", "layer1.ln_2_out"} <= set(FAMILIES["ouro"].W.leaves_by_name(params))
     config = config_from_dict(CFG)
     assert (config.block_applications, config.head_readings, config.head_dim) == (8, 4, 8)
     assert config.exit_entropy_coef == 0.05 and config.layer_norm_epsilon == 1e-6 and not config.tie_word_embeddings
@@ -96,10 +53,9 @@ def test_registered_under_its_model_type_and_the_seeded_weights_fit_the_program_
 
 @pytest.mark.parametrize("docs", [(23, 41), (10, 37, 17)], ids=["two_documents", "three_documents"])
 def test_every_pass_s_logits_of_a_packed_row_follow_the_reference(docs):
-    model, weights, params = model_and_weights()
-    wrapper = wrapper_for()
+    model, weights, params, _ = built("ouro")
     text = packed_row(docs)
-    batch = wrapper.prepare_inputs_and_labels(jnp.asarray(text)[None])
+    batch = wrapper_for(CFG).prepare_inputs_and_labels(jnp.asarray(text)[None])
     with jax.default_matmul_precision("highest"):
         passes = model.apply(
             {"params": params}, batch["input_ids"], position_ids=batch["position_ids"], segment_ids=batch["segment_ids"],
@@ -119,43 +75,22 @@ def test_every_pass_s_logits_of_a_packed_row_follow_the_reference(docs):
 
 
 def two_rows():
-    return jnp.asarray(np.stack([packed_row((23, 41)), packed_row((10, 37, 17), seed=2)]))
+    return text_of(FAMILIES["ouro"].gradient_rows["chunked_head"])
 
 
-@pytest.mark.parametrize("fused", [True, False], ids=["chunked_head", "whole_logits"])
-def test_the_loss_its_parts_and_every_leaf_s_gradient_follow_the_reference(fused):
-    cfg = dict(CFG, fused_lm_head_loss=fused)
-    _, weights, params = model_and_weights(cfg)
-    wrapper = wrapper_for(cfg, gradient_checkpointing_args={"checkpoint_every": 1})
-    text = two_rows()
-    with jax.default_matmul_precision("highest"):
-        (loss, counters), grads = jax.value_and_grad(lambda p: wrapper.loss(p, text, train=True), has_aux=True)(params)
-        (ref_loss, parts), ref_grads = jax.value_and_grad(lambda p: reference.batch_loss(cfg, p, text), has_aux=True)(weights)
-    np.testing.assert_allclose(loss, ref_loss, rtol=2e-5)
-    for t in range(4):
-        np.testing.assert_allclose(counters[f"pass_loss_{t + 1}"], parts["pass_ce"][t], rtol=2e-5)
-        np.testing.assert_allclose(counters[f"exit_mass_{t + 1}"], parts["exit_mass"][t], rtol=2e-5, atol=1e-6)
-    np.testing.assert_allclose(counters["weighted_loss"], parts["weighted"], rtol=2e-5)
-    np.testing.assert_allclose(counters["exit_entropy"], parts["entropy"], rtol=2e-5)
-    np.testing.assert_allclose(counters["last_pass_loss"], parts["pass_ce"][3], rtol=2e-5)
-    assert abs(sum(float(counters[f"exit_mass_{t + 1}"]) for t in range(4)) - 1.0) < 1e-5
-    # the loss is its parts: the weighted cross-entropy less beta x the entropy, plus a z-loss of some 1e-4 x lse^2
+def test_the_loss_is_its_parts():
+    """The weighted cross-entropy less beta x the entropy, plus a z-loss of some 1e-4 x lse^2."""
+    (loss, counters), _ = program_loss_and_grads("ouro", "chunked_head")
     assert 0 < float(loss - (counters["weighted_loss"] - 0.05 * counters["exit_entropy"])) < 1e-2
-    mine, ref = W.leaves_by_name(grads), W.leaves_by_name(W.unrolled_program_tree(ref_grads, cfg))
-    assert set(mine) == set(ref)
-    for name, leaf in ref.items():
-        assert float(jnp.abs(leaf).max()) > 0, name  # the gate and its bias too: the loss reaches them
-        np.testing.assert_allclose(mine[name], leaf, rtol=2e-3, atol=2e-3 * float(jnp.abs(leaf).max()), err_msg=name)
 
 
 def test_a_shared_weight_s_gradient_is_the_sum_over_four_untied_copies():
     """The program's own stack applied four times in a Python loop over FOUR copies of its
     parameters (no scan, no sharing), the loss through the same head and gate: the gradient of the
     scanned model's one stack is the sum of the four copies' gradients, leaf by leaf."""
-    model, _, params = model_and_weights(checkpoint_every=1)
+    model, params = model_of("ouro", checkpoint_every=1), built("ouro")[2]
     config = config_from_dict(CFG)
-    wrapper = wrapper_for()
-    batch = wrapper.prepare_inputs_and_labels(two_rows())
+    batch = wrapper_for(CFG).prepare_inputs_and_labels(two_rows())
     ids, positions, segments, labels = batch["input_ids"], batch["position_ids"], batch["segment_ids"], batch["labels"]
     rope = get_cos_sin(RoPEParams.from_config(config.head_dim, config.rope_theta, None, config.n_positions), positions, dtype=jnp.float32)
     stack = OuroStack(config=config)
@@ -199,7 +134,7 @@ def test_the_exit_distribution_sums_to_one_and_a_gate_that_never_stops_leaves_th
         grads = jax.grad(lambda g: jnp.sum(exit_distribution(g)[1] * exit_distribution(g)[0]))(jnp.full((2, 4, 2), value))
         assert bool(jnp.all(jnp.isfinite(grads)))
     # the model with the gate's bias far negative: nothing stops early, the loss is the fourth pass's cross-entropy (+ z)
-    _, _, params = model_and_weights()
+    params = built("ouro")[2]
     never = dict(params, exit_gate={"kernel": jnp.zeros_like(params["exit_gate"]["kernel"]), "bias": jnp.asarray([-60.0])})
     wrapper = wrapper_for(dict(CFG, z_loss_coef=0.0))
     with jax.default_matmul_precision("highest"):
@@ -209,62 +144,14 @@ def test_the_exit_distribution_sums_to_one_and_a_gate_that_never_stops_leaves_th
     assert abs(float(counters["pass_loss_4"]) - float(counters["pass_loss_1"])) > 1e-3
 
 
-def batches(steps=3, rows=2, seed=0):
-    rng = np.random.default_rng(seed)
-    out = []
-    for _ in range(steps):
-        text = rng.integers(1, CFG["vocab_size"], size=(rows, CFG["n_positions"] + 1)).astype(np.int32)
-        for row in text:
-            row[rng.integers(5, 60, size=2)] = 0  # document boundaries (eos)
-        out.append(text)
-    return out
-
-
-def test_the_trainer_s_step_follows_the_reference():
-    """Three steps of `make_train_step` (the loss through `ModelWrapperForPretraining`, AdamW from
-    `get_optimizer`) against the reference's three steps: each loss, each pass's loss and exit
-    mass from the step's counters, the first gradient's per-leaf norms, the parameters' change."""
-    seed = 11
-    wrapper = wrapper_for(gradient_checkpointing_args={"checkpoint_every": 1})
-    assert wrapper.step_counter_names == pass_step_counter_names(4)
-    schedule = get_scheduler(0, 0, None, 10, LRDecaySchedule.constant, 0.1, base_lr=OPTIMIZER["lr"])
-    optimizer = get_optimizer("TorchAdamW", {k: OPTIMIZER[k] for k in ("weight_decay", "betas", "eps")}, schedule, model_config=wrapper.config)
-    from dolomite_engine_tpu.distributed import TrainState
-
-    start = W.unrolled_program_tree(W.make_all(CFG, seed), CFG)
-    state = TrainState(step=jnp.zeros((), jnp.int32), params=start, opt_state=optimizer.init(start), fp8=None)
-    step = jax.jit(make_train_step(
-        lambda p, micro, rng: wrapper.loss(p, micro["text"], rngs=None, train=True), optimizer,
-        gradient_clipping=OPTIMIZER["gradient_clipping"], has_aux=True,
-    ))
-    data = batches()
-    losses, counters, first_nu = [], [], None
-    with jax.default_matmul_precision("highest"):
-        for text in data:
-            state, metrics = step(state, {"text": jnp.asarray(text)[None]}, jax.random.PRNGKey(0))
-            losses.append(float(metrics["loss"]))
-            counters.append(jax.device_get(metrics["counters"]))
-            if first_nu is None:
-                adam = [s for s in jax.tree.leaves(state.opt_state, is_leaf=lambda x: hasattr(x, "nu")) if hasattr(s, "nu")][0]
-                first_nu = adam.nu
-    ref = reference.train_steps(CFG, seed, data, OPTIMIZER)
-    np.testing.assert_allclose(losses, ref["losses"], rtol=2e-5)
-    for mine, pass_losses, mass in zip(counters, ref["pass_losses"], ref["exit_mass"]):
-        np.testing.assert_allclose([mine[f"pass_loss_{t + 1}"] for t in range(4)], pass_losses, rtol=2e-5)
-        np.testing.assert_allclose([mine[f"exit_mass_{t + 1}"] for t in range(4)], mass, rtol=1e-4)
-    b2 = OPTIMIZER["betas"][1]
-    grad_norms = {k: float(np.sqrt(np.sum(v) / (1 - b2))) for k, v in W.leaves_by_name(first_nu).items()}
-    gap, where = compare.worst_leaf_gap(grad_norms, ref["grad_norms"])
-    assert gap < 2e-3, (gap, where)
-    delta = jax.tree.map(lambda a, b: a - b, state.params, start)
-    delta_norms = {k: float(jnp.sqrt(jnp.sum(jnp.square(v)))) for k, v in W.leaves_by_name(delta).items()}
-    gap, where = compare.worst_leaf_gap(delta_norms, ref["delta_norms"])
-    assert gap < 2e-3, (gap, where)
-    assert min(delta_norms.values()) > 0  # the gate's bias too
-    # the reference's controls are other programs: three passes and an unweighted loss both leave these limits
-    three = reference.train_steps(CFG, seed, data[:1], OPTIMIZER, passes=3)
+def test_the_reference_s_controls_are_other_programs():
+    """Three passes and an unweighted loss both leave the limits the trainer's step is held to
+    (`family_contract`'s three steps against the reference's)."""
+    data = batches()[:1]
+    ref = reference.train_steps(CFG, 11, data, OPTIMIZER)
+    three = reference.train_steps(CFG, 11, data, OPTIMIZER, passes=3)
     assert len(three["pass_losses"][0]) == 3 and compare.worst_leaf_gap(three["grad_norms"], ref["grad_norms"])[0] > 0.05
-    plain = reference.train_steps(CFG, seed, data[:1], OPTIMIZER, weigh=False)
+    plain = reference.train_steps(CFG, 11, data, OPTIMIZER, weigh=False)
     np.testing.assert_allclose(plain["losses"][0], np.mean(plain["pass_losses"][0]), rtol=1e-3)  # (+ the z-loss)
     assert plain["grad_norms"]["gate_w"] == 0.0 == plain["grad_norms"]["gate_b"] and ref["grad_norms"]["gate_w"] > 0
 
@@ -275,7 +162,7 @@ def test_the_loop_is_one_scan_whatever_the_number_of_passes(passes):
     its transpose) whose body holds each block's attention once: the count of dot_generals does not
     grow with the passes."""
     cfg = dict(CFG, total_ut_steps=passes)
-    model, _, params = model_and_weights(cfg, checkpoint_every=1)
+    params = built("ouro")[2]
     text = two_rows()
     wrapper = wrapper_for(cfg, gradient_checkpointing_args={"checkpoint_every": 1})
     jaxpr = str(jax.make_jaxpr(jax.grad(lambda p: wrapper.loss(p, text, train=True)[0]))(params))
@@ -318,8 +205,8 @@ def test_loop_plan_and_remat_plan_events_are_written_once(tmp_path):
     telemetry = Telemetry(sink_path=str(sink), rank=0)
     install_telemetry(telemetry)
     try:
-        _, _, params = model_and_weights()
-        wrapper = wrapper_for(gradient_checkpointing_args={"checkpoint_every": 1})
+        params = built("ouro")[2]
+        wrapper = wrapper_for(CFG, gradient_checkpointing_args={"checkpoint_every": 1})
         for _ in range(2):  # traced again: nothing new to say
             jax.make_jaxpr(jax.grad(lambda p: wrapper.loss(p, two_rows(), train=True)[0]))(params)
     finally:
@@ -338,10 +225,9 @@ def test_what_the_family_refuses_from_one_place(eight_devices):
 
     ids = jnp.zeros((1, 16), jnp.int32)
     message = "is not built; the training path on dp / fsdp meshes only"
-    scanned, _, _ = model_and_weights(scan_layers=True)
     with pytest.raises(NotImplementedError, match="ouro: scan_layers .*" + message):
-        scanned.init(jax.random.PRNGKey(0), ids)
-    model, _, params = model_and_weights()
+        model_of("ouro", scan_layers=True).init(jax.random.PRNGKey(0), ids)
+    model, _, params, _ = built("ouro")
     with pytest.raises(NotImplementedError, match="ouro: a KV cache .* per pass and layer.*" + message):
         model.apply({"params": params}, ids, kv_caches=[None] * 2, cache_index=0)
     with pytest.raises(NotImplementedError, match="ouro: a KV cache .*" + message):
@@ -370,12 +256,12 @@ def test_the_family_trains_on_an_fsdp_mesh_to_the_single_device_loss(eight_devic
     rng = np.random.default_rng(5)
     text = rng.integers(1, CFG["vocab_size"], size=(8, CFG["n_positions"] + 1)).astype(np.int32)
     text[:, 30] = 0
-    _, _, params = model_and_weights()
-    alone = float(wrapper_for().loss(params, jnp.asarray(text), train=True)[0])
+    params = built("ouro")[2]
+    alone = float(wrapper_for(CFG).loss(params, jnp.asarray(text), train=True)[0])
     MeshManager()
     mesh = MeshManager.get_mesh()
     try:
-        wrapper = wrapper_for(zero_stage=3, gradient_checkpointing_args={"checkpoint_every": 1})
+        wrapper = wrapper_for(CFG, zero_stage=3, gradient_checkpointing_args={"checkpoint_every": 1})
         schedule = get_scheduler(0, 0, None, 10, LRDecaySchedule.constant, 0.1, base_lr=1e-3)
         optimizer = get_optimizer("TorchAdamW", {"weight_decay": 0.1, "betas": (0.9, 0.95), "eps": 1e-10}, schedule)
         state, _ = create_sharded_train_state(wrapper, optimizer, mesh, jax.random.PRNGKey(0))

@@ -2,7 +2,7 @@
 kernel's output and log-sum-exp, so its gradient holds one forward kernel a block; the looped
 stack applies every block `total_ut_steps` times, keeps nothing, and holds two an application.
 
-The families' small sizes of their own test files at 128 tokens a row (the kernel's block), the
+The families' small sizes of `family_contract.py`'s table at 128 tokens a row (the kernel's block), the
 kernel interpreted: programs and counts, never a time.
 """
 
@@ -20,22 +20,20 @@ from dolomite_engine_tpu.models.modeling_utils import ATTENTION_KERNEL_RESIDUALS
 from dolomite_engine_tpu.train_utils import estimate_remat_activation_bytes, get_model_tflops
 from dolomite_engine_tpu.utils.telemetry import Telemetry, install_telemetry, uninstall_telemetry
 
-from . import test_afmoe, test_joyai_flash, test_lfm2_moe, test_nemotron_h, test_ouro
+from .family_contract import FAMILIES, built
 from .test_remat_attention_kernel import count_kernels, through_splash  # noqa: F401 (a fixture)
 
 SEQ, ROWS = 128, 2
-FAMILIES = {"afmoe": test_afmoe, "joyai_llm_flash": test_joyai_flash, "lfm2_moe": test_lfm2_moe, "nemotron_h": test_nemotron_h, "ouro": test_ouro}
 # (blocks that attend, applications of each a step)
 ATTENDING = {"afmoe": (5, 1), "joyai_llm_flash": (3 + 1, 1), "lfm2_moe": (1, 1), "nemotron_h": (1, 1), "ouro": (2, 4)}
 
 
 def cfg_of(family: str) -> dict:
-    return dict(FAMILIES[family].CFG, n_positions=SEQ)
+    return dict(FAMILIES[family].cfg, n_positions=SEQ)
 
 
 def loss_and_params(family: str, policy: dict):
-    module, cfg = FAMILIES[family], cfg_of(family)
-    params = (module.model_and_params if family == "nemotron_h" else module.model_and_weights)(cfg)[-1]
+    _, _, params, cfg = built(family, n_positions=SEQ)
     wrapper = ModelWrapperForPretraining(
         mode=Mode.training, pretrained_config=cfg, dtype="fp32", sequence_length=SEQ, reset_attention_mask=True,
         reset_position_ids=True, zero_stage=0, gradient_checkpointing_args={"checkpoint_every": 1, **policy},

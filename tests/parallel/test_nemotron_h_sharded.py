@@ -15,7 +15,9 @@ from dolomite_engine_tpu.optimization import get_optimizer, get_scheduler
 from dolomite_engine_tpu.parallel.mesh import MeshManager, named_sharding
 from dolomite_engine_tpu.train_utils import make_train_step
 
-from ..models.test_nemotron_h import CFG, batches
+from ..models.family_contract import FAMILIES, batches
+
+CFG = FAMILIES["nemotron_h"].cfg
 
 
 def wrapper():

@@ -21,6 +21,7 @@ from flax import linen as nn
 from dolomite_engine_tpu.models import config_from_dict
 from dolomite_engine_tpu.models.gpt_dolomite import GPTDolomiteForCausalLM
 from dolomite_engine_tpu.train_utils import TrainState, make_train_step
+from tests.models.family_contract import FAMILIES
 
 
 def _lowered(accumulation: int = 1, collect_health: bool = False, scan_layers: bool = True):
@@ -163,7 +164,7 @@ def test_joyai_flash_names_its_layers_and_both_passes_through_the_head():
     under `blocks/mtp` and `head_loss/mtp/mtp_head_loss` — forward and backward — so that
     `benchmark/phases.py` counts the module with the blocks and the head."""
     from dolomite_engine_tpu.models import get_model_class
-    from tests.models.test_joyai_flash import CFG
+    CFG = FAMILIES["joyai_llm_flash"].cfg
 
     model = get_model_class("joyai_llm_flash")(config=config_from_dict(CFG), checkpoint_every=1, dtype=jnp.bfloat16)
     ids = jnp.zeros((1, 32), jnp.int32)
@@ -207,7 +208,7 @@ def test_lfm2_moe_names_its_operators_and_its_experts_without_a_shared_one():
     forward and backward, has no `moe_shared_expert` anywhere, and leaves no matmul of the blocks
     outside an operator's or a feed-forward's scope."""
     from dolomite_engine_tpu.models import get_model_class
-    from tests.models.test_lfm2_moe import CFG
+    CFG = FAMILIES["lfm2_moe"].cfg
 
     model = get_model_class("lfm2_moe")(config=config_from_dict(CFG), checkpoint_every=1, dtype=jnp.bfloat16)
     ids = jnp.zeros((1, 32), jnp.int32)
@@ -246,7 +247,7 @@ def test_ouro_names_the_pass_its_norms_the_gate_and_the_weighting():
     `exit_gate`, `head_loss` with `pass_weighting` inside it — forward and backward; every matmul of
     the blocks sits inside the scan over passes; and the head is read by one pair of scans."""
     from dolomite_engine_tpu.models import get_model_class
-    from tests.models.test_ouro import CFG
+    CFG = FAMILIES["ouro"].cfg
 
     model = get_model_class("ouro")(config=config_from_dict(CFG), checkpoint_every=1, dtype=jnp.bfloat16)
     ids = jnp.zeros((1, 32), jnp.int32)
@@ -290,7 +291,7 @@ def test_afmoe_names_its_two_kinds_of_attention_the_gate_and_its_norms():
     `attention_gate` inside those, `dense_mlp`, the experts' five, `block_norms` — forward and
     backward, and leaves no matmul of the blocks outside a layer's scope."""
     from dolomite_engine_tpu.models import get_model_class
-    from tests.models.test_afmoe import CFG
+    CFG = FAMILIES["afmoe"].cfg
 
     model = get_model_class("afmoe")(config=config_from_dict(CFG), checkpoint_every=1, dtype=jnp.bfloat16)
     ids = jnp.zeros((1, 32), jnp.int32)
